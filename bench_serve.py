@@ -383,6 +383,8 @@ def main(argv=None) -> int:
         requests, n = min(requests, 12), min(n, 24)
     from perf.trace import _bootstrap
     _bootstrap()
+    from elemental_tpu.core.compile_cache import enable_compile_cache
+    enable_compile_cache()
     doc = run_bench(requests, n, grid_spec, seed)
     doc.update(run_fleet_bench(requests, n, seed))
     print(json.dumps(doc))

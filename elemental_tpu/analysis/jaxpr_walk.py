@@ -2,7 +2,7 @@
 
 The core of the static comm-plan analyzer (ISSUE 3): given a closed jaxpr
 (from ``jax.make_jaxpr`` over a distributed driver -- tracing only, no
-device execution), walk every equation recursively -- into ``pjit`` calls,
+device execution), walk every equation recursively -- into ``jit`` calls,
 ``shard_map`` bodies, ``scan``/``while``/``cond`` sub-jaxprs, custom-deriv
 call jaxprs -- and emit one :class:`CollectiveEvent` per collective
 equation encountered, annotated with
@@ -10,7 +10,7 @@ equation encountered, annotated with
   * the mesh axes it communicates over and their total size,
   * the operand shape/dtype and an estimated per-device byte volume
     (ring-algorithm cost model, see :func:`estimate_bytes`),
-  * the nesting path (``pjit:_redistribute_jit/shard_map``),
+  * the nesting path (``jit:_redistribute_jit/shard_map``),
   * a static trip-count multiplier (``scan`` lengths compose; ``while``
     bodies are marked non-static since XLA cannot bound them),
   * whether the event sits on a conditional branch.
@@ -27,12 +27,10 @@ from __future__ import annotations
 
 import dataclasses
 
-try:
-    # the blessed public location (jax >= 0.4.35; survives the removal of
-    # jax.core internals in newer releases -- cf. core/compat.py)
-    from jax.extend import core as jcore
-except ImportError:                                    # pragma: no cover
-    from jax import core as jcore
+from jax.extend import core as jcore
+# a nested ``jax.jit`` call is recognised by the primitive OBJECT the
+# installed JAX exports, never by its name (which JAX has renamed before)
+from jax.extend.core.primitives import jit_p as _JIT_P
 
 #: jaxpr primitive names treated as collectives.
 COLLECTIVE_PRIMS = (
@@ -170,8 +168,8 @@ def _sub_jaxprs(val):
 
 def _scope_label(eqn) -> str:
     name = eqn.params.get("name")
-    if eqn.primitive.name == "pjit" and name:
-        return f"pjit:{name}"
+    if eqn.primitive is _JIT_P and name:
+        return f"jit:{name}"
     if eqn.primitive.name == "scan":
         return f"scan[{eqn.params.get('length', '?')}]"
     return eqn.primitive.name
@@ -231,7 +229,7 @@ def _walk(jaxpr, axis_env, path, mult, static, conditional, out):
 
 
 def count_pjit_calls(closed_jaxpr, name: str) -> int:
-    """Number of ``pjit`` equations named ``name`` anywhere in the traced
+    """Number of nested ``jax.jit`` equations named ``name`` anywhere in the traced
     program -- e.g. ``_redistribute_jit`` / ``_panel_spread_jit`` call
     sites, cross-checkable against the engine's Python-level counters."""
     jaxpr = closed_jaxpr.jaxpr if isinstance(closed_jaxpr, jcore.ClosedJaxpr) \
@@ -242,7 +240,7 @@ def count_pjit_calls(closed_jaxpr, name: str) -> int:
 def _count_pjit(jaxpr, name: str) -> int:
     total = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pjit" and eqn.params.get("name") == name:
+        if eqn.primitive is _JIT_P and eqn.params.get("name") == name:
             total += 1
         for val in eqn.params.values():
             for sub in _sub_jaxprs(val):

@@ -282,8 +282,8 @@ def rule_mem_budget(mplan, budget_factor: float) -> list:
 
 
 def rule_vmem_overflow(panel_checks) -> list:
-    """EL007: gate-admitted panels whose real kernel allocation
-    overflows the VMEM budget."""
+    """EL007: gate-admitted panels whose compiled kernel would overflow
+    the scoped-VMEM limit it is compiled with."""
     out = []
     seen = set()
     for chk in panel_checks:
@@ -295,7 +295,8 @@ def rule_vmem_overflow(panel_checks) -> list:
             f"{chk.op} panel {chk.shape} {chk.dtype}: use_pallas prices "
             f"{chk.gate_bytes} B (admitted, budget {chk.budget} B) but "
             f"the fused kernel actually allocates {chk.kernel_bytes} B "
-            f"-- the gate would dispatch a kernel that overflows VMEM",
+            f"against a scoped limit of {chk.limit} B -- the gate would "
+            f"dispatch a kernel the compiler refuses",
             severity="error",
             fix_hint=(f"raise the copies= the dispatch site passes to "
                       f"use_pallas so the gate prices >= "

@@ -5,7 +5,7 @@ extracts every collective a traced driver issues, this module walks the
 SAME closed jaxpr and computes what the program keeps *resident*:
 
 * **per-device peak live bytes** -- a last-use liveness walk over every
-  equation, recursing into ``pjit`` calls, ``shard_map`` bodies and
+  equation, recursing into ``jit`` calls, ``shard_map`` bodies and
   ``scan``/``while``/``cond`` sub-jaxprs exactly like the collective
   walker.  Inside ``shard_map`` the avals are already per-device and are
   counted verbatim; outside, stacked-storage arrays are sharded over the
@@ -52,13 +52,11 @@ import json
 
 import numpy as np
 
-try:
-    from jax.extend import core as jcore
-except ImportError:                                    # pragma: no cover
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 from ..core.dist import stride as dist_stride
-from ..kernels.common import LANE, PANEL_VMEM_BUDGET, SUBLANE, round_up
+from ..kernels.common import (LANE, PANEL_VMEM_BUDGET, PANEL_VMEM_LIMIT,
+                              SUBLANE, round_up)
 from .jaxpr_walk import _scope_label, _sub_jaxprs
 
 MEM_SCHEMA = "memory_plan/v1"
@@ -441,10 +439,11 @@ class PanelVmemCheck:
     shape: tuple
     dtype: str
     gate_bytes: int              # what use_pallas prices (copies x tiles)
-    kernel_bytes: int            # what the pallas_call actually allocates
-    budget: int
+    kernel_bytes: int            # what the compiled kernel allocates
+    budget: int                  # the gate's budget
+    limit: int                   # the scoped-VMEM limit it is compiled with
     admitted: bool               # gate_bytes <= budget (use_pallas yes)
-    fits: bool                   # kernel_bytes <= budget
+    fits: bool                   # kernel_bytes <= limit
 
     @property
     def overflow(self) -> bool:
@@ -455,45 +454,49 @@ class PanelVmemCheck:
         return {"op": self.op, "shape": list(self.shape),
                 "dtype": self.dtype, "gate_bytes": self.gate_bytes,
                 "kernel_bytes": self.kernel_bytes, "budget": self.budget,
-                "admitted": self.admitted, "fits": self.fits}
+                "limit": self.limit, "admitted": self.admitted,
+                "fits": self.fits}
 
 
 def kernel_vmem_bytes(op: str, shape, dtype) -> int:
-    """The fused kernel's ACTUAL VMEM residents for one panel.
+    """The scoped VMEM one compiled panel kernel allocates: its resident
+    refs (the real ``pallas_call`` operands, outputs and scratch) plus
+    the full-panel temporaries Mosaic stacks beside them.  The temporary
+    counts are fitted to what the v5e compiler reported at the gate's
+    corners (float32, PR 25):
 
-    Read off the real ``pallas_call`` out_shapes + in-kernel functional
-    carries:
-
-    * ``lu_panel``: tile-padded input + packed output + the carried
-      working panel (3 x (mp, wp)) + the (wp, 1) int32 pivot vector;
-    * ``potrf_inv``: the input block is SQUARE-padded to a LANE multiple
-      on BOTH axes (``pad_square``) and carried as D/L/Li/T -- 4 square
-      residents at ``round_up(w, LANE)``, NOT the gate's (8, 128) tile
-      padding;
-    * ``qr_panel``: padded input + packed output + carried B (3 x
-      (mp, wp)) + the (tp, tp) larft T accumulator + the (wp, 1) tau.
+    * ``lu_panel``: input + packed output, and about three more panels
+      of temporaries -- 26.3 MiB for a (5456, 256) panel of 5.3 MiB;
+    * ``potrf_inv``: D, L, Li at ``round_up(w, LANE)`` square plus two
+      ``bs``-square scratch blocks, and about 1.5 squares of temporaries
+      -- 19.9 MiB for a 1024-square block;
+    * ``qr_panel``: input + packed output + about four panels of
+      temporaries, plus the T, B and T^T squares -- 24.6 MiB for a
+      (4096, 256) panel, 30.3 MiB for a 1024-square one.
     """
     z = np.dtype(dtype).itemsize
     m, w = int(shape[0]), int(shape[1])
+    wp = round_up(w, LANE)
     if op == "cholesky":
-        wp = round_up(w, LANE)
-        return 4 * wp * wp * z
-    mp, wp = round_up(m, SUBLANE), round_up(w, LANE)
+        bs = min(512, wp)
+        return (9 * wp * wp // 2 + 2 * bs * bs) * z
+    mp = round_up(m, SUBLANE)
     if op == "lu":
-        return 3 * mp * wp * z + wp * np.dtype(np.int32).itemsize
+        return 5 * max(mp, wp) * wp * z
     if op == "qr":
-        tp = round_up(wp, LANE)
-        return 3 * mp * wp * z + tp * tp * z + wp * z
+        return (6 * mp * wp + 3 * wp * wp) * z
     raise KeyError(f"no fused panel kernel for op {op!r}")
 
 
 def check_panel_vmem(op: str, shape, dtype="float32", *,
-                     budget: int = PANEL_VMEM_BUDGET) -> PanelVmemCheck:
+                     budget: int = PANEL_VMEM_BUDGET,
+                     limit: int = PANEL_VMEM_LIMIT) -> PanelVmemCheck:
     """Cross-check ONE panel shape: gate pricing vs kernel allocation.
 
     ``admitted`` reproduces :meth:`PanelPlan.use_pallas` exactly at the
     default budget (asserted by tests/analysis); ``fits`` is the truth
-    the gate is supposed to imply."""
+    the gate is supposed to imply: the compiled kernel stays inside the
+    scoped-VMEM ``limit`` it is compiled with."""
     copies = PANEL_GATE_COPIES[op]
     z = np.dtype(dtype).itemsize
     mp = round_up(int(shape[0]), SUBLANE)
@@ -503,7 +506,8 @@ def check_panel_vmem(op: str, shape, dtype="float32", *,
     return PanelVmemCheck(op=op, shape=tuple(int(s) for s in shape),
                           dtype=np.dtype(dtype).name, gate_bytes=gate,
                           kernel_bytes=kern, budget=int(budget),
-                          admitted=gate <= budget, fits=kern <= budget)
+                          limit=int(limit), admitted=gate <= budget,
+                          fits=kern <= limit)
 
 
 def panel_shapes(op: str, n: int, nb: int):

@@ -13,8 +13,10 @@ ops (trapezoidal masks, diagonals) need the cyclic index maps.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
+from ..core.dist import Dist
 from ..core.distmatrix import DistMatrix
 from ..redist.engine import redistribute, transpose_dist
 
@@ -92,8 +94,17 @@ def index_dependent_map(A: DistMatrix, fn) -> DistMatrix:
 
 
 def index_dependent_fill(A: DistMatrix, fn) -> DistMatrix:
-    """IndexDependentFill: B[i,j] = fn(i, j)."""
-    return index_dependent_map(A, lambda i, j, a: fn(i, j) + jnp.zeros_like(a))
+    """IndexDependentFill: B[i,j] = fn(i, j).
+
+    The result does not read A's entries, so under ``jit`` no dataflow
+    carries A's placement over to it: a fill traced with no sharded input
+    lands whole on one device.  It is therefore pinned to A's storage
+    sharding explicitly."""
+    out = index_dependent_map(A, lambda i, j, a: fn(i, j) + jnp.zeros_like(a))
+    if A.cdist is Dist.CIRC:             # [CIRC,CIRC] lives on one device
+        return out
+    return out.with_local(jax.lax.with_sharding_constraint(
+        out.local, A.grid.sharding(A.spec)))
 
 
 def make_trapezoidal(A: DistMatrix, uplo: str, offset: int = 0) -> DistMatrix:

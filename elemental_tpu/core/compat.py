@@ -1,23 +1,13 @@
-"""jax version compatibility shims.
+"""The one spelling of ``shard_map`` the codebase uses.
 
-The library targets current jax (``jax.shard_map`` with ``check_vma``);
-older builds still ship the same machinery as
-``jax.experimental.shard_map.shard_map`` with the ``check_rep`` keyword.
-Routing every shard_map call through :func:`shard_map` keeps the rest of
-the codebase on the modern spelling while remaining runnable on the older
-runtimes some CI/dev containers carry.
+Every shard_map call goes through :func:`shard_map`, which fixes the
+keyword set (``check_vma`` off by default) in one place.
 """
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:                                       # pragma: no cover - old jax only
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -9,8 +9,8 @@ primitive into one ``pallas_call`` that keeps the replicated panel
 resident in VMEM:
 
 * :func:`lu_panel` -- pivot search + column scale + rank-1/chunked
-  trailing updates, bit-twin of ``lapack.lu._panel_lu`` (pivot sequence
-  identical in unblocked mode);
+  trailing updates, twin of ``lapack.lu._panel_lu`` (identical pivot
+  sequence, factor to rounding);
 * :func:`potrf_inv` -- blocked potrf + triangular inverse, twin of
   ``lapack.cholesky._potrf_inv_impl`` (residual-bounded);
 * :func:`qr_panel` -- larfg reflector chain + larft T build, twin of
@@ -22,9 +22,10 @@ resolved knob into a :class:`PanelPlan`, and each call site asks
 ``plan.use_pallas(shape, dtype)`` -- a STATIC trace-time gate that
 falls back to the XLA twin for complex dtypes and for panels whose
 working set exceeds the VMEM budget, so the fused kernels never
-silently spill.  Off-TPU the kernels run under
+silently spill.  On the CPU backend the kernels run under
 ``pl.pallas_call(interpret=True)``, which is how CPU CI pins the twins
-(see ``tests/kernels/``).
+(see ``tests/kernels/``); on a TPU backend they are compiled by Mosaic
+(``tests/test_chip_compile.py`` compiles each for a described v5e).
 
 Panels are replicated-local compute: a ``pallas_call`` is a local
 primitive with no collectives, so every comm-plan golden is byte-
@@ -37,8 +38,8 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 
-from .common import (LANE, PANEL_VMEM_BUDGET, SUBLANE, interpret_default,
-                     pad_square, pad_tiles, panel_fits, round_up)
+from .common import (LANE, PANEL_VMEM_BUDGET, PANEL_VMEM_LIMIT, SUBLANE,
+                     interpret_default, pad_tiles, panel_fits, round_up)
 from .lu_panel import lu_panel
 from .chol_panel import potrf_inv
 from .qr_panel import qr_panel
@@ -48,8 +49,7 @@ from .qr_panel import qr_panel
 #: keep the status-quo path (same convention as tune.knobs.LU_PANELS).
 PANEL_IMPLS = ("xla", "pallas")
 
-#: LU chunk ladder, pinned from a v5e A/B sweep (perf/ab_harness.py lu,
-#: BENCH_r05: 512/64 beat 256/64 and 512/128 by 4-7%% at N=16384).
+#: LU chunk ladder (512/64; sweep with ``perf/ab_harness.py lu``).
 #: Single source of truth -- lapack.lu, the A/B harness, and bench
 #: provenance all read it through default_inners() / resolve_panel()
 #: rather than importing a bare module constant that monkeypatching
@@ -104,21 +104,18 @@ def resolve_panel(panel_impl=None, *, dtype=None, inners=None,
     """Turn a resolved ``panel_impl`` knob value into a
     :class:`PanelPlan`.
 
-    ``None`` means the status-quo XLA path ('auto' is resolved by
+    ``None`` means the status-quo XLA path.  'auto' is resolved by
     ``tune.resolve_knobs`` BEFORE this point -- drivers never pass it
-    here).  Complex dtypes fall back to 'xla' silently by design: the
-    knob is a performance hint and the XLA twin is the same math, so a
+    here, and one that does is refused.  Complex dtypes fall back to
+    'xla' silently by design: the knob is a performance hint and the XLA twin is the same math, so a
     complex matrix through ``panel_impl='pallas'`` must factor, not
     raise (pinned by tests/kernels/test_dispatch.py).
     """
     impl = "xla" if panel_impl is None else str(panel_impl)
-    if impl == "auto":
-        # defensive: an unresolved 'auto' (e.g. tuner disabled) keeps
-        # the status-quo path rather than guessing at the backend here
-        impl = "xla"
     if impl not in PANEL_IMPLS:
         raise ValueError(
-            f"panel_impl must be one of {PANEL_IMPLS + ('auto',)}, "
+            f"panel_impl must be one of {PANEL_IMPLS} by the time it is "
+            f"resolved ('auto' is the tuner's to turn into one), "
             f"got {panel_impl!r}")
     src = source if source is not None else (
         "default" if panel_impl is None else "explicit")

@@ -10,11 +10,16 @@ column recurrence, the per-block inverse is an in-kernel forward
 substitution, and the inverse assembly / trailing updates are the same
 MXU dots the reference issues -- all inside one ``pallas_call``.
 
-The block recurrences are written with masked row/column extraction
-(``where``-sums over exact zeros) instead of gathers: everything stays
-(b, b)-shaped and Mosaic-friendly.  The math matches the reference
-block-for-block but the scalar recurrences round differently from
-XLA's native potrf/trsm, so the twin contract is residual-bounded
+Everything is written in the forms the TPU compiler lowers: the
+right-looking factorization runs IN PLACE on the ``L`` output ref
+through static, tile-aligned block windows (the block is padded to a
+LANE multiple with an identity diagonal, so the padded extent factors
+as itself); the ``bs``-sized diagonal recurrences run on scratch refs,
+rows through dynamic sublane loads/stores, columns through lane-masked
+reductions of the SYMMETRIC running block (row j is column j, so no
+(b, 1) -> (1, b) transpose is ever needed).  The math matches the
+reference block-for-block but the scalar recurrences round differently
+from XLA's native potrf/trsm, so the twin contract is residual-bounded
 (``L L^H ~ A``, ``Li L ~ I``), not bit-pinned -- see
 ``tests/kernels/test_chol_panel.py`` for the documented bounds.
 """
@@ -26,97 +31,105 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import interpret_default, pad_square
+from .common import (LANE, compiler_params, interpret_default,
+                     kernel_trace, loop32, round_up)
 
 _HI = lax.Precision.HIGHEST
 
 
-def _chol_unb(B):
-    """Unblocked lower Cholesky of a (b, b) symmetrized block: column
-    recurrence with masked extraction, valid in the lower triangle."""
-    b = B.shape[0]
-    dt = B.dtype
-    ri = lax.broadcasted_iota(jnp.int32, (b, b), 0)
-    ci = lax.broadcasted_iota(jnp.int32, (b, b), 1)
-    rcol = ri[:, :1]
+def _dot_nt(a, b, precision):
+    """a @ b^T on the MXU without materializing the transpose."""
+    return lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                           precision=precision,
+                           preferred_element_type=a.dtype)
 
-    def body(j, A):
-        # columns < j hold finished L columns; the lower triangle of
-        # columns >= j holds the running Schur complement
-        piv = jnp.sum(jnp.where((ri == j) & (ci == j), A, 0))
-        dj = jnp.sqrt(piv)
+
+def _chol_unb(a_ref):
+    """Unblocked lower Cholesky, in place, of the symmetric (b, b) block
+    in ``a_ref``: right-looking column recurrence that keeps the running
+    Schur complement fully symmetric, so the multiplier ROW comes from a
+    dynamic sublane load and the multiplier COLUMN from a lane-masked
+    reduction of the same values.  On exit the lower triangle holds L
+    (the strict upper triangle is scratch)."""
+    b = a_ref.shape[0]
+    ci = lax.broadcasted_iota(jnp.int32, (b, b), 1)
+    rcol = lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    crow = lax.broadcasted_iota(jnp.int32, (1, b), 1)
+
+    def body(j, carry):
+        A = a_ref[...]
+        rowj = a_ref[pl.ds(j, 1), :]
         colj = jnp.sum(jnp.where(ci == j, A, 0), axis=1, keepdims=True)
-        lcol = jnp.where(rcol > j, colj / dj, jnp.zeros_like(colj))
-        lcol = jnp.where(rcol == j, dj.astype(dt), lcol)
-        outer = lcol * jnp.swapaxes(jnp.conj(lcol), 0, 1)
-        A = A - jnp.where((ci > j) & (ri >= ci), outer, 0)
-        return jnp.where(ci == j, lcol, A)
+        dj = jnp.sqrt(jnp.sum(jnp.where(crow == j, rowj, 0), axis=1,
+                              keepdims=True))
+        lrow = jnp.where(crow > j, rowj / dj, 0)
+        lcol = jnp.where(rcol > j, colj / dj, 0)
+        A = A - lcol * lrow
+        a_ref[...] = jnp.where(ci == j, jnp.where(rcol == j, dj, lcol), A)
+        return carry
 
-    return jnp.tril(lax.fori_loop(0, b, body, B))
-
-
-def _trinv_unb(L):
-    """Forward-substitution inverse of a (b, b) lower-triangular block:
-    row i of L^{-1} from rows < i, one masked (1, b) x (b, b) dot per
-    step."""
-    b = L.shape[0]
-    dt = L.dtype
-    ri = lax.broadcasted_iota(jnp.int32, (b, b), 0)
-    ci = lax.broadcasted_iota(jnp.int32, (b, b), 1)
-    crow = ci[:1, :]
-    one = jnp.ones((), dt)
-
-    def body(i, X):
-        lrow = jnp.sum(jnp.where(ri == i, L, 0), axis=0, keepdims=True)
-        dii = jnp.sum(jnp.where(crow == i, lrow, 0))
-        lstrict = jnp.where(crow < i, lrow, jnp.zeros_like(lrow))
-        corr = jnp.dot(lstrict, X, precision=_HI)
-        erow = jnp.where(crow == i, one, jnp.zeros_like(lrow))
-        newrow = (erow - corr) / dii
-        return jnp.where(ri == i, newrow, X)
-
-    return lax.fori_loop(0, b, body, jnp.zeros((b, b), dt))
+    loop32(0, b, body)
 
 
-def _potrf_inv_kernel(d_ref, l_ref, li_ref, *, w, bs, precision):
-    D = d_ref[...]
-    dt = D.dtype
-    # symmetrize from the lower triangle, as the reference does (the
-    # padded border is zero and stays zero)
-    d = jnp.tril(D)
-    d = d + jnp.conj(jnp.tril(d, -1)).T
-    L = jnp.zeros_like(d)
-    Li = jnp.zeros_like(d)
-    T = d
-    # block writes go through dynamic_update_slice (static starts): the
-    # .at[].set scatter path constant-folds its index arrays, and when a
-    # slice covers the whole (unpadded) block those fold to EMPTY int32
-    # constants the kernel would illegally capture
-    for s in range(0, w, bs):
-        e = min(s + bs, w)
-        dkk = T[s:e, s:e]
-        dkk = jnp.tril(dkk) + jnp.conj(jnp.tril(dkk, -1)).T
-        Lkk = _chol_unb(dkk)
-        Likk = _trinv_unb(Lkk)
-        L = lax.dynamic_update_slice(L, Lkk, (s, s))
+def _trinv_unb(l_ref, x_ref):
+    """Forward-substitution inverse of the (b, b) lower-triangular block
+    in ``l_ref`` into ``x_ref``: row i of L^{-1} from rows < i, one
+    (1, b) x (b, b) dot per step."""
+    b = l_ref.shape[0]
+    dt = l_ref.dtype
+    crow = lax.broadcasted_iota(jnp.int32, (1, b), 1)
+    x_ref[...] = jnp.zeros((b, b), dt)
+
+    def body(i, carry):
+        lrow = l_ref[pl.ds(i, 1), :]
+        dii = jnp.sum(jnp.where(crow == i, lrow, 0), axis=1, keepdims=True)
+        lstrict = jnp.where(crow < i, lrow, 0)
+        corr = jnp.dot(lstrict, x_ref[...], precision=_HI,
+                       preferred_element_type=dt)
+        erow = jnp.where(crow == i, jnp.ones((), dt), jnp.zeros((), dt))
+        x_ref[pl.ds(i, 1), :] = (erow - corr) / dii
+        return carry
+
+    loop32(0, b, body)
+
+
+def _potrf_inv_kernel(d_ref, l_ref, li_ref, *scratch, bs, precision):
+    wp = d_ref.shape[0]
+    dt = d_ref.dtype
+    # symmetrize from the lower triangle, as the reference does; the
+    # running Schur complement lives in l_ref, full-symmetric, and each
+    # finished block column overwrites it with L
+    d = jnp.tril(d_ref[...])
+    l_ref[...] = d + jnp.tril(d, -1).T
+    li_ref[...] = jnp.zeros((wp, wp), dt)
+    for s in range(0, wp, bs):
+        e = min(s + bs, wp)
+        # a narrower last block has scratch of its own size (a row loaded
+        # at a dynamic sublane offset must span its ref's full width)
+        blk, x = scratch[:2] if e - s == bs else scratch[2:]
+        blk[...] = l_ref[s:e, s:e]
+        _chol_unb(blk)
+        Lkk = jnp.tril(blk[...])
+        blk[...] = Lkk
+        _trinv_unb(blk, x)
+        Likk = x[...]
+        l_ref[s:e, s:e] = Lkk
         # inverse assembly: Li[s:e, :s] = -Likk @ L[s:e, :s] @ Li[:s, :s]
         if s > 0:
             corr = jnp.dot(
-                Likk, jnp.dot(L[s:e, :s], Li[:s, :s], precision=precision),
-                precision=precision)
-            Li = lax.dynamic_update_slice(Li, -corr.astype(dt), (s, 0))
-        Li = lax.dynamic_update_slice(Li, Likk, (s, s))
-        if e < w:
-            B21 = jnp.dot(T[e:w, s:e], jnp.conj(Likk).T,
-                          precision=precision).astype(dt)
-            L = lax.dynamic_update_slice(L, B21, (e, s))
-            T = lax.dynamic_update_slice(
-                T, T[e:w, e:w] - jnp.dot(B21, jnp.conj(B21).T,
-                                         precision=precision).astype(dt),
-                (e, e))
-    l_ref[...] = L
-    li_ref[...] = Li
+                Likk, jnp.dot(l_ref[s:e, :s], li_ref[:s, :s],
+                              precision=precision,
+                              preferred_element_type=dt),
+                precision=precision, preferred_element_type=dt)
+            li_ref[s:e, :s] = -corr
+        li_ref[s:e, s:e] = Likk
+        if e < wp:
+            B21 = _dot_nt(l_ref[e:, s:e], Likk, precision)
+            l_ref[e:, s:e] = B21
+            l_ref[e:, e:] = l_ref[e:, e:] - _dot_nt(B21, B21, precision)
+    l_ref[...] = jnp.tril(l_ref[...])
 
 
 def potrf_inv(D, precision=None, *, bs: int = 512, interpret=None):
@@ -130,13 +143,29 @@ def potrf_inv(D, precision=None, *, bs: int = 512, interpret=None):
                          "dispatch falls back to xla for complex dtypes")
     # factor-forming dots run at full accumulation, matching lu._hi
     precision = _HI if precision is None else precision
-    Dp = pad_square(D)
-    kern = functools.partial(_potrf_inv_kernel, w=w, bs=int(bs),
-                             precision=precision)
-    shp = jax.ShapeDtypeStruct(Dp.shape, D.dtype)
-    L, Li = pl.pallas_call(
-        kern,
-        out_shape=(shp, shp),
-        interpret=interpret_default(interpret),
-    )(Dp)
+    # tile-aligned blocks: bs is a LANE multiple and the block is padded
+    # to a LANE multiple with an identity diagonal (diag(A, I) factors as
+    # diag(L, I), so the pad never touches the real factor)
+    wp = round_up(w, LANE)
+    bs = min(round_up(bs, LANE), wp)
+    Dp = D
+    if wp != w:
+        Dp = jnp.pad(D, ((0, wp - w), (0, wp - w))) + jnp.diag(
+            (jnp.arange(wp) >= w).astype(D.dtype))
+    kern = functools.partial(_potrf_inv_kernel, bs=bs, precision=precision)
+    shp = jax.ShapeDtypeStruct((wp, wp), D.dtype)
+    scratch = [pltpu.VMEM((b, b), D.dtype)
+               for b in (bs, wp % bs) if b for _ in range(2)]
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    interpret = interpret_default(interpret)
+    with kernel_trace(interpret):
+        L, Li = pl.pallas_call(
+            kern,
+            out_shape=(shp, shp),
+            in_specs=[vmem],
+            out_specs=(vmem, vmem),
+            scratch_shapes=scratch,
+            compiler_params=compiler_params(),
+            interpret=interpret,
+        )(Dp)
     return L[:w, :w], Li[:w, :w]

@@ -10,12 +10,18 @@ panel is VMEM-resident: the reflector chain, the Gram product
 ``pallas_call``, returning ``(packed V\\R, tau, T)`` so the driver
 skips the separate ``_larft`` call entirely.
 
-The kernel body mirrors the reference op-for-op (same degenerate
-guards, same HIGHEST-precision dots), but the padded-operand reductions
-group differently than the XLA (M,)-vector sums, so the twin contract
-is residual-bounded (``Q R ~ A``, orthonormal Q), not bit-pinned --
-see ``tests/kernels/test_qr_panel.py`` for the documented bounds.
-Real dtypes only; complex panels are gated back to XLA by the
+The kernel body mirrors the reference step-for-step (same degenerate
+guards) in the forms the TPU compiler lowers: it works IN PLACE on the
+output ref; a column is read by a lane-masked reduction and written back
+by a lane-masked select; scalars stay (1, 1)-shaped; the reflector's row
+product v^H P is a sublane reduction on the VPU (exact float32, where
+the reference asks the MXU for HIGHEST); and the T recurrence runs on
+T^T, row by row through dynamic sublane loads/stores (B = V^H V is
+symmetric, so row i of B is its column i), with one aligned transpose at
+the end.  Reductions group differently than the XLA (M,)-vector sums, so
+the twin contract is residual-bounded (``Q R ~ A``, orthonormal Q), not
+bit-pinned -- see ``tests/kernels/test_qr_panel.py`` for the documented
+bounds.  Real dtypes only; complex panels are gated back to XLA by the
 ``panel_impl`` dispatch.
 """
 from __future__ import annotations
@@ -26,70 +32,72 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import LANE, interpret_default, pad_tiles, round_up
+from .common import (compiler_params, interpret_default, kernel_trace,
+                     loop32, pad_tiles)
 
 _HI = lax.Precision.HIGHEST
 
 
-def _qr_panel_kernel(p_ref, out_ref, tau_ref, t_ref, *, m, k):
-    P = p_ref[...]
-    mp, wp = P.shape
-    dt = P.dtype
+def _qr_panel_kernel(p_ref, out_ref, tau_ref, t_ref, b_ref, tt_ref, *, k):
+    mp, wp = out_ref.shape
+    dt = out_ref.dtype
+    out_ref[...] = p_ref[...]
+    tau_ref[...] = jnp.zeros((1, wp), dt)
     ridx = lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
     cidx = lax.broadcasted_iota(jnp.int32, (1, wp), 1)
+    one, zero = jnp.ones((), dt), jnp.zeros((), dt)
 
-    def body(j, state):
+    def body(j, carry):
         # the larfg recurrence of _panel_qr, column-masked: padded rows
-        # are zero and contribute exact zeros to sigma / the row dot
-        P, tau = state
-        col = lax.dynamic_slice_in_dim(P, j, 1, 1)
-        alpha = lax.dynamic_slice(P, (j, j), (1, 1))[0, 0]
+        # are zero and contribute exact zeros to sigma / the row product
+        P = out_ref[...]
+        col = jnp.sum(jnp.where(cidx == j, P, 0), axis=1, keepdims=True)
+        alpha = jnp.sum(jnp.where(ridx == j, col, 0), axis=0, keepdims=True)
         tail = jnp.where(ridx > j, col, 0)
-        sigma = jnp.sum(jnp.abs(tail) ** 2)
-        anorm = jnp.sqrt(jnp.abs(alpha) ** 2 + sigma)
-        re_a = jnp.real(alpha)
-        beta = -jnp.sign(jnp.where(re_a == 0, 1.0, re_a)) * anorm
+        sigma = jnp.sum(tail * tail, axis=0, keepdims=True)
+        anorm = jnp.sqrt(alpha * alpha + sigma)
+        beta = -jnp.sign(jnp.where(alpha == 0, one, alpha)) * anorm
         degenerate = anorm == 0
-        safe_beta = jnp.where(degenerate, 1.0, beta)
-        tau_j = jnp.where(degenerate, 0.0, (safe_beta - alpha) / safe_beta)
+        safe_beta = jnp.where(degenerate, one, beta)
+        tau_j = jnp.where(degenerate, zero, (safe_beta - alpha) / safe_beta)
         denom = alpha - safe_beta
-        safe_denom = jnp.where(denom == 0, 1.0, denom)
-        v = jnp.where(ridx > j, col / safe_denom, jnp.zeros_like(col))
-        v = jnp.where(ridx == j,
-                      jnp.where(degenerate, 0.0, 1.0).astype(dt), v)
-        w = jnp.dot(jnp.swapaxes(jnp.conj(v), 0, 1), P, precision=_HI)
-        upd = (jnp.conj(tau_j) * v) * w
-        P = P - jnp.where(cidx > j, upd, 0)
+        safe_denom = jnp.where(denom == 0, one, denom)
+        v = jnp.where(ridx > j, col / safe_denom, 0)
+        v = jnp.where(ridx == j, jnp.where(degenerate, zero, one), v)
+        w = jnp.sum(v * P, axis=0, keepdims=True)
+        P = P - jnp.where(cidx > j, (tau_j * v) * w, 0)
         newcol = jnp.where(ridx > j, v, col)
-        newcol = jnp.where(ridx == j, jnp.asarray(beta, dt), newcol)
-        P = lax.dynamic_update_slice_in_dim(P, newcol, j, 1)
-        tau = lax.dynamic_update_slice(
-            tau, jnp.asarray(tau_j, dt).reshape(1, 1), (j, 0))
-        return P, tau
+        newcol = jnp.where(ridx == j, beta, newcol)
+        out_ref[...] = jnp.where(cidx == j, newcol, P)
+        tau_ref[...] = jnp.where(cidx == j, tau_j, tau_ref[...])
+        return carry
 
-    P, tau = lax.fori_loop(0, k, body, (P, jnp.zeros((wp, 1), dt)))
+    loop32(0, k, body)
 
     # larft, fused: V from the packed panel, one Gram dot, then the
-    # forward-columnwise T recurrence of _larft.  Padded V columns are
-    # unit vectors e_j but every T write is masked to kidx < i < k, so
-    # the padded border of T stays exactly zero.
-    V = jnp.tril(P, -1) + jnp.eye(mp, wp, dtype=dt)
-    B = jnp.dot(jnp.swapaxes(jnp.conj(V), 0, 1), V, precision=_HI)
-    kidx = lax.broadcasted_iota(jnp.int32, (wp, 1), 0)
+    # forward-columnwise T recurrence of _larft, run on T^T so each step
+    # is one row: T^T[i, :] = -tau_i * (B[i, :i] @ T^T), T^T[i, i] =
+    # tau_i.  Padded V columns are unit vectors e_j but every read is
+    # masked to < i < k, so the padded border of T stays exactly zero.
+    P = out_ref[...]
+    V = jnp.where(ridx > cidx, P, 0) + jnp.where(ridx == cidx, one, zero)
+    b_ref[...] = lax.dot_general(V, V, (((0,), (0,)), ((), ())),
+                                 precision=_HI, preferred_element_type=dt)
+    tt_ref[...] = jnp.zeros((wp, wp), dt)
+    tau = tau_ref[...]
 
-    def tbody(i, T):
-        coli = lax.dynamic_slice_in_dim(B, i, 1, 1)
-        coli = jnp.where(kidx < i, coli, jnp.zeros_like(coli))
-        taui = lax.dynamic_slice(tau, (i, 0), (1, 1))[0, 0]
-        newcol = -taui * jnp.dot(T, coli, precision=_HI)
-        newcol = jnp.where(kidx == i, taui.astype(dt), newcol)
-        return lax.dynamic_update_slice_in_dim(T, newcol, i, 1)
+    def tbody(i, carry):
+        bi = jnp.where(cidx < i, b_ref[pl.ds(i, 1), :], 0)
+        taui = jnp.sum(jnp.where(cidx == i, tau, 0), axis=1, keepdims=True)
+        row = -taui * jnp.dot(bi, tt_ref[...], precision=_HI,
+                              preferred_element_type=dt)
+        tt_ref[pl.ds(i, 1), :] = jnp.where(cidx == i, taui, row)
+        return carry
 
-    T = lax.fori_loop(0, k, tbody, jnp.zeros((wp, wp), dt))
-    out_ref[...] = P
-    tau_ref[...] = tau
-    t_ref[...] = T
+    loop32(0, k, tbody)
+    t_ref[...] = tt_ref[...].T
 
 
 def qr_panel(P, *, interpret=None):
@@ -102,13 +110,20 @@ def qr_panel(P, *, interpret=None):
                          "dispatch falls back to xla for complex dtypes")
     Pp = pad_tiles(P)
     mp, wp = Pp.shape
-    tp = round_up(wp, LANE)
-    kern = functools.partial(_qr_panel_kernel, m=M, k=k)
-    packed, tau, T = pl.pallas_call(
-        kern,
-        out_shape=(jax.ShapeDtypeStruct((mp, wp), P.dtype),
-                   jax.ShapeDtypeStruct((wp, 1), P.dtype),
-                   jax.ShapeDtypeStruct((tp, tp), P.dtype)),
-        interpret=interpret_default(interpret),
-    )(Pp)
-    return packed[:M, :k], tau[:k, 0], T[:k, :k]
+    kern = functools.partial(_qr_panel_kernel, k=k)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    sq = pltpu.VMEM((wp, wp), P.dtype)
+    interpret = interpret_default(interpret)
+    with kernel_trace(interpret):
+        packed, tau, T = pl.pallas_call(
+            kern,
+            out_shape=(jax.ShapeDtypeStruct((mp, wp), P.dtype),
+                       jax.ShapeDtypeStruct((1, wp), P.dtype),
+                       jax.ShapeDtypeStruct((wp, wp), P.dtype)),
+            in_specs=[vmem],
+            out_specs=(vmem, vmem, vmem),
+            scratch_shapes=[sq, sq],
+            compiler_params=compiler_params(),
+            interpret=interpret,
+        )(Pp)
+    return packed[:M, :k], tau[0, :k], T[:k, :k]

@@ -320,6 +320,7 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     before.
     """
     _check_mcmr(A)
+    precision = _hi(precision)
     if any(isinstance(v, str) for v in (nb, lookahead, crossover)) \
             or comm_precision == "auto" or redist_path == "auto" \
             or panel_impl == "auto":
@@ -522,6 +523,7 @@ def hpd_solve(A: DistMatrix, B: DistMatrix, uplo: str = "L",
 def cholesky_solve_after(L: DistMatrix, B: DistMatrix, uplo: str = "L",
                          nb: int | None = None, precision=None) -> DistMatrix:
     """Re-use an existing factor (``cholesky::SolveAfter``)."""
+    precision = _hi(precision)
     if uplo.upper().startswith("U"):
         Y = trsm("L", "U", "C", L, B, nb=nb, precision=precision)
         return trsm("L", "U", "N", L, Y, nb=nb, precision=precision)

@@ -108,7 +108,14 @@ def _hi(precision):
     BLAS semantics -- the default (bf16-input) matmul precision costs
     ~1e-2-level factor error on TPU, a silent accuracy downgrade.  An
     explicitly passed precision (including ``lax.Precision.DEFAULT`` for
-    bf16-MXU throughput on the trailing updates) is honored unchanged."""
+    bf16-MXU throughput on the trailing updates) is honored unchanged.
+
+    Every public factor/solve driver of this layer resolves its
+    ``precision`` argument through here ON ENTRY, so the trailing updates
+    and the triangular sweeps -- most of the flops -- run under the same
+    policy as the panel pieces, not at whatever ``None`` means to
+    ``jnp.matmul`` on the backend (full float32 on the CPU, one bf16 pass
+    on a TPU)."""
     return precision if precision is not None else lax.Precision.HIGHEST
 
 
@@ -559,8 +566,13 @@ def _local_lu_array(a, m: int, n: int, ib: int, precision,
             tm.tick("panel", k, Pf, pperm)
         perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
         # full trailing-block gather + contiguous writeback (TPU scatters
-        # of dynamic row sets benchmark SLOWER than this full gather)
-        a = a.at[s:].set(jnp.take(a[s:], pperm, axis=0))
+        # of dynamic row sets benchmark SLOWER than this full gather).
+        # Memory: the rows are gathered straight from ``a`` (no a[s:]
+        # slice copy), and pperm is a permutation, always in bounds, so
+        # mode='clip' (the default 'fill' adds a select over a second
+        # copy of the block).  Each was a 4 GiB temp at N = 32768, and
+        # together they put lu_solve past a 16 GB chip.
+        a = a.at[s:].set(jnp.take(a, s + pperm, axis=0, mode="clip"))
         tm.tick("swap", k, a)
         a = a.at[s:, s:e].set(Pf)
         if e >= n:
@@ -719,6 +731,7 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
     (default) is the unguarded zero-overhead path, bit-identical to
     before -- pinned by the comm-plan goldens."""
     _check_mcmr(A)
+    precision = _hi(precision)
     if any(isinstance(v, str) for v in (nb, lookahead, crossover)) \
             or panel == "auto" or comm_precision == "auto" \
             or redist_path == "auto" or panel_impl == "auto":
@@ -998,6 +1011,7 @@ def lu_solve(A: DistMatrix, B: DistMatrix, nb: int | None = None,
 def lu_solve_after(LU_: DistMatrix, perm, B: DistMatrix, nb: int | None = None,
                    precision=None) -> DistMatrix:
     """X = U^{-1} L^{-1} P B (``lu::SolveAfter``)."""
+    precision = _hi(precision)
     Bp = permute_rows(B, perm)
     Y = trsm("L", "L", "N", LU_, Bp, unit=True, nb=nb, precision=precision)
     return trsm("L", "U", "N", LU_, Y, nb=nb, precision=precision)

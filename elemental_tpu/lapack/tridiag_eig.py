@@ -319,8 +319,8 @@ def tridiag_eig(d, e, grid=None, vectors: bool = True,
     n x n array is ever materialized.
 
     The whole driver runs under ONE jit (static plan metadata): eager
-    per-op dispatch of its hundreds of small secular-stage ops is fine on
-    CPU but pathological on remote/tunneled TPU backends.
+    per-op dispatch of its hundreds of small secular-stage ops costs a
+    host round trip each.
     """
     d = jnp.asarray(d)
     e = jnp.asarray(e)

@@ -102,7 +102,10 @@ def _group_by_thread(tracer: Tracer) -> tuple:
     Records with no thread attribution (legacy ``thread=0``) fold into
     the tracer's HOME thread, which keeps the pre-ISSUE-20 single-thread
     layout (driver track 0, steps 1, phase lanes...) byte-stable.
-    Returns ``(home_ident, {ident: group})`` where each group holds
+    A foreign thread is keyed by ``(ident, name)``: the OS hands a dead
+    thread's ident to the next one started, so workers that run one
+    after another would otherwise share a track.
+    Returns ``(home_ident, {key: group})`` where each group holds
     ``spans``/``phases``/``comms``/``instants`` lists plus a display
     ``name`` and first-event time for deterministic track ordering.
     """
@@ -111,6 +114,8 @@ def _group_by_thread(tracer: Tracer) -> tuple:
 
     def add(kind, ev, t):
         th = getattr(ev, "thread", 0) or home
+        if th != home:
+            th = (th, getattr(ev, "thread_name", "") or "")
         g = groups.get(th)
         if g is None:
             g = groups[th] = {"spans": [], "phases": [], "comms": [],
@@ -229,7 +234,7 @@ def chrome_trace_doc(tracer: Tracer, **meta) -> dict:
                      key=lambda th: (groups[th]["first"], th))
     for th in foreign:
         g = groups[th]
-        label = g["name"] or f"thread-{th}"
+        label = g["name"] or f"thread-{th[0]}"
         tid_span = next_tid
         next_tid += 1
         events.append({"ph": "M", "pid": _PID, "tid": tid_span,

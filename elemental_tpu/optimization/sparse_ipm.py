@@ -87,8 +87,7 @@ def _pcg_device(A: DistSparseMatrix, d2: DistMultiVec, reg,
     """Jacobi-preconditioned CG on the regularized normal operator
     w -> A D^2 A' w + reg w, as ONE device call (lax.while_loop): the
     eager host loop costs ~6 dispatches + 3 blocking scalar reads per
-    iteration, which dominates wall-clock at scale (and is hopeless on
-    high-latency tunneled backends)."""
+    iteration, which dominates wall-clock at scale."""
 
     def op(w):
         t = A.spmv_adjoint(w)

@@ -880,23 +880,17 @@ def _machine_terms(grid_shape=None):
 
     Measured ``redist_constants/v1`` recorded by ``perf.redist_bench
     --record`` for this (grid, backend) take precedence over the static
-    :mod:`..tune.cost_model` ring model; safe TPU-ish defaults when the
-    tune subsystem is unavailable."""
+    :mod:`..tune.cost_model` ring model.  A backend the model has no row
+    for raises: there is no default machine."""
+    from ..tune.cache import load_redist_constants
+    from ..tune.cost_model import machine_for
     backend = jax.default_backend()
     if grid_shape is not None:
-        try:
-            from ..tune.cache import load_redist_constants
-            doc = load_redist_constants(tuple(grid_shape), backend)
-        except Exception:
-            doc = None
+        doc = load_redist_constants(tuple(grid_shape), backend)
         if doc is not None:
             return float(doc["alpha_s"]), float(doc["bw_bytes_per_s"])
-    try:
-        from ..tune.cost_model import machine_for
-        mm = machine_for(backend)
-        return mm.latency_s, mm.bw_bytes_per_s
-    except Exception:
-        return 2e-6, 4.5e10
+    mm = machine_for(backend)
+    return mm.latency_s, mm.bw_bytes_per_s
 
 
 def _direct_wins(plan, gshape, itemsize) -> bool:

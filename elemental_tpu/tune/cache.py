@@ -4,7 +4,7 @@ One JSON file per key under the cache directory; the key is
 ``(op, shape-bucket, dtype, grid, backend)`` -- shape dims are bucketed to
 the next power of two so near-identical problems share an entry.  Layout:
 
-    ~/.cache/elemental_tpu/tuning/              (default; override with
+    <checkout>/.tune_cache/                     (default; override with
     $ELEMENTAL_TPU_TUNE_CACHE)
       cholesky__b32768x32768__float32__g2x2__tpu.json
 
@@ -60,7 +60,10 @@ REDIST_SCHEMA = "redist_constants/v1"
 #: environment override for the cache directory
 ENV_DIR = "ELEMENTAL_TPU_TUNE_CACHE"
 
-_DEFAULT_DIR = os.path.join("~", ".cache", "elemental_tpu", "tuning")
+#: beside the package, like the compile cache (core/compile_cache.py):
+#: the library reads and writes nothing outside its checkout unless told
+_DEFAULT_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), ".tune_cache")
 
 
 def cache_dir() -> str:

@@ -94,7 +94,14 @@ MACHINES = {
 
 
 def machine_for(backend: str) -> MachineModel:
-    return MACHINES.get(str(backend).lower(), MACHINES["cpu"])
+    """The constants row of ``backend``; a backend without a row is an
+    error, never priced as some other machine."""
+    try:
+        return MACHINES[str(backend).lower()]
+    except KeyError:
+        raise ValueError(
+            f"no machine model for backend {backend!r}; known: "
+            f"{sorted(MACHINES)}") from None
 
 
 @dataclasses.dataclass

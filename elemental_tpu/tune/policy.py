@@ -90,12 +90,7 @@ def wants_auto(*values) -> bool:
 
 def _context(op: str, dims, dtype, grid) -> TuneContext:
     import jax.numpy as jnp
-    backend = "cpu"
-    try:
-        devs = grid.mesh.devices
-        backend = devs.flat[0].platform
-    except (AttributeError, IndexError):
-        pass
+    backend = grid.mesh.devices.flat[0].platform
     return TuneContext(op=op, dims=tuple(int(d) for d in dims),
                        dtype=jnp.dtype(dtype).name,
                        grid_shape=(grid.height, grid.width), backend=backend)

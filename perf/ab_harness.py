@@ -63,12 +63,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache_tpu")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
 import importlib                                              # noqa: E402
 
 import elemental_tpu as el                                    # noqa: E402
+from elemental_tpu.core.compile_cache import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 chol_mod = importlib.import_module("elemental_tpu.lapack.cholesky")
 lu_mod = importlib.import_module("elemental_tpu.lapack.lu")

@@ -57,10 +57,6 @@ def _bootstrap():
     if _REPO not in sys.path:
         sys.path.insert(0, _REPO)
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
     jax.config.update("jax_platform_name", "cpu")
     # match the test harness (tests/conftest.py): the comm plans are
@@ -68,10 +64,7 @@ def _bootstrap():
     # plans are not -- integer pivot avals double under x64 -- so the
     # CLI must trace in the same mode the golden gate tests run in
     jax.config.update("jax_enable_x64", True)
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", 8)
 
 
 def _grid(r: int, c: int):

@@ -66,15 +66,11 @@ def _bootstrap() -> None:
     should see the real chip when there is one)."""
     if _REPO not in sys.path:
         sys.path.insert(0, _REPO)
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
     try:
         jax.config.update("jax_num_cpu_devices", 8)
-    except (AttributeError, RuntimeError):
-        pass      # older jax (XLA_FLAGS path) / backend already initialized
+    except RuntimeError:
+        pass      # backend already initialized
 
 
 def _grid(spec: str | None):

@@ -41,17 +41,10 @@ def _bootstrap(force_cpu: bool) -> None:
         sys.path.insert(0, _REPO)
     if force_cpu:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
     import jax
     if force_cpu:
         jax.config.update("jax_platform_name", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass
+    jax.config.update("jax_num_cpu_devices", 8)
 
 
 def _grid(spec: str | None):

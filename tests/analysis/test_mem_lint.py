@@ -69,16 +69,21 @@ def test_declared_factors_cover_both_grids():
 # EL007 vmem-overflow + dynamic-gate agreement
 # ---------------------------------------------------------------------
 
-#: a panel the 16 MiB gate ADMITS (3 tile-padded f32 copies of
-#: 1024x1024 = 12 MiB) but whose qr kernel -- with its square (tp, tp)
-#: larft accumulator on top -- actually allocates ~16.2 MiB: the exact
-#: divergence class EL007 exists to catch
+#: a panel the 16 MiB gate ADMITS (4 tile-padded f32 copies of
+#: 1024x1024 = 16 MiB) but whose compiled qr kernel allocates about
+#: 30 MiB: under the compiler's DEFAULT 16 MiB scoped limit -- what the
+#: kernels were dispatched with before they set vmem_limit_bytes -- it
+#: is refused, the exact divergence class EL007 exists to catch
 _OVERSIZED = ("qr", (1024, 1024), "float32")
+_DEFAULT_GRANT = 16 * 2 ** 20
 
 
 def test_el007_fires_on_oversized_panel():
     op, shape, dtype = _OVERSIZED
-    chk = an.check_panel_vmem(op, shape, dtype)
+    # with the limit the kernels ask for, the gate's corner fits ...
+    assert not an.check_panel_vmem(op, shape, dtype).overflow
+    # ... with the compiler's own default it does not
+    chk = an.check_panel_vmem(op, shape, dtype, limit=_DEFAULT_GRANT)
     assert chk.admitted and not chk.fits and chk.overflow
     (f,) = rule_vmem_overflow([chk])
     assert f.rule == "EL007" and f.severity == "error"
@@ -88,8 +93,8 @@ def test_el007_fires_on_oversized_panel():
 def test_el007_dynamic_gate_agrees_on_oversized_panel():
     """The dynamic gate verdict for the seeded EL007 panel: use_pallas
     ADMITS it (that is the bug class -- the kernel would overflow), and
-    pricing at the kernel's honest resident count (4 copies: 3 panels +
-    the square larft T) makes the SAME gate refuse it."""
+    pricing at the compiled kernel's honest footprint makes the SAME
+    gate refuse it."""
     op, shape, _ = _OVERSIZED
     gate_copies = an.PANEL_GATE_COPIES[op]
     plan = PanelPlan(impl="pallas", inners=(512, 64), source="test")

@@ -201,7 +201,7 @@ def test_gate_bytes_reproduce_use_pallas():
 
 def test_kernel_bytes_exceed_gate_for_cholesky_odd_width():
     """The genuine gate/kernel divergence EL007 exists to catch: potrf's
-    pad_square LANE-pads BOTH axes, so non-128-multiple widths allocate
+    kernel LANE-pads BOTH axes, so non-128-multiple widths allocate
     MORE than the (8,128) tile pricing admits."""
     chk = an.check_panel_vmem("cholesky", (72, 72), "float32")
     assert chk.kernel_bytes > chk.gate_bytes

@@ -27,6 +27,31 @@ def test_resolve_defaults():
         resolve_panel("mosaic")
 
 
+def test_unresolved_auto_is_refused_not_sent_to_xla():
+    # 'auto' is the tuner's to resolve; reaching the dispatch with it is a
+    # driver bug, and quietly running the XLA ladder would hide it
+    with pytest.raises(ValueError, match="auto"):
+        resolve_panel("auto")
+
+
+@pytest.mark.parametrize("backend,interpret,expect", [
+    ("cpu", None, True), ("cpu", False, False), ("cpu", True, True),
+    ("tpu", None, False), ("tpu", False, False),
+    ("tpu", True, ValueError), ("gpu", None, NotImplementedError)])
+def test_interpret_policy_per_backend(monkeypatch, backend, interpret,
+                                      expect):
+    # CPU interprets (the tests) unless told to lower; a TPU compiles or
+    # fails; any other backend has no lowering and is refused
+    import jax
+    from elemental_tpu.kernels import interpret_default
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if isinstance(expect, bool):
+        assert interpret_default(interpret) is expect
+    else:
+        with pytest.raises(expect):
+            interpret_default(interpret)
+
+
 def test_complex_resolves_to_xla_silently():
     plan = resolve_panel("pallas", dtype=jnp.complex64)
     assert plan.impl == "xla" and plan.source == "complex-xla"

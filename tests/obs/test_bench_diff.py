@@ -95,14 +95,6 @@ def test_no_baselines_or_metrics_is_not_an_error(bd, tmp_path, capsys):
     assert "no comparable metrics" in capsys.readouterr().out
 
 
-def test_repo_trajectory_gates_clean(bd):
-    """The real recorded trajectory must pass its own gate (this is the
-    same invocation tools/check.sh runs)."""
-    repo = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
-    cur = os.path.join(repo, "BENCH_r05.json")
-    assert bd.main(["--check", cur]) == 0
-
-
 def test_obs_wire_bytes_key_accepted_not_gated(bd, tmp_path, capsys):
     """ISSUE 8: a current doc carrying the new obs.redist_wire_bytes
     total (and a comm_precision tuner provenance field) passes the gate

@@ -1,0 +1,106 @@
+"""The three fused panel kernels, compiled by the TPU's own compiler for
+a DESCRIBED v5e chip (no chip attached, nothing runs): at the widths
+``chip_smoke.py`` gives them and at the largest shapes their VMEM gate
+admits.  A kernel body that Mosaic refuses -- a primitive with no
+lowering, a slice off the (8, 128) tiling, more VMEM than the compiler
+grants -- fails here, at no chip time.
+
+The topology is described inside a module-scoped fixture (never at
+import), and every compile happens in this process: only one process may
+hold the TPU library at a time.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elemental_tpu.kernels import (PANEL_VMEM_BUDGET, PanelPlan, lu_panel,
+                                   potrf_inv, qr_panel)
+
+PLAN = PanelPlan(impl="pallas")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it out
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def _compile(fn, shape, one_chip):
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = jax.jit(fn).lower(x).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+#: (kernel, shape the gate is asked about, copies it is asked with) -- the
+#: call sites' own figures (lapack/lu.py, cholesky.py, qr.py)
+_LU = lambda p: lu_panel(p, p.shape[1], inner=PLAN.pallas_inner,     # noqa: E731
+                         interpret=False)
+_LU_UNB = lambda p: lu_panel(p, p.shape[1], inner=0, interpret=False)  # noqa: E731
+_CHOL = lambda d: potrf_inv(d, interpret=False)                      # noqa: E731
+_QR = lambda p: qr_panel(p, interpret=False)                         # noqa: E731
+
+
+@pytest.mark.parametrize("fn,shape,copies", [
+    # the widths chip_smoke.py's kernel phase uses (N = 2048, nb = 256)
+    pytest.param(_LU, (2048, 256), 3, id="lu-smoke-first-panel"),
+    pytest.param(_LU, (256, 256), 3, id="lu-smoke-last-panel"),
+    pytest.param(_LU_UNB, (2048, 256), 3, id="lu-unblocked"),
+    pytest.param(_CHOL, (256, 256), 4, id="chol-smoke"),
+    pytest.param(_QR, (2048, 256), 4, id="qr-smoke-first-panel"),
+    # the largest shapes the gate admits, per width
+    pytest.param(_LU, (5456, 256), 3, id="lu-gate-max-256"),
+    pytest.param(_CHOL, (1024, 1024), 4, id="chol-gate-max"),
+    pytest.param(_QR, (4096, 256), 4, id="qr-gate-max-256"),
+    pytest.param(_QR, (2048, 512), 4, id="qr-gate-max-512"),
+    pytest.param(_QR, (1024, 1024), 4, id="qr-gate-max-1024"),
+])
+def test_kernel_compiles_for_v5e(fn, shape, copies, one_chip):
+    assert PLAN.use_pallas(shape, jnp.float32, copies=copies), \
+        "the gate sends this shape to XLA; the case no longer tests a kernel"
+    _compile(fn, shape, one_chip)
+
+
+@pytest.mark.parametrize("shape,copies", [
+    ((5464, 256), 3), ((1152, 1152), 4), ((4104, 256), 4)])
+def test_gate_refuses_the_next_shape_up(shape, copies):
+    # the cases above are the gate's corners: one tile more and it says no
+    assert not PLAN.use_pallas(shape, jnp.float32, copies=copies)
+    assert copies * shape[0] * shape[1] * 4 > PANEL_VMEM_BUDGET
+
+
+def test_compilers_default_vmem_grant_refuses_a_gate_corner(one_chip,
+                                                            monkeypatch):
+    # why every panel kernel sets vmem_limit_bytes: under the compiler's
+    # own default the largest Cholesky block the gate admits is refused
+    from elemental_tpu.kernels import chol_panel
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(chol_panel, "compiler_params",
+                        lambda: pltpu.CompilerParams())
+    with pytest.raises(Exception, match="(?i)vmem"):
+        # a fresh function: jit would hand back the earlier compile of _CHOL
+        _compile(lambda d: potrf_inv(d, interpret=False), (1024, 1024),
+                 one_chip)

@@ -146,3 +146,18 @@ def test_crossover_default_matches_driver_constants():
     from elemental_tpu.lapack.cholesky import _CROSSOVER as CHOL
     from elemental_tpu.lapack.lu import _CROSSOVER as LU
     assert DEFAULT_CROSSOVER == CHOL == LU
+
+
+def test_unknown_backend_is_an_error_not_the_cpu_row():
+    assert cm.machine_for("TPU") is cm.MACHINES["tpu"]
+    with pytest.raises(ValueError, match="no machine model"):
+        cm.machine_for("metal")
+
+
+def test_engine_machine_terms_refuse_an_unknown_backend(monkeypatch):
+    from elemental_tpu.redist import engine
+    cpu = cm.machine_for("cpu")
+    assert engine._machine_terms() == (cpu.latency_s, cpu.bw_bytes_per_s)
+    monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+    with pytest.raises(ValueError, match="no machine model"):
+        engine._machine_terms()

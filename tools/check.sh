@@ -4,7 +4,7 @@
 # self-check + the tier-1 tests/tune subset + the calu/tsqr lapack gate
 # (comm lint/diff on the lu/qr variants, golden-coverage check, lu/qr
 # tests) + the observability smoke (perf.trace run on a tiny 1x1
-# problem) + the bench-trajectory regression gate (bench_diff) + the
+# problem) + the
 # resilience gate (certified-solve smoke on 1x1 + 2x2 grids incl. an
 # injected fault, and the fault-injection/health test suite).  Run
 # from anywhere; exits non-zero on ANY finding.  Future PRs run this
@@ -25,7 +25,7 @@
 #                             #   telemetry smoke (perf.trace serve
 #                             #   --smoke: lifecycle timelines, SLO
 #                             #   snapshot, flight-record replay) +
-#                             #   bench_diff gate + tests/obs
+#                             #   tests/obs
 #   tools/check.sh lapack     # calu/tsqr gate: lu/qr comm lint + golden diff,
 #                             #   golden-coverage check, lapack lu/qr tests
 #   tools/check.sh resilience # certified-solve smoke (1x1 + 2x2, CPU-safe)
@@ -147,15 +147,6 @@ if [ "$what" = "all" ] || [ "$what" = "obs" ]; then
     # flight-record replay of the grid-loss chaos cell
     JAX_PLATFORMS=cpu python -m perf.trace serve --smoke \
         --out /tmp/el_serve_trace_smoke.json >/dev/null || rc=1
-    echo "== bench-trajectory regression gate =="
-    # newest recorded bench vs the best of the earlier rounds (10% default
-    # threshold on the roofline-normalized ratios)
-    latest=$(ls BENCH_r*.json 2>/dev/null | sort | tail -1)
-    if [ -n "$latest" ]; then
-        python tools/bench_diff.py --check "$latest" || rc=1
-    else
-        echo "no BENCH_r*.json trajectory; skipping"
-    fi
     echo "== obs tier-1 tests =="
     python -m pytest tests/obs -q -m 'not slow' -p no:cacheprovider || rc=1
 fi
