@@ -17,7 +17,7 @@ chip whose peak is not in the table below, fails.  Timing is the host
 clock around work that ends in ``jax.block_until_ready``.
 
 ``--phases`` additionally drives one EAGER Cholesky through the
-``perf.phase_timer.PhaseTimer`` hook and emits its per-step
+``elemental_tpu.obs.PhaseTimer`` hook and emits its per-step
 diag/panel/update breakdown as a second ``phase_timings/v1`` JSON line
 after the headline (at a reduced N: the eager run holds more live
 buffers than the donate-input jit).
@@ -295,7 +295,7 @@ def main():
         # cholesky phase attribution alongside the headline: one eager run
         # through the PhaseTimer hook (smaller N -- the eager driver
         # cannot donate its input)
-        from perf.phase_timer import PhaseTimer
+        from elemental_tpu.obs import PhaseTimer
         del lu_arr, perm
         n_ph = min(n_chol, 16384)
 

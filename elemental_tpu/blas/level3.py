@@ -32,7 +32,8 @@ from jax import lax
 from ..core.dist import MC, MR, VC, VR, STAR
 from ..core.distmatrix import DistMatrix, zeros as dm_zeros
 from ..core.view import view, update_view
-from ..obs.tracer import NULL_HOOK as _NULL_HOOK, phase_hook as _phase_hook
+from ..obs.tracer import (NULL_HOOK as _NULL_HOOK, phase_hook as _phase_hook,
+                          scoped as _scoped)
 from ..redist.engine import redistribute, transpose_dist, panel_spread
 from .level1 import _global_indices
 
@@ -84,6 +85,7 @@ def _mask_triangle(C: DistMatrix, uplo: str, strict: bool = False):
 # Gemm (SUMMA)
 # ---------------------------------------------------------------------
 
+@_scoped("el.gemm")
 def gemm(A: DistMatrix, B: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None = None,
          orient_a: str = "N", orient_b: str = "N", alg: str = "auto",
          nb: int | str | None = None, precision=None,
@@ -173,14 +175,15 @@ def gemm(A: DistMatrix, B: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None
     if alg == "gspmd":
         # one-shot: re-land B's k-rows on A's k-col cyclic order ([MR,STAR]),
         # then a single storage matmul -- GSPMD inserts the psum over mr.
-        Bk = redistribute(B, MR, STAR, comm_precision=cp)
-        d = jnp.matmul(A.local, Bk.local, precision=precision)
-        D = DistMatrix(d, (m, n), MC, STAR, 0, 0, A.grid)
-        out = redistribute(D, MC, MR)
-        res = C.with_local(_safe_astype(
-            alpha * out.local + (beta * C.local if _nonzero(beta) else 0),
-            C.dtype))
-        tm.tick("panel", 0, res.local)
+        with tm.phase("panel", 0) as ph:
+            Bk = redistribute(B, MR, STAR, comm_precision=cp)
+            d = jnp.matmul(A.local, Bk.local, precision=precision)
+            D = DistMatrix(d, (m, n), MC, STAR, 0, 0, A.grid)
+            out = redistribute(D, MC, MR)
+            res = C.with_local(_safe_astype(
+                alpha * out.local + (beta * C.local if _nonzero(beta) else 0),
+                C.dtype))
+            ph.done(res.local)
         return res
     raise ValueError(f"unknown gemm alg {alg!r}")
 
@@ -197,12 +200,14 @@ def _summa_c(alpha, A, B, beta, C, nb, precision, tm=_NULL_HOOK, cp=None,
     acc = beta * C.local if _nonzero(beta) else jnp.zeros_like(C.local)
     for i, s in enumerate(range(0, k, kb)):
         e = min(s + kb, k)
-        A1 = redistribute(view(A, cols=(s, e)), MC, STAR,
-                          comm_precision=cp, path=rp)
-        B1 = redistribute(view(B, rows=(s, e)), STAR, MR,
-                          comm_precision=cp, path=rp)
-        acc = acc + alpha * jnp.matmul(A1.local, B1.local, precision=precision)
-        tm.tick("panel", i, acc)
+        with tm.phase("panel", i) as ph:
+            A1 = redistribute(view(A, cols=(s, e)), MC, STAR,
+                              comm_precision=cp, path=rp)
+            B1 = redistribute(view(B, rows=(s, e)), STAR, MR,
+                              comm_precision=cp, path=rp)
+            acc = acc + alpha * jnp.matmul(A1.local, B1.local,
+                                           precision=precision)
+            ph.done(acc)
     return C.with_local(_safe_astype(acc, C.dtype))
 
 
@@ -220,15 +225,16 @@ def _summa_a(alpha, A, B, beta, C, nb, precision, tm=_NULL_HOOK, cp=None,
                        if _nonzero(beta) else jnp.zeros_like(C.local))
     for i, s in enumerate(range(0, n, jb)):
         e = min(s + jb, n)
-        B1 = redistribute(view(B, cols=(s, e)), MR, STAR,
-                          comm_precision=cp, path=rp)
-        d = jnp.matmul(A.local, B1.local, precision=precision)   # [MC,STAR] storage
-        D1 = DistMatrix(d, (m, e - s), MC, STAR, 0, 0, A.grid)
-        panel = redistribute(D1, MC, MR)
-        cur = view(out, cols=(s, e))
-        out = update_view(out, cur.with_local(cur.local + _safe_astype(alpha * panel.local, C.dtype)),
-                          cols=(s, e))
-        tm.tick("panel", i, out.local)
+        with tm.phase("panel", i) as ph:
+            B1 = redistribute(view(B, cols=(s, e)), MR, STAR,
+                              comm_precision=cp, path=rp)
+            d = jnp.matmul(A.local, B1.local, precision=precision)   # [MC,STAR] storage
+            D1 = DistMatrix(d, (m, e - s), MC, STAR, 0, 0, A.grid)
+            panel = redistribute(D1, MC, MR)
+            cur = view(out, cols=(s, e))
+            out = update_view(out, cur.with_local(cur.local + _safe_astype(alpha * panel.local, C.dtype)),
+                              cols=(s, e))
+            ph.done(out.local)
     return out
 
 
@@ -245,15 +251,16 @@ def _summa_b(alpha, A, B, beta, C, nb, precision, tm=_NULL_HOOK, cp=None,
                        if _nonzero(beta) else jnp.zeros_like(C.local))
     for i, s in enumerate(range(0, m, ib)):
         e = min(s + ib, m)
-        A1T = redistribute(transpose_dist(view(A, rows=(s, e))), MC, STAR,
-                           comm_precision=cp, path=rp)
-        d = jnp.matmul(A1T.local.T, B.local, precision=precision)  # [STAR,MR] storage
-        D1 = DistMatrix(d, (e - s, n), STAR, MR, 0, 0, A.grid)
-        panel = redistribute(D1, MC, MR)
-        cur = view(out, rows=(s, e))
-        out = update_view(out, cur.with_local(cur.local + _safe_astype(alpha * panel.local, C.dtype)),
-                          rows=(s, e))
-        tm.tick("panel", i, out.local)
+        with tm.phase("panel", i) as ph:
+            A1T = redistribute(transpose_dist(view(A, rows=(s, e))), MC, STAR,
+                               comm_precision=cp, path=rp)
+            d = jnp.matmul(A1T.local.T, B.local, precision=precision)  # [STAR,MR] storage
+            D1 = DistMatrix(d, (e - s, n), STAR, MR, 0, 0, A.grid)
+            panel = redistribute(D1, MC, MR)
+            cur = view(out, rows=(s, e))
+            out = update_view(out, cur.with_local(cur.local + _safe_astype(alpha * panel.local, C.dtype)),
+                              rows=(s, e))
+            ph.done(out.local)
     return out
 
 
@@ -270,18 +277,19 @@ def _summa_dot(alpha, A, B, beta, C, precision, tm=_NULL_HOOK, cp=None,
     matmul.  ``beta`` may be any scalar (incl. complex); a complex result
     landing in a real C still raises through :func:`_safe_astype`."""
     m, n = C.gshape
-    if A.grid.size == 1:
-        d = jnp.matmul(A.local, B.local, precision=precision)
-    else:
-        Avc = redistribute(A, STAR, VC, comm_precision=cp, path=rp)
-        Bvc = redistribute(B, VC, STAR, comm_precision=cp, path=rp)
-        dl = jnp.matmul(Avc.local, Bvc.local, precision=precision)
-        D = DistMatrix(dl, (m, n), STAR, STAR, 0, 0, A.grid)
-        d = redistribute(D, MC, MR).local
-    res = C.with_local(_safe_astype(
-        alpha * d + (beta * C.local if _nonzero(beta) else 0),
-        C.dtype))
-    tm.tick("panel", 0, res.local)
+    with tm.phase("panel", 0) as ph:
+        if A.grid.size == 1:
+            d = jnp.matmul(A.local, B.local, precision=precision)
+        else:
+            Avc = redistribute(A, STAR, VC, comm_precision=cp, path=rp)
+            Bvc = redistribute(B, VC, STAR, comm_precision=cp, path=rp)
+            dl = jnp.matmul(Avc.local, Bvc.local, precision=precision)
+            D = DistMatrix(dl, (m, n), STAR, STAR, 0, 0, A.grid)
+            d = redistribute(D, MC, MR).local
+        res = C.with_local(_safe_astype(
+            alpha * d + (beta * C.local if _nonzero(beta) else 0),
+            C.dtype))
+        ph.done(res.local)
     return res
 
 
@@ -313,27 +321,30 @@ def _summa_slice(alpha, A, B, beta, C, precision, tm=_NULL_HOOK, cp=None):
     twins elsewhere."""
     m, n = C.gshape
     g = A.grid
-    if g.size == 1:
-        d = jnp.matmul(A.local, B.local, precision=precision)
-    else:
-        from ..redist.plan import slice_row_mode
-        if slice_row_mode(m, n, (g.height, g.width)):
-            As = redistribute(A, VC, STAR, comm_precision=cp, path="direct")
-            Bs = redistribute(B, STAR, STAR, comm_precision=cp,
-                              path="direct")
-            dl = jnp.matmul(As.local, Bs.local, precision=precision)
-            D = DistMatrix(dl, (m, n), VC, STAR, 0, 0, g)
+    with tm.phase("panel", 0) as ph:
+        if g.size == 1:
+            d = jnp.matmul(A.local, B.local, precision=precision)
         else:
-            As = redistribute(A, STAR, STAR, comm_precision=cp,
-                              path="direct")
-            Bs = redistribute(B, STAR, VR, comm_precision=cp, path="direct")
-            dl = jnp.matmul(As.local, Bs.local, precision=precision)
-            D = DistMatrix(dl, (m, n), STAR, VR, 0, 0, g)
-        d = redistribute(D, MC, MR, path="direct").local
-    res = C.with_local(_safe_astype(
-        alpha * d + (beta * C.local if _nonzero(beta) else 0),
-        C.dtype))
-    tm.tick("panel", 0, res.local)
+            from ..redist.plan import slice_row_mode
+            if slice_row_mode(m, n, (g.height, g.width)):
+                As = redistribute(A, VC, STAR, comm_precision=cp,
+                                  path="direct")
+                Bs = redistribute(B, STAR, STAR, comm_precision=cp,
+                                  path="direct")
+                dl = jnp.matmul(As.local, Bs.local, precision=precision)
+                D = DistMatrix(dl, (m, n), VC, STAR, 0, 0, g)
+            else:
+                As = redistribute(A, STAR, STAR, comm_precision=cp,
+                                  path="direct")
+                Bs = redistribute(B, STAR, VR, comm_precision=cp,
+                                  path="direct")
+                dl = jnp.matmul(As.local, Bs.local, precision=precision)
+                D = DistMatrix(dl, (m, n), STAR, VR, 0, 0, g)
+            d = redistribute(D, MC, MR, path="direct").local
+        res = C.with_local(_safe_astype(
+            alpha * d + (beta * C.local if _nonzero(beta) else 0),
+            C.dtype))
+        ph.done(res.local)
     return res
 
 
@@ -375,6 +386,7 @@ def trrk(uplo: str, alpha, A_mc: DistMatrix, B_mr: DistMatrix, beta, C: DistMatr
     return C.with_local(jnp.where(mask, _safe_astype(tri_new, C.dtype), C.local))
 
 
+@_scoped("el.herk")
 def herk(uplo: str, A: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None = None,
          orient: str = "N", nb: int | str | None = None, precision=None,
          conj: bool = True, comm_precision: str | None = None,
@@ -418,22 +430,27 @@ def herk(uplo: str, A: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None = N
     acc = beta * C.local if _nonzero(beta) else jnp.zeros_like(C.local)
     for i, s in enumerate(range(0, k, kb)):
         e = min(s + kb, k)
-        if redist_path == "direct":
-            # One one-shot exchange per panel; the [MC,STAR] panel and its
-            # [STAR,MR] adjoint are then zero-round local filters.
-            A1_ss = redistribute(view(A, cols=(s, e)), STAR, STAR,
-                                 comm_precision=comm_precision, path="direct")
-            A1_mc = redistribute(A1_ss, MC, STAR)
-            A1H_mr = redistribute(transpose_dist(A1_ss, conj=conj), STAR, MR)
-        else:
-            A1_vc = redistribute(view(A, cols=(s, e)), VC, STAR,
-                                 comm_precision=comm_precision,
-                                 path=redist_path)
-            A1_mc, A1H_mr = panel_spread(A1_vc, conj=conj,
-                                         comm_precision=comm_precision)
-        tm.tick("spread", i, A1_mc.local, A1H_mr.local)
-        acc = acc + alpha * jnp.matmul(A1_mc.local, A1H_mr.local, precision=precision)
-        tm.tick("update", i, acc)
+        with tm.phase("spread", i) as ph:
+            if redist_path == "direct":
+                # One one-shot exchange per panel; the [MC,STAR] panel and
+                # its [STAR,MR] adjoint are then zero-round local filters.
+                A1_ss = redistribute(view(A, cols=(s, e)), STAR, STAR,
+                                     comm_precision=comm_precision,
+                                     path="direct")
+                A1_mc = redistribute(A1_ss, MC, STAR)
+                A1H_mr = redistribute(transpose_dist(A1_ss, conj=conj),
+                                      STAR, MR)
+            else:
+                A1_vc = redistribute(view(A, cols=(s, e)), VC, STAR,
+                                     comm_precision=comm_precision,
+                                     path=redist_path)
+                A1_mc, A1H_mr = panel_spread(A1_vc, conj=conj,
+                                             comm_precision=comm_precision)
+            ph.done(A1_mc.local, A1H_mr.local)
+        with tm.phase("update", i) as ph:
+            acc = acc + alpha * jnp.matmul(A1_mc.local, A1H_mr.local,
+                                           precision=precision)
+            ph.done(acc)
     return C.with_local(jnp.where(mask, _safe_astype(acc, C.dtype), C.local))
 
 
@@ -447,6 +464,7 @@ def syrk(uplo: str, A: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None = N
 # Trsm (blocked panel solves)
 # ---------------------------------------------------------------------
 
+@_scoped("el.trsm")
 def trsm(side: str, uplo: str, orient: str, A: DistMatrix, B: DistMatrix,
          alpha=1.0, unit: bool = False, nb: int | str | None = None,
          precision=None, comm_precision: str | None = None,
@@ -509,38 +527,40 @@ def _trsm_left(uplo: str, trans: bool, conj: bool, A: DistMatrix, B: DistMatrix,
         starts = starts[::-1]
     for k, s in enumerate(starts):
         e = min(s + ib, m)
-        A11 = redistribute(view(A, rows=(s, e), cols=(s, e)), STAR, STAR,
-                           comm_precision=cp, path=rp)
-        # mask to the stored triangle so opposite-triangle garbage (e.g. the
-        # packed L\U format of lu()) can never leak into the solve
-        a11 = jnp.tril(A11.local) if lower else jnp.triu(A11.local)
-        B1 = redistribute(view(X, rows=(s, e)), STAR, VR, comm_precision=cp,
-                          path=rp)
-        x1 = lax.linalg.triangular_solve(
-            a11, B1.local, left_side=True, lower=lower,
-            transpose_a=trans, conjugate_a=conj, unit_diagonal=unit)
-        X1 = DistMatrix(x1, B1.gshape, STAR, VR, 0, 0, A.grid)
-        X1_mr = redistribute(X1, STAR, MR, comm_precision=cp, path=rp)
-        X = update_view(X, redistribute(X1_mr, MC, MR), rows=(s, e))  # local filter
-        tm.tick("solve", k, X.local)
+        with tm.phase("solve", k) as ph:
+            A11 = redistribute(view(A, rows=(s, e), cols=(s, e)), STAR, STAR,
+                               comm_precision=cp, path=rp)
+            # mask to the stored triangle so opposite-triangle garbage (e.g.
+            # the packed L\U format of lu()) can never leak into the solve
+            a11 = jnp.tril(A11.local) if lower else jnp.triu(A11.local)
+            B1 = redistribute(view(X, rows=(s, e)), STAR, VR,
+                              comm_precision=cp, path=rp)
+            x1 = lax.linalg.triangular_solve(
+                a11, B1.local, left_side=True, lower=lower,
+                transpose_a=trans, conjugate_a=conj, unit_diagonal=unit)
+            X1 = DistMatrix(x1, B1.gshape, STAR, VR, 0, 0, A.grid)
+            X1_mr = redistribute(X1, STAR, MR, comm_precision=cp, path=rp)
+            X = update_view(X, redistribute(X1_mr, MC, MR), rows=(s, e))  # local filter
+            ph.done(X.local)
         # trailing update of the not-yet-solved rows
         lo, hi = (e, m) if forward else (0, s)
         if lo >= hi:
             continue
-        if trans:
-            # T21 = op(A)[hi-part, s:e] = op(A[s:e, hi-part])
-            A1p = redistribute(view(A, rows=(s, e), cols=(lo, hi)), STAR, MC,
-                               comm_precision=cp, path=rp)
-            a_loc = A1p.local.T            # [MC,STAR]-storage of A1p^T
-        else:
-            A1p = redistribute(view(A, rows=(lo, hi), cols=(s, e)), MC, STAR,
-                               comm_precision=cp, path=rp)
-            a_loc = A1p.local
-        if conj:
-            a_loc = jnp.conj(a_loc)
-        X = local_rank_update(X, a_loc, X1_mr.local, rows=(lo, hi),
-                              precision=precision)
-        tm.tick("update", k, X.local)
+        with tm.phase("update", k) as ph:
+            if trans:
+                # T21 = op(A)[hi-part, s:e] = op(A[s:e, hi-part])
+                A1p = redistribute(view(A, rows=(s, e), cols=(lo, hi)), STAR,
+                                   MC, comm_precision=cp, path=rp)
+                a_loc = A1p.local.T            # [MC,STAR]-storage of A1p^T
+            else:
+                A1p = redistribute(view(A, rows=(lo, hi), cols=(s, e)), MC,
+                                   STAR, comm_precision=cp, path=rp)
+                a_loc = A1p.local
+            if conj:
+                a_loc = jnp.conj(a_loc)
+            X = local_rank_update(X, a_loc, X1_mr.local, rows=(lo, hi),
+                                  precision=precision)
+            ph.done(X.local)
     return X
 
 

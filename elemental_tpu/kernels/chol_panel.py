@@ -167,5 +167,6 @@ def potrf_inv(D, precision=None, *, bs: int = 512, interpret=None):
             scratch_shapes=scratch,
             compiler_params=compiler_params(),
             interpret=interpret,
+            name="el_potrf_inv_panel",
         )(Dp)
     return L[:w, :w], Li[:w, :w]

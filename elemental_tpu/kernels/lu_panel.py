@@ -167,6 +167,7 @@ def lu_panel(P, nbw: int, precision=None, *, inner: int = 0,
             out_specs=(vmem, pl.BlockSpec(memory_space=pltpu.SMEM)),
             compiler_params=compiler_params(),
             interpret=interpret,
+            name="el_lu_panel",
         )(Pp)
     packed = packed[:M, :w]
 

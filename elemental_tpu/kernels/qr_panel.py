@@ -125,5 +125,6 @@ def qr_panel(P, *, interpret=None):
             scratch_shapes=[sq, sq],
             compiler_params=compiler_params(),
             interpret=interpret,
+            name="el_qr_panel",
         )(Pp)
     return packed[:M, :k], tau[0, :k], T[:k, :k]

@@ -43,17 +43,22 @@ device, but zero further collectives.  ``crossover=None`` picks the
 default; pass an int to override (``perf/ab_harness.py cholesky`` sweeps
 it).
 
-Phase timing (``timer``)
-------------------------
-Pass a ``perf.phase_timer.PhaseTimer`` and call ``cholesky`` EAGERLY: the
-driver ticks at every diag / panel / spread / update (/tail) boundary and
-the timer attributes per-step wall-clock (same ``phase_timings/v1`` schema
-as LU; ``python perf/ab_harness.py phases cholesky`` is the CLI).
+Phases (``timer``)
+------------------
+The driver marks diag / panel / spread / update (/tail) with the scoped
+form of its hook (``with tm.phase(phase, k)``, :mod:`elemental_tpu.obs`):
+under ``jit`` the compiled program's ops carry
+``el.cholesky/k<step>/<phase>`` in their names, which is what a device
+trace is split by.  Pass an ``elemental_tpu.obs.PhaseTimer`` and call
+``cholesky`` EAGERLY and the same blocks also charge per-step wall-clock
+(same ``phase_timings/v1`` schema as LU; ``python perf/ab_harness.py
+phases cholesky`` is the CLI).
 """
 from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -65,7 +70,8 @@ from ..redist.engine import (apply_fault, redistribute, transpose_dist,
 from ..redist.quantize import check_comm_precision
 from ..blas.level1 import make_trapezoidal, _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle, trsm
-from .lu import _hi, _NULL_TIMER, _phase_hook
+from ..obs.tracer import NULL_HOOK, scoped as _scoped
+from .lu import _hi, _phase_hook
 
 #: Trailing-matrix size at which the distributed loop gathers the tail and
 #: finishes locally (look-ahead schedule only, unless overridden).  The
@@ -167,72 +173,73 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
         so the latency-bound ``_potrf_inv`` inner loop is data-independent
         of the wide remainder stripes and XLA may overlap them (the same
         pipeline as ``lu._local_lu``)."""
-    tm = timer if timer is not None else _NULL_TIMER
+    tm = timer if timer is not None else NULL_HOOK
     dt = a.dtype
     q = 2 * ib
     panels = []
     T = a
     nxt = None
+
+    def diag_and_panel(step, src, w, below):
+        # diag block ``step`` from src[:w, :w]; its panel solve, one
+        # matmul, from the rows under it
+        with tm.phase("diag", step) as ph:
+            L11, Li11 = _potrf_inv(src[:w, :w], precision, plan=plan)
+            ph.done(L11)
+        L21 = None
+        if below:
+            with tm.phase("panel", step) as ph:
+                L21 = jnp.matmul(src[w:, :w], jnp.conj(Li11).T,
+                                 precision=_hi(precision)).astype(dt)
+                ph.done(L21)
+        return L11, Li11, L21
+
     if lookahead:
         w0 = min(ib, n)
-        L11, Li11 = _potrf_inv(T[:w0, :w0], precision, plan=plan)
-        tm.tick("diag", 0, L11)
-        L21 = None
-        if w0 < n:
-            L21 = jnp.matmul(T[w0:, :w0], jnp.conj(Li11).T,
-                             precision=_hi(precision)).astype(dt)
-            tm.tick("panel", 0, L21)
-        nxt = (L11, Li11, L21)
+        nxt = diag_and_panel(0, T, w0, w0 < n)
     for k, s in enumerate(range(0, n, ib)):
         w = min(ib, n - s)
         if lookahead:
             L11, Li11, L21 = nxt
         else:
-            L11, Li11 = _potrf_inv(T[:w, :w], precision, plan=plan)
-            tm.tick("diag", k, L11)
-            L21 = None
-            if s + w < n:
-                L21 = jnp.matmul(T[w:, :w], jnp.conj(Li11).T,
-                                 precision=_hi(precision)).astype(dt)
-                tm.tick("panel", k, L21)
+            L11, Li11, L21 = diag_and_panel(k, T, w, s + w < n)
         if s + w == n:
             panels.append(L11)
             break
-        panels.append(jnp.concatenate([L11, L21], axis=0))
-        T2 = T[w:, w:]
-        mt = T2.shape[0]
+        with tm.phase("panel", k):
+            panels.append(jnp.concatenate([L11, L21], axis=0))
         if not lookahead:
-            for i in range(0, mt, q):
-                iq = min(i + q, mt)
-                upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[:iq, :]).T,
-                                 precision=precision)
-                T2 = T2.at[i:iq, :iq].set(T2[i:iq, :iq] - upd.astype(dt))
-            T = T2
-            tm.tick("update", k, T)
+            with tm.phase("update", k) as ph:
+                T2 = T[w:, w:]
+                mt = T2.shape[0]
+                for i in range(0, mt, q):
+                    iq = min(i + q, mt)
+                    upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[:iq, :]).T,
+                                     precision=precision)
+                    T2 = T2.at[i:iq, :iq].set(T2[i:iq, :iq] - upd.astype(dt))
+                T = T2
+                ph.done(T)
             continue
         # look-ahead: the next panel's column strip updates first (one tall
         # narrow matmul), diag block k+1 factors + panel k+1 solves from it;
         # the wide remainder stripes read only the pre-update T2, so the
         # replicated _potrf_inv and the MXU stripes can overlap.
-        w2 = min(ib, mt)
-        strip = T2[:, :w2] - jnp.matmul(L21, jnp.conj(L21[:w2, :]).T,
-                                        precision=precision).astype(dt)
-        L11n, Li11n = _potrf_inv(strip[:w2, :w2], precision, plan=plan)
-        tm.tick("diag", k + 1, L11n)
-        L21n = None
-        if w2 < mt:
-            L21n = jnp.matmul(strip[w2:, :], jnp.conj(Li11n).T,
-                              precision=_hi(precision)).astype(dt)
-            tm.tick("panel", k + 1, L21n)
-        nxt = (L11n, Li11n, L21n)
-        T2 = T2.at[:, :w2].set(strip)
-        for i in range(w2, mt, q):
-            iq = min(i + q, mt)
-            upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[w2:iq, :]).T,
-                             precision=precision)
-            T2 = T2.at[i:iq, w2:iq].set(T2[i:iq, w2:iq] - upd.astype(dt))
-        T = T2
-        tm.tick("update", k, T)
+        with tm.phase("update", k):
+            T2 = T[w:, w:]
+            mt = T2.shape[0]
+            w2 = min(ib, mt)
+            strip = T2[:, :w2] - jnp.matmul(L21, jnp.conj(L21[:w2, :]).T,
+                                            precision=precision).astype(dt)
+        nxt = diag_and_panel(k + 1, strip, w2, w2 < mt)
+        with tm.phase("update", k) as ph:
+            T2 = T2.at[:, :w2].set(strip)
+            for i in range(w2, mt, q):
+                iq = min(i + q, mt)
+                upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[w2:iq, :]).T,
+                                 precision=precision)
+                T2 = T2.at[i:iq, w2:iq].set(T2[i:iq, w2:iq] - upd.astype(dt))
+            T = T2
+            ph.done(T)
     out = jnp.zeros((n, n), dt)
     s = 0
     for P in panels:
@@ -254,6 +261,7 @@ def _local_cholesky(A: DistMatrix, nb: int | None, precision,
     return make_trapezoidal(A.with_local(out), "L")
 
 
+@_scoped("el.cholesky")
 def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
              precision=None, lookahead: bool | str = True,
              crossover: int | str | None = None,
@@ -269,7 +277,8 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     ``crossover`` is the trailing-matrix size at which the distributed loop
     gathers the tail once and finishes locally (``None`` = :data:`_CROSSOVER`
     with look-ahead, disabled classic; 0 never crosses over); ``timer``
-    enables eager per-phase wall-clock attribution (``perf/phase_timer.py``).
+    enables eager per-phase wall-clock attribution
+    (``elemental_tpu.obs.PhaseTimer``).
 
     ``panel_impl`` (``None`` | ``'xla'`` | ``'pallas'`` | ``'auto'``)
     selects the diagonal-block factor/inverse IMPLEMENTATION: ``'pallas'``
@@ -375,129 +384,131 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     xover = (_CROSSOVER if lookahead else 0) if crossover is None \
         else max(int(crossover), 0)
     L = A
+    cp = comm_precision
+
+    def factor_diag(step, src, lo, hi):
+        # replicated diagonal-block factor + inverse: every device runs
+        # the same deterministic _potrf_inv, so the panel Trsm is a matmul
+        with tm.phase("diag", step) as ph:
+            A11 = redistribute(view(src, rows=(lo, hi), cols=(lo, hi)),
+                               STAR, STAR, comm_precision=cp, path=rp)
+            L11, Li11 = _potrf_inv(A11.local, precision, plan=plan)
+            ph.done(L11)
+        return L11, Li11
+
+    def solve_panel(step, src, rows, cols, Li11):
+        # L21 = A21 L11^{-H} on the [VC,STAR] panel
+        with tm.phase("panel", step) as ph:
+            A21_vc = redistribute(view(src, rows=rows, cols=cols), VC, STAR,
+                                  comm_precision=cp, path=rp)
+            x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
+                             precision=_hi(precision)).astype(A.dtype)
+            L21_vc = DistMatrix(x21, (rows[1] - rows[0], cols[1] - cols[0]),
+                                VC, STAR, 0, 0, g)
+            ph.done(L21_vc)
+        return L21_vc
+
     if lookahead:
         # prologue: factor diag block 0 + solve panel 0 from the input
         e0 = min(ib, m)
-        A11 = redistribute(view(L, rows=(0, e0), cols=(0, e0)), STAR, STAR,
-                           comm_precision=comm_precision, path=rp)
-        L11, Li11 = _potrf_inv(A11.local, precision, plan=plan)
-        tm.tick("diag", 0, L11)
-        L21_vc = None
-        if e0 < m:
-            A21_vc = redistribute(view(L, rows=(e0, m), cols=(0, e0)),
-                                  VC, STAR, comm_precision=comm_precision,
-                                  path=rp)
-            x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
-                             precision=_hi(precision)).astype(L.dtype)
-            L21_vc = DistMatrix(x21, (m - e0, e0), VC, STAR, 0, 0, g)
-            tm.tick("panel", 0, L21_vc)
+        L11, Li11 = factor_diag(0, L, 0, e0)
+        L21_vc = solve_panel(0, L, (e0, m), (0, e0), Li11) if e0 < m \
+            else None
         nxt = (L11, Li11, L21_vc)
     for k, s in enumerate(range(0, m, ib)):
         e = min(s + ib, m)
         if lookahead:
             L11, Li11, L21_vc = nxt
         else:
-            A11 = redistribute(view(L, rows=(s, e), cols=(s, e)),
-                               STAR, STAR, comm_precision=comm_precision,
-                               path=rp)
-            # replicated diagonal-block factor + inverse: every device runs
-            # the same deterministic _potrf_inv, so the panel Trsm below is
-            # a matmul
-            L11, Li11 = _potrf_inv(A11.local, precision, plan=plan)
-            tm.tick("diag", k, L11)
-        L11_ss = DistMatrix(L11, (e - s, e - s), STAR, STAR, 0, 0, g)
-        L = update_view(L, redistribute(L11_ss, MC, MR), rows=(s, e), cols=(s, e))
+            L11, Li11 = factor_diag(k, L, s, e)
+        with tm.phase("diag", k):
+            L11_ss = DistMatrix(L11, (e - s, e - s), STAR, STAR, 0, 0, g)
+            L = update_view(L, redistribute(L11_ss, MC, MR), rows=(s, e),
+                            cols=(s, e))
         if e == m:
             break
         if not lookahead:
-            A21_vc = redistribute(view(L, rows=(e, m), cols=(s, e)),
-                                  VC, STAR, comm_precision=comm_precision,
-                                  path=rp)
-            x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
-                             precision=_hi(precision)).astype(L.dtype)  # A21 L11^{-H}
-            L21_vc = DistMatrix(x21, (m - e, e - s), VC, STAR, 0, 0, g)
-            tm.tick("panel", k, L21_vc)
-        L21_mc, L21H_mr = panel_spread(L21_vc, conj=True,
-                                       comm_precision=comm_precision)
-        tm.tick("spread", k, L21_mc, L21H_mr)
+            L21_vc = solve_panel(k, L, (e, m), (s, e), Li11)
+        with tm.phase("spread", k) as ph:
+            L21_mc, L21H_mr = panel_spread(L21_vc, conj=True,
+                                           comm_precision=cp)
+            ph.done(L21_mc, L21H_mr)
         tail = bool(xover) and m - e <= xover
         if not lookahead:
-            A22 = view(L, rows=(e, m), cols=(e, m))
-            upd = jnp.matmul(L21_mc.local, L21H_mr.local, precision=precision)
-            mask = _mask_triangle(A22, "L")
-            A22new = jnp.where(mask, A22.local - upd.astype(L.dtype), A22.local)
-            L = update_view(L, A22.with_local(A22new), rows=(e, m), cols=(e, m))
-            L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m), cols=(s, e))
-            tm.tick("update", k, L)
+            with tm.phase("update", k) as ph:
+                A22 = view(L, rows=(e, m), cols=(e, m))
+                upd = jnp.matmul(L21_mc.local, L21H_mr.local,
+                                 precision=precision)
+                mask = _mask_triangle(A22, "L")
+                A22new = jnp.where(mask, A22.local - upd.astype(L.dtype),
+                                   A22.local)
+                L = update_view(L, A22.with_local(A22new), rows=(e, m),
+                                cols=(e, m))
+                L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
+                                cols=(s, e))
+                ph.done(L)
         else:
             # (a) narrow strip update: the next panel's columns of A22
-            e2 = min(e + ib, m)
-            A22a = view(L, rows=(e, m), cols=(e, e2))
-            L21H_a = view(L21H_mr, cols=(0, e2 - e))
-            maskA = _mask_triangle(A22a, "L")
-            stripD = A22a.with_local(jnp.where(
-                maskA,
-                A22a.local - jnp.matmul(L21_mc.local, L21H_a.local,
-                                        precision=precision).astype(L.dtype),
-                A22a.local))
+            with tm.phase("update", k):
+                e2 = min(e + ib, m)
+                A22a = view(L, rows=(e, m), cols=(e, e2))
+                L21H_a = view(L21H_mr, cols=(0, e2 - e))
+                maskA = _mask_triangle(A22a, "L")
+                stripD = A22a.with_local(jnp.where(
+                    maskA,
+                    A22a.local - jnp.matmul(L21_mc.local, L21H_a.local,
+                                            precision=precision
+                                            ).astype(L.dtype),
+                    A22a.local))
             if not tail:
                 # factor diag block k+1 + solve panel k+1 from the strip,
                 # off the critical path of the wide remainder update
-                A11n = redistribute(view(stripD, rows=(0, e2 - e),
-                                         cols=(0, e2 - e)), STAR, STAR,
-                                    comm_precision=comm_precision, path=rp)
-                L11n, Li11n = _potrf_inv(A11n.local, precision, plan=plan)
-                tm.tick("diag", k + 1, L11n)
-                L21n_vc = None
-                if e2 < m:
-                    A21n = redistribute(view(stripD, rows=(e2 - e, m - e),
-                                             cols=(0, e2 - e)), VC, STAR,
-                                        comm_precision=comm_precision,
-                                        path=rp)
-                    x21n = jnp.matmul(A21n.local, jnp.conj(Li11n).T,
-                                      precision=_hi(precision)).astype(L.dtype)
-                    L21n_vc = DistMatrix(x21n, (m - e2, e2 - e), VC, STAR,
-                                         0, 0, g)
-                    tm.tick("panel", k + 1, L21n_vc)
+                L11n, Li11n = factor_diag(k + 1, stripD, 0, e2 - e)
+                L21n_vc = solve_panel(k + 1, stripD, (e2 - e, m - e),
+                                      (0, e2 - e), Li11n) if e2 < m else None
                 nxt = (L11n, Li11n, L21n_vc)
             # (b) wide remainder update; operands captured pre-writeback so
             # it is data-independent of the step-k+1 factorization above
-            restD = None
-            if e2 < m:
-                A22b = view(L, rows=(e, m), cols=(e2, m))
-                L21H_b = view(L21H_mr, cols=(e2 - e, m - e))
-                I, J = _global_indices(A22b)
-                maskB = (J[None, :] + (e2 - e)) <= I[:, None]
-                restD = A22b.with_local(jnp.where(
-                    maskB,
-                    A22b.local - jnp.matmul(L21_mc.local, L21H_b.local,
-                                            precision=precision).astype(L.dtype),
-                    A22b.local))
-            L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m), cols=(s, e))
-            L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
-            if restD is not None:
-                L = update_view(L, restD, rows=(e, m), cols=(e2, m))
-            tm.tick("update", k, L)
+            with tm.phase("update", k) as ph:
+                restD = None
+                if e2 < m:
+                    A22b = view(L, rows=(e, m), cols=(e2, m))
+                    L21H_b = view(L21H_mr, cols=(e2 - e, m - e))
+                    I, J = _global_indices(A22b)
+                    maskB = (J[None, :] + (e2 - e)) <= I[:, None]
+                    restD = A22b.with_local(jnp.where(
+                        maskB,
+                        A22b.local - jnp.matmul(L21_mc.local, L21H_b.local,
+                                                precision=precision
+                                                ).astype(L.dtype),
+                        A22b.local))
+                L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
+                                cols=(s, e))
+                L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
+                if restD is not None:
+                    L = update_view(L, restD, rows=(e, m), cols=(e2, m))
+                ph.done(L)
         if tail:
             # crossover-to-local: one gather of the (fully updated) trailing
             # block, replicated sequential finish, one scatter back -- the
             # remaining t/nb steps of per-step collective latency collapse
             # into a single round trip
-            Atail = redistribute(view(L, rows=(e, m), cols=(e, m)),
-                                 STAR, STAR,
-                                 comm_precision=comm_precision, path=rp)
-            lt = _local_chol_array(Atail.local, m - e, ib, precision,
-                                   lookahead=lookahead, plan=plan)
-            Lt_ss = DistMatrix(lt, (m - e, m - e), STAR, STAR, 0, 0, g)
-            L = update_view(L, redistribute(Lt_ss, MC, MR),
-                            rows=(e, m), cols=(e, m))
-            tm.tick("tail", k, L)
+            with tm.phase("tail", k) as ph:
+                Atail = redistribute(view(L, rows=(e, m), cols=(e, m)),
+                                     STAR, STAR, comm_precision=cp, path=rp)
+                lt = _local_chol_array(Atail.local, m - e, ib, precision,
+                                       lookahead=lookahead, plan=plan)
+                Lt_ss = DistMatrix(lt, (m - e, m - e), STAR, STAR, 0, 0, g)
+                L = update_view(L, redistribute(Lt_ss, MC, MR),
+                                rows=(e, m), cols=(e, m))
+                ph.done(L)
             break
     if hm is not None:
         hm.report()
     return make_trapezoidal(L, "L")
 
 
+@_scoped("el.hpd_solve")
 def hpd_solve(A: DistMatrix, B: DistMatrix, uplo: str = "L",
               nb: int | None = None, precision=None, info: bool = False,
               health=None):
@@ -512,8 +523,10 @@ def hpd_solve(A: DistMatrix, B: DistMatrix, uplo: str = "L",
     residual-certified path use
     ``elemental_tpu.resilience.certified_solve('hpd', A, B)``."""
     uplo = "U" if uplo.upper().startswith("U") else "L"
-    F = cholesky(A, uplo, nb=nb, precision=precision, health=health)
-    X = cholesky_solve_after(F, B, uplo, nb=nb, precision=precision)
+    with jax.named_scope("factor"):
+        F = cholesky(A, uplo, nb=nb, precision=precision, health=health)
+    with jax.named_scope("sweeps"):
+        X = cholesky_solve_after(F, B, uplo, nb=nb, precision=precision)
     if not info:
         return X
     from ..resilience.health import factor_diag_info

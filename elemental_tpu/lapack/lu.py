@@ -55,14 +55,16 @@ n=16384 (each entry of the Schur complement accumulates bf16 rounding
 growth bound but well above the f32 default.  Leave it ``None`` for
 bit-equivalent-to-classic factors.
 
-Phase timing (``timer``)
-------------------------
-Pass a ``perf.phase_timer.PhaseTimer``-shaped object (``start()`` +
-``tick(phase, step, *arrays)``) and call ``lu`` EAGERLY (outside jit): the
-driver synchronizes at every panel / swap / solve / update boundary and the
-timer attributes per-step wall-clock.  ``python perf/ab_harness.py phases``
-emits the resulting JSON.  With ``timer=None`` (default) the hooks are
-dead code and the driver jits as one fused program.
+Phases (``timer``)
+------------------
+The driver marks panel / swap / solve / update (/tail) with the scoped
+form of its hook (``with tm.phase(phase, k)``, :mod:`elemental_tpu.obs`).
+With ``timer=None`` (default) the driver jits as one fused program whose
+ops carry ``el.lu/k<step>/<phase>`` in their names: a device trace is
+split by them.  Pass an ``elemental_tpu.obs.PhaseTimer`` and call ``lu``
+EAGERLY (outside jit) and the same blocks also synchronize at every
+boundary and charge per-step wall-clock; ``python perf/ab_harness.py
+phases`` emits the resulting JSON.
 
 Data-dependent pivots are traced values, so the whole factorization jits;
 the packed L\\U layout and the permutation-vector convention follow LAPACK
@@ -119,10 +121,11 @@ def _hi(precision):
     return precision if precision is not None else lax.Precision.HIGHEST
 
 
-# The zero-overhead null tick hook and the driver-entry hook resolver now
-# live in the observability subsystem (ISSUE 5); the historical name is
-# kept for this module's importers (cholesky, tests).
-from ..obs.tracer import NULL_HOOK as _NULL_TIMER, phase_hook as _phase_hook
+# The null hook, the driver-entry hook resolver and the driver-scope
+# decorator live in the observability subsystem; cholesky, qr and abft
+# import ``_phase_hook`` from here.
+from ..obs.tracer import (NULL_HOOK, phase_hook as _phase_hook,
+                          scoped as _scoped)
 
 
 # ---------------------------------------------------------------------
@@ -550,65 +553,76 @@ def _local_lu_array(a, m: int, n: int, ib: int, precision,
     kend = min(m, n)
     perm = jnp.arange(m)
     upd = precision if update_precision is None else update_precision
-    tm = timer if timer is not None else _NULL_TIMER
+    tm = timer if timer is not None else NULL_HOOK
     tm.start()
     if lookahead:
         w0 = min(ib, kend)
-        nxt = _panel_dispatch(a[:, :w0], w0, precision, plan)
-        tm.tick("panel", 0, nxt)
+        with tm.phase("panel", 0) as ph:
+            nxt = _panel_dispatch(a[:, :w0], w0, precision, plan)
+            ph.done(nxt)
     for k, s in enumerate(range(0, kend, ib)):
         e = min(s + ib, kend)
         nbw = e - s
         if lookahead:
             Pf, pperm = nxt
         else:
-            Pf, pperm = _panel_dispatch(a[s:, s:e], nbw, precision, plan)
-            tm.tick("panel", k, Pf, pperm)
-        perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
-        # full trailing-block gather + contiguous writeback (TPU scatters
-        # of dynamic row sets benchmark SLOWER than this full gather).
-        # Memory: the rows are gathered straight from ``a`` (no a[s:]
-        # slice copy), and pperm is a permutation, always in bounds, so
-        # mode='clip' (the default 'fill' adds a select over a second
-        # copy of the block).  Each was a 4 GiB temp at N = 32768, and
-        # together they put lu_solve past a 16 GB chip.
-        a = a.at[s:].set(jnp.take(a, s + pperm, axis=0, mode="clip"))
-        tm.tick("swap", k, a)
-        a = a.at[s:, s:e].set(Pf)
+            with tm.phase("panel", k) as ph:
+                Pf, pperm = _panel_dispatch(a[s:, s:e], nbw, precision, plan)
+                ph.done(Pf, pperm)
+        with tm.phase("swap", k) as ph:
+            perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
+            # full trailing-block gather + contiguous writeback (TPU
+            # scatters of dynamic row sets benchmark SLOWER than this full
+            # gather).  Memory: the rows are gathered straight from ``a``
+            # (no a[s:] slice copy), and pperm is a permutation, always in
+            # bounds, so mode='clip' (the default 'fill' adds a select
+            # over a second copy of the block).  Each was a 4 GiB temp at
+            # N = 32768, and together they put lu_solve past a 16 GB chip.
+            a = a.at[s:].set(jnp.take(a, s + pperm, axis=0, mode="clip"))
+            ph.done(a)
+        with tm.phase("panel", k):
+            a = a.at[s:, s:e].set(Pf)
         if e >= n:
             continue
-        Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw], -1)
-                               + jnp.eye(nbw, dtype=a.dtype),
-                               nbw, precision)
-        U1n = jnp.matmul(Li11, a[s:e, e:], precision=_hi(precision)
-                         ).astype(a.dtype)
-        tm.tick("solve", k, U1n)
+        with tm.phase("solve", k) as ph:
+            Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw], -1)
+                                   + jnp.eye(nbw, dtype=a.dtype),
+                                   nbw, precision)
+            U1n = jnp.matmul(Li11, a[s:e, e:], precision=_hi(precision)
+                             ).astype(a.dtype)
+            ph.done(U1n)
         if not lookahead or e >= kend:
-            a = a.at[s:e, e:].set(U1n)
+            with tm.phase("solve", k):
+                a = a.at[s:e, e:].set(U1n)
             if e < m:
-                u = jnp.matmul(Pf[nbw:], U1n, precision=upd)
-                a = a.at[e:, e:].set(a[e:, e:] - u.astype(a.dtype))
-                tm.tick("update", k, a)
+                with tm.phase("update", k) as ph:
+                    u = jnp.matmul(Pf[nbw:], U1n, precision=upd)
+                    a = a.at[e:, e:].set(a[e:, e:] - u.astype(a.dtype))
+                    ph.done(a)
             continue
         # look-ahead: (a) narrow strip update -> factor panel k+1 off the
         # critical path -> (b) wide remainder update.  Both updates read
         # the pre-writeback ``a``, so XLA sees them as independent.
         e2 = min(e + ib, kend)
         w = e2 - e
-        L21 = Pf[nbw:]
-        strip = a[e:, e:e2] - jnp.matmul(L21, U1n[:, :w],
-                                         precision=upd).astype(a.dtype)
-        nxt = _panel_dispatch(strip, w, precision, plan)
-        tm.tick("panel", k + 1, nxt)
-        a = a.at[s:e, e:].set(U1n)
-        if e2 < n:
-            rest = a[e:, e2:] - jnp.matmul(L21, U1n[:, w:],
-                                           precision=upd).astype(a.dtype)
-            a = a.at[e:, e2:].set(rest)
-        # the strip region a[e:, e:e2] is dead from here on: step k+1's
-        # swap + panel writeback fully overwrite it, so skipping its
-        # writeback saves one (m-e) x nb store per step
-        tm.tick("update", k, a)
+        with tm.phase("update", k):
+            L21 = Pf[nbw:]
+            strip = a[e:, e:e2] - jnp.matmul(L21, U1n[:, :w],
+                                             precision=upd).astype(a.dtype)
+        with tm.phase("panel", k + 1) as ph:
+            nxt = _panel_dispatch(strip, w, precision, plan)
+            ph.done(nxt)
+        with tm.phase("solve", k):
+            a = a.at[s:e, e:].set(U1n)
+        with tm.phase("update", k) as ph:
+            if e2 < n:
+                rest = a[e:, e2:] - jnp.matmul(L21, U1n[:, w:],
+                                               precision=upd).astype(a.dtype)
+                a = a.at[e:, e2:].set(rest)
+            # the strip region a[e:, e:e2] is dead from here on: step k+1's
+            # swap + panel writeback fully overwrite it, so skipping its
+            # writeback saves one (m-e) x nb store per step
+            ph.done(a)
     return a, perm
 
 
@@ -621,6 +635,7 @@ def _local_lu_array(a, m: int, n: int, ib: int, precision,
 _CROSSOVER = 4096
 
 
+@_scoped("el.lu")
 def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
        update_precision=None, lookahead: bool | str = True,
        crossover: int | str | None = None, panel: str = "classic",
@@ -644,7 +659,7 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
     ``L21 @ U12``
     updates (e.g. ``lax.Precision.DEFAULT`` for bf16-MXU throughput at a
     documented ~1e-3 residual cost); ``timer`` enables eager per-phase
-    wall-clock attribution (see ``perf/phase_timer.py``).
+    wall-clock attribution (``elemental_tpu.obs.PhaseTimer``).
 
     ``panel`` selects the panel strategy:
 
@@ -784,8 +799,9 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
         if not calu or Ploc.shape[0] <= w:
             Pf, pperm = _panel_dispatch(Ploc, w, precision, plan)
         else:
-            pperm = _tournament_pivots(Ploc, w, r)
-            tm.tick("tournament", step, pperm)
+            with tm.phase("tournament", step) as ph:
+                pperm = _tournament_pivots(Ploc, w, r)
+                ph.done(pperm)
             Pp = jnp.take(Ploc, pperm, axis=0)
             Pf = _nopiv_panel(Pp, w, precision)
         Pf, = apply_fault("compute", (Pf,))
@@ -805,13 +821,23 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
         # every view to a legal boundary and column-masking the writebacks.
         return min(-(-e // c) * c, n)
 
+    cp = comm_precision
+
+    def gather_and_factor(step, src, rows, cols, w):
+        # the panel's columns (a view of ``src``; all of it when ``rows``
+        # is None) gathered to every device, then the replicated panel
+        # factorization
+        with tm.phase("panel", step) as ph:
+            if rows is not None:
+                src = view(src, rows=rows, cols=cols)
+            pan = redistribute(src, STAR, STAR, comm_precision=cp, path=rp)
+            out = factor_panel(pan.local[:, :w], w, step)
+            ph.done(*out)
+        return out
+
     if lookahead:
-        e0_up = col_up(min(ib, kend))
-        panel0 = redistribute(view(A, rows=(0, m), cols=(0, e0_up)),
-                              STAR, STAR, comm_precision=comm_precision,
-                              path=rp)
-        nxt = factor_panel(panel0.local[:, :min(ib, kend)], min(ib, kend), 0)
-        tm.tick("panel", 0, nxt)
+        w0 = min(ib, kend)
+        nxt = gather_and_factor(0, A, (0, m), (0, col_up(w0)), w0)
     for k, s in enumerate(range(0, kend, ib)):
         e = min(s + ib, kend)
         nbw = e - s
@@ -824,67 +850,68 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
         if lookahead:
             Pf, pperm = nxt
         else:
-            panel = redistribute(view(A, rows=(s, m), cols=(s, e_up)),
-                                 STAR, STAR,
-                                 comm_precision=comm_precision, path=rp)
-            Pf, pperm = factor_panel(panel.local[:, :nbw], nbw, k)
-            tm.tick("panel", k, Pf, pperm)
-        perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
-        # move only the rows the panel permutation displaced (<= 2*nbw)
-        # across ALL columns (the panel region is overwritten right after)
-        idx, src = _moved_rows(pperm, nbw)
-        valid = idx < (m - s)
-        A = _apply_swaps_moved(A, idx + s, jnp.clip(src, 0, m - s - 1) + s,
-                               valid)
-        tm.tick("swap", k, A)
+            Pf, pperm = gather_and_factor(k, A, (s, m), (s, e_up), nbw)
+        with tm.phase("swap", k) as ph:
+            perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
+            # move only the rows the panel permutation displaced (<= 2*nbw)
+            # across ALL columns (the panel region is overwritten right
+            # after)
+            idx, src = _moved_rows(pperm, nbw)
+            valid = idx < (m - s)
+            A = _apply_swaps_moved(A, idx + s,
+                                   jnp.clip(src, 0, m - s - 1) + s, valid)
+            ph.done(A)
         # write back the factored panel (rows s..m of cols s..e)
-        if e_up > e:
-            Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
-        else:
-            Pf_w = Pf
-        Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
-        A = _update_cols_lt(A, redistribute(Pf_ss, MC, MR), (s, m), (s, e_up), e)
+        with tm.phase("panel", k):
+            if e_up > e:
+                Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
+            else:
+                Pf_w = Pf
+            Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
+            A = _update_cols_lt(A, redistribute(Pf_ss, MC, MR), (s, m),
+                                (s, e_up), e)
         # U12 := L11^{-1} A12 ; A22 -= L21 U12.  The solve runs over the full
         # legal column range (s, n) and the writeback keeps only cols >= e.
         if e >= n:
             continue
-        Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw, :], -1)
-                               + jnp.eye(nbw, dtype=Pf.dtype),
-                               nbw, precision)
-        if calu:
-            # one-psum row-block solve: the contraction over the block's
-            # rows distributes across grid rows and a single psum lands
-            # [STAR,MR] -- one round instead of the classic all_to_all +
-            # all_gather pair below
-            U1n_mr = _rowblock_solve_jit(
-                view(A, rows=(s, e), cols=(s, n)), Li11, _hi(precision),
-                "bf16" if comm_precision and quantizable(A.dtype) else None)
-        else:
-            A1n = redistribute(view(A, rows=(s, e), cols=(s, n)),
-                               STAR, VR, comm_precision=comm_precision,
-                               path=rp)
-            u1n = jnp.matmul(Li11, A1n.local, precision=_hi(precision)
-                             ).astype(Pf.dtype)
-            U1n = DistMatrix(u1n, (nbw, n - s), STAR, VR, 0, 0, g)
-            U1n_mr = redistribute(U1n, STAR, MR,
-                                  comm_precision=comm_precision, path=rp)
-        tm.tick("solve", k, U1n_mr)
+        with tm.phase("solve", k) as ph:
+            Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw, :], -1)
+                                   + jnp.eye(nbw, dtype=Pf.dtype),
+                                   nbw, precision)
+            if calu:
+                # one-psum row-block solve: the contraction over the
+                # block's rows distributes across grid rows and a single
+                # psum lands [STAR,MR] -- one round instead of the classic
+                # all_to_all + all_gather pair below
+                U1n_mr = _rowblock_solve_jit(
+                    view(A, rows=(s, e), cols=(s, n)), Li11, _hi(precision),
+                    "bf16" if cp and quantizable(A.dtype) else None)
+            else:
+                A1n = redistribute(view(A, rows=(s, e), cols=(s, n)),
+                                   STAR, VR, comm_precision=cp, path=rp)
+                u1n = jnp.matmul(Li11, A1n.local, precision=_hi(precision)
+                                 ).astype(Pf.dtype)
+                U1n = DistMatrix(u1n, (nbw, n - s), STAR, VR, 0, 0, g)
+                U1n_mr = redistribute(U1n, STAR, MR, comm_precision=cp,
+                                      path=rp)
+            ph.done(U1n_mr)
         if not lookahead or e >= kend:
-            A = _update_cols_ge(A, redistribute(U1n_mr, MC, MR), (s, e),
-                                (s, n), e)
+            with tm.phase("solve", k):
+                A = _update_cols_ge(A, redistribute(U1n_mr, MC, MR), (s, e),
+                                    (s, n), e)
             if e < m:      # only non-final panels: e is stride-aligned here
-                U12_mr = view(U1n_mr, cols=(e - s, n - s))
-                L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR, STAR,
-                                    0, 0, g)
-                L21_mc = redistribute(L21_ss, MC, STAR)
-                A = local_rank_update(A, L21_mc.local, U12_mr.local,
-                                      rows=(e, m), cols=(e, n),
-                                      precision=upd)
-                tm.tick("update", k, A)
+                with tm.phase("update", k) as ph:
+                    U12_mr = view(U1n_mr, cols=(e - s, n - s))
+                    L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR,
+                                        STAR, 0, 0, g)
+                    L21_mc = redistribute(L21_ss, MC, STAR)
+                    A = local_rank_update(A, L21_mc.local, U12_mr.local,
+                                          rows=(e, m), cols=(e, n),
+                                          precision=upd)
+                    ph.done(A)
             if tail:
                 A, perm = _lu_tail(A, perm, e, ib, precision, upd,
-                                   lookahead, tm, k, comm_precision, rp,
-                                   plan)
+                                   lookahead, tm, k, cp, rp, plan)
                 break
             continue
         # look-ahead: split the trailing update at the next panel boundary.
@@ -893,40 +920,42 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
         # independent and free to overlap.
         e2 = min(e + ib, kend)
         e2_up = col_up(e2)
-        L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR, STAR, 0, 0, g)
-        L21_mc = redistribute(L21_ss, MC, STAR)
-        U12a = view(U1n_mr, cols=(e - s, e2_up - s))
-        A22a = view(A, rows=(e, m), cols=(e, e2_up))
-        stripD = A22a.with_local(
-            A22a.local - jnp.matmul(L21_mc.local, U12a.local,
-                                    precision=upd).astype(A.dtype))
+        with tm.phase("update", k):
+            L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR, STAR, 0, 0,
+                                g)
+            L21_mc = redistribute(L21_ss, MC, STAR)
+            U12a = view(U1n_mr, cols=(e - s, e2_up - s))
+            A22a = view(A, rows=(e, m), cols=(e, e2_up))
+            stripD = A22a.with_local(
+                A22a.local - jnp.matmul(L21_mc.local, U12a.local,
+                                        precision=upd).astype(A.dtype))
         if not tail:
             # factor panel k+1 from the freshly updated strip (gshape
             # already (m-e, e2_up-e) from the view metadata); skipped when
             # the tail finish below refactors the whole trailing block
-            strip_ss = redistribute(stripD, STAR, STAR,
-                                    comm_precision=comm_precision, path=rp)
-            nxt = factor_panel(strip_ss.local[:, :e2 - e], e2 - e, k + 1)
-            tm.tick("panel", k + 1, nxt)
-        # (b) wide remainder update, cols >= e2_up
-        if e2_up < n:
-            U12b = view(U1n_mr, cols=(e2_up - s, n - s))
-            A22b = view(A, rows=(e, m), cols=(e2_up, n))
-            restD = A22b.with_local(
-                A22b.local - jnp.matmul(L21_mc.local, U12b.local,
-                                        precision=upd).astype(A.dtype))
-        else:
-            restD = None
+            nxt = gather_and_factor(k + 1, stripD, None, None, e2 - e)
+        with tm.phase("update", k):
+            # (b) wide remainder update, cols >= e2_up
+            if e2_up < n:
+                U12b = view(U1n_mr, cols=(e2_up - s, n - s))
+                A22b = view(A, rows=(e, m), cols=(e2_up, n))
+                restD = A22b.with_local(
+                    A22b.local - jnp.matmul(L21_mc.local, U12b.local,
+                                            precision=upd).astype(A.dtype))
+            else:
+                restD = None
         # writebacks (U row block, strip, remainder)
-        A = _update_cols_ge(A, redistribute(U1n_mr, MC, MR), (s, e),
-                            (s, n), e)
-        A = update_view(A, stripD, rows=(e, m), cols=(e, e2_up))
-        if restD is not None:
-            A = update_view(A, restD, rows=(e, m), cols=(e2_up, n))
-        tm.tick("update", k, A)
+        with tm.phase("solve", k):
+            A = _update_cols_ge(A, redistribute(U1n_mr, MC, MR), (s, e),
+                                (s, n), e)
+        with tm.phase("update", k) as ph:
+            A = update_view(A, stripD, rows=(e, m), cols=(e, e2_up))
+            if restD is not None:
+                A = update_view(A, restD, rows=(e, m), cols=(e2_up, n))
+            ph.done(A)
         if tail:
             A, perm = _lu_tail(A, perm, e, ib, precision, upd, lookahead,
-                               tm, k, comm_precision, rp, plan)
+                               tm, k, cp, rp, plan)
             break
     if hm is not None:
         hm.report()
@@ -946,19 +975,21 @@ def _lu_tail(A: DistMatrix, perm, e: int, ib: int, precision, upd,
     collective latency collapse into a single round trip."""
     m, n = A.gshape
     g = A.grid
-    Atail = redistribute(view(A, rows=(e, m), cols=(e, n)), STAR, STAR,
-                         comm_precision=comm_precision, path=redist_path)
-    at, pt = _local_lu_array(Atail.local, m - e, n - e, ib, precision,
-                             upd, lookahead, plan=plan)
-    # the tail's composed row permutation applies to the WHOLE row range
-    # (the left factored columns must see the same swaps); cols >= e are
-    # overwritten by the factored-tail writeback right after
-    A = _apply_swaps_moved(A, jnp.arange(m - e) + e, pt + e,
-                           jnp.ones(m - e, dtype=bool))
-    At_ss = DistMatrix(at, (m - e, n - e), STAR, STAR, 0, 0, g)
-    A = update_view(A, redistribute(At_ss, MC, MR), rows=(e, m), cols=(e, n))
-    perm = perm.at[e:].set(jnp.take(perm[e:], pt, axis=0))
-    tm.tick("tail", k, A)
+    with tm.phase("tail", k) as ph:
+        Atail = redistribute(view(A, rows=(e, m), cols=(e, n)), STAR, STAR,
+                             comm_precision=comm_precision, path=redist_path)
+        at, pt = _local_lu_array(Atail.local, m - e, n - e, ib, precision,
+                                 upd, lookahead, plan=plan)
+        # the tail's composed row permutation applies to the WHOLE row
+        # range (the left factored columns must see the same swaps); cols
+        # >= e are overwritten by the factored-tail writeback right after
+        A = _apply_swaps_moved(A, jnp.arange(m - e) + e, pt + e,
+                               jnp.ones(m - e, dtype=bool))
+        At_ss = DistMatrix(at, (m - e, n - e), STAR, STAR, 0, 0, g)
+        A = update_view(A, redistribute(At_ss, MC, MR), rows=(e, m),
+                        cols=(e, n))
+        perm = perm.at[e:].set(jnp.take(perm[e:], pt, axis=0))
+        ph.done(A)
     return A, perm
 
 
@@ -983,6 +1014,7 @@ def _update_cols_ge(A, block, rows, cols, e):
     return _blend_update(A, block, rows, cols, lambda J: J >= e - cols[0])
 
 
+@_scoped("el.lu_solve")
 def lu_solve(A: DistMatrix, B: DistMatrix, nb: int | None = None,
              precision=None, panel: str = "classic", info: bool = False,
              health=None):
@@ -999,9 +1031,11 @@ def lu_solve(A: DistMatrix, B: DistMatrix, nb: int | None = None,
     ``health`` forwards to :func:`lu` (the resilience guards).  For the
     full residual-certified path use
     ``elemental_tpu.resilience.certified_solve('lu', A, B)``."""
-    LU_, perm = lu(A, nb=nb, precision=precision, panel=panel,
-                   health=health)
-    X = lu_solve_after(LU_, perm, B, nb=nb, precision=precision)
+    with jax.named_scope("factor"):
+        LU_, perm = lu(A, nb=nb, precision=precision, panel=panel,
+                       health=health)
+    with jax.named_scope("sweeps"):
+        X = lu_solve_after(LU_, perm, B, nb=nb, precision=precision)
     if not info:
         return X
     from ..resilience.health import factor_diag_info

@@ -33,7 +33,7 @@ from ..core.compat import shard_map
 from ..redist.engine import apply_fault, redistribute
 from ..blas.level3 import _blocksize, _check_mcmr, trsm
 from .lu import (_update_cols_lt, _update_cols_ge, _hi, _phase_hook,
-                 _nopiv_panel)
+                 _nopiv_panel, _scoped)
 
 
 # ---------------------------------------------------------------------
@@ -207,6 +207,7 @@ def _panel_qr_tsqr(P, r: int, precision=None):
 # blocked Householder QR
 # ---------------------------------------------------------------------
 
+@_scoped("el.qr")
 def qr(A: DistMatrix, nb: int | str | None = None, precision=None,
        panel: str = "classic", panel_impl: str | None = None,
        comm_precision: str | None = None,
@@ -319,37 +320,44 @@ def qr(A: DistMatrix, nb: int | str | None = None, precision=None,
         e = min(s + ib, kend)
         nbw = e - s
         e_up = min(-(-e // c) * c, n)
-        panel_ss = redistribute(view(A, rows=(s, m), cols=(s, e_up)),
-                                STAR, STAR,
-                                comm_precision=comm_precision,
-                                path=redist_path)
-        Tk = None
-        if panel == "tsqr":
-            Pf, tau = _panel_qr_tsqr(panel_ss.local[:, :nbw], r, precision)
-        else:
-            Pf, tau, Tk = _panel_qr_dispatch(panel_ss.local[:, :nbw], plan)
-        Pf, = apply_fault("compute", (Pf,))
-        taus.append(tau)
-        tm.tick("panel", k, Pf, tau)
-        if e_up > e:
-            Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
-        else:
-            Pf_w = Pf
-        Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
-        A = _update_cols_lt(A, redistribute(Pf_ss, MC, MR), (s, m), (s, e_up), e)
+        with tm.phase("panel", k) as ph:
+            panel_ss = redistribute(view(A, rows=(s, m), cols=(s, e_up)),
+                                    STAR, STAR,
+                                    comm_precision=comm_precision,
+                                    path=redist_path)
+            Tk = None
+            if panel == "tsqr":
+                Pf, tau = _panel_qr_tsqr(panel_ss.local[:, :nbw], r,
+                                         precision)
+            else:
+                Pf, tau, Tk = _panel_qr_dispatch(panel_ss.local[:, :nbw],
+                                                 plan)
+            Pf, = apply_fault("compute", (Pf,))
+            taus.append(tau)
+            ph.done(Pf, tau)
+        with tm.phase("panel", k):
+            if e_up > e:
+                Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
+            else:
+                Pf_w = Pf
+            Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
+            A = _update_cols_lt(A, redistribute(Pf_ss, MC, MR), (s, m),
+                                (s, e_up), e)
         if e < n:
-            V = _panel_v(Pf)
-            T = Tk if Tk is not None else _larft(V, tau)
-            V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
-            V_mc = redistribute(V_ss, MC, STAR)
-            A2 = view(A, rows=(s, m), cols=(s, n))
-            W = jnp.matmul(jnp.conj(V_mc.local).T, A2.local,
-                           precision=_hi(precision))          # [STAR,MR] storage
-            W = jnp.matmul(jnp.conj(T).T, W, precision=_hi(precision))
-            upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
-            A = _update_cols_ge(A, A2.with_local(A2.local - upd.astype(A.dtype)),
-                                (s, m), (s, n), e)
-            tm.tick("update", k, A)
+            with tm.phase("update", k) as ph:
+                V = _panel_v(Pf)
+                T = Tk if Tk is not None else _larft(V, tau)
+                V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
+                V_mc = redistribute(V_ss, MC, STAR)
+                A2 = view(A, rows=(s, m), cols=(s, n))
+                W = jnp.matmul(jnp.conj(V_mc.local).T, A2.local,
+                               precision=_hi(precision))   # [STAR,MR] storage
+                W = jnp.matmul(jnp.conj(T).T, W, precision=_hi(precision))
+                upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
+                A = _update_cols_ge(
+                    A, A2.with_local(A2.local - upd.astype(A.dtype)),
+                    (s, m), (s, n), e)
+                ph.done(A)
     _record_qr_nb(A, ib)
     if hm is not None:
         hm.report()

@@ -5,15 +5,16 @@ Four pieces, one subsystem -- the layer every perf PR reports through:
   :mod:`.tracer`       span tracer: ``Tracer`` (explicit nested spans via
                        ``span()``, driver tick channels, engine collective
                        observer) + :func:`phase_hook`, the one-line driver
-                       integration all six tuned drivers call
+                       integration all six tuned drivers call, and the
+                       hook's scoped form ``tm.phase(phase, step)``: the
+                       ONE way a driver marks a phase, in both modes
   :mod:`.metrics`      counters / gauges / histograms ->
                        ``obs_metrics/v1`` (op invocation counts,
                        redistribute calls/bytes, tuning-cache events,
                        phase-time histograms)
-  :mod:`.phase_timer`  ``PhaseTimer`` -- the historical per-phase
-                       attribution tool, now a shim over the tracer
-                       (``phase_timings/v1`` unchanged;
-                       ``perf.phase_timer`` re-exports from here)
+  :mod:`.phase_timer`  ``PhaseTimer`` -- per-phase wall-clock of an
+                       EAGER run, a shim over the tracer
+                       (``phase_timings/v1`` unchanged)
   :mod:`.export`       Chrome-trace/Perfetto ``trace.json`` rendering
                        (thread-keyed tracks + request flow events)
 
@@ -27,6 +28,46 @@ Fleet request telemetry (ISSUE 20) adds three serving-tier modules:
   :mod:`.flight`       fault-triggered flight recorder ->
                        ``flight_record/v1``
 
+Scope grammar (stable; what a device trace of a COMPILED run is split by)
+-------------------------------------------------------------------------
+Under ``jax.jit`` nothing times a phase, but every phase still names its
+ops: the scoped form always enters ``jax.named_scope`` (trace-time
+metadata only; the optimized HLO is identical with and without it, once
+``metadata={...}`` is stripped), and the names reach the compiled
+program as ``metadata={op_name="jit(f)/.../el.cholesky/k03/update/..."}``,
+where xprof / Perfetto show them and ``benchmark/scopes.py`` sums device
+time by them.  A path is made of:
+
+  ``el.<driver>``          a public driver: ``cholesky``, ``lu``, ``qr``,
+                           ``gemm``, ``herk``, ``trsm`` (:func:`scoped`);
+                           the one-device paths carry the same names
+  ``k<step>/<phase>``      inside a driver: the step, two digits or more
+                           (``k03``, ``k117``), then a phase of
+                           :data:`PHASES` -- ``diag``, ``panel``,
+                           ``swap``, ``solve``, ``spread``, ``update``,
+                           ``tail`` (CALU adds ``tournament``, the serve
+                           loop ``batch``).  A nested driver or local
+                           finish nests its own (``k14/tail/k00/diag``):
+                           the FIRST ``k<step>`` gives an op its phase
+  ``el.hpd_solve`` /       the public solves, which open ``factor`` and
+  ``el.lu_solve``          ``sweeps`` around their two stages, so a sweep
+                           reads ``el.hpd_solve/sweeps/el.trsm/k02/solve``
+  ``el.redist.<SRC>.to.<DST>``  every public ``redistribute`` entry
+                           (``el.redist.MC_MR.to.VC_STAR``), around ALL
+                           it emits: the collectives and the local pack /
+                           unpack / reshape / copy beside them;
+                           ``el.redist.panel_spread`` and
+                           ``el.redist.row_permute`` likewise
+  ``el_potrf_inv_panel`` / the ``name=`` of the three Pallas panel
+  ``el_lu_panel`` /        kernels (``kernels/``), which is how a trace
+  ``el_qr_panel``          shows a ``pallas_call``
+
+An op in an ``el.`` scope but outside any phase (a driver's final
+assembly or mask) belongs to the driver; an op with no ``el.`` segment
+was made by the compiler or was not named.  The persistent compile cache
+is keyed with these names (``core/compile_cache.py``), so an executable
+from the cache carries this build's.
+
 CLI: ``python -m perf.trace {run,summary,export,serve}``.  Regression
 gate over the bench trajectory: ``tools/bench_diff.py`` (wired into
 ``tools/check.sh``).
@@ -37,8 +78,8 @@ from .metrics import (SCHEMA as METRICS_SCHEMA, FAMILIES as HIST_FAMILIES,
                       hist_family, inc, observe, set_gauge,
                       set_hist_family)
 from .tracer import (TRACE_SCHEMA, CommEvent, InstantEvent, NullHook,
-                     NULL_HOOK, PhaseRecord, Span, Tracer, active_tracer,
-                     phase_hook, ring_bytes)
+                     NULL_HOOK, PhaseHook, PhaseRecord, Span, Tracer,
+                     active_tracer, phase_hook, ring_bytes, scoped)
 from .phase_timer import PHASES, SCHEMA as PHASE_TIMINGS_SCHEMA, PhaseTimer
 from .export import (CHROME_SCHEMA, chrome_trace_doc,
                      phase_timings_to_chrome, write_json)
@@ -52,8 +93,8 @@ __all__ = [
     "current_metrics", "metrics_scope", "hist_family", "inc", "observe",
     "set_gauge", "set_hist_family",
     "TRACE_SCHEMA", "CommEvent", "InstantEvent", "NullHook", "NULL_HOOK",
-    "PhaseRecord", "Span", "Tracer", "active_tracer", "phase_hook",
-    "ring_bytes",
+    "PhaseHook", "PhaseRecord", "Span", "Tracer", "active_tracer",
+    "phase_hook", "ring_bytes", "scoped",
     "PHASES", "PHASE_TIMINGS_SCHEMA", "PhaseTimer",
     "CHROME_SCHEMA", "chrome_trace_doc", "phase_timings_to_chrome",
     "write_json",

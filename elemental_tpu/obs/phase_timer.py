@@ -1,24 +1,22 @@
 """``PhaseTimer``: per-phase wall-clock attribution (``phase_timings/v1``).
 
-The original ``perf/phase_timer.py`` implementation, now a THIN SHIM over
-the span tracer (:mod:`.tracer`): ticks land as the tracer's
-:class:`~elemental_tpu.obs.tracer.PhaseRecord` intervals and the report
-aggregates them into the byte-identical ``phase_timings/v1`` document the
-old standalone class produced (``tests/perf/test_phase_smoke.py`` pins
-the schema; ``perf.phase_timer`` re-exports everything here for its
-historical importers).
+A THIN SHIM over the span tracer (:mod:`.tracer`): ticks land as the
+tracer's :class:`~elemental_tpu.obs.tracer.PhaseRecord` intervals and the
+report aggregates them into the ``phase_timings/v1`` document
+(``tests/perf/test_phase_smoke.py`` pins the schema).
 
-Any driver that accepts a ``timer`` argument calls
-``timer.tick(phase, step, *arrays)`` at its phase boundaries.  The timer
-synchronizes on the phase's outputs (``jax.block_until_ready``) and
-charges the elapsed wall-clock since the previous tick to
-``(phase, step)``, so a run yields a machine-readable breakdown per
-blocked step.
+Any driver that accepts a ``timer`` argument closes its phases through
+the hook's scoped form (``with timer.phase(phase, step) as ph: ...;
+ph.done(*arrays)``), whose exit calls ``timer.tick(phase, step,
+*arrays)``.  The timer synchronizes on the phase's outputs
+(``jax.block_until_ready``) and charges the elapsed wall-clock since the
+previous tick to ``(phase, step)``, so a run yields a machine-readable
+breakdown per blocked step.
 
-Usage (EAGER -- wrapping the driver in jit would fuse the phases away and
-make the ticks no-ops on tracers)::
+Usage (EAGER -- under jit the driver fuses into one program; its phases
+then show in a device trace by their scope names, not here)::
 
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     t = PhaseTimer()
     LU, perm = el.lu(A, nb=2048, timer=t)
     print(t.json(driver="lu", n=n, nb=2048))
@@ -45,7 +43,7 @@ from __future__ import annotations
 
 import json
 
-from .tracer import Tracer
+from .tracer import PhaseHook, Tracer
 
 SCHEMA = "phase_timings/v1"
 
@@ -55,7 +53,7 @@ SCHEMA = "phase_timings/v1"
 PHASES = ("diag", "panel", "swap", "solve", "spread", "update", "tail")
 
 
-class PhaseTimer:
+class PhaseTimer(PhaseHook):
     """Accumulates (phase, step, seconds) records from a driver's ticks.
 
     Backed by a private (metrics-silent) :class:`Tracer` whose tick

@@ -1,7 +1,25 @@
 """Runtime span tracer: nested spans, driver phase hooks, collective events.
 
-The structural half of the observability subsystem (ISSUE 5).  A
-:class:`Tracer` records three kinds of evidence from ONE eager run:
+The structural half of the observability subsystem (ISSUE 5).  Every
+driver marks its phases ONE way, the scoped form of the hook that
+:func:`phase_hook` hands it::
+
+    with tm.phase("panel", k) as ph:
+        ...the phase's work...
+        ph.done(L21_vc)
+
+That form works in both modes.  It always enters
+``jax.named_scope("k<step>/<phase>")`` (trace-time metadata only), so
+under ``jax.jit`` -- where the hook is :data:`NULL_HOOK` -- the phase
+still names its ops in the compiled program and a device trace can be
+split by phase (grammar in :mod:`elemental_tpu.obs`).  When a
+``PhaseTimer`` or an active :class:`Tracer` stands behind the hook,
+``ph.done(*arrays)`` makes the block's exit also ``tick`` the hook: the
+host-clock protocol below, for EAGER runs.  A block left without
+``done`` (a naming-only scope, a ``continue``, an exception) ticks
+nothing.
+
+A :class:`Tracer` records three kinds of evidence from ONE eager run:
 
   * explicit spans -- ``with tracer.span(name, sync=outputs, **attrs):``
     context-manager blocks that nest via a stack; ``sync`` takes the
@@ -9,13 +27,14 @@ The structural half of the observability subsystem (ISSUE 5).  A
     ``jax.block_until_ready`` on them, so the recorded wall-clock is
     honest under jax's async dispatch;
   * phase records -- the driver hooks.  Every tuned driver (``cholesky``,
-    ``lu``, ``qr``, ``gemm``, ``trsm``, ``herk``) calls the PhaseTimer
-    tick protocol (``start()`` + ``tick(phase, step, *arrays)``) at its
-    phase boundaries; a tracer-backed :class:`_TickChannel` turns those
-    ticks into (driver, phase, step, t0, t1) records, from which the
-    exporter synthesizes the driver -> step -> phase span nesting.
-    ``tick`` blocks on the phase's outputs exactly like the original
-    ``perf.phase_timer.PhaseTimer`` (which is now a shim over this);
+    ``lu``, ``qr``, ``gemm``, ``trsm``, ``herk``) arms its hook
+    (``start()``) and closes each phase through the scoped form, whose
+    exit calls ``tick(phase, step, *arrays)``; a tracer-backed
+    :class:`_TickChannel` turns those ticks into (driver, phase, step,
+    t0, t1) records, from which the exporter synthesizes the driver ->
+    step -> phase span nesting.  ``tick`` blocks on the phase's outputs
+    and charges the time since the previous tick, as
+    :class:`~elemental_tpu.obs.phase_timer.PhaseTimer` always did;
   * collective events -- while a tracer is ACTIVE (``with tracer:``), it
     registers an observer on the redistribution engine's trace hook, so
     every public ``redistribute``/``panel_spread`` entry lands as an
@@ -26,10 +45,9 @@ The structural half of the observability subsystem (ISSUE 5).  A
 Activation (``with tracer:``) also makes the tracer the process-current
 one, so :func:`phase_hook` -- the single line each driver runs at entry
 -- routes the driver's ticks here without any driver-level plumbing.
-Like the PhaseTimer it generalizes, the tracer is an EAGER-mode tool:
-under ``jax.jit`` the ticks see tracers and degrade to no-ops (the
-driver fuses into one program and there are no phase boundaries to
-time).
+The tracer's clock is an EAGER-mode tool: under ``jax.jit`` nothing is
+attached, the driver fuses into one program, and the phases leave their
+names (the scopes above) instead of host times.
 
 Metrics: unless constructed with ``metrics=False``, every phase record
 feeds a ``phase_seconds{driver,phase}`` histogram and every collective
@@ -42,6 +60,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 
@@ -146,8 +165,53 @@ def ring_bytes(gshape, dtype, grid_shape) -> int:
     return payload * (p - 1) // p
 
 
-class NullHook:
-    """Zero-overhead stand-in so drivers can call tick() unconditionally."""
+def scoped(name: str):
+    """Decorator: run the function inside ``jax.named_scope(name)`` (a
+    public driver's ``el.<driver>`` scope).  The scope is looked up at
+    call time, and costs nothing outside a trace."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
+class _Phase:
+    """One ``with hook.phase(phase, step) as ph:`` block."""
+    __slots__ = ("_hook", "_phase", "_step", "_arrays", "_scope")
+
+    def __init__(self, hook, phase, step):
+        self._hook = hook
+        self._phase = phase
+        self._step = step
+        self._arrays = None
+        self._scope = None
+
+    def done(self, *arrays):
+        """The phase is complete and ``arrays`` are its outputs: on the
+        block's exit the hook ticks (and blocks on them, when it times)."""
+        self._arrays = arrays
+
+    def __enter__(self):
+        self._scope = jax.named_scope(
+            f"k{int(self._step):02d}/{self._phase}")
+        self._scope.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._scope.__exit__(exc_type, exc, tb)
+        if exc_type is None and self._arrays is not None:
+            self._hook.tick(self._phase, self._step, *self._arrays)
+        return False
+
+
+class PhaseHook:
+    """What every hook a driver may hold offers: ``start()``, and the
+    scoped form ``phase(phase, step)``.  ``tick`` is what that form calls
+    on exit (and what forwarding hooks pass on), not a second way for a
+    driver to mark a phase."""
     __slots__ = ()
 
     def start(self):
@@ -156,11 +220,23 @@ class NullHook:
     def tick(self, phase, step, *arrays):
         pass
 
+    def phase(self, phase, step) -> _Phase:
+        """Scope the ops of ``(phase, step)``: ``k<step>/<phase>`` in the
+        compiled program's op names; with ``done(*arrays)`` inside the
+        block, its exit ticks this hook."""
+        return _Phase(self, phase, step)
+
+
+class NullHook(PhaseHook):
+    """Stand-in when nothing times the run (the hook ``jit`` gets): the
+    scoped form still names the phase, and its tick does nothing."""
+    __slots__ = ()
+
 
 NULL_HOOK = NullHook()
 
 
-class _TickChannel:
+class _TickChannel(PhaseHook):
     """One driver invocation's tick stream (PhaseTimer protocol)."""
     __slots__ = ("tracer", "driver", "attrs", "call", "_t")
 
@@ -186,7 +262,7 @@ class _TickChannel:
         self._t = now
 
 
-class _Fanout:
+class _Fanout(PhaseHook):
     """Tick fan-out: an explicit PhaseTimer AND the active tracer both see
     every tick (the first hook's block_until_ready makes the second ~free)."""
     __slots__ = ("hooks",)
@@ -411,14 +487,17 @@ def phase_hook(driver: str, timer=None, **attrs):
     metrics registry), then returns
 
       * the explicit ``timer`` when no tracer is active (classic
-        PhaseTimer usage, unchanged),
+        PhaseTimer usage, unchanged; a caller's own object that has only
+        ``start()`` and ``tick()`` is wrapped so that it, too, offers the
+        scoped form),
       * the active tracer's fresh channel when one is activated,
       * a fan-out over both when both are present,
-      * the shared :data:`NULL_HOOK` when neither -- drivers stay
-        zero-overhead dead code under jit, exactly like the old
-        ``_NULL_TIMER``.
+      * the shared :data:`NULL_HOOK` when neither -- what ``jit`` gets:
+        the scoped form names the phases and times nothing.
     """
     _metrics.inc("op_calls", op=driver)
+    if timer is not None and not hasattr(timer, "phase"):
+        timer = _Fanout((timer,))     # a bare start()/tick() object
     tr = _ACTIVE
     if tr is None:
         return timer if timer is not None else NULL_HOOK

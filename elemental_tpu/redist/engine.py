@@ -42,6 +42,7 @@ from ..core.dist import (
     stride as dist_stride, gather_axes, rank_of, md_slot_of_global,
 )
 from ..core.distmatrix import DistMatrix, _check_pair
+from ..obs.tracer import scoped as _scoped
 from .plan import compile_plan
 from .quantize import (QUANT_TILE, check_comm_precision, q8_pack, q8_unpack,
                        quantizable)
@@ -1168,7 +1169,8 @@ def panel_spread(A: DistMatrix, conj: bool = True, comm_precision=None):
                          f"panel, got {A}")
     REDIST_COUNTS["panel_spread"] += 1
     wire = _wire_mode(A, comm_precision, q8_ok=True)
-    mc, mr = _panel_spread_jit(A, conj, wire)
+    with jax.named_scope("el.redist.panel_spread"):
+        mc, mr = _panel_spread_jit(A, conj, wire)
     if _FAULT_INJECTOR is not None:
         lmc, lmr = _FAULT_INJECTOR.apply("panel_spread",
                                          (mc.local, mr.local))
@@ -1192,6 +1194,7 @@ def _storage_row_of(i, S: int, lr: int):
     return (i % S) * lr + i // S
 
 
+@_scoped("el.redist.row_permute")
 def move_rows(A: DistMatrix, targets, sources, valid) -> DistMatrix:
     """Move global rows ``sources`` to positions ``targets`` in ONE
     storage-level gather/scatter pass, dropping entries where ``valid`` is
@@ -1231,6 +1234,7 @@ def move_rows(A: DistMatrix, targets, sources, valid) -> DistMatrix:
     return out
 
 
+@_scoped("el.redist.row_permute")
 def permute_rows_storage(A: DistMatrix, perm, inverse: bool = False
                          ) -> DistMatrix:
     """``B[i] = A[perm[i]]`` as ONE storage-level gather for a zero-aligned
@@ -1388,6 +1392,17 @@ def redistribute(A: DistMatrix, cdist: Dist, rdist: Dist,
     _check_pair(cdist, rdist)
     if path not in REDIST_PATHS:
         raise ValueError(f"path must be one of {REDIST_PATHS}, got {path!r}")
+    with jax.named_scope(f"el.redist.{A.cdist.name}_{A.rdist.name}.to."
+                         f"{cdist.name}_{rdist.name}"):
+        return _redistribute(A, cdist, rdist, calign, ralign,
+                             comm_precision, path)
+
+
+def _redistribute(A: DistMatrix, cdist: Dist, rdist: Dist, calign: int,
+                  ralign: int, comm_precision, path) -> DistMatrix:
+    """:func:`redistribute` after its argument checks, inside its
+    ``el.redist.<SRC>.to.<DST>`` scope: the route, the collectives and the
+    local pack / unpack beside them."""
     REDIST_COUNTS[(A.dist, (cdist, rdist))] += 1
     grid_shape = (A.grid.height, A.grid.width)
     circ = cdist is CIRC or A.cdist is CIRC

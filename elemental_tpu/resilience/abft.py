@@ -369,6 +369,17 @@ def resolve_abft(abft) -> AbftGuard:
     return abft if isinstance(abft, AbftGuard) else AbftGuard()
 
 
+def _commit_phases(tm, k, phases) -> None:
+    """A committed step's ``(phase, output arrays)`` closed on the hook in
+    order, through the scoped form.  The step's ops were named as they
+    were traced (naming-only scopes in ``step_fn``); the timing ticks are
+    buffered per attempt and land here only after the step commits, so
+    health never sees a rolled-back attempt."""
+    for phase, arrays in phases:
+        with tm.phase(phase, k) as ph:
+            ph.done(*arrays)
+
+
 # ---------------------------------------------------------------------
 # guarded LU (classic right-looking schedule + per-panel transactions)
 # ---------------------------------------------------------------------
@@ -421,89 +432,94 @@ def abft_lu(A, nb=None, precision=None, update_precision=None,
         e = min(s + ib, kend)
         nbw = e - s
         e_up = col_up(e)
-        pan_v = view(A, rows=(s, m), cols=(s, e_up))
-        pan_sum = _colsum(pan_v)
-        pan_mass = _colsum(pan_v, absval=True)
-        panel = redistribute(pan_v, STAR, STAR, comm_precision=cp)
-        ploc = panel.local[:m - s, :e_up - s]
-        guard.check("panel_gather", pan_sum, jnp.sum(ploc, axis=0),
-                    mass=pan_mass, kind="transport", rows=m - s)
-        Pf, pperm = _panel_dispatch(ploc[:, :nbw], nbw, precision, plan)
-        Pf, = apply_fault("compute", (Pf,))
-        # factor invariant: colsums survive the panel's row permutation
-        cL = (jnp.sum(jnp.tril(Pf[:nbw], -1), axis=0)
-              + jnp.sum(Pf[nbw:], axis=0) + 1.0)
-        U11 = jnp.triu(Pf[:nbw])
-        guard.check("panel", jnp.matmul(cL, U11),
-                    jnp.sum(ploc[:, :nbw], axis=0),
-                    mass=jnp.sum(jnp.abs(ploc[:, :nbw]), axis=0),
-                    kind="compute", rows=m - s, nb=nbw)
-        perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
-        idx, src = _moved_rows(pperm, nbw)
-        valid = idx < (m - s)
-        A = _apply_swaps_moved(A, idx + s,
-                               jnp.clip(src, 0, m - s - 1) + s, valid)
+        with tm.phase("panel", k):
+            pan_v = view(A, rows=(s, m), cols=(s, e_up))
+            pan_sum = _colsum(pan_v)
+            pan_mass = _colsum(pan_v, absval=True)
+            panel = redistribute(pan_v, STAR, STAR, comm_precision=cp)
+            ploc = panel.local[:m - s, :e_up - s]
+            guard.check("panel_gather", pan_sum, jnp.sum(ploc, axis=0),
+                        mass=pan_mass, kind="transport", rows=m - s)
+            Pf, pperm = _panel_dispatch(ploc[:, :nbw], nbw, precision, plan)
+            Pf, = apply_fault("compute", (Pf,))
+            # factor invariant: colsums survive the panel's row permutation
+            cL = (jnp.sum(jnp.tril(Pf[:nbw], -1), axis=0)
+                  + jnp.sum(Pf[nbw:], axis=0) + 1.0)
+            U11 = jnp.triu(Pf[:nbw])
+            guard.check("panel", jnp.matmul(cL, U11),
+                        jnp.sum(ploc[:, :nbw], axis=0),
+                        mass=jnp.sum(jnp.abs(ploc[:, :nbw]), axis=0),
+                        kind="compute", rows=m - s, nb=nbw)
+        with tm.phase("swap", k):
+            perm = perm.at[s:].set(jnp.take(perm[s:], pperm, axis=0))
+            idx, src = _moved_rows(pperm, nbw)
+            valid = idx < (m - s)
+            A = _apply_swaps_moved(A, idx + s,
+                                   jnp.clip(src, 0, m - s - 1) + s, valid)
         ticks.append(("swap", (A,)))
-        if e_up > e:
-            Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
-        else:
-            Pf_w = Pf
-        Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
-        pf_w = redistribute(Pf_ss, MC, MR)
-        guard.check("panel_write", jnp.sum(Pf_w, axis=0), _colsum(pf_w),
-                    mass=jnp.sum(jnp.abs(Pf_w), axis=0),
-                    kind="transport", rows=m - s)
-        A = _update_cols_lt(A, pf_w, (s, m), (s, e_up), e)
+        with tm.phase("panel", k):
+            if e_up > e:
+                Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
+            else:
+                Pf_w = Pf
+            Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
+            pf_w = redistribute(Pf_ss, MC, MR)
+            guard.check("panel_write", jnp.sum(Pf_w, axis=0), _colsum(pf_w),
+                        mass=jnp.sum(jnp.abs(Pf_w), axis=0),
+                        kind="transport", rows=m - s)
+            A = _update_cols_lt(A, pf_w, (s, m), (s, e_up), e)
         if e >= n:
             return (A, perm), Pf, pperm, ticks
-        Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw, :], -1)
-                               + jnp.eye(nbw, dtype=Pf.dtype),
-                               nbw, precision)
-        a1n_v = view(A, rows=(s, e), cols=(s, n))
-        a1n_sum = _colsum(a1n_v)
-        a1n_mass = _colsum(a1n_v, absval=True)
-        A1n = redistribute(a1n_v, STAR, VR, comm_precision=cp)
-        guard.check("solve_gather", a1n_sum, _colsum(A1n),
-                    mass=a1n_mass, kind="transport", rows=nbw)
-        u1n = jnp.matmul(Li11, A1n.local, precision=_hi(precision)
-                         ).astype(Pf.dtype)
-        U1n = DistMatrix(u1n, (nbw, n - s), STAR, VR, 0, 0, g)
-        cL11 = jnp.sum(jnp.tril(Pf[:nbw], -1), axis=0) + 1.0
-        guard.check("solve", _wcolsum(U1n, cL11), _colsum(A1n),
-                    mass=_wcolsum(U1n, cL11, absval=True) + a1n_mass,
-                    kind="compute", rows=nbw, nb=nbw)
-        U1n_mr = redistribute(U1n, STAR, MR, comm_precision=cp)
-        guard.check("solve_move", _colsum(U1n), _colsum(U1n_mr),
-                    mass=_colsum(U1n, absval=True), kind="transport",
-                    rows=nbw)
-        u_w = redistribute(U1n_mr, MC, MR)
-        guard.check("u_write", _colsum(U1n_mr), _colsum(u_w),
-                    mass=_colsum(U1n_mr, absval=True), kind="transport",
-                    rows=nbw)
-        A = _update_cols_ge(A, u_w, (s, e), (s, n), e)
+        with tm.phase("solve", k):
+            Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw, :], -1)
+                                   + jnp.eye(nbw, dtype=Pf.dtype),
+                                   nbw, precision)
+            a1n_v = view(A, rows=(s, e), cols=(s, n))
+            a1n_sum = _colsum(a1n_v)
+            a1n_mass = _colsum(a1n_v, absval=True)
+            A1n = redistribute(a1n_v, STAR, VR, comm_precision=cp)
+            guard.check("solve_gather", a1n_sum, _colsum(A1n),
+                        mass=a1n_mass, kind="transport", rows=nbw)
+            u1n = jnp.matmul(Li11, A1n.local, precision=_hi(precision)
+                             ).astype(Pf.dtype)
+            U1n = DistMatrix(u1n, (nbw, n - s), STAR, VR, 0, 0, g)
+            cL11 = jnp.sum(jnp.tril(Pf[:nbw], -1), axis=0) + 1.0
+            guard.check("solve", _wcolsum(U1n, cL11), _colsum(A1n),
+                        mass=_wcolsum(U1n, cL11, absval=True) + a1n_mass,
+                        kind="compute", rows=nbw, nb=nbw)
+            U1n_mr = redistribute(U1n, STAR, MR, comm_precision=cp)
+            guard.check("solve_move", _colsum(U1n), _colsum(U1n_mr),
+                        mass=_colsum(U1n, absval=True), kind="transport",
+                        rows=nbw)
+            u_w = redistribute(U1n_mr, MC, MR)
+            guard.check("u_write", _colsum(U1n_mr), _colsum(u_w),
+                        mass=_colsum(U1n_mr, absval=True), kind="transport",
+                        rows=nbw)
+            A = _update_cols_ge(A, u_w, (s, e), (s, n), e)
         ticks.append(("solve", (U1n_mr,)))
         if e < m:
-            t_view = view(A, rows=(e, m), cols=(e, n))
-            t_pre = _colsum(t_view)
-            t_mass = _colsum(t_view, absval=True)
-            U12_mr = view(U1n_mr, cols=(e - s, n - s))
-            L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR, STAR,
-                                0, 0, g)
-            L21_mc = redistribute(L21_ss, MC, STAR)
-            cL21 = jnp.sum(Pf[nbw:, :], axis=0)
-            guard.check("l21_move", cL21, _colsum(L21_mc),
-                        mass=jnp.sum(jnp.abs(Pf[nbw:, :]), axis=0),
-                        kind="transport", rows=m - e)
-            A = local_rank_update(A, L21_mc.local, U12_mr.local,
-                                  rows=(e, m), cols=(e, n), precision=upd)
-            # Huang-Abraham: predicted trailing colsums from the
-            # REPLICATED panel, measured against the updated block
-            delta = _wcolsum(U12_mr, cL21)
-            dmass = _wcolsum(U12_mr, cL21, absval=True)
-            guard.check("update", t_pre - delta,
-                        _colsum(view(A, rows=(e, m), cols=(e, n))),
-                        mass=t_mass + dmass, kind="compute",
-                        rows=m - e, nb=nbw)
+            with tm.phase("update", k):
+                t_view = view(A, rows=(e, m), cols=(e, n))
+                t_pre = _colsum(t_view)
+                t_mass = _colsum(t_view, absval=True)
+                U12_mr = view(U1n_mr, cols=(e - s, n - s))
+                L21_ss = DistMatrix(Pf[nbw:, :], (m - e, nbw), STAR, STAR,
+                                    0, 0, g)
+                L21_mc = redistribute(L21_ss, MC, STAR)
+                cL21 = jnp.sum(Pf[nbw:, :], axis=0)
+                guard.check("l21_move", cL21, _colsum(L21_mc),
+                            mass=jnp.sum(jnp.abs(Pf[nbw:, :]), axis=0),
+                            kind="transport", rows=m - e)
+                A = local_rank_update(A, L21_mc.local, U12_mr.local,
+                                      rows=(e, m), cols=(e, n), precision=upd)
+                # Huang-Abraham: predicted trailing colsums from the
+                # REPLICATED panel, measured against the updated block
+                delta = _wcolsum(U12_mr, cL21)
+                dmass = _wcolsum(U12_mr, cL21, absval=True)
+                guard.check("update", t_pre - delta,
+                            _colsum(view(A, rows=(e, m), cols=(e, n))),
+                            mass=t_mass + dmass, kind="compute",
+                            rows=m - e, nb=nbw)
             ticks.append(("update", (A,)))
         return (A, perm), Pf, pperm, ticks
 
@@ -511,9 +527,7 @@ def abft_lu(A, nb=None, precision=None, update_precision=None,
     for k, s in enumerate(range(0, kend, ib)):
         state, Pf, pperm, ticks = run_step(
             guard, k, lambda st: step_fn(st, k, s), state)
-        tm.tick("panel", k, Pf, pperm)
-        for phase, arrs in ticks:
-            tm.tick(phase, k, *arrs)
+        _commit_phases(tm, k, [("panel", (Pf, pperm))] + ticks)
     guard.flag_health(hm)
     guard.report()
     if hm is not None:
@@ -560,87 +574,89 @@ def abft_cholesky(A, nb=None, precision=None, comm_precision=None,
         ticks = []
         e = min(s + ib, m)
         w = e - s
-        a11_v = view(L, rows=(s, e), cols=(s, e))
-        a11_sum = _colsum(a11_v)
-        a11_mass = _colsum(a11_v, absval=True)
-        A11 = redistribute(a11_v, STAR, STAR, comm_precision=cp)
-        aloc = A11.local[:w, :w]
-        guard.check("diag_gather", a11_sum, jnp.sum(aloc, axis=0),
-                    mass=a11_mass, kind="transport", rows=w)
-        L11, Li11 = _potrf_inv(A11.local, precision, plan=plan)
-        d = jnp.tril(aloc)
-        d = d + jnp.conj(jnp.tril(d, -1)).T
-        cL = jnp.sum(L11, axis=0)
-        guard.check("diag", jnp.matmul(cL, jnp.conj(L11).T),
-                    jnp.sum(d, axis=0),
-                    mass=jnp.sum(jnp.abs(d), axis=0),
-                    kind="compute", rows=w, nb=w)
-        L11_ss = DistMatrix(L11, (w, w), STAR, STAR, 0, 0, g)
-        l11_w = redistribute(L11_ss, MC, MR)
-        guard.check("diag_write", jnp.sum(L11, axis=0), _colsum(l11_w),
-                    mass=jnp.sum(jnp.abs(L11), axis=0),
-                    kind="transport", rows=w)
-        L = update_view(L, l11_w, rows=(s, e), cols=(s, e))
+        with tm.phase("diag", k):
+            a11_v = view(L, rows=(s, e), cols=(s, e))
+            a11_sum = _colsum(a11_v)
+            a11_mass = _colsum(a11_v, absval=True)
+            A11 = redistribute(a11_v, STAR, STAR, comm_precision=cp)
+            aloc = A11.local[:w, :w]
+            guard.check("diag_gather", a11_sum, jnp.sum(aloc, axis=0),
+                        mass=a11_mass, kind="transport", rows=w)
+            L11, Li11 = _potrf_inv(A11.local, precision, plan=plan)
+            d = jnp.tril(aloc)
+            d = d + jnp.conj(jnp.tril(d, -1)).T
+            cL = jnp.sum(L11, axis=0)
+            guard.check("diag", jnp.matmul(cL, jnp.conj(L11).T),
+                        jnp.sum(d, axis=0),
+                        mass=jnp.sum(jnp.abs(d), axis=0),
+                        kind="compute", rows=w, nb=w)
+            L11_ss = DistMatrix(L11, (w, w), STAR, STAR, 0, 0, g)
+            l11_w = redistribute(L11_ss, MC, MR)
+            guard.check("diag_write", jnp.sum(L11, axis=0), _colsum(l11_w),
+                        mass=jnp.sum(jnp.abs(L11), axis=0),
+                        kind="transport", rows=w)
+            L = update_view(L, l11_w, rows=(s, e), cols=(s, e))
         if e == m:
             return L, L11, ticks
-        a21_v = view(L, rows=(e, m), cols=(s, e))
-        a21_sum = _colsum(a21_v)
-        a21_mass = _colsum(a21_v, absval=True)
-        A21_vc = redistribute(a21_v, VC, STAR, comm_precision=cp)
-        guard.check("panel_gather", a21_sum, _colsum(A21_vc),
-                    mass=a21_mass, kind="transport", rows=m - e)
-        x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
-                         precision=_hi(precision)).astype(L.dtype)
-        L21_vc = DistMatrix(x21, (m - e, w), VC, STAR, 0, 0, g)
-        cx = _colsum(L21_vc)
-        cx_mass = _colsum(L21_vc, absval=True)
-        # panel solve invariant: colsum(L21 L11^H) == colsum(A21) --
-        # the check that catches a corrupted Li11 (the second output of
-        # the 'compute' fault seam)
-        guard.check("panel", jnp.matmul(cx, jnp.conj(L11).T),
-                    _colsum(A21_vc), mass=a21_mass + cx_mass,
-                    kind="compute", rows=m - e, nb=w)
+        with tm.phase("panel", k):
+            a21_v = view(L, rows=(e, m), cols=(s, e))
+            a21_sum = _colsum(a21_v)
+            a21_mass = _colsum(a21_v, absval=True)
+            A21_vc = redistribute(a21_v, VC, STAR, comm_precision=cp)
+            guard.check("panel_gather", a21_sum, _colsum(A21_vc),
+                        mass=a21_mass, kind="transport", rows=m - e)
+            x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
+                             precision=_hi(precision)).astype(L.dtype)
+            L21_vc = DistMatrix(x21, (m - e, w), VC, STAR, 0, 0, g)
+            cx = _colsum(L21_vc)
+            cx_mass = _colsum(L21_vc, absval=True)
+            # panel solve invariant: colsum(L21 L11^H) == colsum(A21) --
+            # the check that catches a corrupted Li11 (the second output of
+            # the 'compute' fault seam)
+            guard.check("panel", jnp.matmul(cx, jnp.conj(L11).T),
+                        _colsum(A21_vc), mass=a21_mass + cx_mass,
+                        kind="compute", rows=m - e, nb=w)
         ticks.append(("panel", (L21_vc,)))
-        L21_mc, L21H_mr = panel_spread(L21_vc, conj=True,
-                                       comm_precision=cp)
-        guard.check("spread_mc", cx, _colsum(L21_mc), mass=cx_mass,
-                    kind="transport", rows=m - e)
-        guard.check("spread_mr", jnp.conj(_rowsum(L21_vc)),
-                    _colsum(L21H_mr), mass=_colsum(L21H_mr, absval=True),
-                    kind="transport", rows=w)
+        with tm.phase("spread", k):
+            L21_mc, L21H_mr = panel_spread(L21_vc, conj=True,
+                                           comm_precision=cp)
+            guard.check("spread_mc", cx, _colsum(L21_mc), mass=cx_mass,
+                        kind="transport", rows=m - e)
+            guard.check("spread_mr", jnp.conj(_rowsum(L21_vc)),
+                        _colsum(L21H_mr), mass=_colsum(L21H_mr, absval=True),
+                        kind="transport", rows=w)
         ticks.append(("spread", (L21_mc, L21H_mr)))
-        A22 = view(L, rows=(e, m), cols=(e, m))
-        t_pre = _colsum(A22)
-        t_mass = _colsum(A22, absval=True)
-        upd = jnp.matmul(L21_mc.local, L21H_mr.local, precision=precision)
-        mask = _mask_triangle(A22, "L")
-        mupd = jnp.where(mask, upd.astype(L.dtype), 0)
-        # masked-lower update: no separable column identity, so the
-        # predicted delta reduces the update product itself
-        # (consistency-grade; operands are transport/solve-checked above)
-        delta = _colsum(A22.with_local(mupd))
-        dmass = _colsum(A22.with_local(jnp.abs(mupd)))
-        A22new = jnp.where(mask, A22.local - upd.astype(L.dtype),
-                           A22.local)
-        L = update_view(L, A22.with_local(A22new), rows=(e, m),
-                        cols=(e, m))
-        guard.check("update", t_pre - delta,
-                    _colsum(view(L, rows=(e, m), cols=(e, m))),
-                    mass=t_mass + dmass, kind="compute",
-                    rows=m - e, nb=w)
-        l21_w = redistribute(L21_mc, MC, MR)
-        guard.check("panel_write", _colsum(L21_mc), _colsum(l21_w),
-                    mass=cx_mass, kind="transport", rows=m - e)
-        L = update_view(L, l21_w, rows=(e, m), cols=(s, e))
+        with tm.phase("update", k):
+            A22 = view(L, rows=(e, m), cols=(e, m))
+            t_pre = _colsum(A22)
+            t_mass = _colsum(A22, absval=True)
+            upd = jnp.matmul(L21_mc.local, L21H_mr.local, precision=precision)
+            mask = _mask_triangle(A22, "L")
+            mupd = jnp.where(mask, upd.astype(L.dtype), 0)
+            # masked-lower update: no separable column identity, so the
+            # predicted delta reduces the update product itself
+            # (consistency-grade; operands are transport/solve-checked above)
+            delta = _colsum(A22.with_local(mupd))
+            dmass = _colsum(A22.with_local(jnp.abs(mupd)))
+            A22new = jnp.where(mask, A22.local - upd.astype(L.dtype),
+                               A22.local)
+            L = update_view(L, A22.with_local(A22new), rows=(e, m),
+                            cols=(e, m))
+            guard.check("update", t_pre - delta,
+                        _colsum(view(L, rows=(e, m), cols=(e, m))),
+                        mass=t_mass + dmass, kind="compute",
+                        rows=m - e, nb=w)
+            l21_w = redistribute(L21_mc, MC, MR)
+            guard.check("panel_write", _colsum(L21_mc), _colsum(l21_w),
+                        mass=cx_mass, kind="transport", rows=m - e)
+            L = update_view(L, l21_w, rows=(e, m), cols=(s, e))
         ticks.append(("update", (L,)))
         return L, L11, ticks
 
     L = A
     for k, s in enumerate(range(0, m, ib)):
         L, L11, ticks = run_step(guard, k, lambda st: step_fn(st, k, s), L)
-        tm.tick("diag", k, L11)
-        for phase, arrs in ticks:
-            tm.tick(phase, k, *arrs)
+        _commit_phases(tm, k, [("diag", (L11,))] + ticks)
     guard.flag_health(hm)
     guard.report()
     if hm is not None:
@@ -697,73 +713,75 @@ def abft_qr(A, nb=None, precision=None, panel="classic",
         e = min(s + ib, kend)
         nbw = e - s
         e_up = min(-(-e // c) * c, n)
-        pan_v = view(A, rows=(s, m), cols=(s, e_up))
-        pan_sum = _colsum(pan_v)
-        pan_mass = _colsum(pan_v, absval=True)
-        panel_ss = redistribute(pan_v, STAR, STAR, comm_precision=cp)
-        ploc = panel_ss.local[:m - s, :e_up - s]
-        guard.check("panel_gather", pan_sum, jnp.sum(ploc, axis=0),
-                    mass=pan_mass, kind="transport", rows=m - s)
-        Tk = None
-        if panel == "tsqr":
-            Pf, tau = _panel_qr_tsqr(ploc[:, :nbw], r, precision)
-        else:
-            Pf, tau, Tk = _panel_qr_dispatch(ploc[:, :nbw], plan)
-        Pf, = apply_fault("compute", (Pf,))
-        # factor invariant: panel = (I - V T V^H) [R; 0], so
-        # colsum(panel) == colsum(R) - cV @ (T @ (V1^H R))
-        V = _panel_v(Pf)
-        T = Tk if Tk is not None else _larft(V, tau)
-        R11 = jnp.triu(Pf[:nbw])
-        cV = jnp.sum(V, axis=0)
-        rpred = (jnp.sum(R11, axis=0)
-                 - jnp.matmul(cV, jnp.matmul(
-                     T, jnp.matmul(jnp.conj(V[:nbw]).T, R11))))
-        guard.check("panel", rpred, jnp.sum(ploc[:, :nbw], axis=0),
-                    mass=jnp.sum(jnp.abs(ploc[:, :nbw]), axis=0),
-                    kind="compute", rows=m - s, nb=nbw)
-        if e_up > e:
-            Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
-        else:
-            Pf_w = Pf
-        Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
-        pf_w = redistribute(Pf_ss, MC, MR)
-        guard.check("panel_write", jnp.sum(Pf_w, axis=0), _colsum(pf_w),
-                    mass=jnp.sum(jnp.abs(Pf_w), axis=0),
-                    kind="transport", rows=m - s)
-        A = _update_cols_lt(A, pf_w, (s, m), (s, e_up), e)
-        if e < n:
-            V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
-            V_mc = redistribute(V_ss, MC, STAR)
-            guard.check("v_move", cV, _colsum(V_mc),
-                        mass=jnp.sum(jnp.abs(V), axis=0),
+        with tm.phase("panel", k):
+            pan_v = view(A, rows=(s, m), cols=(s, e_up))
+            pan_sum = _colsum(pan_v)
+            pan_mass = _colsum(pan_v, absval=True)
+            panel_ss = redistribute(pan_v, STAR, STAR, comm_precision=cp)
+            ploc = panel_ss.local[:m - s, :e_up - s]
+            guard.check("panel_gather", pan_sum, jnp.sum(ploc, axis=0),
+                        mass=pan_mass, kind="transport", rows=m - s)
+            Tk = None
+            if panel == "tsqr":
+                Pf, tau = _panel_qr_tsqr(ploc[:, :nbw], r, precision)
+            else:
+                Pf, tau, Tk = _panel_qr_dispatch(ploc[:, :nbw], plan)
+            Pf, = apply_fault("compute", (Pf,))
+            # factor invariant: panel = (I - V T V^H) [R; 0], so
+            # colsum(panel) == colsum(R) - cV @ (T @ (V1^H R))
+            V = _panel_v(Pf)
+            T = Tk if Tk is not None else _larft(V, tau)
+            R11 = jnp.triu(Pf[:nbw])
+            cV = jnp.sum(V, axis=0)
+            rpred = (jnp.sum(R11, axis=0)
+                     - jnp.matmul(cV, jnp.matmul(
+                         T, jnp.matmul(jnp.conj(V[:nbw]).T, R11))))
+            guard.check("panel", rpred, jnp.sum(ploc[:, :nbw], axis=0),
+                        mass=jnp.sum(jnp.abs(ploc[:, :nbw]), axis=0),
+                        kind="compute", rows=m - s, nb=nbw)
+            if e_up > e:
+                Pf_w = jnp.pad(Pf, ((0, 0), (0, e_up - e)))
+            else:
+                Pf_w = Pf
+            Pf_ss = DistMatrix(Pf_w, (m - s, e_up - s), STAR, STAR, 0, 0, g)
+            pf_w = redistribute(Pf_ss, MC, MR)
+            guard.check("panel_write", jnp.sum(Pf_w, axis=0), _colsum(pf_w),
+                        mass=jnp.sum(jnp.abs(Pf_w), axis=0),
                         kind="transport", rows=m - s)
-            A2 = view(A, rows=(s, m), cols=(s, n))
-            t_pre = _colsum(A2)
-            t_mass = _colsum(A2, absval=True)
-            W = jnp.matmul(jnp.conj(V_mc.local).T, A2.local,
-                           precision=_hi(precision))
-            W = jnp.matmul(jnp.conj(T).T, W, precision=_hi(precision))
-            upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
-            # Huang-Abraham: 1^T (V_mc W) == cV @ W, cV from the
-            # REPLICATED panel -- independent of the transported V_mc.
-            # The strip's first nbw global columns hold the already-
-            # written packed panel; _update_cols_ge leaves them
-            # untouched, so their predicted delta is exactly zero.
-            _, J = _indices(A2)
-            delta = _scatter_cols(jnp.matmul(cV, W), J, n - s)
-            dmass = _scatter_cols(
-                jnp.matmul(jnp.abs(cV), jnp.abs(W)), J, n - s)
-            keep = jnp.arange(n - s) >= nbw
-            delta = jnp.where(keep, delta, 0)
-            dmass = jnp.where(keep, dmass, 0)
-            A = _update_cols_ge(
-                A, A2.with_local(A2.local - upd.astype(A.dtype)),
-                (s, m), (s, n), e)
-            guard.check("update", t_pre - delta,
-                        _colsum(view(A, rows=(s, m), cols=(s, n))),
-                        mass=t_mass + dmass, kind="compute",
-                        rows=m - s, nb=nbw)
+            A = _update_cols_lt(A, pf_w, (s, m), (s, e_up), e)
+        if e < n:
+            with tm.phase("update", k):
+                V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
+                V_mc = redistribute(V_ss, MC, STAR)
+                guard.check("v_move", cV, _colsum(V_mc),
+                            mass=jnp.sum(jnp.abs(V), axis=0),
+                            kind="transport", rows=m - s)
+                A2 = view(A, rows=(s, m), cols=(s, n))
+                t_pre = _colsum(A2)
+                t_mass = _colsum(A2, absval=True)
+                W = jnp.matmul(jnp.conj(V_mc.local).T, A2.local,
+                               precision=_hi(precision))
+                W = jnp.matmul(jnp.conj(T).T, W, precision=_hi(precision))
+                upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
+                # Huang-Abraham: 1^T (V_mc W) == cV @ W, cV from the
+                # REPLICATED panel -- independent of the transported V_mc.
+                # The strip's first nbw global columns hold the already-
+                # written packed panel; _update_cols_ge leaves them
+                # untouched, so their predicted delta is exactly zero.
+                _, J = _indices(A2)
+                delta = _scatter_cols(jnp.matmul(cV, W), J, n - s)
+                dmass = _scatter_cols(
+                    jnp.matmul(jnp.abs(cV), jnp.abs(W)), J, n - s)
+                keep = jnp.arange(n - s) >= nbw
+                delta = jnp.where(keep, delta, 0)
+                dmass = jnp.where(keep, dmass, 0)
+                A = _update_cols_ge(
+                    A, A2.with_local(A2.local - upd.astype(A.dtype)),
+                    (s, m), (s, n), e)
+                guard.check("update", t_pre - delta,
+                            _colsum(view(A, rows=(s, m), cols=(s, n))),
+                            mass=t_mass + dmass, kind="compute",
+                            rows=m - s, nb=nbw)
             ticks.append(("update", (A,)))
         return A, Pf, tau, ticks
 
@@ -774,9 +792,7 @@ def abft_qr(A, nb=None, precision=None, panel="classic",
         A, Pf, tau, ticks = run_step(
             guard, k, lambda st: step_fn(st, k, s), A)
         taus.append(tau)
-        tm.tick("panel", k, Pf, tau)
-        for phase, arrs in ticks:
-            tm.tick(phase, k, *arrs)
+        _commit_phases(tm, k, [("panel", (Pf, tau))] + ticks)
     _record_qr_nb(A, ib)
     guard.flag_health(hm)
     guard.report()

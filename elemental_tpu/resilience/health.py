@@ -55,6 +55,8 @@ import dataclasses
 
 import numpy as np
 
+from ..obs.tracer import NULL_HOOK, PhaseHook, _Fanout
+
 HEALTH_SCHEMA = "health_report/v1"
 
 #: growth-estimate flag threshold: |intermediate| exceeding ``max|A|`` by
@@ -104,7 +106,7 @@ class _Check:
     diag_signed: object | None  # jnp scalar: min REAL diag (cholesky sign)
 
 
-class HealthMonitor:
+class HealthMonitor(PhaseHook):
     """Tick-protocol numerical-health guard (see module docstring).
 
     Reusable as the ``health=`` argument of ``lu``/``cholesky`` (the
@@ -277,24 +279,6 @@ def last_health_report(driver: str | None = None) -> dict | None:
     return _LAST.get(driver if driver is not None else "_latest")
 
 
-class _HookPair:
-    """Tick fan-out of (existing hook, monitor) -- the resilience twin of
-    ``obs.tracer._Fanout``, kept local so health stays importable without
-    touching the tracer's private surface."""
-    __slots__ = ("hooks",)
-
-    def __init__(self, hooks):
-        self.hooks = tuple(hooks)
-
-    def start(self):
-        for h in self.hooks:
-            h.start()
-
-    def tick(self, phase, step, *arrays):
-        for h in self.hooks:
-            h.tick(phase, step, *arrays)
-
-
 def attach_health(driver: str, health, hook, scale_from=None):
     """Resolve a driver's ``health=`` argument into (hook', monitor).
 
@@ -308,10 +292,9 @@ def attach_health(driver: str, health, hook, scale_from=None):
         return hook, None
     mon = health if isinstance(health, HealthMonitor) else HealthMonitor()
     mon.begin(driver, scale_from=scale_from)
-    from ..obs.tracer import NULL_HOOK
     if hook is NULL_HOOK or hook is None:
         return mon, mon
-    return _HookPair((hook, mon)), mon
+    return _Fanout((hook, mon)), mon
 
 
 def factor_diag_info(op: str, factor) -> dict:

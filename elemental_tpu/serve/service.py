@@ -313,9 +313,9 @@ class SolverService:
         tr = active_tracer()
         span = tr.span(f"serve:batch:{bucket.key()}", n=len(live)) \
             if tr is not None else _null_cm()
-        with span:
+        with tm.phase("batch", bi) as ph, span:
             xs, seconds = self.executor.run(bucket, live)
-        tm.tick("batch", bi)
+            ph.done()
         self._complete_batch(bucket, live, xs, seconds)
 
     def _prepare_batch(self, bucket: Bucket, reqs) -> list:

@@ -41,7 +41,7 @@ harness uses ALL visible devices -- on CPU export
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to exercise the
 distributed schedule without hardware.
 
-``phases`` drives ``perf.phase_timer.PhaseTimer`` through the real driver
+``phases`` drives ``elemental_tpu.obs.PhaseTimer`` through the real driver
 (eagerly, sync at each phase boundary) and emits the ``phase_timings/v1``
 JSON -- the hook future perf PRs use to attribute regressions.
 
@@ -513,7 +513,7 @@ def run_phases(*args):
     """Per-step phase wall-clock through the REAL driver (eager, PhaseTimer
     syncs at each boundary) -> one phase_timings/v1 JSON line.
     ``phases [lu|cholesky] [N NB]`` (driver defaults to lu)."""
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     args = list(args)
     driver = "lu"
     if args and not args[0].isdigit():

@@ -5,6 +5,10 @@ import jax
 
 from elemental_tpu.core import compile_cache as cc
 
+#: the names an op carries are part of the key; the caller's stack is not
+KEYED_BY = {"jax_compilation_cache_include_metadata_in_key": True,
+            "jax_traceback_in_locations_limit": 1}
+
 
 def test_unset_env_gives_checkout_dir(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
@@ -16,16 +20,17 @@ def test_unset_env_gives_checkout_dir(monkeypatch):
         os.path.abspath(cc.__file__))))
     assert path == os.path.join(root, ".jax_compile_cache")
     assert os.path.exists(os.path.join(root, "chip_smoke.py"))
-    assert seen == {"jax_compilation_cache_dir": path}
+    assert seen == {"jax_compilation_cache_dir": path, **KEYED_BY}
 
 
 def test_set_env_wins_and_nothing_is_updated(monkeypatch, tmp_path):
+    """No directory is set in code; what an entry is keyed by is."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-
-    def boom(*a):
-        raise AssertionError("the environment's directory must stand")
-    monkeypatch.setattr(jax.config, "update", boom)
+    seen = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: seen.__setitem__(k, v))
     assert cc.enable_compile_cache() == str(tmp_path)
+    assert seen == KEYED_BY
 
 
 def test_no_code_sets_the_cache_dir_but_the_helper():

@@ -196,7 +196,8 @@ def test_engine_observer_fires_on_real_redistribute(grid24):
 # ---------------------------------------------------------------------
 
 def test_phase_timer_shim_reexport_identity():
-    from perf.phase_timer import PHASES, SCHEMA, PhaseTimer
+    from elemental_tpu.obs import PHASES, PhaseTimer
+    from elemental_tpu.obs import PHASE_TIMINGS_SCHEMA as SCHEMA
     from elemental_tpu.obs import phase_timer as obs_pt
     assert PhaseTimer is obs_pt.PhaseTimer
     assert SCHEMA == obs_pt.SCHEMA == "phase_timings/v1"

@@ -1,6 +1,6 @@
 """Perf-observability smoke: tiny LU/Cholesky through the phase-timing hook.
 
-Slow-tier guard for the ``perf/phase_timer.py`` + ``lu/cholesky(...,
+Slow-tier guard for the ``elemental_tpu.obs.PhaseTimer`` + ``lu/cholesky(...,
 timer=...)`` paths (ISSUE 1/2 CI satellites): asserts the
 ``phase_timings/v1`` JSON schema so the attribution tooling future perf
 PRs rely on cannot silently rot.
@@ -16,7 +16,7 @@ pytestmark = pytest.mark.slow
 
 
 def _check_schema(doc, n, nb, nsteps):
-    from perf.phase_timer import SCHEMA, PHASES
+    from elemental_tpu.obs.phase_timer import SCHEMA, PHASES
     assert doc["schema"] == SCHEMA
     assert doc["driver"] == "lu"
     assert doc["n"] == n and doc["nb"] == nb
@@ -36,7 +36,7 @@ def _check_schema(doc, n, nb, nsteps):
 
 @pytest.mark.parametrize("lookahead", [True, False])
 def test_lu_phase_timer_schema_distributed(grid24, lookahead):
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     n, nb = 48, 16
     rng = np.random.default_rng(0)
     F = rng.normal(size=(n, n)) + n * np.eye(n)
@@ -56,7 +56,7 @@ def test_lu_phase_timer_schema_distributed(grid24, lookahead):
 def test_lu_phase_timer_schema_local():
     """Same schema off the sequential (1x1-grid) driver."""
     import jax
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     g1 = el.Grid([jax.devices()[0]])
     n, nb = 64, 16
     rng = np.random.default_rng(1)
@@ -71,7 +71,7 @@ def test_lu_phase_timer_schema_local():
 def test_lu_phase_timer_tail_crossover(grid24):
     """The LU crossover step attributes its gathered local finish to
     'tail' (the ISSUE-3 rider mirroring the cholesky PR-2 tail)."""
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     n, nb = 48, 16
     rng = np.random.default_rng(7)
     F = rng.normal(size=(n, n)) + n * np.eye(n)
@@ -97,7 +97,7 @@ def _spd(n, seed):
 
 
 def _check_chol_schema(doc, n, nb, nsteps, tail=False):
-    from perf.phase_timer import SCHEMA, PHASES
+    from elemental_tpu.obs.phase_timer import SCHEMA, PHASES
     assert doc["schema"] == SCHEMA
     assert doc["driver"] == "cholesky"
     assert doc["n"] == n and doc["nb"] == nb
@@ -118,7 +118,7 @@ def _check_chol_schema(doc, n, nb, nsteps, tail=False):
 
 @pytest.mark.parametrize("lookahead", [True, False])
 def test_cholesky_phase_timer_schema_distributed(grid24, lookahead):
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     n, nb = 48, 16
     F = _spd(n, 2)
     A = el.from_global(F, el.MC, el.MR, grid=grid24)
@@ -137,7 +137,7 @@ def test_cholesky_phase_timer_schema_distributed(grid24, lookahead):
 
 def test_cholesky_phase_timer_tail_crossover(grid24):
     """The crossover step attributes its gathered local finish to 'tail'."""
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     n, nb = 48, 16
     F = _spd(n, 3)
     A = el.from_global(F, el.MC, el.MR, grid=grid24)
@@ -154,7 +154,7 @@ def test_cholesky_phase_timer_tail_crossover(grid24):
 def test_cholesky_phase_timer_schema_local():
     """Same schema off the sequential (1x1-grid) driver."""
     import jax
-    from perf.phase_timer import PhaseTimer
+    from elemental_tpu.obs import PhaseTimer
     g1 = el.Grid([jax.devices()[0]])
     n, nb = 64, 16
     F = _spd(n, 4)
