@@ -42,6 +42,7 @@ from ..core.dist import (
     stride as dist_stride, gather_axes, rank_of, md_slot_of_global,
 )
 from ..core.distmatrix import DistMatrix, _check_pair
+from ..obs import metrics as _metrics
 from ..obs.tracer import scoped as _scoped
 from .plan import compile_plan
 from .quantize import (QUANT_TILE, check_comm_precision, q8_pack, q8_unpack,
@@ -275,6 +276,53 @@ def _pad_dim(x, dim: int, target: int):
     return jnp.pad(x, pads)
 
 
+def _interleave(g, dim: int):
+    """The local unpack after a gather over a cyclic dimension: ``g`` holds
+    ``S`` rank-ordered blocks, shape ``(S, ...)``; the result has
+    ``shape[dim] * S`` along block dimension ``dim``, index
+    ``i = iLoc*S + s``.  Pure data movement (bit-exact, non-finite values
+    included).
+
+    ONE cyclic dimension per call.  The TPU compiler lays this out in
+    whole tiles whichever dimension it is (a minor-dimension interleave
+    becomes transpose, row interleave, transpose), but a single transpose
+    that interleaves rows AND columns leaves it an intermediate whose minor
+    dimension is ``S``, padded to 128 lanes: 1 GiB and 2.4 ms for a 16 MB
+    block on a v5e (PERF.md 6, PR 29).  A 2-D unpack is two calls.
+
+    Counted at trace time in ``redist_unpack``: ``impl="tiled"`` when the
+    blocks are whole (8, 128) tiles of a 4-byte dtype, so that nothing the
+    compiler moves is padded; ``"generic"`` for ragged, narrow or other-width
+    blocks, which run the same formula on padded tiles.  ``dim`` is 1 for
+    the minor (lane) dimension of the block, else 0."""
+    S = g.shape[0]
+    if S == 1:
+        return g[0]
+    shape = list(g.shape[1:])
+    whole = (g.dtype.itemsize == 4 and len(shape) >= 2
+             and shape[-1] % 128 == 0 and shape[-2] % 8 == 0)
+    _metrics.inc("redist_unpack", impl="tiled" if whole else "generic",
+                 dim=int(dim == len(shape) - 1))
+    shape[dim] *= S
+    return jnp.moveaxis(g, 0, dim + 1).reshape(shape)
+
+
+def _deinterleave(x, dim: int, S: int, shift):
+    """The mirror of :func:`_interleave`: the cyclic slice
+    ``i = iLoc*S + shift`` of dimension ``dim`` (extent a multiple of
+    ``S``; ``shift`` may be traced)."""
+    shape = list(x.shape)
+    shape[dim : dim + 1] = [shape[dim] // S, S]           # (..., l_out, S, ...)
+    if dim == x.ndim - 1:
+        # left alone, the TPU compiler transposes first and de-interleaves
+        # rows; merged with a producer's reshape (an _interleave's) it cannot,
+        # and pads the minor dimension of S to 128 lanes: 7.5 GB for a
+        # 60 MB panel, and the 2x2 cell no longer fits (PERF.md 6, PR 29)
+        x = lax.optimization_barrier(x)
+    return lax.dynamic_index_in_dim(x.reshape(shape), shift, axis=dim + 1,
+                                    keepdims=False)
+
+
 def _gather_dim(x, dim: int, d: Dist, align: int, extent: int, r: int, c: int):
     """Rebuild the full (true-extent) dimension on every device."""
     if d is MD:
@@ -295,11 +343,7 @@ def _gather_dim(x, dim: int, d: Dist, align: int, extent: int, r: int, c: int):
     g = lax.all_gather(x, gather_axes(d), axis=0)        # (S, ...) rank-ordered
     if align:
         g = jnp.roll(g, -align, axis=0)                   # block s <- shift s
-    g = jnp.moveaxis(g, 0, dim + 1)                       # interleave position
-    shape = list(x.shape)
-    shape[dim] = x.shape[dim] * S
-    g = g.reshape(shape)                                  # index i = iLoc*S + s
-    return lax.slice_in_dim(g, 0, extent, axis=dim)
+    return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
 
 
 def _filter_md(x, dim: int, extent: int, r: int, c: int):
@@ -318,11 +362,7 @@ def _filter_dim(x, dim: int, S: int, shift, l_out: int):
     """Select this device's cyclic slice of a replicated dimension."""
     if S == 1:
         return _pad_dim(x, dim, l_out)
-    x = _pad_dim(x, dim, S * l_out)
-    shape = list(x.shape)
-    shape[dim : dim + 1] = [l_out, S]
-    x = x.reshape(shape)                                  # (..., l_out, S, ...)
-    return lax.dynamic_index_in_dim(x, shift, axis=dim + 1, keepdims=False)
+    return _deinterleave(_pad_dim(x, dim, S * l_out), dim, S, shift)
 
 
 def _partial_gather_dim(x, dim: int, axes, nblocks: int, l_out: int):
@@ -335,21 +375,14 @@ def _partial_gather_dim(x, dim: int, axes, nblocks: int, l_out: int):
     if nblocks == 1:                    # degenerate: nothing to exchange
         return lax.slice_in_dim(x, 0, l_out, axis=dim)
     g = lax.all_gather(x, axes, axis=0)                   # (nblocks, l_in, ...)
-    g = jnp.moveaxis(g, 0, dim + 1)
-    shape = list(x.shape)
-    shape[dim] = x.shape[dim] * nblocks
-    g = g.reshape(shape)                                  # jLoc = iLoc*nb + b
-    return lax.slice_in_dim(g, 0, l_out, axis=dim)
+    return lax.slice_in_dim(_interleave(g, dim), 0, l_out, axis=dim)
 
 
 def _partial_filter_dim(x, dim: int, nblocks: int, sub_rank, l_out: int):
     """M* -> V* ladder: pure-local selection of the finer cyclic slice
     (cf. ``copy::PartialColFilter``)."""
-    x = _pad_dim(x, dim, nblocks * l_out)
-    shape = list(x.shape)
-    shape[dim : dim + 1] = [l_out, nblocks]
-    x = x.reshape(shape)
-    return lax.dynamic_index_in_dim(x, sub_rank, axis=dim + 1, keepdims=False)
+    return _deinterleave(_pad_dim(x, dim, nblocks * l_out), dim, nblocks,
+                         sub_rank)
 
 
 # ---------------------------------------------------------------------
@@ -377,7 +410,7 @@ def _fused_to_v(A: DistMatrix) -> DistMatrix:
     x3 = x.reshape(lt, n_other, lc)         # row t = w*n_other + g
     y = x3 if n_other == 1 \
         else lax.all_to_all(x3, ax, split_axis=1, concat_axis=1)
-    z = jnp.moveaxis(y, 1, 2).reshape(lt, lc * n_other)
+    z = _interleave(jnp.moveaxis(y, 1, 0), 1)  # col j = jLoc*n_other + g
     z = lax.slice_in_dim(z, 0, n, axis=1)
     v = rank_of(dst, r, c)
     gi = jnp.arange(lt) * p + v
@@ -404,7 +437,7 @@ def _fused_from_v(A: DistMatrix) -> DistMatrix:
     x3 = x.reshape(lp, lcd, n_other)        # col j = u*n_other + s
     y = x3 if n_other == 1 \
         else lax.all_to_all(x3, ax, split_axis=2, concat_axis=2)
-    z = jnp.moveaxis(y, 2, 1).reshape(lp * n_other, lcd)
+    z = _interleave(jnp.moveaxis(y, 2, 0), 0)  # row i = iLoc*n_other + s
     lr = ix.max_local_length(m, S_row)
     z = lax.slice_in_dim(z, 0, lr, axis=0)
     q_row = rank_of(dst[0], r, c)
@@ -423,6 +456,16 @@ def _t_meta(A: DistMatrix) -> DistMatrix:
                       A.ralign, A.calign, A.grid)
 
 
+def _interleave_2d(G, dist):
+    """``G[mc, mr, il, jl]`` (the blocks of an [MC,MR] or [MR,MC] matrix) ->
+    the global block: columns, then rows, one :func:`_interleave` each."""
+    if dist == (MC, MR):
+        # global (i, j) = (il*r + mc, jl*c + mr)
+        G = jnp.moveaxis(G, 1, 0)
+    # else (MR, MC): global (i, j) = (il*c + mr, jl*r + mc)
+    return _interleave(_interleave(G, 2), 0)
+
+
 def _fused_to_star_star(A: DistMatrix) -> DistMatrix | None:
     """[MC,MR] / [MR,MC] -> [STAR,STAR] in ONE all_gather over the flattened
     ('mc','mr') axis + a static interleave, instead of the generic route's
@@ -438,14 +481,8 @@ def _fused_to_star_star(A: DistMatrix) -> DistMatrix | None:
     x = A.local
     lr, lc = x.shape
     gx = lax.all_gather(x, ("mc", "mr"), axis=0)      # (r*c, lr, lc), mc-major
-    G = gx.reshape(r, c, lr, lc)
-    if A.dist == (MC, MR):
-        # global (i, j) = (il*r + mc, jl*c + mr)
-        full = G.transpose(2, 0, 3, 1).reshape(lr * r, lc * c)
-    else:                                             # (MR, MC)
-        # global (i, j) = (il*c + mr, jl*r + mc)
-        full = G.transpose(2, 1, 3, 0).reshape(lr * c, lc * r)
-    full = lax.slice(full, (0, 0), (m, n))
+    full = lax.slice(_interleave_2d(gx.reshape(r, c, lr, lc), A.dist),
+                     (0, 0), (m, n))
     return DistMatrix(full, A.gshape, STAR, STAR, 0, 0, g)
 
 
@@ -1035,11 +1072,7 @@ def _gather_dim_q8(x, dim: int, d: Dist, extent: int, r: int, c: int,
     if S == 1:
         return lax.slice_in_dim(x, 0, extent, axis=dim)
     g = _q8_gather_blocks(x, gather_axes(d), tile)
-    g = jnp.moveaxis(g, 0, dim + 1)
-    shape = list(x.shape)
-    shape[dim] = x.shape[dim] * S
-    g = g.reshape(shape)
-    return lax.slice_in_dim(g, 0, extent, axis=dim)
+    return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
 
 
 def _to_star_star_q8(A: DistMatrix, tile: int) -> DistMatrix:
@@ -1053,11 +1086,7 @@ def _to_star_star_q8(A: DistMatrix, tile: int) -> DistMatrix:
     if A.dist in ((MC, MR), (MR, MC)) and r > 1 and c > 1:
         lr, lc = x.shape
         G = _q8_gather_blocks(x, ("mc", "mr"), tile).reshape(r, c, lr, lc)
-        if A.dist == (MC, MR):
-            full = G.transpose(2, 0, 3, 1).reshape(lr * r, lc * c)
-        else:
-            full = G.transpose(2, 1, 3, 0).reshape(lr * c, lc * r)
-        full = lax.slice(full, (0, 0), (m, n))
+        full = lax.slice(_interleave_2d(G, A.dist), (0, 0), (m, n))
         return DistMatrix(full, A.gshape, STAR, STAR, 0, 0, g)
     xg = _gather_dim_q8(x, 0, A.cdist, m, r, c, tile)
     xg = _gather_dim_q8(xg, 1, A.rdist, n, r, c, tile)
@@ -1081,46 +1110,58 @@ def _redistribute_q8_jit(A: DistMatrix, tile: int) -> DistMatrix:
 # fused panel spread ([VC,STAR] -> the [MC,STAR]/[STAR,MR] operand pair)
 # ---------------------------------------------------------------------
 
-def _panel_spread_to_pair(A: DistMatrix, conj: bool):
+def _panel_spread_to_pair(A: DistMatrix, conj: bool, tile: int | None = None):
     """Inside shard_map: one (m, k) [VC,STAR] panel -> its [MC,STAR] spread
     AND its [STAR,MR] adjoint, in ONE collective round.
 
-    A single all_gather over the flattened ('mr','mc') axis rebuilds the
-    full panel on every device; both outputs are then pure-local filters
-    (plus the free local transpose for the adjoint).  The separate-call
-    route costs three collective rounds: the [MC,STAR] partial gather, the
-    VC->VR ppermute and the VR->MR partial gather of the adjoint chain.
-    The panels here are thin (k = nb << m), so they are latency-bound and
-    one full-panel round beats three partial rounds despite moving
-    ~m*k instead of ~m*k*(1/r + 1/c) per device -- the collective-fusion
-    trade of the array-redistribution literature (PAPERS.md 2112.01075).
+    A single all_gather over the flattened ('mr','mc') axis brings every
+    device all ``p`` row blocks of the panel (at int8 wire precision when
+    ``tile`` is given: packed, gathered, decoded per source); both outputs
+    are then pure-local unpacks (plus the free local transpose for the
+    adjoint).  The separate-call route costs three collective rounds: the
+    [MC,STAR] partial gather, the VC->VR ppermute and the VR->MR partial
+    gather of the adjoint chain.  The panels here are thin (k = nb << m),
+    so they are latency-bound and one full-panel round beats three partial
+    rounds despite moving ~m*k instead of ~m*k*(1/r + 1/c) per device --
+    the collective-fusion trade of the array-redistribution literature
+    (PAPERS.md 2112.01075).
+
+    Panel row ``i = iLoc*p + v`` is row ``iLoc`` of block ``v = mc + r*mr``.
+    [MC,STAR] keeps the rows ``i % r == mc``: the ``c`` blocks
+    ``v = mc + r*t``, local row ``iLoc*c + t``.  The adjoint's [STAR,MR]
+    keeps ``i % c == mr``: the ``r`` blocks ``v = mr + c*t``, local column
+    ``iLoc*r + t``.  Each output interleaves only the blocks it keeps; the
+    whole panel is never rebuilt and filtered (the same values at half
+    the bytes moved, and no lane de-interleave; PERF.md 6, PR 29).
     """
     g = A.grid
     r, c = g.height, g.width
     m, k = A.gshape
-    full = _gather_dim(A.local, 0, VC, 0, m, r, c)        # replicated (m, k)
-    mc = _from_star_star(full, (m, k), MC, STAR, 0, 0, g)
-    adj = full.T
+    x = A.local
+    if r * c == 1:
+        blocks = x[None]
+    elif tile is None:
+        blocks = lax.all_gather(x, gather_axes(VC), axis=0)   # (p, l, k)
+    else:
+        blocks = _q8_gather_blocks(x, gather_axes(VC), tile)
+    l = x.shape[0]
+
+    def kept(d):
+        # the p/S blocks v = rank + S*t that dist d keeps, interleaved
+        S = dist_stride(d, r, c)
+        mine = lax.dynamic_index_in_dim(blocks.reshape(r * c // S, S, l, k),
+                                        rank_of(d, r, c), axis=1,
+                                        keepdims=False)
+        rows = _interleave(mine, 0)
+        return lax.slice_in_dim(rows, 0, ix.max_local_length(m, S), axis=0)
+
+    mc = _zero_padding(kept(MC), (m, k), MC, STAR, 0, 0, g)
+    adj = kept(MR).T
     if conj:
         adj = jnp.conj(adj)
-    mr = _from_star_star(adj, (k, m), STAR, MR, 0, 0, g)
-    return mc, mr
-
-
-def _panel_spread_to_pair_q8(A: DistMatrix, conj: bool, tile: int):
-    """:func:`_panel_spread_to_pair` at int8 wire precision: the one
-    all_gather moves the packed block-scaled panel, both outputs decode
-    locally -- same single collective round."""
-    g = A.grid
-    r, c = g.height, g.width
-    m, k = A.gshape
-    full = _gather_dim_q8(A.local, 0, VC, m, r, c, tile)
-    mc = _from_star_star(full, (m, k), MC, STAR, 0, 0, g)
-    adj = full.T
-    if conj:
-        adj = jnp.conj(adj)
-    mr = _from_star_star(adj, (k, m), STAR, MR, 0, 0, g)
-    return mc, mr
+    mr = _zero_padding(adj, (k, m), STAR, MR, 0, 0, g)
+    return (DistMatrix(mc, (m, k), MC, STAR, 0, 0, g),
+            DistMatrix(mr, (k, m), STAR, MR, 0, 0, g))
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -1133,7 +1174,7 @@ def _panel_spread_jit(A: DistMatrix, conj: bool, wire=None):
 
     def f(a):
         if wire == "int8":
-            return _panel_spread_to_pair_q8(a, conj, QUANT_TILE)
+            return _panel_spread_to_pair(a, conj, QUANT_TILE)
         if wire == "bf16":
             a = a.with_local(a.local.astype(jnp.bfloat16))
         mc, mr = _panel_spread_to_pair(a, conj)
@@ -1422,7 +1463,6 @@ def _redistribute(A: DistMatrix, cdist: Dist, rdist: Dist, calign: int,
                                      jnp.dtype(A.dtype).itemsize):
                 plan, fallback_reason = None, "arbitration"
     if fallback_reason:
-        from ..obs import metrics as _metrics
         _metrics.inc("redist_fallbacks", reason=fallback_reason)
     if plan is not None and not circ:
         wire = None if plan.kind == "local" \

@@ -35,6 +35,34 @@ Usage:
                                            #   'auto' arbitration consults
                                            #   them before the ring model
 
+    python -m perf.redist_bench --unpack          # the LOCAL unpack that
+                                                  #   follows a gather, alone,
+                                                  #   on one device (below)
+    python -m perf.redist_bench --unpack --blocks "2x2x1024x1024;4x7680x2048:0"
+
+``--unpack`` (ISSUE 29) times ``redist.engine``'s interleave on ONE device,
+on blocks synthesized from a seed, against an elementwise pass over the
+same bytes (one read and one write of the block, what a copy costs), so
+the per-unpack figure of the 2x2 benchmark cell can be read again without
+the four-chip machine.  A block is ``RxCxLRxLC`` (the ``r*c`` blocks of an
+[MC,MR] matrix, unpacked in both dimensions as ``[MC,MR] -> [STAR,STAR]``
+does; also timed through ``one_transpose``, the single 4-D transpose the
+engine used before, whose intermediate the TPU pads 64-fold) or
+``SxLRxLC:dim`` (``S`` blocks interleaved along ``dim``).  The default
+blocks are the cell's: the diagonal block, the crossover tail, and the
+step-0 panels of ``panel_spread``, ``[MC,MR]->[STAR,MC]`` and
+``[MC,MR]->[VC,STAR]``.  One ``redist_unpack_bench/v1`` line per form:
+
+    {"schema": "redist_unpack_bench/v1", "block": "2x2x1024x1024",
+     "form": "engine", "dtype": "float32", "block_mb": 16.777216,
+     "instances": [2, 4], "ms": ..., "x_copy": ..., "device": "TPU v5 lite"}
+
+``ms`` is one unpack's time: two compiled programs unpack ``instances``
+independent blocks each, and the difference of their least times over
+``--reps`` calls, over the difference in blocks, leaves the dispatch and
+the wait out.  ``x_copy`` is that over the ``copy`` form's.  Every form
+is checked equal, bit for bit, to ``engine`` before it is timed.
+
 On a CPU-only host run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (set automatically
 when unset) so the multi-chip grids exist; timings there are functional,
@@ -244,12 +272,106 @@ def record_constants(grid_shape, rows):
     return doc
 
 
+#: the 2x2 cell's unpacks (N = 32768, nb = 2048, f32): the diagonal block,
+#: the crossover tail, then step 0's panel_spread gather (rows, S = 4), the
+#: [VC,*] -> [MC,*] partial gather of [MC,MR]->[STAR,MC] (columns, S = 2)
+#: and the all_to_all unpack of [MC,MR]->[VC,STAR] (columns, S = 2)
+UNPACK_BLOCKS = ("2x2x1024x1024", "2x2x2048x2048", "4x7680x2048:0",
+                 "2x2048x7680:1", "2x7680x1024:1")
+
+#: bytes of input the smaller timed program holds, at most (it holds as much
+#: again in results, and one_transpose's padded temporaries beside them)
+_UNPACK_BYTES = 64 << 20
+
+
+def _unpack_forms(spec: str):
+    """``(block shape, {form: fn(blocks) -> unpacked})`` of one block spec."""
+    import elemental_tpu as el
+    from elemental_tpu.redist import engine
+    dims, _, dim = spec.partition(":")
+    try:
+        shape = tuple(int(v) for v in dims.split("x"))
+        if len(shape) == 4 and not dim:
+            r, c, lr, lc = shape
+            return shape, {
+                "copy": lambda G: G + 1,
+                "engine": lambda G: engine._interleave_2d(G, (el.MC, el.MR)),
+                "one_transpose": lambda G: G.transpose(2, 0, 3, 1).reshape(
+                    lr * r, lc * c)}
+        if len(shape) == 3 and dim in ("0", "1"):
+            return shape, {
+                "copy": lambda g: g + 1,
+                "engine": lambda g: engine._interleave(g, int(dim))}
+    except ValueError:
+        pass
+    raise SystemExit(f"bad block {spec!r}; want 'RxCxLRxLC' or 'SxLRxLC:dim'")
+
+
+def run_unpack(spec: str, reps: int = 7):
+    """Time each form of one float32 block spec on the first device;
+    returns the ``redist_unpack_bench/v1`` rows."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    shape, forms = _unpack_forms(spec)
+    nbytes = int(np.prod(shape)) * 4
+    k = max(1, min(2, _UNPACK_BYTES // nbytes))
+    x = jax.random.normal(jax.random.PRNGKey(29), (2 * k,) + shape,
+                          jnp.float32)
+    want = None
+    rows = []
+    for form, fn in forms.items():
+        # one program of k independent unpacks and one of 2k: the difference
+        # is k unpacks with the dispatch and the wait taken out
+        secs = []
+        for n in (k, 2 * k):
+            f = jax.jit(lambda xs, fn=fn, n=n: tuple(
+                fn(xs[i]) for i in range(n)))
+            out = jax.block_until_ready(f(x))       # compile, untimed
+            if form == "engine":
+                want = out
+            elif form != "copy" and not all(
+                    np.array_equal(np.asarray(a), np.asarray(b))
+                    for a, b in zip(out, want)):
+                raise SystemExit(f"{spec}: {form} differs from engine")
+            del out
+            secs.append(_min_t(lambda: jax.block_until_ready(f(x)), reps))
+        rows.append({"schema": "redist_unpack_bench/v1", "block": spec,
+                     "form": form, "dtype": "float32",
+                     "block_mb": nbytes / 1e6, "instances": [k, 2 * k],
+                     "ms": max(secs[1] - secs[0], 1e-9) / k * 1e3,
+                     "device": jax.devices()[0].device_kind})
+    for row in rows:
+        row["x_copy"] = row["ms"] / rows[0]["ms"]
+    return rows
+
+
+def main_unpack(argv) -> int:
+    blocks, reps = UNPACK_BLOCKS, 7
+    it = iter(argv)
+    for arg in it:
+        if arg == "--unpack":
+            continue
+        elif arg == "--blocks":
+            blocks = tuple(b.strip() for b in next(it).split(";"))
+        elif arg == "--reps":
+            reps = int(next(it))
+        else:
+            raise SystemExit(f"unknown flag {arg!r} with --unpack")
+    for spec in blocks:
+        for row in run_unpack(spec, reps=reps):
+            print(json.dumps(row), flush=True)
+    return 0
+
+
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if argv and argv[0] in ("-h", "--help"):
         print(__doc__)
         return 0
     _bootstrap()
+    if "--unpack" in argv:
+        return main_unpack(argv)
     import jax
     import elemental_tpu as el
 

@@ -176,3 +176,110 @@ def test_redist_trace_records_metadata(grid24):
     assert log[0].gshape == (12, 12) and log[0].dtype == "float64"
     assert log[1].in_id in log[0].out_ids          # fed back untouched
     assert engine._REDIST_TRACE is None            # restored on exit
+
+
+# ---------------------------------------------------------------------
+# the local unpack after a gather (ISSUE 29): ONE interleave primitive,
+# held bit for bit to the formulas it replaced
+# ---------------------------------------------------------------------
+
+def _blocks(shape, dtype, seed):
+    """Seeded blocks of ``dtype`` with a NaN and both infinities planted
+    where the dtype has them."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(seed)
+    if dtype == "int8":
+        return rng.integers(-128, 128, size=shape, dtype=np.int8)
+    x = rng.normal(size=shape).astype(np.float32)
+    flat = x.reshape(-1)
+    flat[[1, flat.size // 2, flat.size - 2]] = [np.nan, np.inf, -np.inf]
+    if dtype == "complex64":
+        z = np.empty(shape, np.complex64)
+        z.real, z.imag = x, x[..., ::-1]
+        return z
+    return jnp.asarray(x).astype(dtype)
+
+
+def _same_bits(a, b):
+    """Equal as raw bytes: the same NaN in the same place counts."""
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.shape == b.shape and a.dtype == b.dtype
+    return a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "complex64", "int8"])
+@pytest.mark.parametrize("block", [(8, 128), (16, 256), (5, 7), (3, 1)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("S", [2, 4, 8])
+def test_interleave_is_the_old_formula(S, dim, block, dtype):
+    """``_interleave`` against ``moveaxis + reshape``, the five hand-written
+    copies it replaced, kept here as the oracle."""
+    import jax.numpy as jnp
+    g = jnp.asarray(_blocks((S,) + block, dtype, seed=S * 10 + dim))
+    shape = list(block)
+    shape[dim] *= S
+    want = jnp.moveaxis(g, 0, dim + 1).reshape(shape)
+    got = engine._interleave(g, dim)
+    assert _same_bits(got, want)
+    # index i = iLoc*S + s, spelled out
+    take = np.asarray(got).take(np.arange(S) + S * (block[dim] - 1), axis=dim)
+    last = np.asarray(g).take(block[dim] - 1, axis=dim + 1)
+    assert _same_bits(take, np.moveaxis(last, 0, dim))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "complex64", "int8"])
+@pytest.mark.parametrize("block", [(8, 128), (5, 7)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("dist", ["MC,MR", "MR,MC"])
+@pytest.mark.parametrize("rc", [(2, 2), (2, 4), (4, 2)],
+                         ids=lambda rc: f"{rc[0]}x{rc[1]}")
+def test_interleave_2d_is_the_old_one_transpose_formula(rc, dist, block,
+                                                        dtype):
+    """The two-call unpack of ``_fused_to_star_star`` against the single
+    4-D transpose it replaced (whose intermediate the TPU pads 64-fold)."""
+    import jax.numpy as jnp
+    from elemental_tpu import MC, MR
+    r, c = rc
+    lr, lc = block
+    G = jnp.asarray(_blocks((r, c, lr, lc), dtype, seed=r * 8 + c))
+    if dist == "MC,MR":
+        want = G.transpose(2, 0, 3, 1).reshape(lr * r, lc * c)
+        got = engine._interleave_2d(G, (MC, MR))
+    else:
+        want = G.transpose(2, 1, 3, 0).reshape(lr * c, lc * r)
+        got = engine._interleave_2d(G, (MR, MC))
+    assert _same_bits(got, want)
+
+
+def _grid_of(rc):
+    import jax
+    from elemental_tpu import Grid
+    return Grid(jax.devices()[: rc[0] * rc[1]], height=rc[0])
+
+
+@pytest.mark.parametrize("shape", [(256, 512), (19, 5)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+@pytest.mark.parametrize("entry", ["to_star_star", "panel_spread",
+                                   "to_star_mc"])
+@pytest.mark.parametrize("rc", [(2, 2), (2, 4), (4, 2)],
+                         ids=lambda rc: f"{rc[0]}x{rc[1]}")
+def test_gather_entries_equal_to_global_bit_for_bit(rc, entry, shape):
+    """The cell's three gather entries on lane-aligned and ragged shapes,
+    float32 with non-finite values planted: every device's block equals
+    what ``from_global`` lays out of the same matrix."""
+    from elemental_tpu import MC, MR, VC, STAR, panel_spread
+    grid = _grid_of(rc)
+    F = np.asarray(_blocks(shape, "float32", seed=shape[0]))
+    if entry == "panel_spread":
+        A = from_global(F, VC, STAR, grid=grid)
+        mc, mrT = panel_spread(A, conj=False)
+        assert _same_bits(mc.local, from_global(F, MC, STAR, grid=grid).local)
+        assert _same_bits(mrT.local,
+                          from_global(F.T, STAR, MR, grid=grid).local)
+        assert _same_bits(to_global(mc), F)
+        return
+    dst = (STAR, STAR) if entry == "to_star_star" else (STAR, MC)
+    B = redistribute(from_global(F, MC, MR, grid=grid), *dst)
+    assert _same_bits(B.local, from_global(F, *dst, grid=grid).local)
+    assert _same_bits(to_global(B), F)

@@ -178,3 +178,55 @@ def test_tune_show_surfaces_invalid_files(cache_env, capsys):
         cmd_show("cholesky")
     out = capsys.readouterr().out
     assert "INVALID" not in out
+
+
+# ---------------------------------------------------------------------
+# redist_unpack: the engine's interleave, counted where it is traced
+# (ISSUE 29)
+# ---------------------------------------------------------------------
+
+def _unpack_counts(shape, dtype="float32"):
+    """``redist_unpack`` counters of one fresh trace of [MC,MR] ->
+    [STAR,STAR] on 2x2 (two unpacks: columns, then rows)."""
+    import jax
+    import numpy as np
+    import elemental_tpu as el
+    grid = el.Grid(jax.devices()[:4], height=2)
+    A = el.from_global(np.ones(shape, dtype), el.MC, el.MR, grid=grid)
+    jax.clear_caches()                   # the counter ticks at trace time
+    with m.scoped() as reg:
+        el.redistribute(A, el.STAR, el.STAR)
+    return {dict(labels)["impl"] + str(dict(labels)["dim"]): n
+            for (_, labels), n in reg.counters("redist_unpack").items()}
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    pytest.param((512, 512), "float32", "tiled", id="aligned-f32"),
+    pytest.param((512, 19), "float32", "generic", id="ragged"),
+    pytest.param((512, 8), "float32", "generic", id="narrow"),
+    pytest.param((512, 512), "float64", "generic", id="aligned-f64"),
+])
+def test_redist_unpack_says_whether_the_blocks_are_whole_tiles(shape, dtype,
+                                                               want):
+    assert _unpack_counts(shape, dtype) == {want + "1": 1, want + "0": 1}
+
+
+@pytest.mark.parametrize("op", ["hpd_solve", "lu_solve"])
+def test_one_chip_solves_never_reach_the_unpack(op, monkeypatch):
+    """The two one-chip benchmark cells are bypassed by construction: on a
+    1x1 grid the compiled solve is the same optimized HLO with the engine's
+    interleave replaced by something that cannot be traced, and the counter
+    reads nothing."""
+    from elemental_tpu.redist import engine
+    import jax
+    from .test_scopes import _compile, stripped
+    jax.clear_caches()              # a cached inner jaxpr is not traced again
+    with m.scoped() as reg:
+        ours = _compile(op, "1x1")
+    assert not reg.counters("redist_unpack")
+
+    def unreachable(*a, **k):
+        raise AssertionError("a 1x1 program traced the interleave")
+    monkeypatch.setattr(engine, "_interleave", unreachable)
+    monkeypatch.setattr(engine, "_interleave_2d", unreachable)
+    assert stripped(_compile(op, "1x1")) == stripped(ours)

@@ -37,3 +37,20 @@ def test_cli_smoke_exits_zero(capsys):
     rows = [json.loads(ln) for ln in out.splitlines() if ln.strip()]
     assert rows and all(r["schema"] == "redist_bench/v1" for r in rows)
     assert all(r["match"] for r in rows)
+
+
+def test_cli_unpack_times_every_form(capsys):
+    """``--unpack`` (ISSUE 29): the local unpack alone on one device, a
+    2-D block through the engine and through the one transpose it
+    replaced, a 1-D block through the engine, each beside a copy."""
+    from perf import redist_bench
+    assert redist_bench.main(["--unpack", "--reps", "1", "--blocks",
+                              "2x2x8x128;4x5x7:0;2x8x128:1"]) == 0
+    rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert all(r["schema"] == "redist_unpack_bench/v1" for r in rows)
+    assert [(r["block"], r["form"]) for r in rows] == [
+        ("2x2x8x128", "copy"), ("2x2x8x128", "engine"),
+        ("2x2x8x128", "one_transpose"), ("4x5x7:0", "copy"),
+        ("4x5x7:0", "engine"), ("2x8x128:1", "copy"),
+        ("2x8x128:1", "engine")]
+    assert all(r["ms"] > 0 and r["x_copy"] > 0 for r in rows)

@@ -3,7 +3,9 @@ a DESCRIBED v5e chip (no chip attached, nothing runs): at the widths
 ``chip_smoke.py`` gives them and at the largest shapes their VMEM gate
 admits.  A kernel body that Mosaic refuses -- a primitive with no
 lowering, a slice off the (8, 128) tiling, more VMEM than the compiler
-grants -- fails here, at no chip time.
+grants -- fails here, at no chip time.  And the redistribution engine's
+local unpacks at the 2x2 benchmark cell's shapes, for the described 2x2:
+a relayout the compiler pads 64-fold shows as temporary bytes.
 
 The topology is described inside a module-scoped fixture (never at
 import), and every compile happens in this process: only one process may
@@ -104,3 +106,52 @@ def test_compilers_default_vmem_grant_refuses_a_gate_corner(one_chip,
         # a fresh function: jit would hand back the earlier compile of _CHOL
         _compile(lambda d: potrf_inv(d, interpret=False), (1024, 1024),
                  one_chip)
+
+
+# ---------------------------------------------------------------------
+# the engine's local unpacks on the described 2x2 (ISSUE 29): what a
+# relayout costs shows here as the bytes the compiler plans beside the
+# operands -- a minor dimension of 2 padded to 128 lanes is 64 times them
+# ---------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def grid22(topo):
+    import elemental_tpu as el
+    return el.Grid(topo.devices, height=2)
+
+
+def _abstract(grid, m, n, cdist, rdist):
+    from elemental_tpu.core.distmatrix import DistMatrix
+    meta = DistMatrix(None, (m, n), cdist, rdist, 0, 0, grid)
+    return meta.with_local(jax.ShapeDtypeStruct(
+        (m, n), jnp.float32, sharding=grid.sharding(meta.spec)))
+
+
+def _temp_bytes(fn, A):
+    return jax.jit(fn).lower(A).compile().memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("n", [2048, 4096])
+def test_gather_to_star_star_unpacks_in_whole_tiles(n, grid22):
+    """The diagonal block and the crossover tail of the 2x2 cell: the one
+    4-D transpose this replaced planned 64 times the block (1.07 GB and
+    4.29 GB) for its lane-padded intermediate."""
+    import elemental_tpu as el
+    A = _abstract(grid22, n, n, el.MC, el.MR)
+    temp = _temp_bytes(lambda a: el.redistribute(a, el.STAR, el.STAR).local, A)
+    assert temp <= 2 * n * n * 4, temp
+
+
+def test_panel_spread_then_column_filter_stays_unpadded(grid22):
+    """Step 0 of the cell's Cholesky: the spread's row interleave feeds the
+    [MC,STAR] -> [MC,MR] write-back, a lane de-interleave.  Merged into one
+    reshape the pair planned 7.5 GB twice and the program no longer fitted
+    the chip; ``_deinterleave`` keeps them apart."""
+    import elemental_tpu as el
+    m, k = 30720, 2048
+    A = _abstract(grid22, m, k, el.VC, el.STAR)
+
+    def step(a):
+        mc, mr = el.panel_spread(a)
+        return el.redistribute(mc, el.MC, el.MR).local, mr.local
+    assert _temp_bytes(step, A) <= 4 * m * k * 4
