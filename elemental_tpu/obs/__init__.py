@@ -68,6 +68,21 @@ was made by the compiler or was not named.  The persistent compile cache
 is keyed with these names (``core/compile_cache.py``), so an executable
 from the cache carries this build's.
 
+Counters of the engine, ticked where an entry's Python runs: every eager
+call, and once per trace under ``jit`` (a cached jaxpr does not tick
+again).  Read them under ``metrics_scope()``:
+
+  ``redist_unpack{impl,dim}``   one local unpack after a gather
+                           (``impl`` ``tiled`` | ``generic``; ``dim`` 1 for
+                           the lane dimension)
+  ``row_permute{kind}``    one storage-level row permutation: ``kind``
+                           ``move`` (``move_rows``: a panel step's pivot
+                           swaps) | ``full`` (``permute_rows_storage``:
+                           all of B); beside it, under the same label,
+                           ``row_permute_rows`` (rows asked to move) and
+                           ``row_permute_wire_bytes`` (worst-case bytes a
+                           device receives: every row crossing chips)
+
 CLI: ``python -m perf.trace {run,summary,export,serve}``.  Regression
 gate over the bench trajectory: ``tools/bench_diff.py`` (wired into
 ``tools/check.sh``).

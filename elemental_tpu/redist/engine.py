@@ -1251,7 +1251,13 @@ def move_rows(A: DistMatrix, targets, sources, valid) -> DistMatrix:
     sentinel's arithmetic image.  No named collective is issued: the
     cross-device row motion lowers through GSPMD's partitioner, so the
     comm-plan analyzer sees the swap phase as zero explicit rounds
-    (``REDIST_COUNTS['row_permute']`` still counts the entry calls)."""
+    (``REDIST_COUNTS['row_permute']`` still counts the entry calls).
+
+    Counted at trace time in ``row_permute{kind="move"}``, with the rows
+    asked to move in ``row_permute_rows`` and the worst-case bytes a device
+    receives (every moved row crossing chips) in ``row_permute_wire_bytes``:
+    what a panel step asks of the wire, whatever the partitioner makes of
+    it."""
     REDIST_COUNTS["row_permute"] += 1
     S, lr = A.col_stride, A.local_rows
     m = A.gshape[0]
@@ -1261,17 +1267,11 @@ def move_rows(A: DistMatrix, targets, sources, valid) -> DistMatrix:
     stor = A.local
     rows = jnp.take(stor, gsrc, axis=0)
     out = A.with_local(stor.at[sidx].set(rows, mode="drop"))
-    # observer seam (ISSUE 12): the obs tracer must see this entry's wire
-    # traffic (<= moved rows x local row width, worst case all cross-chip)
-    # even though GSPMD plans the motion -- observers_only keeps it OUT of
-    # the comm-plan golden aggregation, which pins explicit rounds
+    # wire traffic: <= moved rows x local row width, worst case all
+    # cross-chip
     k = int(targets.shape[0])
-    _trace_record("row_permute", A.dist, A.dist, (k, A.gshape[1]),
-                  A.dtype, A.local, (out.local,),
-                  grid_shape=(A.grid.height, A.grid.width),
-                  path="storage", rounds=0,
-                  wire_bytes=k * stor.shape[1] * jnp.dtype(A.dtype).itemsize,
-                  observers_only=True)
+    _record_row_permute("move", A, out, k,
+                        k * stor.shape[1] * jnp.dtype(A.dtype).itemsize)
     return out
 
 
@@ -1284,7 +1284,8 @@ def permute_rows_storage(A: DistMatrix, perm, inverse: bool = False
     Replaces the historical [STAR,VR] round trip (two collective rounds:
     demote + promote) with a single storage gather whose cross-device
     motion GSPMD plans directly -- the engine-level fast path behind
-    ``lapack.lu.permute_rows``."""
+    ``lapack.lu.permute_rows``.  Counted as ``row_permute{kind="full"}``
+    (see :func:`move_rows`)."""
     if (A.calign, A.ralign) != (0, 0):
         raise ValueError(f"permute_rows_storage needs zero alignments, got {A}")
     REDIST_COUNTS["row_permute"] += 1
@@ -1300,16 +1301,28 @@ def permute_rows_storage(A: DistMatrix, perm, inverse: bool = False
         out = jnp.take(A.local, src, axis=0)
         out = jnp.where((gi < m)[:, None], out, 0)  # keep padding zeroed
         res = A.with_local(out)
-    # observer seam (ISSUE 12): surface the GSPMD-planned full-permutation
-    # motion to the obs tracer (worst case the whole local block crosses
-    # chips); observers_only keeps it out of the round-pinning goldens
-    _trace_record("row_permute", A.dist, A.dist, A.gshape, A.dtype,
-                  A.local, (res.local,),
-                  grid_shape=(A.grid.height, A.grid.width),
-                  path="storage", rounds=0,
-                  wire_bytes=int(A.local.size) * jnp.dtype(A.dtype).itemsize,
-                  observers_only=True)
+    # wire traffic: worst case the whole local block crosses chips
+    _record_row_permute("full", A, res, m,
+                        int(A.local.size) * jnp.dtype(A.dtype).itemsize)
     return res
+
+
+def _record_row_permute(kind: str, A: DistMatrix, out: DistMatrix,
+                        rows: int, wire_bytes: int) -> None:
+    """One storage-level row permutation, for the counters and the
+    observers: ``row_permute{kind}`` entries, the rows they move and the
+    worst-case bytes a device receives; and the observer seam (ISSUE 12):
+    the obs tracer must see this entry's wire traffic even though GSPMD
+    plans the motion -- ``observers_only`` keeps it OUT of the comm-plan
+    golden aggregation, which pins explicit rounds."""
+    _metrics.inc("row_permute", kind=kind)
+    _metrics.inc("row_permute_rows", rows, kind=kind)
+    _metrics.inc("row_permute_wire_bytes", wire_bytes, kind=kind)
+    _trace_record("row_permute", A.dist, A.dist, (rows, A.gshape[1]),
+                  A.dtype, A.local, (out.local,),
+                  grid_shape=(A.grid.height, A.grid.width),
+                  path="storage", rounds=0, wire_bytes=wire_bytes,
+                  observers_only=True)
 
 
 # ---------------------------------------------------------------------

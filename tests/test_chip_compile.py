@@ -155,3 +155,37 @@ def test_panel_spread_then_column_filter_stays_unpadded(grid22):
         mc, mr = el.panel_spread(a)
         return el.redistribute(mc, el.MC, el.MR).local, mr.local
     assert _temp_bytes(step, A) <= 4 * m * k * 4
+
+
+def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
+    """A panel step's pivot swaps at the 2x2 LU cell's size (ISSUE 31):
+    ``move_rows`` of 4096 rows of a [MC,MR] 16384 x 16384 operand, whose
+    cross-device motion the partitioner plans.  It plans 134,549,504 bytes
+    of temporaries beside the 268,435,456-byte shard (0.501 of it: the
+    moved rows at the shard's width, ``f32[4096,8192]``), and the only
+    collective it inserts is ONE all-reduce of those rows over the grid's
+    column, named after the gather it was made from (so the trace books it
+    under ``el.redist.row_permute``).  The bound is a guard against growth:
+    a gather or scatter the partitioner answers by replicating the operand
+    would plan a whole matrix, four shards."""
+    import elemental_tpu as el
+    from elemental_tpu.redist.engine import move_rows
+    n, k = 16384, 4096
+    A = _abstract(grid22, n, n, el.MC, el.MR)
+    idx = jax.ShapeDtypeStruct((k,), jnp.int32)
+    valid = jax.ShapeDtypeStruct((k,), jnp.bool_)
+    compiled = jax.jit(
+        lambda a, t, s, v: move_rows(a, t, s, v).local).lower(
+            A, idx, idx, valid).compile()
+    shard = n * n * 4 // 4                  # float32 bytes on each of four
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 0.6 * shard, temp
+    text = compiled.as_text()
+    collectives = {c: text.count(f" {c}(") + text.count(f" {c}-start(")
+                   for c in ("all-gather", "all-to-all", "all-reduce",
+                             "collective-permute")}
+    assert collectives == {"all-gather": 0, "all-to-all": 0, "all-reduce": 1,
+                           "collective-permute": 0}
+    reduce_line = next(line for line in text.split("\n")
+                       if " all-reduce(" in line)
+    assert "el.redist.row_permute" in reduce_line
