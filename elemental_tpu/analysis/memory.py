@@ -166,18 +166,28 @@ def _walk_scope(jaxpr, div: int, path: tuple, static: bool,
     allocates its own eqn outvars afterward.  The transient "freed then
     re-allocated" boundary never lowers the recorded peak because the
     peak was taken while the scope's outputs were live inside it."""
+    # optimization_barrier is an identity the compiler keeps in place: its
+    # results ARE its operands' buffers, so a use of a result is a use of
+    # the operand and the barrier itself allocates nothing
+    alias: dict = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "optimization_barrier":
+            for out, src in zip(eqn.outvars, eqn.invars):
+                alias[out] = alias.get(src, src)
     last: dict = {}
     for idx, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
             if isinstance(v, jcore.Var):
-                last[v] = idx
+                last[alias.get(v, v)] = idx
     end = len(jaxpr.eqns)
     for v in jaxpr.outvars:
         if isinstance(v, jcore.Var):
-            last[v] = end
+            last[alias.get(v, v)] = end
     inner: dict = {}                     # var -> (bytes, static)
     for idx, eqn in enumerate(jaxpr.eqns):
         prim = eqn.primitive.name
+        if prim == "optimization_barrier":
+            continue
         sub_div = 1 if prim == "shard_map" else div
         sub_static = static and prim != "while"
         label = _scope_label(eqn)
@@ -200,7 +210,8 @@ def _walk_scope(jaxpr, div: int, path: tuple, static: bool,
                 inner[v] = (b, static)
             else:                        # DropVar / immediately dead
                 state.free(b, static)
-        for v in set(x for x in eqn.invars if isinstance(x, jcore.Var)):
+        for v in set(alias.get(x, x) for x in eqn.invars
+                     if isinstance(x, jcore.Var)):
             if last.get(v) == idx and v in inner:
                 b, st = inner.pop(v)
                 state.free(b, st)

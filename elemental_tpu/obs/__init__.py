@@ -75,6 +75,10 @@ again).  Read them under ``metrics_scope()``:
   ``redist_unpack{impl,dim}``   one local unpack after a gather
                            (``impl`` ``tiled`` | ``generic``; ``dim`` 1 for
                            the lane dimension)
+  ``redist_filter{impl,dim}``   its mirror: one local cyclic slice that
+                           makes a replicated (or coarser) dimension
+                           distributed (the same two labels, by the block
+                           it returns)
   ``row_permute{kind}``    one storage-level row permutation: ``kind``
                            ``move`` (``move_rows``: a panel step's pivot
                            swaps) | ``full`` (``permute_rows_storage``:

@@ -276,6 +276,19 @@ def _pad_dim(x, dim: int, target: int):
     return jnp.pad(x, pads)
 
 
+def _count_relayout(name: str, block, dtype, dim: int):
+    """One ``redist_unpack`` / ``redist_filter`` tick, at trace time:
+    ``impl="tiled"`` when the blocks are whole (8, 128) tiles of a 4-byte
+    dtype, so that nothing the compiler moves is padded; ``"generic"`` for
+    ragged, narrow or other-width blocks, which run the same formula on
+    padded tiles.  ``dim`` is 1 for the minor (lane) dimension of the
+    block, else 0."""
+    whole = (dtype.itemsize == 4 and len(block) >= 2
+             and block[-1] % 128 == 0 and block[-2] % 8 == 0)
+    _metrics.inc(name, impl="tiled" if whole else "generic",
+                 dim=int(dim == len(block) - 1))
+
+
 def _interleave(g, dim: int):
     """The local unpack after a gather over a cyclic dimension: ``g`` holds
     ``S`` rank-ordered blocks, shape ``(S, ...)``; the result has
@@ -290,19 +303,13 @@ def _interleave(g, dim: int):
     dimension is ``S``, padded to 128 lanes: 1 GiB and 2.4 ms for a 16 MB
     block on a v5e (PERF.md 6, PR 29).  A 2-D unpack is two calls.
 
-    Counted at trace time in ``redist_unpack``: ``impl="tiled"`` when the
-    blocks are whole (8, 128) tiles of a 4-byte dtype, so that nothing the
-    compiler moves is padded; ``"generic"`` for ragged, narrow or other-width
-    blocks, which run the same formula on padded tiles.  ``dim`` is 1 for
-    the minor (lane) dimension of the block, else 0."""
+    Counted at trace time in ``redist_unpack{impl,dim}``
+    (:func:`_count_relayout`) by the ``S`` blocks it is given."""
     S = g.shape[0]
     if S == 1:
         return g[0]
     shape = list(g.shape[1:])
-    whole = (g.dtype.itemsize == 4 and len(shape) >= 2
-             and shape[-1] % 128 == 0 and shape[-2] % 8 == 0)
-    _metrics.inc("redist_unpack", impl="tiled" if whole else "generic",
-                 dim=int(dim == len(shape) - 1))
+    _count_relayout("redist_unpack", shape, g.dtype, dim)
     shape[dim] *= S
     return jnp.moveaxis(g, 0, dim + 1).reshape(shape)
 
@@ -310,17 +317,29 @@ def _interleave(g, dim: int):
 def _deinterleave(x, dim: int, S: int, shift):
     """The mirror of :func:`_interleave`: the cyclic slice
     ``i = iLoc*S + shift`` of dimension ``dim`` (extent a multiple of
-    ``S``; ``shift`` may be traced)."""
-    shape = list(x.shape)
-    shape[dim : dim + 1] = [shape[dim] // S, S]           # (..., l_out, S, ...)
-    if dim == x.ndim - 1:
-        # left alone, the TPU compiler transposes first and de-interleaves
-        # rows; merged with a producer's reshape (an _interleave's) it cannot,
-        # and pads the minor dimension of S to 128 lanes: 7.5 GB for a
-        # 60 MB panel, and the 2x2 cell no longer fits (PERF.md 6, PR 29)
-        x = lax.optimization_barrier(x)
-    return lax.dynamic_index_in_dim(x.reshape(shape), shift, axis=dim + 1,
-                                    keepdims=False)
+    ``S``; ``shift`` may be traced).  Counted at trace time in
+    ``redist_filter{impl,dim}`` by the block it returns.
+
+    The operand is taken behind ``lax.optimization_barrier``, whichever
+    dimension is sliced.  Alone, the reshape below compiles to whole tiles
+    (a lane slice becomes transpose, row slice, transpose); merged with a
+    producer's reshape, an :func:`_interleave`'s above all, it becomes one
+    reshape whose minor dimension is ``S``, padded to 128 lanes.  Two cases
+    paid for it on a v5e: ``panel_spread``'s row interleave feeding a LANE
+    slice (7.5 GB for a 60 MB panel, and the 2x2 Cholesky cell no longer
+    fitted: PERF.md 6, PR 29), and the [STAR,VR] -> [STAR,MR] partial
+    gather's lane interleave feeding the ROW slice of [STAR,MR] -> [MC,MR]
+    in the LU step (a 4.29 GB temporary for a 67 MB block, written by one
+    op and read by the next, 16 ms of a step: PERF.md 6, PR 32)."""
+    if S == 1:
+        return x
+    block = list(x.shape)
+    block[dim] //= S
+    _count_relayout("redist_filter", block, x.dtype, dim)
+    split = block[: dim + 1] + [S] + block[dim + 1 :]     # (..., l_out, S, ...)
+    return lax.dynamic_index_in_dim(
+        lax.optimization_barrier(x).reshape(split), shift, axis=dim + 1,
+        keepdims=False)
 
 
 def _gather_dim(x, dim: int, d: Dist, align: int, extent: int, r: int, c: int):

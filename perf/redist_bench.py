@@ -39,6 +39,11 @@ Usage:
                                                   #   follows a gather, alone,
                                                   #   on one device (below)
     python -m perf.redist_bench --unpack --blocks "2x2x1024x1024;4x7680x2048:0"
+    python -m perf.redist_bench --filter          # the LOCAL cyclic slice
+                                                  #   that makes a replicated
+                                                  #   dimension distributed,
+                                                  #   alone and behind an
+                                                  #   interleave (below)
 
 ``--unpack`` (ISSUE 29) times ``redist.engine``'s interleave on ONE device,
 on blocks synthesized from a seed, against an elementwise pass over the
@@ -62,6 +67,26 @@ independent blocks each, and the difference of their least times over
 ``--reps`` calls, over the difference in blocks, leaves the dispatch and
 the wait out.  ``x_copy`` is that over the ``copy`` form's.  Every form
 is checked equal, bit for bit, to ``engine`` before it is timed.
+
+``--filter`` (ISSUE 32) is the mirror: ``redist.engine``'s de-interleave
+of a float32 block ``SxLRxLC:dim`` (the ``LR x LC`` block, of which the
+slice ``i = iLoc*S + shift`` of dimension ``dim`` is kept; ``shift`` is an
+argument of the timed program, as it is traced in the engine), or the
+composed ``SxLRxLC:1>0``: ``S`` blocks interleaved along the lanes, the
+result returned AND row-filtered, ONE jitted function, as the 2x2 LU
+cell's row block runs ``[STAR,VR] -> [STAR,MR] -> [MC,MR]``; alone each
+half compiles clean and hides what the pair costs.  The default blocks
+are that cell's.  Forms: ``copy`` (a plain copy of the input block),
+``elementwise`` (one pass over it), ``engine`` (reshape to
+``(..., l, S, ...)`` and index, behind ``optimization_barrier``), and the
+forms the engine does not use, kept to be measured again:
+``reshape_index`` (the same with no barrier: the engine before ISSUE 32
+on rows), ``strided`` (``S`` static strided slices and a select on
+``shift``),
+``switch`` (the same slices as the branches of a ``lax.switch``) and, on
+rows, ``lane_slice`` (reshape to ``(l, S*LC)`` and a lane-aligned
+dynamic slice).  One ``redist_filter_bench/v1`` line per form, the fields
+of ``redist_unpack_bench/v1``.
 
 On a CPU-only host run under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (set automatically
@@ -307,36 +332,35 @@ def _unpack_forms(spec: str):
     raise SystemExit(f"bad block {spec!r}; want 'RxCxLRxLC' or 'SxLRxLC:dim'")
 
 
-def run_unpack(spec: str, reps: int = 7):
-    """Time each form of one float32 block spec on the first device;
-    returns the ``redist_unpack_bench/v1`` rows."""
+def _time_forms(schema, spec, forms, x, extra, reps):
+    """One row per form: two compiled programs run ``fn(x[i], *extra)`` on
+    ``k`` and on all ``2k`` of the independent blocks ``x``, and the
+    difference of their least times over the difference in blocks leaves
+    the dispatch and the wait out.  Every form but ``copy`` and
+    ``elementwise`` is checked equal, bit for bit, to ``engine`` (which
+    comes before it)."""
     import numpy as np
     import jax
-    import jax.numpy as jnp
-    shape, forms = _unpack_forms(spec)
-    nbytes = int(np.prod(shape)) * 4
-    k = max(1, min(2, _UNPACK_BYTES // nbytes))
-    x = jax.random.normal(jax.random.PRNGKey(29), (2 * k,) + shape,
-                          jnp.float32)
+    k, nbytes = x.shape[0] // 2, x[0].nbytes
     want = None
     rows = []
     for form, fn in forms.items():
-        # one program of k independent unpacks and one of 2k: the difference
-        # is k unpacks with the dispatch and the wait taken out
         secs = []
         for n in (k, 2 * k):
-            f = jax.jit(lambda xs, fn=fn, n=n: tuple(
-                fn(xs[i]) for i in range(n)))
-            out = jax.block_until_ready(f(x))       # compile, untimed
+            f = jax.jit(lambda xs, *a, fn=fn, n=n: tuple(
+                fn(xs[i], *a) for i in range(n)))
+            out = jax.block_until_ready(f(x, *extra))   # compile, untimed
             if form == "engine":
                 want = out
-            elif form != "copy" and not all(
+            elif form not in ("copy", "elementwise") and not all(
                     np.array_equal(np.asarray(a), np.asarray(b))
-                    for a, b in zip(out, want)):
+                    for a, b in zip(jax.tree.leaves(out),
+                                    jax.tree.leaves(want))):
                 raise SystemExit(f"{spec}: {form} differs from engine")
             del out
-            secs.append(_min_t(lambda: jax.block_until_ready(f(x)), reps))
-        rows.append({"schema": "redist_unpack_bench/v1", "block": spec,
+            secs.append(_min_t(
+                lambda: jax.block_until_ready(f(x, *extra)), reps))
+        rows.append({"schema": schema, "block": spec,
                      "form": form, "dtype": "float32",
                      "block_mb": nbytes / 1e6, "instances": [k, 2 * k],
                      "ms": max(secs[1] - secs[0], 1e-9) / k * 1e3,
@@ -346,20 +370,119 @@ def run_unpack(spec: str, reps: int = 7):
     return rows
 
 
-def main_unpack(argv) -> int:
-    blocks, reps = UNPACK_BLOCKS, 7
+def _blocks_of(shape, seed):
+    """``2k`` seeded float32 blocks of ``shape``, ``k`` (1 or 2) so that
+    the smaller timed program holds at most ``_UNPACK_BYTES`` of them."""
+    import math
+    import jax
+    import jax.numpy as jnp
+    k = max(1, min(2, _UNPACK_BYTES // (math.prod(shape) * 4)))
+    return jax.random.normal(
+        jax.random.PRNGKey(seed), (2 * k,) + tuple(shape), jnp.float32)
+
+
+def run_unpack(spec: str, reps: int = 7):
+    """Time each form of one float32 block spec on the first device;
+    returns the ``redist_unpack_bench/v1`` rows."""
+    shape, forms = _unpack_forms(spec)
+    return _time_forms("redist_unpack_bench/v1", spec, forms,
+                       _blocks_of(shape, 29), (), reps)
+
+
+#: the 2x2 LU cell's de-interleaves (N = 16384, nb = 2048, f32): the U row
+#: block's write-back [STAR,MR] -> [MC,MR] at steps 0 and 1 (rows, S = 2),
+#: the panel's write-back [STAR,STAR] -> [MC,MR] (rows, then lanes), the
+#: Cholesky cell's [MC,STAR] -> [MC,MR] (lanes), and the row block's chain
+#: [STAR,VR] -> [STAR,MR] -> [MC,MR] at steps 0 and 1, composed
+FILTER_BLOCKS = ("2x2048x8192:0", "2x2048x7168:0", "2x16384x2048:0",
+                 "2x8192x2048:1", "2x15360x2048:1", "2x2048x4096:1>0",
+                 "2x2048x3584:1>0")
+
+
+def _filter_forms(spec: str):
+    """``(input shape, S, {form: fn(block, shift)})`` of one filter spec."""
+    import jax.numpy as jnp
+    from jax import lax
+    from elemental_tpu.redist import engine
+    dims, _, tail = spec.partition(":")
+    try:
+        S, lr, lc = (int(v) for v in dims.split("x"))
+    except ValueError:
+        S = 0
+    if S < 1 or tail not in ("0", "1", "1>0"):
+        raise SystemExit(f"bad block {spec!r}; want 'SxLRxLC:dim' or "
+                         f"'SxLRxLC:1>0'")
+    dim = 0 if tail == "1>0" else int(tail)
+
+    def split(x):
+        shape = list(x.shape)
+        shape[dim:dim + 1] = [shape[dim] // S, S]
+        return x.reshape(shape)
+
+    def index(x, shift):
+        return lax.dynamic_index_in_dim(split(x), shift, axis=dim + 1,
+                                        keepdims=False)
+
+    def strided(x, shift):
+        return lax.select_n(shift, *(
+            lax.slice_in_dim(x, s, x.shape[dim], stride=S, axis=dim)
+            for s in range(S)))
+
+    def lane_slice(x, shift):
+        y = x.reshape(x.shape[0] // S, S * x.shape[1])
+        return lax.dynamic_slice_in_dim(y, shift * x.shape[1], x.shape[1],
+                                        axis=1)
+
+    forms = {
+        "engine": lambda x, shift: engine._deinterleave(x, dim, S, shift),
+        "reshape_index": index,
+        "strided": strided,
+        "switch": lambda x, shift: lax.switch(shift, [
+            lambda x, s=s: lax.slice_in_dim(x, s, x.shape[dim], stride=S,
+                                            axis=dim)
+            for s in range(S)], x)}
+    if dim == 0:
+        forms["lane_slice"] = lane_slice
+    if tail == "1>0":
+        # g holds the S gathered blocks; both results are returned, as the
+        # LU step returns the [STAR,MR] block beside its [MC,MR] write-back
+        def behind_interleave(fn):
+            def composed(g, shift):
+                full = engine._interleave(g, 1)
+                return fn(full, shift), full
+            return composed
+        forms = {form: behind_interleave(fn) for form, fn in forms.items()}
+        shape = (S, lr, lc)
+    else:
+        shape = (lr, lc)
+    return shape, S, {"copy": lambda x, shift: jnp.copy(x),
+                      "elementwise": lambda x, shift: x + 1, **forms}
+
+
+def run_filter(spec: str, reps: int = 7):
+    """Time each form of one filter spec on the first device; returns the
+    ``redist_filter_bench/v1`` rows."""
+    import jax.numpy as jnp
+    shape, S, forms = _filter_forms(spec)
+    return _time_forms("redist_filter_bench/v1", spec, forms,
+                       _blocks_of(shape, 32), (jnp.int32(S - 1),), reps)
+
+
+def main_local(argv, mode, blocks, run) -> int:
+    """``--unpack`` / ``--filter``: one device, one line per form."""
+    reps = 7
     it = iter(argv)
     for arg in it:
-        if arg == "--unpack":
+        if arg == mode:
             continue
         elif arg == "--blocks":
             blocks = tuple(b.strip() for b in next(it).split(";"))
         elif arg == "--reps":
             reps = int(next(it))
         else:
-            raise SystemExit(f"unknown flag {arg!r} with --unpack")
+            raise SystemExit(f"unknown flag {arg!r} with {mode}")
     for spec in blocks:
-        for row in run_unpack(spec, reps=reps):
+        for row in run(spec, reps=reps):
             print(json.dumps(row), flush=True)
     return 0
 
@@ -371,7 +494,9 @@ def main(argv=None) -> int:
         return 0
     _bootstrap()
     if "--unpack" in argv:
-        return main_unpack(argv)
+        return main_local(argv, "--unpack", UNPACK_BLOCKS, run_unpack)
+    if "--filter" in argv:
+        return main_local(argv, "--filter", FILTER_BLOCKS, run_filter)
     import jax
     import elemental_tpu as el
 

@@ -94,6 +94,26 @@ def test_walk_fanout_holds_operand():
     assert an.analyze_jaxpr(closed).peak_bytes == 3 * one
 
 
+def test_walk_sees_through_an_optimization_barrier():
+    """The barrier's result IS its operand's buffer: it allocates nothing,
+    and a use behind it keeps the operand alive (the engine's de-interleave
+    takes every operand behind one, ISSUE 32)."""
+    def plain(x):
+        y = x * 2.0
+        return y.reshape(16, 64) + 1.0
+
+    def barred(x):
+        y = jax.lax.optimization_barrier(x * 2.0)
+        return y.reshape(16, 64) + 1.0
+
+    x = jax.ShapeDtypeStruct((32, 32), jnp.float32)
+    want = an.analyze_jaxpr(jax.make_jaxpr(plain)(x))
+    got = an.analyze_jaxpr(jax.make_jaxpr(barred)(x))
+    assert got.peak_bytes == want.peak_bytes == 3 * 32 * 32 * 4
+    assert [m.live_bytes for m in got.timeline] == [
+        m.live_bytes for m in want.timeline]
+
+
 def test_walk_divides_by_grid_size():
     def f(x):
         return x * 2.0

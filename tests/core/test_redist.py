@@ -283,3 +283,63 @@ def test_gather_entries_equal_to_global_bit_for_bit(rc, entry, shape):
     B = redistribute(from_global(F, MC, MR, grid=grid), *dst)
     assert _same_bits(B.local, from_global(F, *dst, grid=grid).local)
     assert _same_bits(to_global(B), F)
+
+
+# ---------------------------------------------------------------------
+# the local filter that makes a replicated dimension distributed
+# (ISSUE 32): the mirror primitive, held to numpy's strided slice
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("block", [(8, 128), (16, 256), (5, 7), (3, 1)],
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("dim", [0, 1])
+@pytest.mark.parametrize("S", [2, 4])
+def test_deinterleave_is_numpys_strided_slice(S, dim, block):
+    """``_deinterleave`` under ``jit`` with a TRACED shift, as the engine
+    calls it (the shift is a device's rank), on whole-tile and ragged
+    blocks: the slice ``shift::S`` of ``dim``, bit for bit, with NaN, both
+    infinities and -0.0 planted."""
+    import jax
+    shape = list(block)
+    shape[dim] *= S
+    x = np.array(_blocks(tuple(shape), "float32", seed=S * 10 + dim))
+    x.reshape(-1)[[0, x.size // 3, x.size - 1]] = -0.0
+    f = jax.jit(lambda x, shift: engine._deinterleave(x, dim, S, shift))
+    for shift in range(S):
+        want = x[shift::S] if dim == 0 else x[:, shift::S]
+        assert _same_bits(f(x, np.int32(shift)), want)
+    # and the inverse of the interleave: block s back out of the S blocks
+    g = _blocks((S,) + block, "float32", seed=S + dim)
+    for s in range(S):
+        assert _same_bits(
+            engine._deinterleave(engine._interleave(g, dim), dim, S, s), g[s])
+
+
+@pytest.mark.parametrize("shape", [(32, 1024), (16, 512), (19, 5), (13, 64)],
+                         ids=lambda s: f"{s[0]}x{s[1]}")
+@pytest.mark.parametrize("rc", [(2, 2), (1, 4)],
+                         ids=lambda rc: f"{rc[0]}x{rc[1]}")
+def test_lu_row_block_chain_round_trips(rc, shape):
+    """The LU step's row block, as ``lapack/lu.py`` composes it:
+    [MC,MR] -> [STAR,VR] -> [STAR,MR] -> [MC,MR] in ONE jitted function with
+    the [STAR,MR] block returned beside the write-back (the lane interleave
+    feeds the row filter there): every hop equals ``from_global`` of the
+    same matrix and the round trip equals its input, bit for bit."""
+    import jax
+    from elemental_tpu import MC, MR, VR, STAR
+    grid = _grid_of(rc)
+    F = np.array(_blocks(shape, "float32", seed=shape[1]))
+    F.reshape(-1)[[0, F.size - 1]] = -0.0
+    A = from_global(F, MC, MR, grid=grid)
+
+    @jax.jit
+    def chain(a):
+        vr = redistribute(a, STAR, VR)
+        mr = redistribute(vr, STAR, MR)
+        return vr, mr, redistribute(mr, MC, MR)
+    vr, mr, back = chain(A)
+    assert _same_bits(vr.local, from_global(F, STAR, VR, grid=grid).local)
+    assert _same_bits(mr.local, from_global(F, STAR, MR, grid=grid).local)
+    assert _same_bits(back.local, A.local)
+    # to_global itself gives +0.0 for -0.0: equal, not the same bits
+    np.testing.assert_array_equal(np.asarray(to_global(back)), F)

@@ -1,5 +1,6 @@
 """Metrics registry behavior + the ``obs_metrics/v1`` schema pin, and the
 tuning-cache hit/miss/stale counters (ISSUE 5 satellite)."""
+import contextlib
 import json
 import os
 
@@ -185,6 +186,36 @@ def test_tune_show_surfaces_invalid_files(cache_env, capsys):
 # (ISSUE 29)
 # ---------------------------------------------------------------------
 
+@contextlib.contextmanager
+def _fresh_engine_jits():
+    """The engine's two jitted entries rebuilt around NEW function objects,
+    for the time of the block: jax keys its trace cache by the function, so
+    whatever is traced under the block runs the entries' Python again (the
+    counters tick at trace time), whatever an earlier test left cached, and
+    no cache any other test compiled into is cleared."""
+    import jax
+    from elemental_tpu.redist import engine
+
+    def anew(fn):
+        return lambda *args: fn(*args)
+    with pytest.MonkeyPatch.context() as patch:
+        for name, static in (("_redistribute_jit", (1, 2, 3, 4, 5)),
+                             ("_panel_spread_jit", (1, 2))):
+            patch.setattr(engine, name, jax.jit(
+                anew(getattr(engine, name).__wrapped__),
+                static_argnums=static))
+        yield
+
+
+def _relayout_counts(name, fn):
+    """``{impl + dim: ticks}`` of one fresh trace of the engine's entries
+    under ``fn``."""
+    with _fresh_engine_jits(), m.scoped() as reg:
+        fn()
+    return {dict(labels)["impl"] + str(dict(labels)["dim"]): n
+            for (_, labels), n in reg.counters(name).items()}
+
+
 def _unpack_counts(shape, dtype="float32"):
     """``redist_unpack`` counters of one fresh trace of [MC,MR] ->
     [STAR,STAR] on 2x2 (two unpacks: columns, then rows)."""
@@ -193,11 +224,8 @@ def _unpack_counts(shape, dtype="float32"):
     import elemental_tpu as el
     grid = el.Grid(jax.devices()[:4], height=2)
     A = el.from_global(np.ones(shape, dtype), el.MC, el.MR, grid=grid)
-    jax.clear_caches()                   # the counter ticks at trace time
-    with m.scoped() as reg:
-        el.redistribute(A, el.STAR, el.STAR)
-    return {dict(labels)["impl"] + str(dict(labels)["dim"]): n
-            for (_, labels), n in reg.counters("redist_unpack").items()}
+    return _relayout_counts(
+        "redist_unpack", lambda: el.redistribute(A, el.STAR, el.STAR))
 
 
 @pytest.mark.parametrize("shape,dtype,want", [
@@ -230,3 +258,47 @@ def test_one_chip_solves_never_reach_the_unpack(op, monkeypatch):
     monkeypatch.setattr(engine, "_interleave", unreachable)
     monkeypatch.setattr(engine, "_interleave_2d", unreachable)
     assert stripped(_compile(op, "1x1")) == stripped(ours)
+
+
+# ---------------------------------------------------------------------
+# redist_filter: the engine's de-interleave, the mirror (ISSUE 32)
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,nb,want", [
+    pytest.param(1024, 512, "tiled", id="whole-tiles"),
+    pytest.param(96, 48, "generic", id="ragged"),
+])
+def test_redist_filter_counts_of_an_lu_step(n, nb, want):
+    """``lu`` on 2x2 in two panels.  Step 0 filters four times: the
+    panel's write-back [STAR,STAR] -> [MC,MR] (rows, then lanes), the U row
+    block's [STAR,MR] -> [MC,MR] (rows: the one that sat behind the partial
+    gather's lane interleave) and L21's [STAR,STAR] -> [MC,STAR] (rows);
+    the last panel only writes itself back (rows, lanes)."""
+    import jax
+    import numpy as np
+    import elemental_tpu as el
+    grid = el.Grid(jax.devices()[:4], height=2)
+    A = el.from_global(np.random.default_rng(32).normal(
+        size=(n, n)).astype(np.float32), el.MC, el.MR, grid=grid)
+    got = _relayout_counts("redist_filter",
+                           lambda: el.lu(A, nb=nb, crossover=0))
+    assert got == {want + "0": 4, want + "1": 2}
+
+
+def test_redist_filter_is_silent_on_one_chip_and_costs_the_program_nothing(
+        monkeypatch):
+    """On 1x1 no entry reaches the de-interleave; on 2x2 the compiled LU
+    solve is the same optimized HLO with the counter taken out (it ticks
+    at trace time and leaves nothing in the program)."""
+    from elemental_tpu.redist import engine
+    from .test_scopes import _compile, stripped
+    assert not _relayout_counts("redist_filter",
+                                lambda: _compile("lu_solve", "1x1"))
+    texts = []
+
+    def compile_2x2():
+        texts.append(stripped(_compile("lu_solve", "2x2")))
+    assert _relayout_counts("redist_filter", compile_2x2)
+    monkeypatch.setattr(engine, "_count_relayout", lambda *a, **k: None)
+    assert not _relayout_counts("redist_filter", compile_2x2)
+    assert texts[0] == texts[1]

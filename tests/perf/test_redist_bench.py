@@ -4,6 +4,8 @@ cross-check green, and the ``p2p_gbps`` helper feeding bench.py's obs
 block returns both paths."""
 import json
 
+import pytest
+
 
 def test_run_pair_rows_and_match(grid24):
     from perf.redist_bench import run_pair, _dist_pair
@@ -54,3 +56,30 @@ def test_cli_unpack_times_every_form(capsys):
         ("4x5x7:0", "engine"), ("2x8x128:1", "copy"),
         ("2x8x128:1", "engine")]
     assert all(r["ms"] > 0 and r["x_copy"] > 0 for r in rows)
+
+
+def test_cli_filter_times_every_form(capsys):
+    """``--filter`` (ISSUE 32): the local cyclic slice alone (rows, lanes)
+    and composed behind a lane interleave as one jitted function, the
+    engine's form and the forms it does not use, each checked equal to the
+    engine's bit for bit, beside a copy and an elementwise pass."""
+    from perf import redist_bench
+    assert redist_bench.main(["--filter", "--reps", "1", "--blocks",
+                              "2x16x128:0;4x8x12:1;2x8x128:1>0"]) == 0
+    rows = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert all(r["schema"] == "redist_filter_bench/v1" for r in rows)
+    common = ["copy", "elementwise", "engine", "reshape_index", "strided",
+              "switch"]
+    assert [(r["block"], r["form"]) for r in rows] == (
+        [("2x16x128:0", f) for f in common + ["lane_slice"]]
+        + [("4x8x12:1", f) for f in common]
+        + [("2x8x128:1>0", f) for f in common + ["lane_slice"]])
+    assert all(r["ms"] > 0 and r["x_copy"] > 0 for r in rows)
+
+
+@pytest.mark.parametrize("spec", ["2x16x128", "2x16x128:2", "0x16x128:0",
+                                  "2x16:0"])
+def test_cli_filter_refuses_a_bad_block(spec):
+    from perf import redist_bench
+    with pytest.raises(SystemExit, match="bad block"):
+        redist_bench.main(["--filter", "--blocks", spec])

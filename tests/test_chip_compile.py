@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture (never at
 import), and every compile happens in this process: only one process may
 hold the TPU library at a time.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -127,8 +129,54 @@ def _abstract(grid, m, n, cdist, rdist):
         (m, n), jnp.float32, sharding=grid.sharding(meta.spec)))
 
 
-def _temp_bytes(fn, A):
-    return jax.jit(fn).lower(A).compile().memory_analysis().temp_size_in_bytes
+_SHAPE = re.compile(r"\b(pred|[a-z]+\d+)\[([\d,]+)\]\{([\d,]+)(?::([^}]*))?\}")
+
+
+def _padded_small_minor(text, over=64 << 20, under=8):
+    """``[(shape with layout, bytes with padding)]`` of every array in an
+    optimized HLO text that takes more than ``over`` bytes once its two
+    minor-most dimensions are rounded up to its tile, and whose minor-most
+    dimension has an extent below ``under``: the signature of a relayout
+    the TPU compiler pads (an ``f32[1024,2,8192]{1,0,2:T(8,128)}`` holds
+    its dimension of 2 on 128 lanes, 4.29 GB for 67 MB)."""
+    found = set()
+    for shape in _SHAPE.finditer(text):
+        dtype, dims, order, tiling = shape.groups()
+        dims = [int(d) for d in dims.split(",")]
+        order = [int(d) for d in order.split(",")]
+        if len(order) != len(dims) or dims[order[0]] >= under:
+            continue
+        tile = re.match(r"T\((\d+)(?:,(\d+))?\)", tiling or "")
+        tile = [int(t) for t in tile.groups() if t][::-1] if tile else []
+        n = 1 if dtype == "pred" else int(re.sub(r"\D", "", dtype)) // 8
+        for i, d in enumerate(order):
+            t = tile[i] if i < len(tile) else 1
+            n *= -(-dims[d] // t) * t
+        if n > over:
+            found.add((shape.group(0), n))
+    return sorted(found)
+
+
+def test_the_layout_reader_counts_padding():
+    text = """
+  %copy.6 = f32[2,2048,4096]{1,0,2:T(2,128)S(1)} copy(%bitcast.10)
+  %reshape.12 = f32[1024,2,8192]{1,0,2:T(8,128)} reshape(%bitcast.9)
+  %t = (f32[2048,4096,2]{2,1,0:T(8,128)}, u32[]{:T(128)}) all-gather-start(%p)
+  %small = f32[16,2,128]{1,0,2:T(8,128)} reshape(%q)
+  %bf = bf16[8192,8192,1]{2,1,0:T(8,128)(2,1)} copy(%r)
+"""
+    assert _padded_small_minor(text) == [
+        ("bf16[8192,8192,1]{2,1,0:T(8,128)(2,1)}", 8192 * 8192 * 128 * 2),
+        ("f32[1024,2,8192]{1,0,2:T(8,128)}", 1024 * 128 * 8192 * 4),
+        ("f32[2048,4096,2]{2,1,0:T(8,128)}", 2048 * 4096 * 128 * 4)]
+
+
+def _plan(fn, *args):
+    """``(temporary bytes, padded small-minor arrays)`` of ``fn`` compiled
+    for the described chips."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    return (compiled.memory_analysis().temp_size_in_bytes,
+            _padded_small_minor(compiled.as_text()))
 
 
 @pytest.mark.parametrize("n", [2048, 4096])
@@ -138,8 +186,10 @@ def test_gather_to_star_star_unpacks_in_whole_tiles(n, grid22):
     4.29 GB) for its lane-padded intermediate."""
     import elemental_tpu as el
     A = _abstract(grid22, n, n, el.MC, el.MR)
-    temp = _temp_bytes(lambda a: el.redistribute(a, el.STAR, el.STAR).local, A)
+    temp, padded = _plan(
+        lambda a: el.redistribute(a, el.STAR, el.STAR).local, A)
     assert temp <= 2 * n * n * 4, temp
+    assert not padded
 
 
 def test_panel_spread_then_column_filter_stays_unpadded(grid22):
@@ -154,7 +204,46 @@ def test_panel_spread_then_column_filter_stays_unpadded(grid22):
     def step(a):
         mc, mr = el.panel_spread(a)
         return el.redistribute(mc, el.MC, el.MR).local, mr.local
-    assert _temp_bytes(step, A) <= 4 * m * k * 4
+    temp, padded = _plan(step, A)
+    assert temp <= 4 * m * k * 4, temp
+    assert not padded
+
+
+@pytest.mark.parametrize("front", [False, True],
+                         ids=["chain-alone", "behind-hop-and-matmul"])
+@pytest.mark.parametrize("width", [16384, 14336, 12288])
+def test_lu_row_block_chain_stays_unpadded(width, front, grid22):
+    """Steps 0 to 2 of the 2x2 LU cell's row block, COMPOSED as
+    ``lapack/lu.py`` composes it (ISSUE 32): [STAR,VR] -> [STAR,MR], whose
+    result is both returned (the update reads it) and written back through
+    [STAR,MR] -> [MC,MR]; with or without the [MC,MR] -> [STAR,VR] hop and
+    the 2048^2 ``Li11`` matmul in front.  Alone each entry plans at most
+    the block; composed, the partial gather's lane interleave fed the row
+    filter, the compiler merged the two reshapes into one whose minor
+    dimension was the grid's 2, and the chain planned 8,589,934,592 bytes
+    at width 16384: two ``f32[1024,2,8192]{1,0,2:T(8,128)}`` of 4.29 GB for
+    a 67 MB block.  Entries are rehearsed as the driver composes them, with
+    every consumer of a result returned."""
+    import elemental_tpu as el
+    from elemental_tpu.core.distmatrix import DistMatrix
+    nb = 2048
+    block = nb * width * 4 // 2             # float32 bytes of [STAR,MR] a device
+
+    def chain(a, li11):
+        if front:
+            a = el.redistribute(a, el.STAR, el.VR)
+            u = jnp.matmul(li11, a.local, precision=jax.lax.Precision.HIGHEST)
+            a = DistMatrix(u, a.gshape, el.STAR, el.VR, 0, 0, grid22)
+        mr = el.redistribute(a, el.STAR, el.MR)
+        return el.redistribute(mr, el.MC, el.MR).local, mr.local
+    A = _abstract(grid22, nb, width,
+                  *((el.MC, el.MR) if front else (el.STAR, el.VR)))
+    li11 = jax.ShapeDtypeStruct(
+        (nb, nb), jnp.float32,
+        sharding=grid22.sharding(jax.sharding.PartitionSpec()))
+    temp, padded = _plan(chain, A, li11)
+    assert temp <= 4 * block, temp
+    assert not padded
 
 
 def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
@@ -181,6 +270,7 @@ def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= 0.6 * shard, temp
     text = compiled.as_text()
+    assert not _padded_small_minor(text)
     collectives = {c: text.count(f" {c}(") + text.count(f" {c}-start(")
                    for c in ("all-gather", "all-to-all", "all-reduce",
                              "collective-permute")}
