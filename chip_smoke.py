@@ -188,7 +188,7 @@ def _solve_line(phase, name, solve, gen_a, shape_a, nrhs, grid, seed, n_tol,
 # ---------------------------------------------------------------------
 
 #: the algorithmic blocksize the real-size solves are called with: the
-#: one ``bench.py`` documents for N = 32768.  (The library default, 128,
+#: one the benchmark's cells pass.  (The library default, 128,
 #: is sized for the test meshes; at N = 32768 it unrolls 256 panel steps
 #: into one program, which alone takes minutes to compile.)
 NB = 2048

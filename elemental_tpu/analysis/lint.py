@@ -45,8 +45,8 @@ Memory rules (ISSUE 18) run over a ``memory_plan/v1``
         an UNDONATED input aval: the buffer could be donated
         (``donate_argnums``) to halve residency.  Only checked when the
         plan's meta declares its donation set (``meta["donated"]``) --
-        the bench.py donate-input and serve ``__donated`` exec-cache
-        paths become lintable instead of conventions.
+        the serve ``__donated`` exec-cache path becomes lintable
+        instead of a convention.
   EL009 double-materialization  two or more full-matrix ([STAR,STAR])
         gathers of the SAME source operand: ``p`` live replicas paid
         repeatedly for one global operand.

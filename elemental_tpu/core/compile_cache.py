@@ -1,8 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One rule, used by every entry point that turns the cache on
-(``chip_smoke.py``, ``bench.py``, ``bench_serve.py``,
-``perf/ab_harness.py``, ``tests/conftest.py``): where
+(``chip_smoke.py``, ``benchmark/run.py``, ``tests/conftest.py``): where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing
 here sets another directory; where it is not, the cache is
 ``<checkout>/.jax_compile_cache``, derived from this file's own

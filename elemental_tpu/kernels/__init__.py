@@ -49,11 +49,11 @@ from .qr_panel import qr_panel
 #: keep the status-quo path (same convention as tune.knobs.LU_PANELS).
 PANEL_IMPLS = ("xla", "pallas")
 
-#: LU chunk ladder (512/64; sweep with ``perf/ab_harness.py lu``).
-#: Single source of truth -- lapack.lu, the A/B harness, and bench
-#: provenance all read it through default_inners() / resolve_panel()
-#: rather than importing a bare module constant that monkeypatching
-#: would silently go stale on (the ISSUE 17 staleness footgun).
+#: LU chunk ladder (512/64; no other ladder has a ledger line).  Single
+#: source of truth -- lapack.lu reads it through default_inners() /
+#: resolve_panel() rather than importing a bare module constant that
+#: monkeypatching would silently go stale on (the ISSUE 17 staleness
+#: footgun).
 DEFAULT_INNERS = (512, 64)
 
 
@@ -69,8 +69,7 @@ class PanelPlan:
     ``impl`` is the post-'auto' knob value; ``inners`` is the LU chunk
     ladder the XLA path recurses on AND the width the fused kernel's
     blocked mode uses (``pallas_inner``); ``source`` records where the
-    choice came from ('default', 'explicit', 'tuned', 'complex-xla')
-    so bench provenance can attribute a headline move to the knob.
+    choice came from ('default', 'explicit', 'tuned', 'complex-xla').
     """
 
     impl: str = "xla"

@@ -29,8 +29,7 @@ trailing update at the next panel boundary:
 The strip/rest/diag operands are all captured BEFORE any writeback, so the
 replicated ``_potrf_inv`` of step k+1 and the wide remainder matmul share
 no data dependence and XLA is free to overlap them.  ``lookahead=False``
-keeps the classic order -- bit-identical factors, the A/B baseline
-(``perf/ab_harness.py cholesky``).
+keeps the classic order -- bit-identical factors.
 
 Tail crossover-to-local (``crossover``)
 ---------------------------------------
@@ -40,8 +39,7 @@ smaller trailing matmuls.  Once the trailing matrix drops to ``crossover``
 gathered ONCE to [STAR,STAR] and finished with the replicated sequential
 schedule (:func:`_local_chol_array`) -- O(t^3) redundant flops on every
 device, but zero further collectives.  ``crossover=None`` picks the
-default; pass an int to override (``perf/ab_harness.py cholesky`` sweeps
-it).
+default; pass an int to override.
 
 Phases (``timer``)
 ------------------
@@ -51,8 +49,7 @@ under ``jit`` the compiled program's ops carry
 ``el.cholesky/k<step>/<phase>`` in their names, which is what a device
 trace is split by.  Pass an ``elemental_tpu.obs.PhaseTimer`` and call
 ``cholesky`` EAGERLY and the same blocks also charge per-step wall-clock
-(same ``phase_timings/v1`` schema as LU; ``python perf/ab_harness.py
-phases cholesky`` is the CLI).
+(same ``phase_timings/v1`` schema as LU).
 """
 from __future__ import annotations
 
@@ -77,8 +74,8 @@ from .lu import _hi, _phase_hook
 #: finishes locally (look-ahead schedule only, unless overridden).  The
 #: per-step cost floor of the distributed loop is ~3 collective rounds; at
 #: t <= ~4k the whole remaining O(t^3/3) factors locally in less time than
-#: the remaining t/nb rounds cost.  Re-pin via ``perf/ab_harness.py
-#: cholesky`` (crossover sweep) on the target chip/grid.
+#: the remaining t/nb rounds cost (a count of rounds, not a chip reading:
+#: no ledger line has run another value).
 _CROSSOVER = 4096
 
 

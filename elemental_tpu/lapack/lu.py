@@ -63,8 +63,7 @@ With ``timer=None`` (default) the driver jits as one fused program whose
 ops carry ``el.lu/k<step>/<phase>`` in their names: a device trace is
 split by them.  Pass an ``elemental_tpu.obs.PhaseTimer`` and call ``lu``
 EAGERLY (outside jit) and the same blocks also synchronize at every
-boundary and charge per-step wall-clock; ``python perf/ab_harness.py
-phases`` emits the resulting JSON.
+boundary and charge per-step wall-clock (``timer.report()``).
 
 Data-dependent pivots are traced values, so the whole factorization jits;
 the packed L\\U layout and the permutation-vector convention follow LAPACK
@@ -88,19 +87,8 @@ from ..redist.engine import (apply_fault, move_rows, permute_rows_storage,
 from ..redist.quantize import check_comm_precision, quantizable
 from ..blas.level3 import _blocksize, _check_mcmr, local_rank_update, trsm
 
-#: chunk-width ladder for the replicated panel factorization.  A/B-measured
-#: on v5e at n=16384 nb=2048 (perf/ab_harness.py, same-process roofline
-#: brackets): (512,64) 8.18/7.34 TFLOP/s across two runs vs (256,32) 6.53,
-#: (256,64) 6.89, (1024,128) 6.92, (512,64,16) 4.89, (768,96) 7.46.
-#: The pinned tuple now lives in ``kernels.DEFAULT_INNERS`` (single
-#: source shared with the ``panel_impl`` dispatch and bench provenance
-#: -- ISSUE 17); sweep with ``perf/ab_harness.py lu`` (which passes
-#: ``inners=`` explicitly, no module monkeypatching) and re-pin THERE.
-#: This module-level alias survives for historical importers only.
 from ..kernels import default_inners as _default_inners
 from ..kernels import resolve_panel as _resolve_panel
-
-_INNERS = _default_inners()
 
 
 def _hi(precision):
@@ -213,7 +201,7 @@ def _panel_lu(P, nbw: int, precision=None, inners=None):
 
     Returns (packed panel, composed row permutation of the panel)."""
     if inners is None:
-        inners = _INNERS
+        inners = _default_inners()
     if not inners or nbw <= inners[-1]:
         return _panel_lu_unb(P, nbw)
     step, rest = inners[0], inners[1:]
@@ -692,8 +680,7 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
     (``panel='calu'`` tournaments) keep their XLA slab kernels -- the
     knob covers the classic primitives, including the sequential tail.
     ``inners`` optionally overrides the chunk-width ladder
-    (``kernels.DEFAULT_INNERS``) for BOTH implementations; the A/B
-    harness sweeps it through this argument.
+    (``kernels.DEFAULT_INNERS``) for BOTH implementations.
 
     ``comm_precision`` (``None`` | ``'bf16'`` | ``'int8'``) selects the
     WIRE precision of the schedule's bulk redistributions (panel gathers,

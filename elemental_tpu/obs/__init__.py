@@ -87,9 +87,7 @@ again).  Read them under ``metrics_scope()``:
                            ``row_permute_wire_bytes`` (worst-case bytes a
                            device receives: every row crossing chips)
 
-CLI: ``python -m perf.trace {run,summary,export,serve}``.  Regression
-gate over the bench trajectory: ``tools/bench_diff.py`` (wired into
-``tools/check.sh``).
+CLI: ``python -m perf.trace {run,summary,export,serve}``.
 """
 from .metrics import (SCHEMA as METRICS_SCHEMA, FAMILIES as HIST_FAMILIES,
                       MetricsRegistry, REGISTRY,

@@ -20,9 +20,9 @@ The document carries a top-level ``"schema": "obs_chrome_trace/v1"`` key
 (Chrome/Perfetto ignore unknown keys in the object format) pinned by
 ``tests/obs``; run metadata rides ``otherData``.
 
-``phase_timings_to_chrome`` converts a historical ``phase_timings/v1``
-document (``bench.py --phases`` / ``ab_harness.py phases`` output, which
-records durations but no timestamps) into the same trace format by laying
+``phase_timings_to_chrome`` converts a ``phase_timings/v1`` document
+(``PhaseTimer.report()``, which records durations but no timestamps)
+into the same trace format by laying
 the steps out sequentially -- ``python -m perf.trace export`` is the CLI.
 """
 from __future__ import annotations

@@ -20,8 +20,7 @@ isolation pattern as ``engine.redist_counts``), so tests and CLI runs
 read a clean slate without clearing global state.
 
 The JSON document (``obs_metrics/v1``) is STABLE -- pinned by
-``tests/obs`` -- and is what ``python -m perf.trace run`` emits and
-``bench.py`` embeds under its ``"obs"`` key::
+``tests/obs`` -- and is what ``python -m perf.trace run`` emits::
 
     {"schema": "obs_metrics/v1",
      "counters":   [{"name": ..., "labels": {...}, "value": N}, ...],

@@ -21,9 +21,8 @@ then show in a device trace by their scope names, not here)::
     LU, perm = el.lu(A, nb=2048, timer=t)
     print(t.json(driver="lu", n=n, nb=2048))
 
-``python perf/ab_harness.py phases [lu|cholesky]`` is the CLI wrapper;
-``python -m perf.trace`` is the full-subsystem CLI (nested spans +
-collective events + Perfetto export).  Schema (``phase_timings/v1``; LU
+``python -m perf.trace`` is the CLI (nested spans + collective events +
+Perfetto export).  Schema (``phase_timings/v1``; LU
 emits panel/swap/solve/update, Cholesky diag/panel/spread/update and
 ``tail`` on the crossover step)::
 
@@ -36,8 +35,8 @@ emits panel/swap/solve/update, Cholesky diag/panel/spread/update and
 
 Timing note: eager dispatch is asynchronous, so the sync INSIDE tick is
 what makes the attribution honest; each phase's time includes its share of
-dispatch overhead (the same caveat as any op-by-op profile).  Use the A/B
-modes of ``perf/ab_harness.py`` for end-to-end fused-program numbers.
+dispatch overhead (the same caveat as any op-by-op profile).  The fused
+program is timed by ``benchmark/run.py``.
 """
 from __future__ import annotations
 

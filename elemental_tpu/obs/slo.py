@@ -21,10 +21,7 @@ A count-based window (rather than wall-clock) keeps snapshots
 deterministic under the chaos harness's virtual clocks.  ``snapshot``
 emits the STABLE ``serve_slo/v1`` document (series sorted by key) and
 mirrors the headline numbers as gauges (``serve_slo_p99_ms``,
-``serve_slo_burn_latency``, ...) on the current metrics registry;
-``bench_serve.py``'s fleet section records the doc plus the worst
-per-tenant p99 as ``serve_slo_p99_ms``, which ``tools/bench_diff.py``
-gates lower-is-better.
+``serve_slo_burn_latency``, ...) on the current metrics registry.
 """
 from __future__ import annotations
 

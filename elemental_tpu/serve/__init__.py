@@ -36,10 +36,9 @@ into a system that survives production traffic -- the ROADMAP's
                      EWMA each), depth-k pipelined workers, and
                      tenant-aware routing by measured per-grid latency
 
-CLI: ``python -m perf.serve {run,smoke,chaos,fleet-smoke}``; bench:
-``python bench_serve.py`` (p50/p99 + solves/sec + the multi-grid fleet
-section, gated by ``tools/bench_diff.py``); gates: ``tools/check.sh
-serve`` and ``tools/check.sh fleet``.
+CLI: ``python -m perf.serve {run,smoke,chaos,fleet-smoke}``; gates:
+``tools/check.sh serve`` and ``tools/check.sh fleet``.  No benchmark cell
+drives the service yet (``PERF.md`` 7).
 """
 from .admission import (REJECT_SCHEMA, AdmissionController, Bucket,
                         Deadline, SolveRequest, make_bucket, reject_doc,

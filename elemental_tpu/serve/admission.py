@@ -242,7 +242,7 @@ class AdmissionController:
     ``admit(op, A, B, deadline, queue_depth)`` validates the request,
     assigns its bucket, and EITHER returns a :class:`SolveRequest` or a
     ``serve_reject/v1`` dict when the estimated wait cannot fit the
-    deadline (``shed=False`` disables shedding -- bench mode).  The
+    deadline (``shed=False`` disables shedding).  The
     caller owns the queue; ``queue_depth`` is the number of requests
     already waiting in the same bucket."""
 

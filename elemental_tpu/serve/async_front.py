@@ -36,7 +36,7 @@ hook; per-future callbacks fire on the worker thread.
 All core state (queues, breakers, results) is touched ONLY by the
 worker thread -- ``submit`` just enqueues -- so the core needs no
 locks and stays bit-identical to the synchronous path for the same
-request set (the bench asserts exactly that).  The price of pipelining
+request set (``tests/serve/test_async.py`` asserts exactly that).  The price of pipelining
 is that admission/breaker decisions for batch k+1 may be made before
 batch k's outcome lands; the chaos matrix's async column pins that a
 mid-pipeline fault is still isolated to its own batch.

@@ -13,7 +13,7 @@ Four layers, consulted in order by a driver that receives ``'auto'``:
                       the canonical :func:`blocksize_policy`
 
 :mod:`.measure` (imported lazily; it compiles and runs on the real
-backend) times candidates ab_harness-style and records winners.  CLI:
+backend) times candidates in one process and records winners.  CLI:
 ``python -m perf.tune {search,show,clear,explain}``.
 """
 from .knobs import (DEFAULT_CROSSOVER, GEMM_ALGS, NB_LADDER, OPS,

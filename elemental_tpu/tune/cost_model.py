@@ -48,7 +48,8 @@ _MAX_TRACE_STEPS = 6
 #: blocksize-efficiency constants (see module docstring): HALF_NB is the
 #: panel width at which MXU efficiency halves, IMB weights the serialized
 #: panel/tail fraction nb/extent.  With the TPU machine model these place
-#: the optimum at nb=2048 for N=32k -- the ab_harness-measured winner.
+#: the optimum at nb=2048 for N=32k, the value the benchmark's cells pass
+#: (no other nb has a ledger line).
 HALF_NB = 512.0
 IMB = 3.0
 

@@ -24,9 +24,9 @@ import math
 
 from ..core.view import round_up
 
-#: the nb ladder every blocked driver sweeps; mirrors the A/B-measured
-#: ladder of ``perf/ab_harness.py`` (nb=2048 is the measured v5e winner at
-#: N=32k; small entries matter on CPU-sized problems and small grids)
+#: the nb ladder every blocked driver sweeps (the benchmark's cells pass
+#: nb=2048 and no other value has a ledger line; small entries matter on
+#: CPU-sized problems and small grids)
 NB_LADDER = (64, 128, 256, 512, 1024, 2048, 4096)
 
 #: default tail crossover-to-local threshold of the look-ahead schedules

@@ -1,8 +1,7 @@
 """Measurement engine: time candidate configs on the real backend and
 record winners in the persistent cache.
 
-The methodology is ``perf/ab_harness.py``'s, packaged as a library: every
-candidate runs IN ONE PROCESS on the same devices, timings are
+Every candidate runs IN ONE PROCESS on the same devices, timings are
 min-of-reps with the host round-trip latency subtracted and each variant
 is bracketed by a matmul roofline measurement so chip weather is factored
 out of the comparison.  Inputs are regenerated (untimed) per rep because

@@ -2,8 +2,8 @@
 
 Times the SAME redistribution through the chained multi-hop engine
 (``path='chain'``) and the one-shot compiled plan (``path='direct'``) on
-the live device grid, roofline-bracketed like ``perf/ab_harness.py`` so
-chip weather is factored out of an A/B pair.  Each row prints as one
+the live device grid, bracketed by a matmul roofline so chip weather is
+factored out of an A/B pair.  Each row prints as one
 ``redist_bench/v1`` JSON line:
 
     {"schema": "redist_bench/v1", "pair": "[MC,MR]->[MR,STAR]",
@@ -162,7 +162,7 @@ def _label(pair) -> str:
 
 
 def _roofline(n: int) -> float:
-    """Matmul roofline at size n (chip-weather bracket, ab_harness idiom)."""
+    """Matmul roofline at size n (chip-weather bracket)."""
     import jax
     import jax.numpy as jnp
     HI = jax.lax.Precision.HIGHEST
@@ -189,7 +189,7 @@ def _model_bytes(src, dst, gshape, grid_shape, itemsize, path):
 
 def run_pair(grid, n, src, dst, paths, reps=3, check=True):
     """Time one src->dst move under each path; returns a list of row dicts
-    (no JSON printing -- the CLI and bench.py both feed from here)."""
+    (no JSON printing -- the CLI feeds from here)."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -215,7 +215,7 @@ def run_pair(grid, n, src, dst, paths, reps=3, check=True):
 
         def _step(p=path):
             o = el.redistribute(A, dst[0], dst[1], path=p)
-            float(jnp.ravel(o.local)[0])     # force completion (ab_harness)
+            float(jnp.ravel(o.local)[0])     # force completion
 
         dt = max(_min_t(_step, reps), 1e-9)
         rounds, nbytes, plan_kind = _model_bytes(
@@ -234,27 +234,6 @@ def run_pair(grid, n, src, dst, paths, reps=3, check=True):
             "match": match,
         })
     return rows
-
-
-def p2p_gbps(grid, n=None, reps=3):
-    """Informational chain-vs-direct GB/s for ONE representative move
-    ([MC,MR]->[MR,STAR], the 3-hop chain gemm's stationary-C schedule
-    feeds on) -- the ``redist_p2p_gbps`` row bench.py embeds in its obs
-    block.  Returns ``{"chain": gbps, "direct": gbps, ...}``; on a 1x1
-    grid both model-byte counts are zero, so both rates report 0.0.
-    Never raises past bad geometry: callers gate it defensively anyway."""
-    import elemental_tpu as el
-    if n is None:
-        n = 256 if grid.size <= 8 else 4096
-    src = _dist_pair("MC,MR")
-    dst = _dist_pair("MR,STAR")
-    rows = run_pair(grid, n, src, dst, ("chain", "direct"),
-                    reps=reps, check=False)
-    doc = {"pair": rows[0]["pair"], "n": n,
-           "grid": rows[0]["grid"]}
-    for row in rows:
-        doc[row["path"]] = round(row["gbps"], 4)
-    return doc
 
 
 def fit_constants(rows):

@@ -14,8 +14,7 @@ The command-line face of ``elemental_tpu/obs``:
     python -m perf.trace summary trace.json # per-lane totals of a trace
     python -m perf.trace export phases.json --out trace.json
                                             # convert a phase_timings/v1
-                                            #   doc (bench.py --phases /
-                                            #   ab_harness.py phases) to
+                                            #   doc (PhaseTimer.json()) to
                                             #   the same trace format
     python -m perf.trace serve --out trace.json
                                             # drive a small 2-grid fleet

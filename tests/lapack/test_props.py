@@ -1,4 +1,7 @@
 """Props oracles: determinant/condition/inertia/norm estimates."""
+import functools
+
+import jax
 import numpy as np
 import pytest
 
@@ -45,9 +48,17 @@ def test_condition(grid24):
 
 
 def test_two_norm_estimate(grid24):
+    """Run as ONE compiled program.  Called eagerly, forty iterations queue
+    some five hundred small collective programs with no host read between
+    them, and this jaxlib's CPU client deadlocks on a deep queue of those
+    (a rendezvous one participant never joins; XLA aborts the process after
+    40 s): always at 600 unsynchronised eager ``redistribute`` calls, and
+    at this test's depth whenever the host is loaded enough for dispatch to
+    outrun execution, which is how a worker of the six-worker run died."""
     rng = np.random.default_rng(4)
     A = rng.normal(size=(16, 10))
-    est = float(np.asarray(el.two_norm_estimate(_dm(A, grid24), iters=40)))
+    estimate = jax.jit(functools.partial(el.two_norm_estimate, iters=40))
+    est = float(np.asarray(estimate(_dm(A, grid24))))
     ref = np.linalg.norm(A, 2)
     assert abs(est - ref) / ref < 1e-6
 

@@ -15,8 +15,7 @@ from elemental_tpu.serve import (AsyncSolverService, SolverService,
 from .conftest import FakeClock, diag_dom, spd
 
 #: serve_result/v1 keys that must be identical sync vs async (timing
-#: keys excluded -- wall clock legitimately differs); mirrors the
-#: bench_serve.py payload-identity contract
+#: keys excluded -- wall clock legitimately differs)
 SEM_KEYS = ("op", "n", "nrhs", "bucket", "status", "path", "rung",
             "residual", "tol", "retries", "bisected", "timed_out")
 

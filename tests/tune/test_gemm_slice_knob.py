@@ -45,8 +45,7 @@ def test_auto_picks_slice_on_tall_skinny_2x2():
 
 
 def test_auto_picks_slice_on_bench_headline_class():
-    """The bench.py gemm_tall_skinny headline geometry resolves 'slice'
-    (provenance recorded in the bench JSON)."""
+    """A tall-skinny geometry of BASELINE.json's scale resolves 'slice'."""
     assert _pick((65536, 512, 512), _grid(2, 4)) == "slice"
 
 

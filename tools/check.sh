@@ -34,8 +34,8 @@
 #                             #   on 1x1 + 2x2, the chaos acceptance
 #                             #   matrix ({bitflip,scale,nan} x
 #                             #   {redistribute,compute} x {oneshot,
-#                             #   persistent} + the qr op column), the
-#                             #   bench_serve schema smoke, and tests/serve
+#                             #   persistent} + the qr op column), and
+#                             #   tests/serve
 #   tools/check.sh fleet      # solver-fleet gate (ISSUE 19): fleet-smoke
 #                             #   (pipelined multi-grid routing, tenant
 #                             #   quota rejects, grid-loss + saturation
@@ -344,8 +344,6 @@ if [ "$what" = "all" ] || [ "$what" = "serve" ]; then
     JAX_PLATFORMS=cpu python -m perf.serve smoke || rc=1
     echo "== chaos acceptance matrix (faults x targets x modes, 2x2) =="
     JAX_PLATFORMS=cpu python -m perf.serve chaos || rc=1
-    echo "== bench_serve schema smoke (p50/p99 + solves/sec present) =="
-    JAX_PLATFORMS=cpu python bench_serve.py --smoke > /dev/null || rc=1
     echo "== serve tier-1 tests (admission/executor/policy/service/chaos) =="
     python -m pytest tests/serve -q -m 'not slow' -p no:cacheprovider || rc=1
 fi

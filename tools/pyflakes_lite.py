@@ -23,8 +23,7 @@ import ast
 import os
 import sys
 
-DEFAULT_PATHS = ("elemental_tpu", "perf", "examples", "tests", "tools",
-                 "bench.py")
+DEFAULT_PATHS = ("elemental_tpu", "perf", "examples", "tests", "tools")
 
 
 def _py_files(paths):
