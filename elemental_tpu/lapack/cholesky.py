@@ -58,6 +58,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..core.dist import MC, MR, VC, STAR
 from ..core.distmatrix import DistMatrix
@@ -67,6 +68,7 @@ from ..redist.engine import (apply_fault, redistribute, transpose_dist,
 from ..redist.quantize import check_comm_precision
 from ..blas.level1 import make_trapezoidal, _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle, trsm
+from ..obs import metrics as _metrics
 from ..obs.tracer import NULL_HOOK, scoped as _scoped
 from .lu import _hi, _phase_hook
 
@@ -150,30 +152,39 @@ def _potrf_inv_impl(D, precision, bs: int = 512):
 def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
                       timer=None, plan=None):
     """Blocked lower Cholesky of an (n, n) array (lower triangle valid),
-    returning the full lower-triangular factor array.  Shared by the p == 1
-    driver and the distributed tail crossover (where it runs REPLICATED on
-    the gathered trailing block -- deterministic, so every device agrees).
+    returning the lower-triangular factor (zeros above the diagonal).
+    Shared by the p == 1 driver and the distributed tail crossover (where
+    it runs REPLICATED on the gathered trailing block -- deterministic, so
+    every device agrees).
 
-    Schedule (tuned on v5e at N=32768):
+    Schedule:
       * diagonal blocks factored by :func:`_potrf_inv` (small-base potrf +
         matmul inverse assembly) and the panel solve L21 = A21 L11^{-H}
-        done as ONE matmul -- XLA's potrf/trsm at nb=2048 are latency-bound
-        and were ~55%% of total runtime;
-      * the trailing matrix SHRINKS each panel (finished columns are
-        assembled once at the end) -- no aliasing/copy questions;
+        done as ONE matmul -- XLA's potrf/trsm at nb=2048 are latency-bound;
+      * ONE n x n buffer from the first step to the last.  Step k
+        addresses its trailing window ``T[o:, o:]`` by static offsets and
+        writes its finished panel, zeros above it, into the same buffer's
+        columns.  The loop used to copy the shrinking trailing matrix into
+        a smaller array at every step (``T = T[w:, w:]``) so that the next
+        step could index from zero, and to assemble the kept panels at the
+        end: 41.6 GB of HBM traffic for a 4.29 GB operand at N=32768, a
+        tenth of the solve (PERF.md 6, PR 34).  Ticks the trace-time
+        counter ``chol_update`` once a step;
+      * the buffer leaves lower-triangular, so no caller masks the whole
+        of it (one more n x n buffer beside this one);
       * the rank-nb update touches only the LOWER triangle, via row-stripe
-        blocks ``T[i:i+q, :i+q] -= L21[i:i+q] L21[:i+q]^H`` (contiguous
-        row-major writes; half the FLOPs of the full product -- the MXU
-        answer to the reference's recursive ``Trrk``);
+        blocks ``T[o+i:o+i+q, o:o+i+q] -= L21[i:i+q] L21[:i+q]^H`` (half
+        the FLOPs of the full product -- the MXU answer to the reference's
+        recursive ``Trrk``);
       * ``lookahead=True`` additionally computes the next panel's column
         strip first and factors diag block k+1 + its panel solve from it,
         so the latency-bound ``_potrf_inv`` inner loop is data-independent
         of the wide remainder stripes and XLA may overlap them (the same
-        pipeline as ``lu._local_lu``)."""
+        pipeline as ``lu._local_lu``).  ``strip`` and ``L21`` stay values
+        of their own: the matmuls never re-read ``T`` after a write."""
     tm = timer if timer is not None else NULL_HOOK
     dt = a.dtype
     q = 2 * ib
-    panels = []
     T = a
     nxt = None
 
@@ -193,56 +204,72 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
 
     if lookahead:
         w0 = min(ib, n)
-        nxt = diag_and_panel(0, T, w0, w0 < n)
+        nxt = diag_and_panel(0, T[:, :w0], w0, w0 < n)
     for k, s in enumerate(range(0, n, ib)):
         w = min(ib, n - s)
+        o = s + w                   # where the trailing window starts
         if lookahead:
             L11, Li11, L21 = nxt
         else:
-            L11, Li11, L21 = diag_and_panel(k, T, w, s + w < n)
-        if s + w == n:
-            panels.append(L11)
-            break
+            L11, Li11, L21 = diag_and_panel(k, T[s:, s:o], w, o < n)
+        # the finished panel goes into its own columns with zeros above it,
+        # so the buffer leaves the loop lower-triangular and no caller has
+        # to mask (and copy) the whole of it
         with tm.phase("panel", k):
-            panels.append(jnp.concatenate([L11, L21], axis=0))
+            T = T.at[:, s:o].set(jnp.concatenate(
+                [jnp.zeros((s, w), dt), jnp.tril(L11)]
+                + ([] if L21 is None else [L21]), axis=0))
+        if o == n:
+            break
+        _metrics.inc("chol_update")
+        mt = n - o
         if not lookahead:
             with tm.phase("update", k) as ph:
-                T2 = T[w:, w:]
-                mt = T2.shape[0]
                 for i in range(0, mt, q):
                     iq = min(i + q, mt)
                     upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[:iq, :]).T,
                                      precision=precision)
-                    T2 = T2.at[i:iq, :iq].set(T2[i:iq, :iq] - upd.astype(dt))
-                T = T2
+                    T = T.at[o + i:o + iq, o:o + iq].set(
+                        T[o + i:o + iq, o:o + iq] - upd.astype(dt))
                 ph.done(T)
             continue
         # look-ahead: the next panel's column strip updates first (one tall
         # narrow matmul), diag block k+1 factors + panel k+1 solves from it;
-        # the wide remainder stripes read only the pre-update T2, so the
-        # replicated _potrf_inv and the MXU stripes can overlap.
+        # the wide remainder stripes read L21 and their own window of T,
+        # never the strip, so the replicated _potrf_inv and the MXU stripes
+        # can overlap.  The strip is not written back: panel k+1 overwrites
+        # its columns.
         with tm.phase("update", k):
-            T2 = T[w:, w:]
-            mt = T2.shape[0]
             w2 = min(ib, mt)
-            strip = T2[:, :w2] - jnp.matmul(L21, jnp.conj(L21[:w2, :]).T,
-                                            precision=precision).astype(dt)
+            strip = T[o:, o:o + w2] - jnp.matmul(
+                L21, jnp.conj(L21[:w2, :]).T, precision=precision).astype(dt)
         nxt = diag_and_panel(k + 1, strip, w2, w2 < mt)
         with tm.phase("update", k) as ph:
-            T2 = T2.at[:, :w2].set(strip)
             for i in range(w2, mt, q):
                 iq = min(i + q, mt)
                 upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[w2:iq, :]).T,
                                  precision=precision)
-                T2 = T2.at[i:iq, w2:iq].set(T2[i:iq, w2:iq] - upd.astype(dt))
-            T = T2
+                T = T.at[o + i:o + iq, o + w2:o + iq].set(
+                    T[o + i:o + iq, o + w2:o + iq] - upd.astype(dt))
             ph.done(T)
-    out = jnp.zeros((n, n), dt)
-    s = 0
-    for P in panels:
-        out = lax.dynamic_update_slice(out, P, (s, s))
-        s += P.shape[1]
-    return out
+    return T
+
+
+@jax.jit
+def _pin_column_major(x):
+    """``x`` held column-major where it is an intermediate of a compiled
+    program: the layout the TPU compiler gives the one-buffer loop.
+    Without it the compiler re-lays the whole factor out row-major for
+    whoever reads it next (the sweeps of ``hpd_solve``): a second n x n
+    buffer beside the working one (PERF.md 6, PR 34).
+
+    A ``jit`` of its own so that the constraint is always INSIDE a program,
+    under whatever transformation the caller runs: inlined into an
+    enclosing ``jit``, a small program with default layouts at its boundary
+    when called eagerly.  An eager ``with_layout_constraint`` makes an
+    executable whose RESULT has the layout, and jax 0.9.0 hands that one
+    back from the persistent compile cache without it (transposed data)."""
+    return with_layout_constraint(x, Layout(major_to_minor=(1, 0)))
 
 
 def _local_cholesky(A: DistMatrix, nb: int | None, precision,
@@ -255,7 +282,11 @@ def _local_cholesky(A: DistMatrix, nb: int | None, precision,
     ib = max(nb or 2048, 1)
     out = _local_chol_array(A.local, A.gshape[0], ib, precision,
                             lookahead=lookahead, timer=timer, plan=plan)
-    return make_trapezoidal(A.with_local(out), "L")
+    # the layout was read from the TPU compiler; elsewhere nothing asks for
+    # it.  Under jax.disable_jit() the pin's own jit would run it eagerly
+    if A.grid.devices[0].platform == "tpu" and not jax.config.jax_disable_jit:
+        out = _pin_column_major(out)
+    return A.with_local(out)
 
 
 @_scoped("el.cholesky")

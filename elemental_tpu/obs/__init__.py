@@ -68,9 +68,9 @@ was made by the compiler or was not named.  The persistent compile cache
 is keyed with these names (``core/compile_cache.py``), so an executable
 from the cache carries this build's.
 
-Counters of the engine, ticked where an entry's Python runs: every eager
-call, and once per trace under ``jit`` (a cached jaxpr does not tick
-again).  Read them under ``metrics_scope()``:
+Counters of the engine and the drivers, ticked where their Python runs:
+every eager call, and once per trace under ``jit`` (a cached jaxpr does
+not tick again).  Read them under ``metrics_scope()``:
 
   ``redist_unpack{impl,dim}``   one local unpack after a gather
                            (``impl`` ``tiled`` | ``generic``; ``dim`` 1 for
@@ -79,6 +79,10 @@ again).  Read them under ``metrics_scope()``:
                            makes a replicated (or coarser) dimension
                            distributed (the same two labels, by the block
                            it returns)
+  ``chol_update``          one trailing update of the one-chip blocked
+                           Cholesky (``lapack/cholesky.py:_local_chol_array``,
+                           a step of the loop), made where the trailing
+                           matrix lies in the one n x n buffer
   ``row_permute{kind}``    one storage-level row permutation: ``kind``
                            ``move`` (``move_rows``: a panel step's pivot
                            swaps) | ``full`` (``permute_rows_storage``:

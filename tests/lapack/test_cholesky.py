@@ -136,6 +136,189 @@ def test_cholesky_lookahead_matches_classic_local():
                                    rtol=1e-12, atol=1e-13)
 
 
+def _shrinking_chol_reference(a, n, ib, precision, lookahead):
+    """``_local_chol_array`` as it stood before ISSUE 34, plain: the
+    trailing matrix copied into a smaller array at every step
+    (``T = T[w:, w:]``), the finished panels kept in a list and assembled
+    at the end.  The same matmuls on the same operands in the same order
+    as the one-buffer loop, so the lower triangles agree to the bit."""
+    import jax.numpy as jnp
+    from elemental_tpu.lapack.cholesky import _potrf_inv
+    dt, q, panels, T = a.dtype, 2 * ib, [], a
+
+    def diag_and_panel(src, w, below):
+        L11, Li11 = _potrf_inv(src[:w, :w], precision)
+        L21 = (jnp.matmul(src[w:, :w], jnp.conj(Li11).T,
+                          precision=precision).astype(dt) if below else None)
+        return L11, L21
+
+    nxt = diag_and_panel(T, min(ib, n), ib < n) if lookahead else None
+    for s in range(0, n, ib):
+        w = min(ib, n - s)
+        L11, L21 = nxt if lookahead else diag_and_panel(T, w, s + w < n)
+        if s + w == n:
+            panels.append(L11)
+            break
+        panels.append(jnp.concatenate([L11, L21], axis=0))
+        T = T[w:, w:]
+        mt = T.shape[0]
+        w2 = min(ib, mt) if lookahead else 0
+        if lookahead:
+            strip = T[:, :w2] - jnp.matmul(
+                L21, jnp.conj(L21[:w2, :]).T, precision=precision).astype(dt)
+            nxt = diag_and_panel(strip, w2, w2 < mt)
+            T = T.at[:, :w2].set(strip)
+        for i in range(w2, mt, q):
+            iq = min(i + q, mt)
+            upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[w2:iq, :]).T,
+                             precision=precision)
+            T = T.at[i:iq, w2:iq].set(T[i:iq, w2:iq] - upd.astype(dt))
+    out = np.zeros((n, n), dt)
+    for s, P in zip(range(0, n, ib), panels):
+        out[s:, s:s + P.shape[1]] = np.asarray(P)
+    return out
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64])
+@pytest.mark.parametrize("lookahead", [True, False],
+                         ids=["lookahead", "classic"])
+@pytest.mark.parametrize("n", [16, 17, 48, 55, 80],
+                         ids=["ib", "ib+1", "3ib", "3ib+7-ragged", "5ib"])
+def test_local_chol_array_one_buffer(n, lookahead, dtype):
+    """The one-chip blocked loop factors in ONE n x n buffer (ISSUE 34):
+    against ``numpy.linalg.cholesky`` in double precision, and the lower
+    triangle equal TO THE BIT to the shrinking loop it replaced; above
+    the diagonal exact zeros whatever the operand held there (each panel
+    is written with zeros above it, so no caller masks the whole)."""
+    import jax
+    from elemental_tpu.lapack.cholesky import _local_chol_array
+    ib, hi = 16, jax.lax.Precision.HIGHEST
+    rng = np.random.default_rng(34 + n)
+    G = rng.normal(size=(n, n))
+    if np.issubdtype(dtype, np.complexfloating):
+        G = G + 1j * rng.normal(size=(n, n))
+    F = G @ G.conj().T / n + 2 * np.eye(n)
+    # only the lower triangle is valid input: NaN above the diagonal
+    a = jax.numpy.asarray(
+        (np.tril(F) + np.triu(np.full((n, n), np.nan), 1)).astype(dtype))
+    got = np.asarray(_local_chol_array(a, n, ib, hi, lookahead=lookahead))
+    assert not np.triu(got, 1).any()
+    want = np.linalg.cholesky(F)
+    assert got.dtype == dtype
+    assert np.linalg.norm(got - want) < 50 * np.finfo(dtype).eps * n \
+        * np.linalg.norm(want)
+    ref = np.tril(_shrinking_chol_reference(a, n, ib, hi, lookahead))
+    np.testing.assert_array_equal(got, ref)
+
+
+@pytest.mark.parametrize("lookahead", [True, False],
+                         ids=["lookahead", "classic"])
+def test_local_chol_array_never_slices_the_trailing_matrix(lookahead):
+    """No ``slice`` in the loop's jaxpr returns a block with both
+    dimensions over ``n - 2 ib``: the trailing matrix is addressed where
+    it lies, never copied into a smaller array (41.6 GB a solve at
+    N = 32768; PERF.md 6, PR 34).  The compiled program's own copies are
+    counted for a described chip in ``tests/test_chip_compile.py``."""
+    import jax
+    from elemental_tpu.lapack.cholesky import _local_chol_array
+    from elemental_tpu.obs import metrics
+    n, ib = 128, 16
+    a = jax.ShapeDtypeStruct((n, n), np.float32)
+    with metrics.scoped() as reg:
+        jaxpr = jax.make_jaxpr(lambda x: _local_chol_array(
+            x, n, ib, jax.lax.Precision.HIGHEST, lookahead=lookahead))(a)
+    assert sum(reg.counters("chol_update").values()) == n // ib - 1
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from eqns(sub)
+    big = [e.outvars[0].aval.shape for e in eqns(jaxpr.jaxpr)
+           if e.primitive.name in ("slice", "dynamic_slice")
+           and min(e.outvars[0].aval.shape) > n - 2 * ib]
+    assert not big, big
+
+
+_WARM_CACHE_SCRIPT = r"""
+import json, os, sys
+import jax
+jax.config.update("jax_platform_name", "cpu")
+jax.config.update("jax_enable_x64", True)
+from elemental_tpu.core.compile_cache import enable_compile_cache
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+import numpy as np, jax.numpy as jnp
+import elemental_tpu as el
+from elemental_tpu.lapack.cholesky import _pin_column_major
+n, nb = 48, 16
+G = np.random.default_rng(0).normal(size=(n, n)).astype(np.float32)
+S = G @ G.T + n * np.eye(n, dtype=np.float32)
+meta = el.from_global(jnp.asarray(S), el.MC, el.MR,
+                      grid=el.Grid(jax.devices()[:1]))
+F = meta.local
+fns = {"cholesky": (lambda a: el.cholesky(meta.with_local(a), nb=nb).local,
+                    np.linalg.cholesky(S.astype(np.float64))),
+       "pin": (_pin_column_major, S)}
+out = {}
+for name, (fn, want) in fns.items():
+    got = {"eager": fn(F), "vmap": jax.vmap(fn)(F[None])[0],
+           "jvp": jax.jvp(fn, (F,), (jnp.zeros_like(F),))[0],
+           "checkpoint": jax.checkpoint(fn)(F),
+           "jit_vmap": jax.jit(jax.vmap(fn))(F[None])[0]}
+    for how, val in got.items():
+        out[name + "." + how] = float(np.abs(np.asarray(val) - want).max())
+out["entries"] = len(os.listdir(os.environ["JAX_COMPILATION_CACHE_DIR"]))
+print("RESULT " + json.dumps(out))
+"""
+_WARM_CACHE_RUNS = {}
+
+
+def _warm_cache_runs(tmp_path_factory):
+    """The script above run in two fresh processes against ONE empty
+    persistent compile cache: the first fills it, the second is served
+    from it.  Once a worker."""
+    if not _WARM_CACHE_RUNS:
+        import json, os, subprocess, sys
+        repo = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+                   JAX_COMPILATION_CACHE_DIR=str(
+                       tmp_path_factory.mktemp("warm_cache")))
+        for run in ("cold", "warm"):
+            p = subprocess.run([sys.executable, "-c", _WARM_CACHE_SCRIPT],
+                               capture_output=True, text=True, cwd=repo,
+                               env=env, timeout=600)
+            assert p.returncode == 0, p.stderr[-2000:]
+            line = [ln for ln in p.stdout.splitlines()
+                    if ln.startswith("RESULT ")][-1]
+            _WARM_CACHE_RUNS[run] = json.loads(line[len("RESULT "):])
+    return _WARM_CACHE_RUNS
+
+
+@pytest.mark.parametrize("how", ["eager", "vmap", "jvp", "checkpoint",
+                                 "jit_vmap"])
+@pytest.mark.parametrize("what", ["cholesky", "pin"])
+def test_factor_is_right_from_a_warm_compile_cache(tmp_path_factory, what,
+                                                   how):
+    """The one-chip driver, and the layout pin it applies on the TPU,
+    called eagerly under jax's transformations in a process that finds
+    every executable in the persistent compile cache (as a second run of
+    the tests or of the benchmark does).  jax 0.9.0 hands an executable
+    whose RESULT carries a layout back from that cache without it, and an
+    eager ``with_layout_constraint`` makes just such a one: under an eager
+    ``vmap`` the factor came back TRANSPOSED in the second process
+    (REVIEW of PR 34).  The pin is a ``jit`` of its own, so the constraint
+    is never a program's result."""
+    runs = _warm_cache_runs(tmp_path_factory)
+    assert runs["cold"]["entries"] > 0
+    assert runs["warm"]["entries"] == runs["cold"]["entries"]   # all served
+    for run in ("cold", "warm"):
+        assert runs[run][f"{what}.{how}"] < (1e-5 if what == "cholesky"
+                                             else 1e-30), runs[run]
+
+
 def test_cholesky_crossover_boundary(grid24):
     """Tail crossover at thresholds just below / at / above the remaining
     trailing sizes (n=24, nb=8 leaves tails of 16 then 8): every setting

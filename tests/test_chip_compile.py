@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture (never at
 import), and every compile happens in this process: only one process may
 hold the TPU library at a time.
 """
+import math
 import re
 
 import jax
@@ -244,6 +245,63 @@ def test_lu_row_block_chain_stays_unpadded(width, front, grid22):
     temp, padded = _plan(chain, A, li11)
     assert temp <= 4 * block, temp
     assert not padded
+
+
+_BIG_MOVE = re.compile(r" = \w+\[([\d,]+)\]\{[^}]*\} (copy|slice)\(")
+
+
+def _whole_matrix_moves(text, least):
+    """Shapes of the ``copy`` / ``slice`` instructions of an optimized HLO
+    text whose result holds at least ``least`` elements."""
+    found = []
+    for move in _BIG_MOVE.finditer(text):
+        dims = [int(d) for d in move.group(1).split(",")]
+        if math.prod(dims) >= least:
+            found.append((move.group(2), dims))
+    return found
+
+
+def test_the_move_reader_counts_whole_matrix_copies():
+    text = """
+  %slice.174 = f32[14336,14336]{1,0:T(8,128)} slice(%A_local.1), slice={[2048:16384], [2048:16384]}
+  %copy.203 = f32[14336,14336]{0,1:T(8,128)} copy(%slice.174)
+  %slice.9 = f32[12288,2048]{0,1:T(8,128)} slice(%fusion.3), slice={[0:12288], [0:2048]}
+  %fusion.44 = f32[14336,14336]{0,1:T(8,128)} fusion(%copy.203, %custom-call.424), kind=kOutput
+  %copy-start.2 = (f32[16384,8]{0,1}, f32[16384,8]{0,1}, u32[]) copy-start(%copy.5)
+"""
+    assert _whole_matrix_moves(text, 12288 ** 2) == [
+        ("slice", [14336, 14336]), ("copy", [14336, 14336])]
+
+
+def test_one_chip_cholesky_factors_in_one_buffer(topo):
+    """The one-chip ``hpd_solve`` whole, A donated as the benchmark donates
+    it, at N = 16384, nb = 2048 (ISSUE 34; at N = 4096 the compiler keeps
+    the buffers in ``S(1)`` and the size shows nothing).  The blocked loop
+    copied the shrinking trailing matrix at every step: three ``copy`` /
+    ``slice`` of at least (n - 2 nb)^2 elements here (14336^2 twice,
+    12288^2), 41.6 GB of traffic a solve at N = 32768, and a plan of
+    3.612109 GB.  In one buffer it holds ONE, the copy of the operand that
+    cannot be aliased (the result is n x 8), and plans 2.493894144 GB: the
+    operand, the working buffer and nothing else of their size.  The bound
+    is that reading and 1 % (a quarter of an operand is 0.27 GB).  A second
+    whole-matrix temporary beside the working buffer, a masked or re-laid
+    copy of the factor for the sweeps, reads 4.16 GB here; without the
+    layout pin of ``_local_cholesky`` (zeros above the panels kept) the
+    sweeps' ``copy f32[1,16384,1,16384]`` is back: two moves, 4.207338496
+    GB, over the parent's."""
+    import elemental_tpu as el
+    n, nb, nrhs = 16384, 2048, 8
+    grid = el.Grid([topo.devices[0]])
+    A = _abstract(grid, n, n, el.MC, el.MR)
+    B = _abstract(grid, n, nrhs, el.MC, el.MR)
+    compiled = jax.jit(lambda a, b: el.hpd_solve(a, b, nb=nb),
+                       donate_argnums=0).lower(A, B).compile()
+    moves = _whole_matrix_moves(compiled.as_text(), (n - 2 * nb) ** 2)
+    assert len(moves) <= 1, moves
+    mem = compiled.memory_analysis()
+    plan = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert plan <= 2_520_000_000, plan
 
 
 def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
