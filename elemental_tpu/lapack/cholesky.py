@@ -21,15 +21,18 @@ trailing update at the next panel boundary:
 
     write back L11_k                          (from the carried factor)
     (L21, L21^H) := panel_spread(L21_vc)      (one fused collective)
+    write back L21_k
     strip := A22[:, :nb] - L21 L21^H[:, :nb]  (narrow column-strip update)
     factor diag block k+1 from ``strip``      (off the critical path)
     solve panel k+1 from ``strip``            (off the critical path)
-    rest := A22[:, nb:] - L21 L21^H[:, nb:]   (wide MXU update)
+    A22[:, nb:] -= L21 L21^H[:, nb:]          (wide MXU update)
 
-The strip/rest/diag operands are all captured BEFORE any writeback, so the
-replicated ``_potrf_inv`` of step k+1 and the wide remainder matmul share
-no data dependence and XLA is free to overlap them.  ``lookahead=False``
-keeps the classic order -- bit-identical factors.
+Step k+1's replicated ``_potrf_inv`` and panel solve read the ``strip``
+VALUE, the wide remainder update reads its own window of the factor and
+neither of their results, so the two share no data dependence and XLA is
+free to overlap them.  Every window is written where it was just read
+(one working copy of the shard, updated in place; PERF.md 6, PR 35).
+``lookahead=False`` keeps the classic order -- bit-identical factors.
 
 Tail crossover-to-local (``crossover``)
 ---------------------------------------
@@ -411,8 +414,17 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     ib = _blocksize(nb, math.lcm(r, c), m)
     xover = (_CROSSOVER if lookahead else 0) if crossover is None \
         else max(int(crossover), 0)
-    L = A
     cp = comm_precision
+    # The factor is built in ONE copy of the operand, and the mask that
+    # leaves zeros above the diagonal is part of making that copy: no step
+    # writes above the diagonal (every update is masked to the lower
+    # triangle, the diagonal blocks go in lower-triangular, the tail leaves
+    # _local_chol_array so) and none reads there (_potrf_inv takes jnp.tril
+    # of its block).  Masked at the EXIT instead, the select was one more
+    # whole shard beside the working one: at N = 65536 on 2x2 the 4.29 GB
+    # by which the program missed the chip (PERF.md 6, PR 35).
+    with tm.phase("mask", 0):
+        L = make_trapezoidal(A, "L")
 
     def factor_diag(step, src, lo, hi):
         # replicated diagonal-block factor + inverse: every device runs
@@ -436,6 +448,17 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
             ph.done(L21_vc)
         return L21_vc
 
+    def minus_lower(W, L21, L21H, mask):
+        # W - L21 L21H on the window's share of the global lower triangle
+        # (``mask``), W's own values elsewhere.  The mask is on the
+        # product, not on the difference: ``w - where(m, upd, 0)`` reads its
+        # window of L inside the matmul's fusion and writes it in place,
+        # where ``where(m, w - upd, w)`` had the compiler slice the window
+        # out first, most of a shard at step 0.  The same numbers: w - 0
+        # is w
+        upd = jnp.matmul(L21.local, L21H.local, precision=precision)
+        return W.with_local(W.local - jnp.where(mask, upd.astype(A.dtype), 0))
+
     if lookahead:
         # prologue: factor diag block 0 + solve panel 0 from the input
         e0 = min(ib, m)
@@ -450,11 +473,13 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
         else:
             L11, Li11 = factor_diag(k, L, s, e)
         with tm.phase("diag", k):
-            L11_ss = DistMatrix(L11, (e - s, e - s), STAR, STAR, 0, 0, g)
+            L11_ss = DistMatrix(jnp.tril(L11), (e - s, e - s), STAR, STAR,
+                                0, 0, g)
             L = update_view(L, redistribute(L11_ss, MC, MR), rows=(s, e),
                             cols=(s, e))
         if e == m:
             break
+        _metrics.inc("chol_update")
         if not lookahead:
             L21_vc = solve_panel(k, L, (e, m), (s, e), Li11)
         with tm.phase("spread", k) as ph:
@@ -465,56 +490,51 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
         if not lookahead:
             with tm.phase("update", k) as ph:
                 A22 = view(L, rows=(e, m), cols=(e, m))
-                upd = jnp.matmul(L21_mc.local, L21H_mr.local,
-                                 precision=precision)
-                mask = _mask_triangle(A22, "L")
-                A22new = jnp.where(mask, A22.local - upd.astype(L.dtype),
-                                   A22.local)
-                L = update_view(L, A22.with_local(A22new), rows=(e, m),
-                                cols=(e, m))
+                L = update_view(
+                    L, minus_lower(A22, L21_mc, L21H_mr,
+                                   _mask_triangle(A22, "L")),
+                    rows=(e, m), cols=(e, m))
                 L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
                                 cols=(s, e))
                 ph.done(L)
         else:
-            # (a) narrow strip update: the next panel's columns of A22
+            # Every write goes into L where its operand was just read, so
+            # the compiler updates the one working shard in place: panel k's
+            # columns, then (a), then (b), each a read-modify-write of its
+            # own window.  Computed from one captured L and written back
+            # together at the end, the three windows left readers of the
+            # old shard unordered against its writers, and the compiler
+            # answered with a copy of the whole shard at every step: a
+            # second working shard in the plan, and 4.29 GB of copying a
+            # step at N = 65536 (PERF.md 6, PR 35).
             with tm.phase("update", k):
+                L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
+                                cols=(s, e))
+                # (a) narrow strip update: the next panel's columns of A22
                 e2 = min(e + ib, m)
                 A22a = view(L, rows=(e, m), cols=(e, e2))
-                L21H_a = view(L21H_mr, cols=(0, e2 - e))
-                maskA = _mask_triangle(A22a, "L")
-                stripD = A22a.with_local(jnp.where(
-                    maskA,
-                    A22a.local - jnp.matmul(L21_mc.local, L21H_a.local,
-                                            precision=precision
-                                            ).astype(L.dtype),
-                    A22a.local))
+                stripD = minus_lower(A22a, L21_mc,
+                                     view(L21H_mr, cols=(0, e2 - e)),
+                                     _mask_triangle(A22a, "L"))
+                L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
             if not tail:
-                # factor diag block k+1 + solve panel k+1 from the strip,
-                # off the critical path of the wide remainder update
+                # factor diag block k+1 + solve panel k+1 from the strip (the
+                # value, not L), off the critical path of the wide remainder
+                # update, which reads neither result
                 L11n, Li11n = factor_diag(k + 1, stripD, 0, e2 - e)
                 L21n_vc = solve_panel(k + 1, stripD, (e2 - e, m - e),
                                       (0, e2 - e), Li11n) if e2 < m else None
                 nxt = (L11n, Li11n, L21n_vc)
-            # (b) wide remainder update; operands captured pre-writeback so
-            # it is data-independent of the step-k+1 factorization above
+            # (b) wide remainder update
             with tm.phase("update", k) as ph:
-                restD = None
                 if e2 < m:
                     A22b = view(L, rows=(e, m), cols=(e2, m))
-                    L21H_b = view(L21H_mr, cols=(e2 - e, m - e))
                     I, J = _global_indices(A22b)
-                    maskB = (J[None, :] + (e2 - e)) <= I[:, None]
-                    restD = A22b.with_local(jnp.where(
-                        maskB,
-                        A22b.local - jnp.matmul(L21_mc.local, L21H_b.local,
-                                                precision=precision
-                                                ).astype(L.dtype),
-                        A22b.local))
-                L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
-                                cols=(s, e))
-                L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
-                if restD is not None:
-                    L = update_view(L, restD, rows=(e, m), cols=(e2, m))
+                    L = update_view(
+                        L, minus_lower(A22b, L21_mc,
+                                       view(L21H_mr, cols=(e2 - e, m - e)),
+                                       (J[None, :] + (e2 - e)) <= I[:, None]),
+                        rows=(e, m), cols=(e2, m))
                 ph.done(L)
         if tail:
             # crossover-to-local: one gather of the (fully updated) trailing
@@ -533,7 +553,7 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
             break
     if hm is not None:
         hm.report()
-    return make_trapezoidal(L, "L")
+    return L
 
 
 @_scoped("el.hpd_solve")

@@ -46,7 +46,9 @@ time by them.  A path is made of:
                            :data:`PHASES` -- ``diag``, ``panel``,
                            ``swap``, ``solve``, ``spread``, ``update``,
                            ``tail`` (CALU adds ``tournament``, the serve
-                           loop ``batch``).  A nested driver or local
+                           loop ``batch``, the grid Cholesky ``k00/mask``:
+                           the masked copy of the operand it factors
+                           in).  A nested driver or local
                            finish nests its own (``k14/tail/k00/diag``):
                            the FIRST ``k<step>`` gives an op its phase
   ``el.hpd_solve`` /       the public solves, which open ``factor`` and
@@ -79,10 +81,12 @@ not tick again).  Read them under ``metrics_scope()``:
                            makes a replicated (or coarser) dimension
                            distributed (the same two labels, by the block
                            it returns)
-  ``chol_update``          one trailing update of the one-chip blocked
-                           Cholesky (``lapack/cholesky.py:_local_chol_array``,
-                           a step of the loop), made where the trailing
-                           matrix lies in the one n x n buffer
+  ``chol_update``          one trailing update of the blocked Cholesky,
+                           a step of the loop, made where the trailing
+                           matrix lies in the one working buffer: the
+                           one-chip loop (``lapack/cholesky.py:
+                           _local_chol_array``) and the grid loop, whose
+                           replicated tail ticks for its own steps
   ``row_permute{kind}``    one storage-level row permutation: ``kind``
                            ``move`` (``move_rows``: a panel step's pivot
                            swaps) | ``full`` (``permute_rows_storage``:

@@ -1,0 +1,225 @@
+"""The grid branch of ``cholesky`` and ``hpd_solve`` on 2x2, 2x1, 1x2 and
+1x4 (ISSUE 35): against a plain float64 numpy Cholesky solve on seeded
+operands, and the factor's lower triangle equal TO THE BIT to the form the
+loop had before, which masked the whole factor at the exit and wrote a
+step's three windows back together (kept below as the reference).
+"""
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import elemental_tpu as el
+from elemental_tpu import MC, MR, STAR, VC, from_global, to_global
+
+IB = 8
+HI = jax.lax.Precision.HIGHEST
+
+_GRIDS = {"2x2": (2, 2), "2x1": (2, 1), "1x2": (1, 2), "1x4": (1, 4)}
+_SIZES = {"ib": IB, "ib+1": IB + 1, "3ib": 3 * IB, "3ib+7-ragged": 3 * IB + 7,
+          "5ib": 5 * IB}
+#: (lookahead, crossover): the pipelined loop with and without the
+#: replicated tail, the classic order likewise
+_SCHEDULES = {"lookahead-tail": (True, 2 * IB), "lookahead": (True, 0),
+              "classic": (False, 0), "classic-tail": (False, 2 * IB)}
+
+
+def _grid(name):
+    r, c = _GRIDS[name]
+    return el.Grid(jax.devices()[: r * c], height=r)
+
+
+def _operand(n, dtype, seed):
+    """``(F, a)``: a seeded HPD matrix in double precision, and the operand
+    handed to the program: its lower triangle in ``dtype``, NaN above the
+    diagonal (only the lower triangle is valid input)."""
+    rng = np.random.default_rng(seed)
+    G = rng.normal(size=(n, n))
+    if np.issubdtype(dtype, np.complexfloating):
+        G = G + 1j * rng.normal(size=(n, n))
+    F = G @ G.conj().T / n + 2 * np.eye(n)
+    a = (np.tril(F) + np.triu(np.full((n, n), np.nan), 1)).astype(dtype)
+    return F, a
+
+
+def _exit_masked_cholesky_reference(A, nb, lookahead, crossover):
+    """The grid branch of ``cholesky`` as it stood before ISSUE 35, plain
+    (no timer, no wire precision): the loop works in A's own shard, upper
+    triangle and all, computes a step's strip and remainder from ONE
+    captured L and writes the three windows back together, and masks the
+    whole factor at the exit.  The same matmuls on the same operands in the
+    same order as today's, so the lower triangles agree to the bit."""
+    from elemental_tpu.blas.level1 import _global_indices, make_trapezoidal
+    from elemental_tpu.blas.level3 import _blocksize, _mask_triangle
+    from elemental_tpu.core.distmatrix import DistMatrix
+    from elemental_tpu.core.view import update_view, view
+    from elemental_tpu.lapack.cholesky import _local_chol_array, _potrf_inv
+    from elemental_tpu.redist.engine import panel_spread, redistribute
+    g, m = A.grid, A.gshape[0]
+    ib = _blocksize(nb, math.lcm(g.height, g.width), m)
+    L = A
+
+    def factor_diag(src, lo, hi):
+        A11 = redistribute(view(src, rows=(lo, hi), cols=(lo, hi)),
+                           STAR, STAR)
+        return _potrf_inv(A11.local, HI)
+
+    def solve_panel(src, rows, cols, Li11):
+        A21 = redistribute(view(src, rows=rows, cols=cols), VC, STAR)
+        x21 = jnp.matmul(A21.local, jnp.conj(Li11).T,
+                         precision=HI).astype(A.dtype)
+        return DistMatrix(x21, (rows[1] - rows[0], cols[1] - cols[0]),
+                          VC, STAR, 0, 0, g)
+
+    if lookahead:
+        e0 = min(ib, m)
+        L11, Li11 = factor_diag(L, 0, e0)
+        nxt = (L11, Li11,
+               solve_panel(L, (e0, m), (0, e0), Li11) if e0 < m else None)
+    for s in range(0, m, ib):
+        e = min(s + ib, m)
+        if lookahead:
+            L11, Li11, L21_vc = nxt
+        else:
+            L11, Li11 = factor_diag(L, s, e)
+        L = update_view(L, redistribute(
+            DistMatrix(L11, (e - s, e - s), STAR, STAR, 0, 0, g), MC, MR),
+            rows=(s, e), cols=(s, e))
+        if e == m:
+            break
+        if not lookahead:
+            L21_vc = solve_panel(L, (e, m), (s, e), Li11)
+        L21_mc, L21H_mr = panel_spread(L21_vc, conj=True)
+        tail = bool(crossover) and m - e <= crossover
+        if not lookahead:
+            A22 = view(L, rows=(e, m), cols=(e, m))
+            upd = jnp.matmul(L21_mc.local, L21H_mr.local, precision=HI)
+            A22new = jnp.where(_mask_triangle(A22, "L"),
+                               A22.local - upd.astype(L.dtype), A22.local)
+            L = update_view(L, A22.with_local(A22new), rows=(e, m),
+                            cols=(e, m))
+            L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
+                            cols=(s, e))
+        else:
+            e2 = min(e + ib, m)
+            A22a = view(L, rows=(e, m), cols=(e, e2))
+            L21H_a = view(L21H_mr, cols=(0, e2 - e))
+            stripD = A22a.with_local(jnp.where(
+                _mask_triangle(A22a, "L"),
+                A22a.local - jnp.matmul(L21_mc.local, L21H_a.local,
+                                        precision=HI).astype(L.dtype),
+                A22a.local))
+            if not tail:
+                L11n, Li11n = factor_diag(stripD, 0, e2 - e)
+                nxt = (L11n, Li11n,
+                       solve_panel(stripD, (e2 - e, m - e), (0, e2 - e),
+                                   Li11n) if e2 < m else None)
+            restD = None
+            if e2 < m:
+                A22b = view(L, rows=(e, m), cols=(e2, m))
+                L21H_b = view(L21H_mr, cols=(e2 - e, m - e))
+                I, J = _global_indices(A22b)
+                restD = A22b.with_local(jnp.where(
+                    (J[None, :] + (e2 - e)) <= I[:, None],
+                    A22b.local - jnp.matmul(L21_mc.local, L21H_b.local,
+                                            precision=HI).astype(L.dtype),
+                    A22b.local))
+            L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
+                            cols=(s, e))
+            L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
+            if restD is not None:
+                L = update_view(L, restD, rows=(e, m), cols=(e2, m))
+        if tail:
+            Atail = redistribute(view(L, rows=(e, m), cols=(e, m)),
+                                 STAR, STAR)
+            lt = _local_chol_array(Atail.local, m - e, ib, HI,
+                                   lookahead=lookahead)
+            L = update_view(L, redistribute(
+                DistMatrix(lt, (m - e, m - e), STAR, STAR, 0, 0, g), MC, MR),
+                rows=(e, m), cols=(e, m))
+            break
+    return make_trapezoidal(L, "L")
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64])
+@pytest.mark.parametrize("schedule", list(_SCHEDULES))
+@pytest.mark.parametrize("size", list(_SIZES))
+@pytest.mark.parametrize("grid", list(_GRIDS))
+def test_grid_cholesky_masks_at_the_entry(grid, size, schedule, dtype):
+    """The factor against ``numpy.linalg.cholesky`` in double precision;
+    NaN above the diagonal on input gives exact zeros there and no NaN
+    anywhere on output (the operand is masked where the one working copy
+    of it is made, and nothing later writes above the diagonal); the lower
+    triangle equal to the bit to the exit-masked loop it replaced."""
+    g, n = _grid(grid), _SIZES[size]
+    lookahead, crossover = _SCHEDULES[schedule]
+    F, a = _operand(n, dtype, seed=35 + n)
+    A = from_global(a, MC, MR, grid=g)
+    got = np.asarray(to_global(el.cholesky(
+        A, nb=IB, lookahead=lookahead, crossover=crossover)))
+    assert got.dtype == dtype
+    assert not np.triu(got, 1).any()
+    assert np.isfinite(got).all()
+    want = np.linalg.cholesky(F)
+    assert np.linalg.norm(got - want) < 50 * np.finfo(dtype).eps * n \
+        * np.linalg.norm(want)
+    ref = np.asarray(to_global(_exit_masked_cholesky_reference(
+        A, IB, lookahead, crossover)))
+    np.testing.assert_array_equal(got, ref)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64])
+@pytest.mark.parametrize("size", list(_SIZES))
+@pytest.mark.parametrize("grid", list(_GRIDS))
+def test_grid_hpd_solve_against_numpy(grid, size, dtype):
+    """``hpd_solve`` (factor and both sweeps; the driver's own schedule:
+    look-ahead, tail under 4096) against ``numpy.linalg.solve`` in double
+    precision, three right-hand sides, NaN above A's diagonal."""
+    g, n, nrhs = _grid(grid), _SIZES[size], 3
+    F, a = _operand(n, dtype, seed=53 + n)
+    rng = np.random.default_rng(n)
+    B = rng.normal(size=(n, nrhs))
+    if np.issubdtype(dtype, np.complexfloating):
+        B = B + 1j * rng.normal(size=(n, nrhs))
+    X = np.asarray(to_global(el.hpd_solve(
+        from_global(a, MC, MR, grid=g),
+        from_global(B.astype(dtype), MC, MR, grid=g), nb=IB)))
+    assert X.dtype == dtype
+    want = np.linalg.solve(F, B.astype(dtype))
+    eps = np.finfo(dtype).eps
+    assert np.linalg.norm(X - want) < 50 * eps * n * np.linalg.norm(want)
+
+
+def _eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("schedule", list(_SCHEDULES))
+def test_grid_factor_has_one_whole_shard_select_and_it_is_first(schedule):
+    """The jaxpr of the 2x2 factor holds ONE ``select_n`` over the whole
+    storage, and it stands before the first matmul: the entry mask.  A
+    second one at the exit was a third whole shard in the plan of
+    ``hpd_solve`` at N = 65536, 4.29 GB a device (PERF.md 6, PR 35).  And
+    the loop ticks ``chol_update`` once for every trailing update, the
+    replicated tail's included."""
+    from elemental_tpu.obs import metrics
+    lookahead, crossover = _SCHEDULES[schedule]
+    g, n = _grid("2x2"), 6 * IB
+    A = from_global(np.zeros((n, n), np.float32), MC, MR, grid=g)
+    with metrics.scoped() as reg:
+        jaxpr = jax.make_jaxpr(lambda a: el.cholesky(
+            A.with_local(a), nb=IB, lookahead=lookahead,
+            crossover=crossover).local)(A.local)
+    assert sum(reg.counters("chol_update").values()) == n // IB - 1
+    names = [(e.primitive.name, e.outvars[0].aval.shape)
+             for e in _eqns(jaxpr.jaxpr) if e.outvars]
+    whole = [i for i, (name, shape) in enumerate(names)
+             if name == "select_n" and shape == A.local.shape]
+    first_matmul = next(i for i, (name, _s) in enumerate(names)
+                        if name == "dot_general")
+    assert len(whole) == 1 and whole[0] < first_matmul, whole
