@@ -372,10 +372,13 @@ def trrk(uplo: str, alpha, A_mc: DistMatrix, B_mr: DistMatrix, beta, C: DistMatr
     untouched.  A is [MC,STAR], B is [STAR,MR] (the reference's
     ``LocalTrrk``, the factorization trailing-update workhorse).
 
-    TPU note: we compute the full local product and mask -- the MXU doesn't
-    exploit triangles, and the masked half is fused away as dead only at the
-    boundary tiles; this matches what the reference's recursive Trrk saves
-    asymptotically but costs nothing extra in wall-clock on TPU at nb<<n.
+    TPU note: this computes the full local product and masks it.  The
+    masked half is NOT free: the compiler multiplies both triangles, and on
+    the chip the grid Cholesky's updates in this form ran at the matmuls'
+    roofline with half the flops thrown away (``cholesky/update`` 1.51 s of
+    a 2.00 s solve at N = 65536 on 2x2; PERF.md 6, PR 36).  The Cholesky
+    drivers walk stripes of the lower trapezoid instead
+    (``lapack/cholesky.py``); a caller with a large C should too.
     """
     if A_mc.dist != (MC, STAR) or B_mr.dist != (STAR, MR):
         raise ValueError("trrk expects A [MC,STAR], B [STAR,MR]")

@@ -10,7 +10,9 @@ Per panel (the LVar3 loop, SURVEY.md §4.2):
   A21 -> [VC,STAR]              1-D cyclic panel, local right-Trsm by L11^H
   (L21, L21^H) spread           fused engine ``panel_spread``: [MC,STAR]
                                 and the [STAR,MR] adjoint in ONE collective
-  A22 -= L21 L21^H (lower tri)  one storage matmul on the MXU, masked
+  A22 -= L21 L21^H (lower tri)  column stripes of the lower trapezoid,
+                                each one storage matmul on the MXU, masked
+                                on its diagonal block
 
 Look-ahead schedule (default on; the Cholesky twin of lu.py's pipeline)
 -----------------------------------------------------------------------
@@ -25,14 +27,19 @@ trailing update at the next panel boundary:
     strip := A22[:, :nb] - L21 L21^H[:, :nb]  (narrow column-strip update)
     factor diag block k+1 from ``strip``      (off the critical path)
     solve panel k+1 from ``strip``            (off the critical path)
-    A22[:, nb:] -= L21 L21^H[:, nb:]          (wide MXU update)
+    A22[:, nb:] -= L21 L21^H[:, nb:]          (wide MXU update, in stripes)
 
 Step k+1's replicated ``_potrf_inv`` and panel solve read the ``strip``
 VALUE, the wide remainder update reads its own window of the factor and
 neither of their results, so the two share no data dependence and XLA is
 free to overlap them.  Every window is written where it was just read
 (one working copy of the shard, updated in place; PERF.md 6, PR 35).
-``lookahead=False`` keeps the classic order -- bit-identical factors.
+``lookahead=False`` keeps the classic order -- the same factor (to the bit
+where the backend's dot does not choose its kernel by the product's shape).
+Either order updates only the lower trapezoid, by column stripes ``2 nb``
+wide (``chol_update_stripe`` counts them): one product over the square window
+with its upper half masked away was half the update's flops discarded at
+the matmuls' roofline (PERF.md 6, PR 36).
 
 Tail crossover-to-local (``crossover``)
 ---------------------------------------
@@ -70,7 +77,7 @@ from ..redist.engine import (apply_fault, redistribute, transpose_dist,
                              panel_spread)
 from ..redist.quantize import check_comm_precision
 from ..blas.level1 import make_trapezoidal, _global_indices
-from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle, trsm
+from ..blas.level3 import _blocksize, _check_mcmr, trsm
 from ..obs import metrics as _metrics
 from ..obs.tracer import NULL_HOOK, scoped as _scoped
 from .lu import _hi, _phase_hook
@@ -304,7 +311,7 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     triangle.  Returns L (A = L L^H) for 'L', U (A = U^H U) for 'U'.
 
     ``lookahead`` selects the pipelined schedule (module docstring; ``False``
-    restores the classic right-looking order, bit-identical factors);
+    restores the classic right-looking order, the same factor);
     ``crossover`` is the trailing-matrix size at which the distributed loop
     gathers the tail once and finishes locally (``None`` = :data:`_CROSSOVER`
     with look-ahead, disabled classic; 0 never crosses over); ``timer``
@@ -448,16 +455,49 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
             ph.done(L21_vc)
         return L21_vc
 
-    def minus_lower(W, L21, L21H, mask):
-        # W - L21 L21H on the window's share of the global lower triangle
-        # (``mask``), W's own values elsewhere.  The mask is on the
-        # product, not on the difference: ``w - where(m, upd, 0)`` reads its
-        # window of L inside the matmul's fusion and writes it in place,
-        # where ``where(m, w - upd, w)`` had the compiler slice the window
-        # out first, most of a shard at step 0.  The same numbers: w - 0
-        # is w
+    def minus_lower(W, L21, L21H, below=0):
+        # W - L21 L21H on the matrix's lower triangle, W's own values
+        # elsewhere; W's first row lies ``below`` rows under the diagonal
+        # entry of its first column.  The mask is on the product, not on the
+        # difference: ``w - where(m, upd, 0)`` reads its window of L inside
+        # the matmul's fusion and writes it in place, where
+        # ``where(m, w - upd, w)`` had the compiler slice the window out
+        # first, most of a shard at step 0.  The same numbers: w - 0 is w
+        I, J = _global_indices(W)
         upd = jnp.matmul(L21.local, L21H.local, precision=precision)
-        return W.with_local(W.local - jnp.where(mask, upd.astype(A.dtype), 0))
+        return W.with_local(W.local - jnp.where(
+            J[None, :] <= I[:, None] + below, upd.astype(A.dtype), 0))
+
+    def update_stripes(L, e, w2, L21, L21H):
+        # The trailing window (origin e) right of its column w2, minus
+        # L21 L21H, by COLUMN stripes of the lower trapezoid, q = 2 ib wide
+        # (_local_chol_array's q):
+        #   L[e+j:, e+j:e+jq] -= L21[j:] L21H[:, j:jq]
+        # One product over the square window, its upper half masked away,
+        # was half the update's flops thrown away at the matmuls' roofline
+        # (PERF.md 6, PR 36).  Stripe ends are multiples of ib, so of
+        # lcm(r, c): every stripe is a static local window, the same share
+        # of it on every device, written where it was read; what lies above
+        # a stripe's diagonal block is neither read nor written.
+        # Columns and not rows (_local_chol_array's stripes), and the corner
+        # stripe, which is its own diagonal block, starts one block row
+        # higher: every product here is TALLER than wide.  One that is not
+        # has the TPU compiler lay the whole working shard out row-major,
+        # and the plan then holds one shard more (1.144 GB for 0.845 at
+        # N = 16384 on 2x2; PERF.md 6, PR 36).  The block above the corner
+        # is masked away: one or two block products a step, 0.8 % at N = 65536
+        mt, q = m - e, 2 * ib
+        for j in range(w2, mt, q):
+            jq = min(j + q, mt)
+            i = max(j - ib, 0) if jq == mt else j
+            rows, cols = (e + i, m), (e + j, e + jq)
+            _metrics.inc("chol_update_stripe")
+            L = update_view(
+                L, minus_lower(view(L, rows=rows, cols=cols),
+                               view(L21, rows=(i, mt)),
+                               view(L21H, cols=(j, jq)), i - j),
+                rows=rows, cols=cols)
+        return L
 
     if lookahead:
         # prologue: factor diag block 0 + solve panel 0 from the input
@@ -489,11 +529,7 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
         tail = bool(xover) and m - e <= xover
         if not lookahead:
             with tm.phase("update", k) as ph:
-                A22 = view(L, rows=(e, m), cols=(e, m))
-                L = update_view(
-                    L, minus_lower(A22, L21_mc, L21H_mr,
-                                   _mask_triangle(A22, "L")),
-                    rows=(e, m), cols=(e, m))
+                L = update_stripes(L, e, 0, L21_mc, L21H_mr)
                 L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
                                 cols=(s, e))
                 ph.done(L)
@@ -510,12 +546,11 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
             with tm.phase("update", k):
                 L = update_view(L, redistribute(L21_mc, MC, MR), rows=(e, m),
                                 cols=(s, e))
-                # (a) narrow strip update: the next panel's columns of A22
+                # (a) narrow strip update: the next panel's columns of A22,
+                # one tall matmul (only its top block meets the diagonal)
                 e2 = min(e + ib, m)
-                A22a = view(L, rows=(e, m), cols=(e, e2))
-                stripD = minus_lower(A22a, L21_mc,
-                                     view(L21H_mr, cols=(0, e2 - e)),
-                                     _mask_triangle(A22a, "L"))
+                stripD = minus_lower(view(L, rows=(e, m), cols=(e, e2)),
+                                     L21_mc, view(L21H_mr, cols=(0, e2 - e)))
                 L = update_view(L, stripD, rows=(e, m), cols=(e, e2))
             if not tail:
                 # factor diag block k+1 + solve panel k+1 from the strip (the
@@ -527,14 +562,7 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
                 nxt = (L11n, Li11n, L21n_vc)
             # (b) wide remainder update
             with tm.phase("update", k) as ph:
-                if e2 < m:
-                    A22b = view(L, rows=(e, m), cols=(e2, m))
-                    I, J = _global_indices(A22b)
-                    L = update_view(
-                        L, minus_lower(A22b, L21_mc,
-                                       view(L21H_mr, cols=(e2 - e, m - e)),
-                                       (J[None, :] + (e2 - e)) <= I[:, None]),
-                        rows=(e, m), cols=(e2, m))
+                L = update_stripes(L, e, e2 - e, L21_mc, L21H_mr)
                 ph.done(L)
         if tail:
             # crossover-to-local: one gather of the (fully updated) trailing

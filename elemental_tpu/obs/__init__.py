@@ -87,6 +87,17 @@ not tick again).  Read them under ``metrics_scope()``:
                            one-chip loop (``lapack/cholesky.py:
                            _local_chol_array``) and the grid loop, whose
                            replicated tail ticks for its own steps
+  ``chol_update_stripe``   one stripe of a grid step's trailing update
+                           (``lapack/cholesky.py``, the grid loop only):
+                           the matmul ``L21[j:] L21H[:, j:j+q]``,
+                           ``q = 2 ib``, over a column stripe of the
+                           window's lower trapezoid, right of the
+                           look-ahead's strip (from ``ib``; from 0
+                           classic).  A window of ``j`` blocks ticks
+                           ``j // 2`` times (``(j + 1) // 2`` classic):
+                           240 at N = 65536, 56 at N = 32768 (ib 2048,
+                           tail at 4096), where ``chol_update`` reads 30
+                           and 14 before the tail
   ``row_permute{kind}``    one storage-level row permutation: ``kind``
                            ``move`` (``move_rows``: a panel step's pivot
                            swaps) | ``full`` (``permute_rows_storage``:

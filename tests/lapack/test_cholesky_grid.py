@@ -1,8 +1,11 @@
 """The grid branch of ``cholesky`` and ``hpd_solve`` on 2x2, 2x1, 1x2 and
 1x4 (ISSUE 35): against a plain float64 numpy Cholesky solve on seeded
-operands, and the factor's lower triangle equal TO THE BIT to the form the
-loop had before, which masked the whole factor at the exit and wrote a
-step's three windows back together (kept below as the reference).
+operands, and the factor's lower triangle against the form the loop had
+before, which masked the whole factor at the exit, wrote a step's three
+windows back together and multiplied the whole square of every trailing
+window (kept below as the reference).  Since ISSUE 36 the update walks
+stripes of the lower trapezoid: the sizes from ``9ib`` on have windows of
+more than one stripe, a ragged last stripe and a ragged last row.
 """
 import math
 
@@ -19,7 +22,7 @@ HI = jax.lax.Precision.HIGHEST
 
 _GRIDS = {"2x2": (2, 2), "2x1": (2, 1), "1x2": (1, 2), "1x4": (1, 4)}
 _SIZES = {"ib": IB, "ib+1": IB + 1, "3ib": 3 * IB, "3ib+7-ragged": 3 * IB + 7,
-          "5ib": 5 * IB}
+          "5ib": 5 * IB, "9ib": 9 * IB, "9ib+5-ragged": 9 * IB + 5}
 #: (lookahead, crossover): the pipelined loop with and without the
 #: replicated tail, the classic order likewise
 _SCHEDULES = {"lookahead-tail": (True, 2 * IB), "lookahead": (True, 0),
@@ -49,8 +52,9 @@ def _exit_masked_cholesky_reference(A, nb, lookahead, crossover):
     (no timer, no wire precision): the loop works in A's own shard, upper
     triangle and all, computes a step's strip and remainder from ONE
     captured L and writes the three windows back together, and masks the
-    whole factor at the exit.  The same matmuls on the same operands in the
-    same order as today's, so the lower triangles agree to the bit."""
+    whole factor at the exit.  Each update is ONE product over the square
+    window with its upper half masked away; today's stripes (ISSUE 36) are
+    columns of the same products on the same operands in the same order."""
     from elemental_tpu.blas.level1 import _global_indices, make_trapezoidal
     from elemental_tpu.blas.level3 import _blocksize, _mask_triangle
     from elemental_tpu.core.distmatrix import DistMatrix
@@ -152,7 +156,12 @@ def test_grid_cholesky_masks_at_the_entry(grid, size, schedule, dtype):
     NaN above the diagonal on input gives exact zeros there and no NaN
     anywhere on output (the operand is masked where the one working copy
     of it is made, and nothing later writes above the diagonal); the lower
-    triangle equal to the bit to the exit-masked loop it replaced."""
+    triangle equal to the exit-masked, full-square loop it replaced: to
+    the bit, or, where the CPU backend's dot gives a stripe's product other
+    last bits than the same columns of the full product (it picks its kernel
+    by shape), within the rounding of one update's dot products of length
+    ``ib``, ``ib eps |L| |L|^H`` elementwise (40 of the 224 cases; the
+    largest read: 1.21 eps)."""
     g, n = _grid(grid), _SIZES[size]
     lookahead, crossover = _SCHEDULES[schedule]
     F, a = _operand(n, dtype, seed=35 + n)
@@ -167,7 +176,9 @@ def test_grid_cholesky_masks_at_the_entry(grid, size, schedule, dtype):
         * np.linalg.norm(want)
     ref = np.asarray(to_global(_exit_masked_cholesky_reference(
         A, IB, lookahead, crossover)))
-    np.testing.assert_array_equal(got, ref)
+    if not np.array_equal(got, ref):
+        bound = IB * np.finfo(dtype).eps * (np.abs(ref) @ np.abs(ref).conj().T)
+        assert (np.abs(got - ref) <= bound).all()
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.complex64])
@@ -199,6 +210,29 @@ def _eqns(jaxpr):
             yield from _eqns(sub)
 
 
+def _traced_factor(blocks, schedule):
+    """The 2x2 factor of ``n = blocks * ib`` traced: the trace-time
+    counters' registry, the jaxpr's equations in order, A's storage shape."""
+    from elemental_tpu.obs import metrics
+    lookahead, crossover = _SCHEDULES[schedule]
+    g, n = _grid("2x2"), blocks * IB
+    A = from_global(np.zeros((n, n), np.float32), MC, MR, grid=g)
+    with metrics.scoped() as reg:
+        jaxpr = jax.make_jaxpr(lambda a: el.cholesky(
+            A.with_local(a), nb=IB, lookahead=lookahead,
+            crossover=crossover).local)(A.local)
+    return reg, [e for e in _eqns(jaxpr.jaxpr) if e.outvars], A.local.shape
+
+
+def _assert_one_whole_select_and_it_is_first(eqns, shape):
+    names = [(e.primitive.name, e.outvars[0].aval.shape) for e in eqns]
+    whole = [i for i, (name, s) in enumerate(names)
+             if name == "select_n" and s == shape]
+    first_matmul = next(i for i, (name, _s) in enumerate(names)
+                        if name == "dot_general")
+    assert len(whole) == 1 and whole[0] < first_matmul, whole
+
+
 @pytest.mark.parametrize("schedule", list(_SCHEDULES))
 def test_grid_factor_has_one_whole_shard_select_and_it_is_first(schedule):
     """The jaxpr of the 2x2 factor holds ONE ``select_n`` over the whole
@@ -207,19 +241,48 @@ def test_grid_factor_has_one_whole_shard_select_and_it_is_first(schedule):
     ``hpd_solve`` at N = 65536, 4.29 GB a device (PERF.md 6, PR 35).  And
     the loop ticks ``chol_update`` once for every trailing update, the
     replicated tail's included."""
-    from elemental_tpu.obs import metrics
+    reg, eqns, shape = _traced_factor(6, schedule)
+    assert sum(reg.counters("chol_update").values()) == 6 - 1
+    _assert_one_whole_select_and_it_is_first(eqns, shape)
+
+
+@pytest.mark.parametrize("schedule", list(_SCHEDULES))
+@pytest.mark.parametrize("blocks", [16, 32])
+def test_grid_update_walks_stripes(blocks, schedule):
+    """The trailing updates of the 2x2 factor of ``n = blocks * ib`` walk
+    stripes of the lower trapezoid (ISSUE 36).  ``chol_update_stripe``
+    ticks once for each: a window of ``j`` blocks has ``j // 2`` stripes
+    ``q = 2 ib`` wide right of the look-ahead's strip, ``(j + 1) // 2`` in
+    the classic order: 56 at 16 blocks and 240 at 32 with the tail at two
+    (N = 32768 and 65536 at ib = 2048, crossover 4096).  The matmuls under
+    ``update`` multiply at most 0.61 (0.56) of what full squares of the
+    windows take (0.605 and 0.550 with look-ahead and tail: the stripes'
+    own 0.588 and 0.545, and the block row above the corner stripe).
+    Every one of them is taller than wide wherever the window allows it,
+    which is what keeps the working shard column-major on the chip.
+    ``chol_update`` still ticks once a step, and the entry mask is still
+    the one whole ``select_n``."""
     lookahead, crossover = _SCHEDULES[schedule]
-    g, n = _grid("2x2"), 6 * IB
-    A = from_global(np.zeros((n, n), np.float32), MC, MR, grid=g)
-    with metrics.scoped() as reg:
-        jaxpr = jax.make_jaxpr(lambda a: el.cholesky(
-            A.with_local(a), nb=IB, lookahead=lookahead,
-            crossover=crossover).local)(A.local)
-    assert sum(reg.counters("chol_update").values()) == n // IB - 1
-    names = [(e.primitive.name, e.outvars[0].aval.shape)
-             for e in _eqns(jaxpr.jaxpr) if e.outvars]
-    whole = [i for i, (name, shape) in enumerate(names)
-             if name == "select_n" and shape == A.local.shape]
-    first_matmul = next(i for i, (name, _s) in enumerate(names)
-                        if name == "dot_general")
-    assert len(whole) == 1 and whole[0] < first_matmul, whole
+    reg, eqns, shape = _traced_factor(blocks, schedule)
+    assert sum(reg.counters("chol_update").values()) == blocks - 1
+    windows = range(2 if crossover else 1, blocks)   # j, in blocks
+    stripes = sum(j // 2 if lookahead else (j + 1) // 2 for j in windows)
+    if schedule == "lookahead-tail":
+        assert stripes == {16: 56, 32: 240}[blocks]
+    assert sum(reg.counters("chol_update_stripe").values()) == stripes
+    _assert_one_whole_select_and_it_is_first(eqns, shape)
+    # (M, K) @ (K, N) of every matmul under a step's ``update`` (the
+    # replicated tail's are ``k<step>/tail/k<step>/update``: another buffer)
+    scopes = ((e, str(e.source_info.name_stack)) for e in eqns
+              if e.primitive.name == "dot_general")
+    dots = [(*e.invars[0].aval.shape, e.invars[1].aval.shape[1])
+            for e, scope in scopes
+            if scope.endswith("/update") and "/tail/" not in scope]
+    flops = sum(2 * M * K * N for M, K, N in dots)
+    squares = sum(2 * (j * IB) ** 2 * IB for j in windows)
+    assert flops <= {16: 0.61, 32: 0.56}[blocks] * squares, flops / squares
+    # as wide as tall only where the window has no block row above the
+    # stripe: a whole window of one stripe (classic), a strip ib tall
+    assert all(M > N or M <= 2 * IB for M, K, N in dots), dots
+    if schedule == "lookahead-tail":
+        assert all(M > N for M, K, N in dots), dots

@@ -332,7 +332,14 @@ def test_grid_cholesky_holds_one_working_shard(grid22):
     entry mask that makes the working shard of it, and the two that stand
     INSIDE step 0's update fusion (its window read where it lies, and the
     mask on the product): four, and 844,633,600 bytes, 3.15 shards.  The
-    bound is that reading and 1 %; a second working shard reads 1.11 GB."""
+    bound is that reading and 1 %; a second working shard reads 1.11 GB.
+    Since ISSUE 36 the update walks stripes of the lower trapezoid, twelve
+    matmuls for six: none of them reaches the count's size, two moves are
+    left (the copy of A and the entry mask) and the plan reads 846,343,168
+    bytes.  It read 1,143,753,728 with stripes whose products were as wide
+    as tall or wider (row stripes; column stripes with a square corner): the
+    compiler then lays the working shard out ROW-major, drops the copy of
+    A, and ``memory_analysis()`` reads one shard more."""
     n, nb = 16384, 2048
     compiled = _donated_hpd_solve_on_2x2(grid22, n, nb)
     text = compiled.as_text()
@@ -347,11 +354,13 @@ def test_grid_cholesky_holds_one_working_shard(grid22):
 def test_north_star_size_fits_the_2x2_host(grid22):
     """N = 65536 on 2x2, the ``hpd64k.2x2.b2b`` cell's program (ISSUE 35;
     four to five minutes here, so not in tier-1: by hand, ``-m slow``).
-    The parent was refused: "Used 16.15G of 15.75G hbm".  It plans
-    13,032,259,072 bytes a device, 3.03 shards of 4.29 GB; the budget the
-    issue set is 14.5 GB."""
+    Before ISSUE 35 it was refused: "Used 16.15G of 15.75G hbm".  It planned
+    13,032,259,072 bytes a device with full-square updates, 3.03 shards of
+    4.29 GB, and plans 13,049,128,960 with stripes (ISSUE 36); the bound is
+    the benchmark's 1 % on the first, which is what ``plan_gb`` is held to
+    in ``hpd64k.2x2.b2b``."""
     compiled = _donated_hpd_solve_on_2x2(grid22, 65536)
-    assert _plan_bytes(compiled) <= 14_500_000_000, _plan_bytes(compiled)
+    assert _plan_bytes(compiled) <= 13_163_000_000, _plan_bytes(compiled)
 
 
 def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
