@@ -38,7 +38,9 @@ from ..redist.engine import redistribute, transpose_dist
 from ..blas.level2 import gemv, hemv
 from ..blas.level1 import _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle
-from .lu import _update_cols_lt, _hi
+from ..obs import metrics as _metrics
+from ..obs.tracer import NULL_HOOK
+from .lu import _update_cols_lt, _hi, _phase_hook, _scoped
 from .qr import _larft
 
 
@@ -81,15 +83,20 @@ def _larfg_tail(col, jj, ridx, dtype):
     return _larfg_at(col, jj + 1, ridx, dtype)
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4))
+@partial(jax.jit, static_argnums=(2, 3, 4, 5))
 def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
-                   precision):
+                   precision, step: int):
     """latrd: reduce ``nbw`` columns of the trailing matrix.
 
     ``Atrail`` is the fixed (nt, nt) [MC,MR] trailing view; ``P`` the
     replicated panel columns.  Returns (V, W, d, e, tau) with V/W the
     (nt, nbw) replicated reflector/update panels.
+
+    The column loop names its ops ``k<step>/hemv`` (the two matvecs
+    against the trailing view) and ``k<step>/panel`` (all the rest), side
+    by side: this is one jitted loop, so the names are all a phase is here.
     """
+    tm = NULL_HOOK
     nt = Atrail.gshape[0]
     g = Atrail.grid
     dtype = P.dtype
@@ -102,29 +109,37 @@ def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
 
     def body(jj, carry):
         V, W, d, e, tau = carry
-        col = corrected_col(P, V, W, jj)
-        d = d.at[jj].set(jnp.real(col[jj]).astype(rdtype))
-        v, tau_j, beta = _larfg_tail(col, jj, ridx, dtype)
-        e = e.at[jj].set(beta.astype(rdtype))
+        with tm.phase("panel", step):
+            col = corrected_col(P, V, W, jj)
+            d = d.at[jj].set(jnp.real(col[jj]).astype(rdtype))
+            v, tau_j, beta = _larfg_tail(col, jj, ridx, dtype)
+            e = e.at[jj].set(beta.astype(rdtype))
         # the one distributed op per column: u = A_trail v (Hemv; v's leading
         # zeros make this the reference's A22*v on the true subproblem)
-        u = _unwrap_vec(hemv("L", Atrail, _wrap_vec(v, g), precision=_hi(precision)))
-        u = u - V @ (jnp.conj(W).T @ v) - W @ (jnp.conj(V).T @ v)
-        w = tau_j * u
-        w = jnp.where(ridx > jj, w, 0)
-        w = w - (0.5 * tau_j * (jnp.conj(w) @ v)) * v
-        V = V.at[:, jj].set(v)
-        W = W.at[:, jj].set(w.astype(dtype))
-        tau = tau.at[jj].set(tau_j)
+        with tm.phase("hemv", step):
+            u = _unwrap_vec(hemv("L", Atrail, _wrap_vec(v, g),
+                                 precision=_hi(precision)))
+        with tm.phase("panel", step):
+            u = u - V @ (jnp.conj(W).T @ v) - W @ (jnp.conj(V).T @ v)
+            w = tau_j * u
+            w = jnp.where(ridx > jj, w, 0)
+            w = w - (0.5 * tau_j * (jnp.conj(w) @ v)) * v
+            V = V.at[:, jj].set(v)
+            W = W.at[:, jj].set(w.astype(dtype))
+            tau = tau.at[jj].set(tau_j)
         return V, W, d, e, tau
 
-    init = (jnp.zeros((nt, nbw), dtype), jnp.zeros((nt, nbw), dtype),
-            jnp.zeros((nd,), rdtype), jnp.zeros((nbw,), rdtype),
-            jnp.zeros((nbw,), dtype))
+    with tm.phase("panel", step):
+        init = (jnp.zeros((nt, nbw), dtype), jnp.zeros((nt, nbw), dtype),
+                jnp.zeros((nd,), rdtype), jnp.zeros((nbw,), rdtype),
+                jnp.zeros((nbw,), dtype))
+    # the loop itself stays outside a phase: a scope around it would come
+    # first in the path of every op of its body, hemv's too
     V, W, d, e, tau = lax.fori_loop(0, nbw, body, init)
     if extract_last:
-        col = corrected_col(P, V, W, nbw)
-        d = d.at[nbw].set(jnp.real(col[nbw]).astype(rdtype))
+        with tm.phase("panel", step):
+            col = corrected_col(P, V, W, nbw)
+            d = d.at[nbw].set(jnp.real(col[nbw]).astype(rdtype))
     return V, W, d, e, tau
 
 
@@ -139,6 +154,7 @@ def _packed_panel(V, d, e, nbw: int, dtype):
     return packed
 
 
+@_scoped("el.hermitian_tridiag")
 def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
                       precision=None):
     """Reduce a Hermitian [MC,MR] matrix to real tridiagonal form.
@@ -146,6 +162,12 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     Returns ``(Ap, d, e, tau)``: ``A = Q T Q^H`` with ``T = tridiag(e, d, e)``
     and ``Q = H_0 H_1 ... H_{n-2}`` packed in ``Ap``'s lower triangle
     (``El::HermitianTridiag``).
+
+    Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (the column
+    loop's two matvecs against the trailing view), ``panel`` (the rest of
+    the column loop and the packed panel's store), ``update`` (the rank-2k
+    trailing update and its four hops); ``herm_tridiag_panel`` counts the
+    panels.
     """
     _check_mcmr(A)
     n = A.gshape[0]
@@ -166,51 +188,72 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
 
     ib = _blocksize(nb, math.lcm(r, c), n)
     kend = n - 1                          # reflector columns 0 .. n-2
+    tm = _phase_hook("hermitian_tridiag")
     Ap = A
     d_parts, e_parts, tau_parts = [], [], []
     s = 0
     while s < kend:
+        k = s // ib                       # the panel's number
+        _metrics.inc("herm_tridiag_panel")
         e_col = min(s + ib, kend)
         nbw = e_col - s
         final = e_col == kend
         wp_end = n if final else min(round_up(e_col, c), n)
         Atrail = view(Ap, rows=(s, n), cols=(s, n))
         P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)), STAR, STAR).local
-        V, W, dpan, epan, taupan = _tridiag_panel(Atrail, P, nbw, final, precision)
+        # the column loop names its own phases (hemv beside panel)
+        V, W, dpan, epan, taupan = _tridiag_panel(Atrail, P, nbw, final,
+                                                  precision, k)
         d_parts.append(dpan)
         e_parts.append(epan)
         tau_parts.append(taupan)
-        packed = _packed_panel(V, dpan, epan, nbw, dtype)
+        with tm.phase("panel", k) as ph:
+            packed = _packed_panel(V, dpan, epan, nbw, dtype)
+            if final:
+                # last column: its diagonal entry
+                nt = n - s
+                last = jnp.zeros((nt, 1), dtype).at[nt - 1, 0].set(
+                    dpan[nbw].astype(dtype))
+                packed = jnp.concatenate([packed, last], axis=1)
+                blk = DistMatrix(packed, (nt, nt), STAR, STAR, 0, 0, g)
+                Ap = _update_cols_lt(Ap, redistribute(blk, MC, MR), (s, n),
+                                     (s, n), n)
+            else:
+                wpad = wp_end - s - nbw
+                if wpad:
+                    packed = jnp.pad(packed, ((0, 0), (0, wpad)))
+                blk = DistMatrix(packed, (n - s, wp_end - s), STAR, STAR, 0,
+                                 0, g)
+                Ap = _update_cols_lt(Ap, redistribute(blk, MC, MR), (s, n),
+                                     (s, wp_end), e_col)
+            ph.done(Ap.local)
         if final:
-            # last column: its diagonal entry
-            nt = n - s
-            last = jnp.zeros((nt, 1), dtype).at[nt - 1, 0].set(
-                dpan[nbw].astype(dtype))
-            packed = jnp.concatenate([packed, last], axis=1)
-            blk = DistMatrix(packed, (nt, nt), STAR, STAR, 0, 0, g)
-            Ap = _update_cols_lt(Ap, redistribute(blk, MC, MR), (s, n), (s, n), n)
             break
-        wpad = wp_end - s - nbw
-        if wpad:
-            packed = jnp.pad(packed, ((0, 0), (0, wpad)))
-        blk = DistMatrix(packed, (n - s, wp_end - s), STAR, STAR, 0, 0, g)
-        Ap = _update_cols_lt(Ap, redistribute(blk, MC, MR), (s, n), (s, wp_end), e_col)
         # trailing two-sided update: A22 -= V2 W2^H + W2 V2^H (lower triangle)
-        nt2 = n - e_col
-        V2 = V[e_col - s:, :]
-        W2 = W[e_col - s:, :]
-        V2mc = redistribute(DistMatrix(V2, (nt2, nbw), STAR, STAR, 0, 0, g), MC, STAR)
-        W2mc = redistribute(DistMatrix(W2, (nt2, nbw), STAR, STAR, 0, 0, g), MC, STAR)
-        V2Hmr = redistribute(
-            DistMatrix(jnp.conj(V2).T, (nbw, nt2), STAR, STAR, 0, 0, g), STAR, MR)
-        W2Hmr = redistribute(
-            DistMatrix(jnp.conj(W2).T, (nbw, nt2), STAR, STAR, 0, 0, g), STAR, MR)
-        A22 = view(Ap, rows=(e_col, n), cols=(e_col, n))
-        upd = (jnp.matmul(V2mc.local, W2Hmr.local, precision=_hi(precision))
-               + jnp.matmul(W2mc.local, V2Hmr.local, precision=_hi(precision)))
-        mask = _mask_triangle(A22, "L")
-        newloc = jnp.where(mask, A22.local - upd.astype(dtype), A22.local)
-        Ap = update_view(Ap, A22.with_local(newloc), rows=(e_col, n), cols=(e_col, n))
+        with tm.phase("update", k) as ph:
+            nt2 = n - e_col
+            V2 = V[e_col - s:, :]
+            W2 = W[e_col - s:, :]
+            V2mc = redistribute(
+                DistMatrix(V2, (nt2, nbw), STAR, STAR, 0, 0, g), MC, STAR)
+            W2mc = redistribute(
+                DistMatrix(W2, (nt2, nbw), STAR, STAR, 0, 0, g), MC, STAR)
+            V2Hmr = redistribute(
+                DistMatrix(jnp.conj(V2).T, (nbw, nt2), STAR, STAR, 0, 0, g),
+                STAR, MR)
+            W2Hmr = redistribute(
+                DistMatrix(jnp.conj(W2).T, (nbw, nt2), STAR, STAR, 0, 0, g),
+                STAR, MR)
+            A22 = view(Ap, rows=(e_col, n), cols=(e_col, n))
+            upd = (jnp.matmul(V2mc.local, W2Hmr.local,
+                              precision=_hi(precision))
+                   + jnp.matmul(W2mc.local, V2Hmr.local,
+                                precision=_hi(precision)))
+            mask = _mask_triangle(A22, "L")
+            newloc = jnp.where(mask, A22.local - upd.astype(dtype), A22.local)
+            Ap = update_view(Ap, A22.with_local(newloc), rows=(e_col, n),
+                             cols=(e_col, n))
+            ph.done(Ap.local)
         s = e_col
     d = jnp.concatenate(d_parts)
     e_ = jnp.concatenate(e_parts)
@@ -228,12 +271,15 @@ def _tridiag_v_panel(P, nbw: int):
     return V + jnp.eye(nt, nbw, k=-1, dtype=P.dtype)
 
 
+@_scoped("el.apply_q_herm_tridiag")
 def apply_q_herm_tridiag(Ap: DistMatrix, tau, B: DistMatrix,
                          orient: str = "N", nb: int | None = None,
                          precision=None) -> DistMatrix:
     """B := Q B ('N') or Q^H B ('C') with Q from :func:`hermitian_tridiag`
     (the back-transform of ``El::HermitianEig``, ``herm_eig::`` +
-    ``ApplyPackedReflectors``).  ``nb`` must match the factorization's."""
+    ``ApplyPackedReflectors``).  ``nb`` must match the factorization's.
+    Each panel's ops are named ``el.apply_q_herm_tridiag/k<panel>/apply``
+    (the panel's number in the factorization) and tick ``apply_q_panel``."""
     _check_mcmr(Ap, B)
     n = Ap.gshape[0]
     if B.gshape[0] != n:
@@ -242,25 +288,31 @@ def apply_q_herm_tridiag(Ap: DistMatrix, tau, B: DistMatrix,
     r, c = g.height, g.width
     ib = _blocksize(nb, math.lcm(r, c), n)
     kend = n - 1
+    tm = _phase_hook("apply_q_herm_tridiag")
     starts = list(range(0, kend, ib))
     if orient == "N":
         starts = starts[::-1]
     for s in starts:
+        _metrics.inc("apply_q_panel")
         e_col = min(s + ib, kend)
         nbw = e_col - s
         wp_end = n if e_col == kend else min(round_up(e_col, c), n)
-        P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)), STAR, STAR).local
-        V = _tridiag_v_panel(P, nbw)
-        T = _larft(V, tau[s:e_col])
-        Tm = jnp.conj(T).T if orient == "C" else T
-        V_mc = redistribute(
-            DistMatrix(V, (n - s, nbw), STAR, STAR, 0, 0, g), MC, STAR)
-        B2 = view(B, rows=(s, n))
-        Wl = jnp.matmul(jnp.conj(V_mc.local).T, B2.local, precision=_hi(precision))
-        Wl = jnp.matmul(Tm, Wl, precision=_hi(precision))
-        upd = jnp.matmul(V_mc.local, Wl, precision=_hi(precision))
-        B = update_view(B, B2.with_local(B2.local - upd.astype(B.dtype)),
-                        rows=(s, n))
+        with tm.phase("apply", s // ib) as ph:
+            P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)),
+                             STAR, STAR).local
+            V = _tridiag_v_panel(P, nbw)
+            T = _larft(V, tau[s:e_col])
+            Tm = jnp.conj(T).T if orient == "C" else T
+            V_mc = redistribute(
+                DistMatrix(V, (n - s, nbw), STAR, STAR, 0, 0, g), MC, STAR)
+            B2 = view(B, rows=(s, n))
+            Wl = jnp.matmul(jnp.conj(V_mc.local).T, B2.local,
+                            precision=_hi(precision))
+            Wl = jnp.matmul(Tm, Wl, precision=_hi(precision))
+            upd = jnp.matmul(V_mc.local, Wl, precision=_hi(precision))
+            B = update_view(B, B2.with_local(B2.local - upd.astype(B.dtype)),
+                            rows=(s, n))
+            ph.done(B.local)
     return B
 
 

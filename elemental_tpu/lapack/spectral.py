@@ -32,7 +32,7 @@ from ..redist.interior import interior_view
 from ..blas.level1 import diagonal_scale, make_trapezoidal
 from .cholesky import cholesky
 from .condense import hermitian_tridiag, apply_q_herm_tridiag, _real_dtype
-from .lu import permute_cols, _hi
+from .lu import permute_cols, _hi, _scoped
 from .qr import qr, apply_q
 from .tridiag_eig import tridiag_eig
 
@@ -81,6 +81,7 @@ def _subset_slice(w, subset):
     raise ValueError(f"bad subset {subset!r}")
 
 
+@_scoped("el.herm_eig")
 def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
              subset=None, nb: int | None = None, approach: str = "tridiag",
              precision=None, dc_min: int | None = None,
@@ -88,6 +89,21 @@ def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     """Eigendecomposition of a Hermitian [MC,MR] matrix: ``A = Z diag(w) Z^H``
     (``El::HermitianEig``).  Returns ascending real ``w`` (replicated) and,
     when ``vectors``, the distributed eigenvector matrix ``Z``.
+
+    The driver is ONE traceable program: ``jax.jit(lambda A:
+    herm_eig(A, nb=nb), donate_argnums=0)`` compiles the reduction, the
+    tridiagonal solve and the back-transform together, with no host round
+    trip, for ``subset=None`` and for an ``('index', il, iu)`` subset (a
+    static column slice).  A ``('value', lo, hi)`` subset reads ``w`` on the
+    host to find its columns, so it cannot be traced: call it eagerly.
+
+    Its ops carry the scopes of the three stages under ``el.herm_eig``
+    (grammar in :mod:`elemental_tpu.obs`):
+    ``el.hermitian_tridiag/k<panel>/{hemv,panel,update}``,
+    ``el.tridiag_eig/k<level>/{leaf,secular,merge}`` (n above ``dc_min``) and
+    ``el.apply_q_herm_tridiag/k<panel>/apply``; the trace-time counters
+    ``herm_tridiag_panel``, ``dc_merge{kind}`` and ``apply_q_panel`` count
+    the panels and the merges.
     """
     _check_mcmr(A)
     n = A.gshape[0]

@@ -56,7 +56,14 @@ from ..redist.engine import redistribute
 from ..redist.interior import interior_view, interior_update
 from ..blas.level1 import index_dependent_fill
 from ..blas.level3 import gemm
-from .lu import _hi
+from ..obs import metrics as _metrics
+from ..obs.tracer import NULL_HOOK
+from .lu import _hi, _scoped
+
+#: the divide and conquer is ONE jitted program, so nothing times a phase
+#: eagerly: its phases only name their ops (``k<level>/leaf``, ``secular``,
+#: ``merge``; ``level`` counts merges from the leaves, which are level 0)
+_TM = NULL_HOOK
 
 
 def _sec_dtype():
@@ -238,44 +245,69 @@ def _v_entries(row_pos, col_pos, perm, ds, tau, aidx, zhat, cninv, flip,
 # replicated batched phase
 # ---------------------------------------------------------------------
 
-def _merge_replicated(lam1, lam2, Q1, Q2, beta, scale, n_iters, chunk,
-                      precision):
-    """One merge on replicated data: returns (lam_new, Q_new) with
-    Q_new = blockdiag(Q1, Q2) @ V.  All matmul work on the MXU."""
-    nm = lam1.shape[0]
+def _merge_replicated(lam1, lam2, Q1, Q2, betas, scale, n_iters, chunk,
+                      precision, level: int):
+    """One LEVEL of merges on replicated data, batched over the leading
+    subproblem axis: returns (lam_new, Q_new) with
+    Q_new[b] = blockdiag(Q1[b], Q2[b]) @ V[b].  All matmul work on the MXU.
+    The phases are opened AROUND the two ``vmap``s: a scope entered inside
+    one reaches the program as ``vmap(k01/secular)``, which no reader of
+    the grammar takes for a phase."""
+    nm = lam1.shape[1]
     n2 = 2 * nm
-    D = jnp.concatenate([lam1, lam2])
-    z = jnp.concatenate([Q1[-1, :], Q2[0, :]])
-    lam, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
-        D, z, beta, scale, n_iters, chunk)
-    rows = jnp.arange(n2)[:, None]
-    cols = jnp.arange(n2)[None, :]
-    V = _v_entries(rows, cols, perm, ds, tau, aidx, zhat, cninv, flip,
-                   Q1.dtype)
-    # eigenvector accumulation is factor-forming: full f32 accumulation
-    # (default bf16-input matmul costs ~1e-3 residuals on TPU)
-    top = jnp.matmul(Q1, V[:nm, :], precision=_hi(precision))
-    bot = jnp.matmul(Q2, V[nm:, :], precision=_hi(precision))
-    return lam.astype(lam1.dtype), jnp.concatenate([top, bot], axis=0)
+
+    def secular(lam1, lam2, Q1, Q2, beta):
+        D = jnp.concatenate([lam1, lam2])
+        z = jnp.concatenate([Q1[-1, :], Q2[0, :]])
+        lam, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
+            D, z, beta, scale, n_iters, chunk)
+        rows = jnp.arange(n2)[:, None]
+        cols = jnp.arange(n2)[None, :]
+        return lam, _v_entries(rows, cols, perm, ds, tau, aidx, zhat, cninv,
+                               flip, Q1.dtype)
+
+    def products(Q1, Q2, V):
+        # eigenvector accumulation is factor-forming: full f32 accumulation
+        # (default bf16-input matmul costs ~1e-3 residuals on TPU)
+        top = jnp.matmul(Q1, V[:nm, :], precision=_hi(precision))
+        bot = jnp.matmul(Q2, V[nm:, :], precision=_hi(precision))
+        return jnp.concatenate([top, bot], axis=0)
+
+    with _TM.phase("secular", level):
+        lam, V = jax.vmap(secular)(lam1, lam2, Q1, Q2, betas)
+    with _TM.phase("merge", level):
+        Q = jax.vmap(products)(Q1, Q2, V)
+    return lam.astype(lam1.dtype), Q
 
 
-def _merge_rows_only(lam1, lam2, fr1, lr1, fr2, lr2, beta, scale, n_iters,
-                     chunk, precision):
-    """Eigenvalue-only merge: carries just the FIRST and LAST rows of the
+def _merge_rows_only(lam1, lam2, fr1, lr1, fr2, lr2, betas, scale, n_iters,
+                     chunk, level: int):
+    """One level of eigenvalue-only merges, batched like
+    :func:`_merge_replicated`: carries just the FIRST and LAST rows of the
     eigenvector matrix (enough to form the next level's z), O(nm^2) work,
     O(nm) state."""
-    nm = lam1.shape[0]
+    nm = lam1.shape[1]
     n2 = 2 * nm
-    D = jnp.concatenate([lam1, lam2])
-    z = jnp.concatenate([lr1, fr2])
-    lam, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
-        D, z, beta, scale, n_iters, chunk)
-    rows = jnp.arange(n2)[:, None]
-    cols = jnp.arange(n2)[None, :]
-    V = _v_entries(rows, cols, perm, ds, tau, aidx, zhat, cninv, flip,
-                   fr1.dtype)
-    fr = jnp.concatenate([fr1, jnp.zeros_like(fr2)]) @ V
-    lr = jnp.concatenate([jnp.zeros_like(lr1), lr2]) @ V
+
+    def secular(lam1, lam2, fr1, lr1, fr2, beta):
+        D = jnp.concatenate([lam1, lam2])
+        z = jnp.concatenate([lr1, fr2])
+        lam, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
+            D, z, beta, scale, n_iters, chunk)
+        rows = jnp.arange(n2)[:, None]
+        cols = jnp.arange(n2)[None, :]
+        return lam, _v_entries(rows, cols, perm, ds, tau, aidx, zhat, cninv,
+                               flip, fr1.dtype)
+
+    def products(fr1, lr1, fr2, lr2, V):
+        fr = jnp.concatenate([fr1, jnp.zeros_like(fr2)]) @ V
+        lr = jnp.concatenate([jnp.zeros_like(lr1), lr2]) @ V
+        return fr, lr
+
+    with _TM.phase("secular", level):
+        lam, V = jax.vmap(secular)(lam1, lam2, fr1, lr1, fr2, betas)
+    with _TM.phase("merge", level):
+        fr, lr = jax.vmap(products)(fr1, lr1, fr2, lr2, V)
     return lam.astype(lam1.dtype), fr, lr
 
 
@@ -305,6 +337,7 @@ def _leaf_eigh(d_adj, e_leaf, base: int, B: int):
     return jnp.linalg.eigh(dmat)
 
 
+@_scoped("el.tridiag_eig")
 def tridiag_eig(d, e, grid=None, vectors: bool = True,
                 leaf_max: int = 96, repl_max: int = 512,
                 chunk: int = 1024, precision=None):
@@ -321,6 +354,12 @@ def tridiag_eig(d, e, grid=None, vectors: bool = True,
     The whole driver runs under ONE jit (static plan metadata): eager
     per-op dispatch of its hundreds of small secular-stage ops costs a
     host round trip each.
+
+    Scopes (``el.tridiag_eig/k<level>/...``, level 0 the leaves, then one a
+    level of merges): ``leaf`` (the batched dense leaves), ``secular`` (the
+    secular equation, the Gu-Eisenstat weights and the fill of V),
+    ``merge`` (the eigenvector products and their stores).  ``dc_merge``
+    counts the merges by ``kind`` (``replicated`` | ``distributed``).
     """
     d = jnp.asarray(d)
     e = jnp.asarray(e)
@@ -359,36 +398,39 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
     # leaf-interior e, laid out (B, base): column base-1 unused
     e_leaf = jnp.concatenate([ep, jnp.zeros((1,), sdt)]).reshape(nblk, base)
 
-    lam, Q = _leaf_eigh(d_adj, e_leaf, base, nblk)
-    if vectors:
-        Q = Q.astype(odt)        # O(n^3) matmul work runs in storage dtype
+    with _TM.phase("leaf", 0):
+        lam, Q = _leaf_eigh(d_adj, e_leaf, base, nblk)
+        if vectors:
+            Q = Q.astype(odt)    # O(n^3) matmul work runs in storage dtype
 
     # ---- replicated batched phase ------------------------------------
     B, nm = nblk, base
-    merge_v = jax.vmap(_merge_replicated,
-                       in_axes=(0, 0, 0, 0, 0, None, None, None, None))
-    rows_v = jax.vmap(_merge_rows_only,
-                      in_axes=(0, 0, 0, 0, 0, 0, 0, None, None, None, None))
+    level = 0
     if not vectors:
         fr, lr = Q[:, 0, :], Q[:, -1, :]
     while B > 1 and 2 * nm <= max(repl_max, 2 * base):
+        level += 1
+        _metrics.inc("dc_merge", B // 2, kind="replicated")
         betas = ep[jnp.arange(B // 2) * 2 * nm + nm - 1]
         if vectors:
-            lam, Q = merge_v(lam[0::2], lam[1::2], Q[0::2], Q[1::2], betas,
-                             scale, n_iters, chunk, precision)
+            lam, Q = _merge_replicated(lam[0::2], lam[1::2], Q[0::2], Q[1::2],
+                                       betas, scale, n_iters, chunk, precision,
+                                       level)
         else:
-            lam, fr, lr = rows_v(lam[0::2], lam[1::2], fr[0::2], lr[0::2],
-                                 fr[1::2], lr[1::2], betas, scale, n_iters,
-                                 chunk, precision)
+            lam, fr, lr = _merge_rows_only(
+                lam[0::2], lam[1::2], fr[0::2], lr[0::2], fr[1::2], lr[1::2],
+                betas, scale, n_iters, chunk, level)
         B //= 2
         nm *= 2
 
     if not vectors:
         while B > 1:
+            level += 1
+            _metrics.inc("dc_merge", B // 2, kind="replicated")
             betas = ep[jnp.arange(B // 2) * 2 * nm + nm - 1]
-            lam, fr, lr = rows_v(lam[0::2], lam[1::2], fr[0::2], lr[0::2],
-                                 fr[1::2], lr[1::2], betas, scale, n_iters,
-                                 chunk, precision)
+            lam, fr, lr = _merge_rows_only(
+                lam[0::2], lam[1::2], fr[0::2], lr[0::2], fr[1::2], lr[1::2],
+                betas, scale, n_iters, chunk, level)
             B //= 2
             nm *= 2
         return lam[0][:n].astype(odt)
@@ -420,36 +462,41 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
     lam_full = lam.reshape(-1)
 
     while B > 1:
+        level += 1
         for p in range(B // 2):
+            _metrics.inc("dc_merge", kind="distributed")
             o = p * 2 * nm
-            beta = ep[o + nm - 1]
-            lam1 = lam_full[o:o + nm]
-            lam2 = lam_full[o + nm:o + 2 * nm]
-            Q1 = interior_view(Qd, (o, o + nm), (o, o + nm))
-            Q2 = interior_view(Qd, (o + nm, o + 2 * nm), (o + nm, o + 2 * nm))
-            z1 = redistribute(interior_view(Q1, (nm - 1, nm), (0, nm)),
-                              STAR, STAR).local[0]
-            z2 = redistribute(interior_view(Q2, (0, 1), (0, nm)),
-                              STAR, STAR).local[0]
-            D = jnp.concatenate([lam1, lam2])
-            z = jnp.concatenate([z1, z2]).astype(sdt)
-            lamn, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
-                D, z, beta, scale, n_iters, chunk)
+            with _TM.phase("secular", level):
+                beta = ep[o + nm - 1]
+                lam1 = lam_full[o:o + nm]
+                lam2 = lam_full[o + nm:o + 2 * nm]
+                Q1 = interior_view(Qd, (o, o + nm), (o, o + nm))
+                Q2 = interior_view(Qd, (o + nm, o + 2 * nm),
+                                   (o + nm, o + 2 * nm))
+                z1 = redistribute(interior_view(Q1, (nm - 1, nm), (0, nm)),
+                                  STAR, STAR).local[0]
+                z2 = redistribute(interior_view(Q2, (0, 1), (0, nm)),
+                                  STAR, STAR).local[0]
+                D = jnp.concatenate([lam1, lam2])
+                z = jnp.concatenate([z1, z2]).astype(sdt)
+                lamn, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
+                    D, z, beta, scale, n_iters, chunk)
 
-            def vfill(i, j, _p=perm, _ds=ds, _tau=tau, _ai=aidx, _zh=zhat,
-                      _cn=cninv, _fl=flip):
-                return _v_entries(i, j, _p, _ds, _tau, _ai, _zh, _cn, _fl,
-                                  odt)
+                def vfill(i, j, _p=perm, _ds=ds, _tau=tau, _ai=aidx,
+                          _zh=zhat, _cn=cninv, _fl=flip):
+                    return _v_entries(i, j, _p, _ds, _tau, _ai, _zh, _cn,
+                                      _fl, odt)
 
-            V = index_dependent_fill(
-                dm_zeros(2 * nm, 2 * nm, MC, MR, grid, dtype=odt), vfill)
-            Vtop = interior_view(V, (0, nm), (0, 2 * nm))
-            Vbot = interior_view(V, (nm, 2 * nm), (0, 2 * nm))
-            Ztop = gemm(Q1, Vtop, precision=_hi(precision))
-            Zbot = gemm(Q2, Vbot, precision=_hi(precision))
-            Qd = interior_update(Qd, Ztop, (o, o))
-            Qd = interior_update(Qd, Zbot, (o + nm, o))
-            lam_full = lax.dynamic_update_slice(lam_full, lamn, (o,))
+                V = index_dependent_fill(
+                    dm_zeros(2 * nm, 2 * nm, MC, MR, grid, dtype=odt), vfill)
+            with _TM.phase("merge", level):
+                Vtop = interior_view(V, (0, nm), (0, 2 * nm))
+                Vbot = interior_view(V, (nm, 2 * nm), (0, 2 * nm))
+                Ztop = gemm(Q1, Vtop, precision=_hi(precision))
+                Zbot = gemm(Q2, Vbot, precision=_hi(precision))
+                Qd = interior_update(Qd, Ztop, (o, o))
+                Qd = interior_update(Qd, Zbot, (o + nm, o))
+                lam_full = lax.dynamic_update_slice(lam_full, lamn, (o,))
         B //= 2
         nm *= 2
 

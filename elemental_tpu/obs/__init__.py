@@ -39,8 +39,11 @@ where xprof / Perfetto show them and ``benchmark/scopes.py`` sums device
 time by them.  A path is made of:
 
   ``el.<driver>``          a public driver: ``cholesky``, ``lu``, ``qr``,
-                           ``gemm``, ``herk``, ``trsm`` (:func:`scoped`);
-                           the one-device paths carry the same names
+                           ``gemm``, ``herk``, ``trsm``, ``herm_eig`` and
+                           its three stages ``hermitian_tridiag``,
+                           ``tridiag_eig``, ``apply_q_herm_tridiag``
+                           (:func:`scoped`); the one-device paths carry
+                           the same names
   ``k<step>/<phase>``      inside a driver: the step, two digits or more
                            (``k03``, ``k117``), then a phase of
                            :data:`PHASES` -- ``diag``, ``panel``,
@@ -48,8 +51,23 @@ time by them.  A path is made of:
                            ``tail`` (CALU adds ``tournament``, the serve
                            loop ``batch``, the grid Cholesky ``k00/mask``:
                            the masked copy of the operand it factors
-                           in).  A nested driver or local
-                           finish nests its own (``k14/tail/k00/diag``):
+                           in).  The Hermitian eigensolve adds five:
+                           ``hermitian_tridiag`` names ``k<panel>/hemv``
+                           (the column loop's two matvecs against the
+                           trailing view) BESIDE ``k<panel>/panel`` (the
+                           rest of the column loop, the packed panel's
+                           store) and ``k<panel>/update`` (the rank-2k
+                           trailing update); ``tridiag_eig`` names
+                           ``k<level>/leaf`` (level 0, the batched dense
+                           leaves), ``k<level>/secular`` (the secular
+                           equation, the Gu-Eisenstat weights, the fill
+                           of V) and ``k<level>/merge`` (the eigenvector
+                           products and their stores), ``level`` counting
+                           merges from the leaves up;
+                           ``apply_q_herm_tridiag`` names
+                           ``k<panel>/apply``.  A nested driver or local
+                           finish nests its own (``k14/tail/k00/diag``,
+                           ``k05/merge/el.gemm/k00/panel``):
                            the FIRST ``k<step>`` gives an op its phase
   ``el.hpd_solve`` /       the public solves, which open ``factor`` and
   ``el.lu_solve``          ``sweeps`` around their two stages, so a sweep
@@ -105,6 +123,15 @@ not tick again).  Read them under ``metrics_scope()``:
                            ``row_permute_rows`` (rows asked to move) and
                            ``row_permute_wire_bytes`` (worst-case bytes a
                            device receives: every row crossing chips)
+  ``herm_tridiag_panel``   one panel of ``hermitian_tridiag`` (a column
+                           loop and, but for the last, a rank-2k update)
+  ``dc_merge{kind}``       one merge of ``tridiag_eig``: ``kind``
+                           ``replicated`` (a level of the vmapped batch
+                           ticks once for each of its merges) |
+                           ``distributed`` (unrolled on the [MC,MR]
+                           eigenvector matrix: 31 at n = 16384 with the
+                           defaults, 32 subproblems of 512 up to one)
+  ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
 
 CLI: ``python -m perf.trace {run,summary,export,serve}``.
 """
