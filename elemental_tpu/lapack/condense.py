@@ -6,10 +6,14 @@ panel, then a Her2k-style two-sided trailing update -- SURVEY.md §4.5) and
 ``condense/Hessenberg/**`` (``El::Hessenberg``).
 
 TPU-first design: the reduction panel loop is ONE jitted ``lax.fori_loop``
-per panel (LAPACK ``latrd`` semantics).  Per column the only distributed
-work is a single :func:`~elemental_tpu.blas.level2.hemv` against the fixed
-trailing view (the reference's distributed Hemv with [MC,STAR]/[MR,STAR]
-accumulators); the V/W panels live replicated (n x nb -- small).  The
+per panel (LAPACK ``latrd`` semantics).  Once a panel the fixed trailing
+view is made Hermitian-full (its stored lower triangle mirrored above the
+diagonal: one transpose exchange); per column the only distributed work is
+then a single plain :func:`~elemental_tpu.blas.level2.gemv` against that
+full view, ONE read of it a column (the reference's distributed Hemv reads
+one triangle through two accumulators, [MC,STAR] and [MR,STAR]; here the
+second is paid once a panel instead of once a column); the V/W panels live
+replicated (n x nb -- small).  The
 trailing update ``A22 -= V W^H + W V^H`` is one masked storage matmul on
 the MXU (exactly the reference's rank-2k update), so all O(n^3/MXU-friendly)
 FLOPs are large matmuls and all latency-bound work is batched into one
@@ -35,7 +39,7 @@ from ..core.dist import MC, MR, STAR
 from ..core.distmatrix import DistMatrix
 from ..core.view import view, update_view, round_up
 from ..redist.engine import redistribute, transpose_dist
-from ..blas.level2 import gemv, hemv
+from ..blas.level2 import gemv
 from ..blas.level1 import _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle
 from ..obs import metrics as _metrics
@@ -83,22 +87,36 @@ def _larfg_tail(col, jj, ridx, dtype):
     return _larfg_at(col, jj + 1, ridx, dtype)
 
 
+def _hermitian_full(Atrail: DistMatrix) -> DistMatrix:
+    """The (nt, nt) [MC,MR] trailing view as a FULL Hermitian matrix: the
+    stored lower triangle (diagonal included, as stored) where it is, its
+    conjugate mirror above.  Only stored entries are read: whatever the
+    view holds above the diagonal never reaches the result.  (Not
+    :func:`~elemental_tpu.blas.level1.make_symmetric`: that sums two masked
+    triangles and, conjugating, makes the diagonal real; ``hemv`` read it
+    as stored.)"""
+    mirror = redistribute(transpose_dist(Atrail, conj=True), MC, MR)
+    return Atrail.with_local(jnp.where(_mask_triangle(Atrail, "L"),
+                                       Atrail.local, mirror.local))
+
+
 @partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
+def _tridiag_panel(Afull: DistMatrix, P, nbw: int, extract_last: bool,
                    precision, step: int):
     """latrd: reduce ``nbw`` columns of the trailing matrix.
 
-    ``Atrail`` is the fixed (nt, nt) [MC,MR] trailing view; ``P`` the
-    replicated panel columns.  Returns (V, W, d, e, tau) with V/W the
-    (nt, nbw) replicated reflector/update panels.
+    ``Afull`` is the fixed (nt, nt) [MC,MR] trailing view made Hermitian
+    (:func:`_hermitian_full`); ``P`` the replicated panel columns.  Returns
+    (V, W, d, e, tau) with V/W the (nt, nbw) replicated reflector/update
+    panels.
 
-    The column loop names its ops ``k<step>/hemv`` (the two matvecs
-    against the trailing view) and ``k<step>/panel`` (all the rest), side
-    by side: this is one jitted loop, so the names are all a phase is here.
+    The column loop names its ops ``k<step>/hemv`` (the one matvec against
+    the full trailing view) and ``k<step>/panel`` (all the rest), side by
+    side: this is one jitted loop, so the names are all a phase is here.
     """
     tm = NULL_HOOK
-    nt = Atrail.gshape[0]
-    g = Atrail.grid
+    nt = Afull.gshape[0]
+    g = Afull.grid
     dtype = P.dtype
     rdtype = _real_dtype(dtype)
     ridx = jnp.arange(nt)
@@ -114,10 +132,11 @@ def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
             d = d.at[jj].set(jnp.real(col[jj]).astype(rdtype))
             v, tau_j, beta = _larfg_tail(col, jj, ridx, dtype)
             e = e.at[jj].set(beta.astype(rdtype))
-        # the one distributed op per column: u = A_trail v (Hemv; v's leading
-        # zeros make this the reference's A22*v on the true subproblem)
+        # the one distributed op per column: u = A_trail v, one read of the
+        # full view (v's leading zeros make this the reference's A22*v on
+        # the true subproblem)
         with tm.phase("hemv", step):
-            u = _unwrap_vec(hemv("L", Atrail, _wrap_vec(v, g),
+            u = _unwrap_vec(gemv(Afull, _wrap_vec(v, g),
                                  precision=_hi(precision)))
         with tm.phase("panel", step):
             u = u - V @ (jnp.conj(W).T @ v) - W @ (jnp.conj(V).T @ v)
@@ -163,11 +182,12 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     and ``Q = H_0 H_1 ... H_{n-2}`` packed in ``Ap``'s lower triangle
     (``El::HermitianTridiag``).
 
-    Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (the column
-    loop's two matvecs against the trailing view), ``panel`` (the rest of
-    the column loop and the packed panel's store), ``update`` (the rank-2k
+    Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (once a
+    panel the trailing view's mirror into a full Hermitian matrix, then
+    the column loop's one matvec against it), ``panel`` (the rest of the
+    column loop and the packed panel's store), ``update`` (the rank-2k
     trailing update and its four hops); ``herm_tridiag_panel`` counts the
-    panels.
+    panels and ``herm_tridiag_symmetrize`` the mirrors (one a panel).
     """
     _check_mcmr(A)
     n = A.gshape[0]
@@ -199,10 +219,14 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
         nbw = e_col - s
         final = e_col == kend
         wp_end = n if final else min(round_up(e_col, c), n)
-        Atrail = view(Ap, rows=(s, n), cols=(s, n))
+        # once a panel, never once a column: the matvec's operand
+        _metrics.inc("herm_tridiag_symmetrize")
+        with tm.phase("hemv", k) as ph:
+            Afull = _hermitian_full(view(Ap, rows=(s, n), cols=(s, n)))
+            ph.done(Afull.local)
         P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)), STAR, STAR).local
         # the column loop names its own phases (hemv beside panel)
-        V, W, dpan, epan, taupan = _tridiag_panel(Atrail, P, nbw, final,
+        V, W, dpan, epan, taupan = _tridiag_panel(Afull, P, nbw, final,
                                                   precision, k)
         d_parts.append(dpan)
         e_parts.append(epan)

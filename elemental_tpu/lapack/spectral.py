@@ -102,8 +102,9 @@ def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     ``el.hermitian_tridiag/k<panel>/{hemv,panel,update}``,
     ``el.tridiag_eig/k<level>/{leaf,secular,merge}`` (n above ``dc_min``) and
     ``el.apply_q_herm_tridiag/k<panel>/apply``; the trace-time counters
-    ``herm_tridiag_panel``, ``dc_merge{kind}`` and ``apply_q_panel`` count
-    the panels and the merges.
+    ``herm_tridiag_panel``, ``herm_tridiag_symmetrize``, ``dc_merge{kind}``
+    and ``apply_q_panel`` count the panels, the mirrors of the trailing view
+    (one a panel) and the merges.
     """
     _check_mcmr(A)
     n = A.gshape[0]

@@ -53,8 +53,10 @@ time by them.  A path is made of:
                            the masked copy of the operand it factors
                            in).  The Hermitian eigensolve adds five:
                            ``hermitian_tridiag`` names ``k<panel>/hemv``
-                           (the column loop's two matvecs against the
-                           trailing view) BESIDE ``k<panel>/panel`` (the
+                           (once a panel the trailing view's mirror
+                           into a full Hermitian matrix, then the column
+                           loop's one matvec against it) BESIDE
+                           ``k<panel>/panel`` (the
                            rest of the column loop, the packed panel's
                            store) and ``k<panel>/update`` (the rank-2k
                            trailing update); ``tridiag_eig`` names
@@ -125,6 +127,10 @@ not tick again).  Read them under ``metrics_scope()``:
                            device receives: every row crossing chips)
   ``herm_tridiag_panel``   one panel of ``hermitian_tridiag`` (a column
                            loop and, but for the last, a rank-2k update)
+  ``herm_tridiag_symmetrize``   one mirror of a panel's trailing view
+                           into a full Hermitian matrix, the operand of
+                           the column loop's matvec: one a panel (64 at
+                           n = 16384, nb 256), never one a column
   ``dc_merge{kind}``       one merge of ``tridiag_eig``: ``kind``
                            ``replicated`` (a level of the vmapped batch
                            ticks once for each of its merges) |

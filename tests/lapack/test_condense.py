@@ -3,11 +3,14 @@
 Model: reference ``tests/lapack_like/HermitianTridiag.cpp`` -- residual
 ``||A - Q T Q^H||/||A||`` + orthogonality ``||I - Q^H Q||``, real & complex.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from elemental_tpu import from_global, to_global, MC, MR
+from elemental_tpu import Grid, from_global, obs, to_global, MC, MR
+from elemental_tpu.blas.level2 import hemv
+from elemental_tpu.lapack import condense
 from elemental_tpu.lapack.condense import (
     hermitian_tridiag, apply_q_herm_tridiag, hessenberg, apply_q_hessenberg)
 from elemental_tpu.matrices.basic import identity
@@ -56,6 +59,75 @@ def test_hermitian_tridiag_uplo_upper(grid24):
     T = _tridiag_full(d, e)
     np.testing.assert_allclose(np.linalg.eigvalsh(T), np.linalg.eigvalsh(A),
                                rtol=1e-10, atol=1e-10)
+
+
+# the once-a-panel mirror of the trailing view (ISSUE 38)
+
+def _grid(name):
+    return (Grid(jax.devices()[:1]) if name == "1x1"
+            else Grid(jax.devices(), height=2))
+
+
+def _stored(result):
+    """``(Ap, d, e, tau)`` on the host, of ``Ap`` the lower triangle only."""
+    Ap, d, e, tau = result
+    Ap = np.asarray(to_global(Ap))
+    return [Ap[np.tril_indices(Ap.shape[0])], *map(np.asarray, (d, e, tau))]
+
+
+@pytest.mark.parametrize("n,nb", [(24, 8), (37, 8), (40, 16), (17, 4),
+                                  (9, 16)])
+def test_one_mirror_a_panel(grid24, n, nb):
+    """``herm_tridiag_symmetrize`` ticks once a panel, never once a column."""
+    with obs.metrics_scope() as reg:
+        hermitian_tridiag(from_global(_herm(n, jnp.float64), MC, MR, grid24),
+                          nb=nb)
+    (panels,) = reg.counters("herm_tridiag_panel").values()
+    (mirrors,) = reg.counters("herm_tridiag_symmetrize").values()
+    assert mirrors == panels == -(-(n - 1) // nb)
+
+
+@pytest.mark.parametrize("poison", [np.nan, 1e30])
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.complex128])
+@pytest.mark.parametrize("grid_name", ["1x1", "2x4"])
+def test_hermitian_tridiag_lower_never_reads_the_upper_triangle(
+        grid_name, dtype, poison):
+    """The twin of ``test_hermitian_tridiag_uplo_upper``: with ``uplo='L'``
+    the mirror is built from stored entries only, so whatever stands above
+    the diagonal changes no bit of ``d``, ``e``, ``tau`` or the packed
+    lower triangle."""
+    n, grid = 29, _grid(grid_name)
+    A = _herm(n, dtype, seed=5)
+    Abad = A.copy()
+    Abad[np.triu_indices(n, 1)] = poison
+    clean = hermitian_tridiag(from_global(A, MC, MR, grid), nb=8)
+    dirty = hermitian_tridiag(from_global(Abad, MC, MR, grid), nb=8)
+    for want, got in zip(_stored(clean), _stored(dirty)):
+        assert np.all(np.isfinite(got))
+        assert np.array_equal(want, got)
+
+
+@pytest.mark.parametrize("grid_name", ["1x1", "2x4"])
+def test_mirror_keeps_the_stored_diagonal(grid_name, monkeypatch):
+    """A Hermitian input whose diagonal carries a small imaginary part: the
+    mirror takes the diagonal from the stored triangle, not from its
+    conjugate, so the result is what the two-accumulator ``hemv`` on the
+    lower triangle gave before (the column loop rebuilt around it here; a
+    conjugated diagonal would move it by the imaginary part, 1e-3)."""
+    n, grid = 26, _grid(grid_name)
+    A = _herm(n, jnp.complex128, seed=7)
+    A[np.diag_indices(n)] += 1e-3j * np.arange(1, n + 1)
+    Ad = from_global(A, MC, MR, grid)
+    got = hermitian_tridiag(Ad, nb=8)
+    monkeypatch.setattr(condense, "gemv", lambda A, x, precision: hemv(
+        "L", A, x, precision=precision))
+    # a NEW function object: jax keys its trace cache by the function
+    panel = condense._tridiag_panel.__wrapped__
+    monkeypatch.setattr(condense, "_tridiag_panel", jax.jit(
+        lambda *args: panel(*args), static_argnums=(2, 3, 4, 5)))
+    want = hermitian_tridiag(Ad, nb=8)
+    for w, g in zip(_stored(want), _stored(got)):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-12 * np.abs(w).max())
 
 
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.complex128])

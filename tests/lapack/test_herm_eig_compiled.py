@@ -86,7 +86,8 @@ def compiled(grid_name, n):
         exe = _lower(grid_name, n)
     counts = {name: {labels: v for (_name, labels), v
                      in reg.counters(name).items()}
-              for name in ("herm_tridiag_panel", "dc_merge", "apply_q_panel")}
+              for name in ("herm_tridiag_panel", "herm_tridiag_symmetrize",
+                           "dc_merge", "apply_q_panel")}
     return exe, counts
 
 
@@ -142,6 +143,18 @@ def test_compiled_program_carries_every_scope_and_classifies(grid_name):
     hemv = [n for n in names if "/hemv" in n]
     assert hemv and not any(re.search(r"k\d\d+/panel/.*hemv", n)
                             for n in hemv)
+    # the once-a-panel mirror of the trailing view (ISSUE 38) is the
+    # matvec's cost: its ops stand OUTSIDE the column loop, under every
+    # panel's own k<panel>/hemv, the final select among them, and read
+    # hemv (on a grid the exchange reads its el.redist. name), never
+    # unscoped, never panel
+    for k in range(5):
+        mirror = [n for n in hemv if "_tridiag_panel" not in n and re.search(
+            rf"/el\.hermitian_tridiag/k{k:02d}/hemv(/|$)", n)]
+        assert any(n.endswith("/hemv/jit(_where)/select_n")
+                   for n in mirror), k
+        assert {scopes.classify(n)[0] for n in mirror} <= {
+            "hemv", scopes.REDIST}, k
     # a distributed merge's gemm nests its own panels under the merge
     assert any(re.search(r"el\.tridiag_eig/.*k02/merge/el\.gemm/k\d\d+/panel",
                          n) for n in names)
@@ -159,6 +172,7 @@ def test_counters_read_the_panels_and_the_merges():
     for grid_name in GRIDS:
         _exe, counts = compiled(grid_name, 320)
         assert counts["herm_tridiag_panel"] == {(): 5}
+        assert counts["herm_tridiag_symmetrize"] == {(): 5}
         assert counts["apply_q_panel"] == {(): 5}
         assert counts["dc_merge"] == {(("kind", "replicated"),): 2,
                                       (("kind", "distributed"),): 1}
@@ -167,6 +181,85 @@ def test_counters_read_the_panels_and_the_merges():
 def test_phases_are_in_the_canonical_list():
     for phases in STAGES.values():
         assert set(phases) <= set(obs.PHASES)
+
+
+# ------------------------------------- one read of the trailing view a column
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_CALLED = re.compile(r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+_WHILE_BODY = re.compile(r" while\(.*body=%?([\w.\-]+)")
+
+
+def column_loops(text):
+    """``{panel: [(computation, instruction)]}``: for every ``while`` of
+    the optimized HLO whose body holds a ``k<panel>/hemv`` op (a column
+    loop of the reduction), the instructions of the body and of every
+    computation it calls, fusions included."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        line = line.strip()
+        found = _COMPUTATION.match(line)
+        if found:
+            name = found.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    loops = {}
+    for body in {b for lines in comps.values() for line in lines
+                 for b in _WHILE_BODY.findall(line)}:
+        seen, todo = [], [body]
+        while todo:
+            c = todo.pop()
+            if c not in seen and c in comps:
+                seen.append(c)
+                todo.extend(x for line in comps[c]
+                            for x in _CALLED.findall(line))
+        lines = [(c, line) for c in seen for line in comps[c]]
+        panels = {int(k) for _c, line in lines for k in re.findall(
+            r"/el\.hermitian_tridiag/[^\"]*?while/body/[^\"]*?k(\d\d+)/hemv",
+            line)}
+        if panels:
+            (k,) = panels
+            assert k not in loops, f"two column loops of panel {k}"
+            loops[k] = lines
+    return loops
+
+
+def square_ops(lines, nt, opcodes):
+    """The instructions among ``lines`` whose RESULT is an nt x nt array
+    and whose opcode is one of ``opcodes``."""
+    shape = re.compile(rf"^(?:ROOT )?%?[\w.\-]+ = \w+\[{nt},{nt}\](?:\{{[^}}]*\}})? "
+                       rf"(?:{'|'.join(opcodes)})\(")
+    return [line for _c, line in lines if shape.match(line)]
+
+
+@pytest.mark.parametrize("grid_name", GRIDS)
+def test_column_loop_reads_the_trailing_view_once(grid_name):
+    """ISSUE 38: the trailing view is made Hermitian-full once a panel and
+    the column loop's matvec is ONE dot against it, the loop's invariant
+    operand as it stands: before, two masked dots a column and, on the
+    chip, the compiler's relayout of the whole view for the transposed one
+    (``tests/test_chip_compile.py`` reads that program; the CPU backend
+    assigns layouts otherwise and never made that copy)."""
+    n, height = 320, 1 if grid_name == "1x1" else 2
+    loops = column_loops(compiled(grid_name, n)[0].as_text())
+    assert sorted(loops) == list(range(5))
+    for k, lines in loops.items():
+        nt = (n - k * NB) // height              # the local view's order
+        dots = [line for _c, line in lines
+                if re.search(r" dot\(", line) and "/hemv/" in line]
+        assert len(dots) == 1, (k, dots)
+        assert "operand_precision={highest,highest}" in dots[0]
+        # its left operand is the loop's invariant, the full view itself
+        lhs = re.search(r" dot\(%?([\w.\-]+),", dots[0]).group(1)
+        (made,) = [line for _c, line in lines
+                   if re.match(rf"(ROOT )?%?{re.escape(lhs)} = ", line)]
+        assert re.search(rf"= f32\[{nt},{nt}\]\S* get-tuple-element\(",
+                         made), (k, made)
+        if nt > NB:         # (at nt == nb the panel's own blocks are square)
+            assert not square_ops(lines, nt, ("copy", "transpose", "select")), k
 
 
 # ------------------------------------------------------- what the names cost

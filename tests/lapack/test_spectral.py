@@ -91,8 +91,11 @@ def test_skew_herm_eig_subset(grid24):
     # residual: A z = (i w) z
     r = F.astype(complex) @ Zg - Zg @ np.diag(1j * np.asarray(w))
     assert np.linalg.norm(r) / max(np.linalg.norm(F), 1) < 1e-11
-    # value window on the imaginary parts: (lo, hi]
-    lo, hi = imag_all[5], imag_all[9]
+    # value window on the imaginary parts: (lo, hi].  Its ends stand 1e-9
+    # above two eigenvalues (gaps are 0.33 and more), not ON them: whether a
+    # computed eigenvalue falls on this or that side of its float64 twin
+    # from numpy is the last bit's business (ISSUE 38 moved one)
+    lo, hi = imag_all[5] + 1e-9, imag_all[9] + 1e-9
     wv = el.skew_herm_eig(_g(F, grid24), vectors=False,
                           subset=("value", lo, hi))
     assert np.allclose(np.asarray(wv), imag_all[6:10], atol=1e-11)

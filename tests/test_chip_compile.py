@@ -396,3 +396,33 @@ def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
     reduce_line = next(line for line in text.split("\n")
                        if " all-reduce(" in line)
     assert "el.redist.row_permute" in reduce_line
+
+
+def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
+    """The whole donated ``jit(herm_eig)`` at n = 1024, nb = 256 (ISSUE 38;
+    10 s).  For the column loop's transposed matvec the TPU compiler
+    re-laid the panel's FIXED trailing view out inside the ``while`` body,
+    ``copy f32[nt,nt]{1,0} -> {0,1}`` once a COLUMN, and two fusions read
+    the view: 16 nt^2 bytes a column where 4 nt^2 do the work, 34.2 of the
+    41.9 s of ``heig.1x1.b2b`` (the CPU backend assigns layouts otherwise
+    and never showed it).  With the view made Hermitian-full once a panel
+    every column loop holds ONE fusion that reads an nt x nt array and no
+    ``copy``, ``transpose`` or ``select`` that makes one."""
+    import elemental_tpu as el
+    from .lapack.test_herm_eig_compiled import column_loops, square_ops
+    n, nb = 1024, 256
+    grid = el.Grid([topo.devices[0]])
+    A = _abstract(grid, n, n, el.MC, el.MR)
+    text = jax.jit(lambda a: el.herm_eig(a, nb=nb),
+                   donate_argnums=0).lower(A).compile().as_text()
+    loops = column_loops(text)
+    assert sorted(loops) == [0, 1, 2, 3]
+    for k, lines in loops.items():
+        nt = n - k * nb
+        if nt == nb:                    # the panel's own blocks are nt x nt
+            continue
+        assert not square_ops(lines, nt, ("copy", "transpose", "select")), k
+        readers = square_ops(
+            [(c, line) for c, line in lines if "fused_computation" in c],
+            nt, ("parameter",))
+        assert len(readers) == 1, (k, readers)
