@@ -26,12 +26,18 @@ import os
 
 import jax
 
+from ..obs import compile_log
+
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compilation cache on; return its directory."""
+    """Turn the persistent compilation cache on; return its directory.
+    The compile log (:mod:`elemental_tpu.obs.compile_log`) starts here
+    too: what is traced, lowered, compiled or read back from this cache
+    is counted and timed from now on."""
+    compile_log.install()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     jax.config.update("jax_traceback_in_locations_limit", 1)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")

@@ -457,8 +457,9 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
         return jnp.where(bi == bj, val, 0.0).astype(odt)
 
     from ..core.distmatrix import zeros as dm_zeros
-    Qd = index_dependent_fill(
-        dm_zeros(npad, npad, MC, MR, grid, dtype=odt), qfill)
+    with _TM.phase("fill", level):
+        Qd = index_dependent_fill(
+            dm_zeros(npad, npad, MC, MR, grid, dtype=odt), qfill)
     lam_full = lam.reshape(-1)
 
     while B > 1:

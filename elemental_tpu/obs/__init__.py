@@ -17,6 +17,11 @@ Four pieces, one subsystem -- the layer every perf PR reports through:
                        (``phase_timings/v1`` unchanged)
   :mod:`.export`       Chrome-trace/Perfetto ``trace.json`` rendering
                        (thread-keyed tracks + request flow events)
+  :mod:`.compile_log`  the other half of a program's life (ISSUE 39):
+                       what JAX traced, lowered, compiled or read back
+                       from the persistent cache, from ``jax.monitoring``
+                       -> self seconds per stage, the cache's counters,
+                       ``compile/<stage>`` spans of an active ``Tracer``
 
 Fleet request telemetry (ISSUE 20) adds three serving-tier modules:
 
@@ -51,7 +56,7 @@ time by them.  A path is made of:
                            ``tail`` (CALU adds ``tournament``, the serve
                            loop ``batch``, the grid Cholesky ``k00/mask``:
                            the masked copy of the operand it factors
-                           in).  The Hermitian eigensolve adds five:
+                           in).  The Hermitian eigensolve adds six:
                            ``hermitian_tridiag`` names ``k<panel>/hemv``
                            (once a panel the trailing view's mirror
                            into a full Hermitian matrix, then the column
@@ -63,7 +68,11 @@ time by them.  A path is made of:
                            ``k<level>/leaf`` (level 0, the batched dense
                            leaves), ``k<level>/secular`` (the secular
                            equation, the Gu-Eisenstat weights, the fill
-                           of V) and ``k<level>/merge`` (the eigenvector
+                           of V), ``k<level>/fill`` (once, between the
+                           replicated levels and the distributed ones:
+                           the batch of eigenvector blocks laid out
+                           block-diagonally in [MC,MR]) and
+                           ``k<level>/merge`` (the eigenvector
                            products and their stores), ``level`` counting
                            merges from the leaves up;
                            ``apply_q_herm_tridiag`` names
@@ -139,6 +148,24 @@ not tick again).  Read them under ``metrics_scope()``:
                            defaults, 32 subproblems of 512 up to one)
   ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
 
+Counters of the compile log (:mod:`.compile_log`), ticked on the current
+registry whenever JAX compiles, in any mode:
+
+  ``compile_seconds{stage}``    SELF seconds of a span of ``stage``
+                           ``trace`` | ``lower`` | ``backend`` (its
+                           duration less that of the spans inside it)
+  ``compile_requests``     one backend compile that asked the persistent
+                           cache; ``compile_cache_hits`` of them were read
+                           back from it, ``compile_cache_misses`` compiled
+
+Host spans (an active ``Tracer`` only; on the profiler's clock through
+``Tracer.epoch_anchor``, and IN a running profiler's host plane as
+``jax.profiler.TraceAnnotation``s): every ``Tracer.span`` by its name,
+every phase of a tick channel as ``<driver>/k<step>/<phase>``, and every
+record of the compile log as ``compile/<stage>`` with attrs ``fun_name``,
+``cache`` (``hit`` | ``miss`` | empty), child of the span open on its
+thread.
+
 CLI: ``python -m perf.trace {run,summary,export,serve}``.
 """
 from .metrics import (SCHEMA as METRICS_SCHEMA, FAMILIES as HIST_FAMILIES,
@@ -152,6 +179,7 @@ from .tracer import (TRACE_SCHEMA, CommEvent, InstantEvent, NullHook,
 from .phase_timer import PHASES, SCHEMA as PHASE_TIMINGS_SCHEMA, PhaseTimer
 from .export import (CHROME_SCHEMA, chrome_trace_doc,
                      phase_timings_to_chrome, write_json)
+from . import compile_log
 from .lifecycle import (SCHEMA as TIMELINE_SCHEMA, EDGES as LIFECYCLE_EDGES,
                         RequestTrace, check_timeline)
 from .slo import (SCHEMA as SLO_SCHEMA, SLOMonitor, SLOTarget)
@@ -166,7 +194,7 @@ __all__ = [
     "phase_hook", "ring_bytes", "scoped",
     "PHASES", "PHASE_TIMINGS_SCHEMA", "PhaseTimer",
     "CHROME_SCHEMA", "chrome_trace_doc", "phase_timings_to_chrome",
-    "write_json",
+    "write_json", "compile_log",
     "TIMELINE_SCHEMA", "LIFECYCLE_EDGES", "RequestTrace", "check_timeline",
     "SLO_SCHEMA", "SLOMonitor", "SLOTarget",
     "FLIGHT_SCHEMA", "FlightRecorder",

@@ -18,7 +18,9 @@ into the Chrome Trace Event JSON-object format, which Perfetto
 
 The document carries a top-level ``"schema": "obs_chrome_trace/v1"`` key
 (Chrome/Perfetto ignore unknown keys in the object format) pinned by
-``tests/obs``; run metadata rides ``otherData``.
+``tests/obs``; run metadata rides ``otherData``, and with it, for a
+tracer that was activated, ``epoch_anchor``: ``{"epoch_ns", "ts_us"}``,
+one instant on the epoch clock and on this document's.
 
 ``phase_timings_to_chrome`` converts a ``phase_timings/v1`` document
 (``PhaseTimer.report()``, which records durations but no timestamps)
@@ -288,8 +290,15 @@ def chrome_trace_doc(tracer: Tracer, **meta) -> dict:
             events.append({"ph": ph, "pid": _PID, "tid": tid,
                            "name": "serve:req", "cat": "lifecycle",
                            "id": str(fid), "ts": us(t)})
+    other = dict(meta)
+    anchor = getattr(tracer, "epoch_anchor", None)
+    if anchor is not None:
+        # one instant on both clocks: epoch nanoseconds beside this
+        # document's own micros, so the spans line up with a profiler
+        # trace (xplane timestamps are on the epoch clock)
+        other["epoch_anchor"] = {"epoch_ns": anchor[0], "ts_us": us(anchor[1])}
     return {"schema": CHROME_SCHEMA, "traceEvents": events,
-            "displayTimeUnit": "ms", "otherData": dict(meta)}
+            "displayTimeUnit": "ms", "otherData": other}
 
 
 def phase_timings_to_chrome(doc: dict, **meta) -> dict:

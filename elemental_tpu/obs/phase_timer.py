@@ -50,10 +50,10 @@ SCHEMA = "phase_timings/v1"
 #: panel/swap/solve/update, Cholesky diag/panel/spread/update + tail,
 #: QR panel/update, gemm panel, trsm solve/update, herk spread/update;
 #: the Hermitian eigensolve names hemv/panel/update in the reduction,
-#: leaf/secular/merge in the divide and conquer, apply in the
+#: leaf/secular/fill/merge in the divide and conquer, apply in the
 #: back-transform)
 PHASES = ("diag", "panel", "swap", "solve", "spread", "update", "tail",
-          "hemv", "leaf", "secular", "merge", "apply")
+          "hemv", "leaf", "secular", "fill", "merge", "apply")
 
 
 class PhaseTimer(PhaseHook):

@@ -207,6 +207,29 @@ class _Phase:
         return False
 
 
+class _AnnotatedPhase(_Phase):
+    """The scoped form behind an active :class:`Tracer`: the block is
+    also a ``jax.profiler.TraceAnnotation`` ``<driver>/k<step>/<phase>``,
+    so with the profiler running the phase stands in the trace's host
+    plane beside the device's ops."""
+    __slots__ = ("_annotation",)
+
+    def __init__(self, hook, driver, phase, step):
+        super().__init__(hook, phase, step)
+        self._annotation = jax.profiler.TraceAnnotation(
+            f"{driver}/k{int(step):02d}/{phase}")
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            return super().__exit__(exc_type, exc, tb)
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
+
+
 class PhaseHook:
     """What every hook a driver may hold offers: ``start()``, and the
     scoped form ``phase(phase, step)``.  ``tick`` is what that form calls
@@ -251,6 +274,9 @@ class _TickChannel(PhaseHook):
         """(Re)arm the clock at a driver's entry."""
         self._t = self.tracer.clock()
 
+    def phase(self, phase, step) -> _Phase:
+        return _AnnotatedPhase(self, self.driver, phase, step)
+
     def tick(self, phase, step, *arrays):
         """Block on ``arrays`` and close the [previous-tick, now] phase."""
         if arrays:
@@ -273,6 +299,12 @@ class _Fanout(PhaseHook):
     def start(self):
         for h in self.hooks:
             h.start()
+
+    def phase(self, phase, step) -> _Phase:
+        for h in self.hooks:
+            if isinstance(h, _TickChannel):
+                return _AnnotatedPhase(self, h.driver, phase, step)
+        return _Phase(self, phase, step)
 
     def tick(self, phase, step, *arrays):
         for h in self.hooks:
@@ -311,6 +343,10 @@ class Tracer:
         self._ncalls = 0
         self._prev_active: Tracer | None = None
         self._unobserve = None
+        #: ``(time.time_ns(), clock())`` read together at activation: what
+        #: puts these spans, JAX's compile spans and a profiler trace (all
+        #: on the epoch clock) on one time line
+        self.epoch_anchor: tuple | None = None
 
     def _thread_stack(self) -> list:
         st = getattr(self._tls, "stack", None)
@@ -344,12 +380,34 @@ class Tracer:
             self.spans.append(s)
         stack.append(s)
         try:
-            yield s
+            with jax.profiler.TraceAnnotation(s.name):
+                yield s
         finally:
             if sync is not None:
                 jax.block_until_ready(sync)
             s.t1 = self.clock()
             stack.pop()
+
+    def compile_span(self, rec, nested: int = 0, parent=None) -> None:
+        """One record of the compile log (:mod:`.compile_log`) as a span
+        ``compile/<stage>``: child of the compile span it is nested in,
+        else of the span open on this thread; its epoch times moved onto
+        this tracer's clock by the anchor."""
+        stack = self._thread_stack()
+        epoch_ns, clock_s = self.epoch_anchor
+        shift = clock_s - epoch_ns * 1e-9
+        ident, tname = self._whoami()
+        attrs = {"fun_name": rec.fun_name, "cache": rec.cache,
+                 "self_s": rec.self_s}
+        if parent is not None:
+            attrs["parent"] = f"compile/{parent.stage}"
+        elif stack:
+            attrs["parent"] = stack[-1].name
+        s = Span(name=f"compile/{rec.stage}", t0=rec.start + shift,
+                 t1=rec.end + shift, depth=len(stack) + nested, attrs=attrs,
+                 thread=ident, thread_name=tname)
+        with self._lock:
+            self.spans.append(s)
 
     # ---- driver tick channels ---------------------------------------
     def channel(self, driver: str, **attrs) -> _TickChannel:
@@ -420,6 +478,8 @@ class Tracer:
         global _ACTIVE
         from ..redist.engine import add_redist_observer
         self._prev_active = _ACTIVE
+        if self.epoch_anchor is None:
+            self.epoch_anchor = (time.time_ns(), self.clock())
         _ACTIVE = self
         self._unobserve = add_redist_observer(self._on_redist)
         return self

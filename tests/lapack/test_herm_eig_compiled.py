@@ -123,7 +123,7 @@ def test_compiled_herm_eig_agrees_with_float64_numpy(grid_name, n):
 
 #: stage -> the phases its ops carry
 STAGES = {"hermitian_tridiag": ("hemv", "panel", "update"),
-          "tridiag_eig": ("leaf", "secular", "merge"),
+          "tridiag_eig": ("leaf", "secular", "fill", "merge"),
           "apply_q_herm_tridiag": ("apply",)}
 
 
