@@ -100,11 +100,13 @@ def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     Its ops carry the scopes of the three stages under ``el.herm_eig``
     (grammar in :mod:`elemental_tpu.obs`):
     ``el.hermitian_tridiag/k<panel>/{hemv,panel,update}``,
-    ``el.tridiag_eig/k<level>/{leaf,secular,merge}`` (n above ``dc_min``) and
+    ``el.tridiag_eig/k<level>/{leaf,secular,fill,merge}`` (n above ``dc_min``;
+    ``fill`` above ``repl_max``) and
     ``el.apply_q_herm_tridiag/k<panel>/apply``; the trace-time counters
-    ``herm_tridiag_panel``, ``herm_tridiag_symmetrize``, ``dc_merge{kind}``
-    and ``apply_q_panel`` count the panels, the mirrors of the trailing view
-    (one a panel) and the merges.
+    ``herm_tridiag_panel``, ``herm_tridiag_symmetrize``, ``dc_merge{kind}``,
+    ``dc_fill_block`` and ``apply_q_panel`` count the panels, the mirrors of
+    the trailing view (one a panel), the merges and the eigenvector blocks
+    placed on the [MC,MR] matrix's diagonal between the two kinds of merge.
     """
     _check_mcmr(A)
     n = A.gshape[0]

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.dist import MC, MR, STAR
-from ..core.distmatrix import DistMatrix
+from ..core.distmatrix import DistMatrix, zeros as dm_zeros
 from ..redist.engine import redistribute
 from ..redist.interior import interior_view, interior_update
 from ..blas.level1 import index_dependent_fill
@@ -62,7 +62,7 @@ from .lu import _hi, _scoped
 
 #: the divide and conquer is ONE jitted program, so nothing times a phase
 #: eagerly: its phases only name their ops (``k<level>/leaf``, ``secular``,
-#: ``merge``; ``level`` counts merges from the leaves, which are level 0)
+#: ``fill``, ``merge``; ``level`` counts merges from the leaves, level 0)
 _TM = NULL_HOOK
 
 
@@ -312,6 +312,28 @@ def _merge_rows_only(lam1, lam2, fr1, lr1, fr2, lr2, betas, scale, n_iters,
 
 
 # ---------------------------------------------------------------------
+# the hand-off between the two phases
+# ---------------------------------------------------------------------
+
+def _place_blocks(Qb, grid) -> DistMatrix:
+    """The replicated (B, nm, nm) batch as the block-diagonal [MC,MR]
+    matrix of order B * nm: zeros, then each block handed over
+    ``[STAR,STAR] -> [MC,MR]`` (a local filter on a grid, nothing on 1x1)
+    and placed whole at its diagonal offset.  B dense copies of nm^2
+    entries, at any ``nm`` on any grid (:func:`interior_update`'s own offset
+    arithmetic): never a function of (i, j) evaluated at all (B nm)^2
+    entries, which the compiler makes a gather an ENTRY."""
+    B, nm, _ = Qb.shape
+    Qd = dm_zeros(B * nm, B * nm, MC, MR, grid, dtype=Qb.dtype)
+    for b in range(B):
+        _metrics.inc("dc_fill_block")
+        block = redistribute(
+            DistMatrix(Qb[b], (nm, nm), STAR, STAR, 0, 0, grid), MC, MR)
+        Qd = interior_update(Qd, block, (b * nm, b * nm))
+    return Qd
+
+
+# ---------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------
 
@@ -358,8 +380,12 @@ def tridiag_eig(d, e, grid=None, vectors: bool = True,
     Scopes (``el.tridiag_eig/k<level>/...``, level 0 the leaves, then one a
     level of merges): ``leaf`` (the batched dense leaves), ``secular`` (the
     secular equation, the Gu-Eisenstat weights and the fill of V),
-    ``merge`` (the eigenvector products and their stores).  ``dc_merge``
-    counts the merges by ``kind`` (``replicated`` | ``distributed``).
+    ``fill`` (once, at the last replicated level's number: the hand-off of
+    the batch of eigenvector blocks to the [MC,MR] matrix, each block
+    placed whole on the diagonal), ``merge`` (the eigenvector products and
+    their stores).  ``dc_merge`` counts the merges by ``kind``
+    (``replicated`` | ``distributed``), ``dc_fill_block`` the blocks the
+    hand-off places.
     """
     d = jnp.asarray(d)
     e = jnp.asarray(e)
@@ -447,19 +473,8 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
     # ---- distributed phase -------------------------------------------
     if grid is None:
         raise ValueError("tridiag_eig: n exceeds repl_max and no grid given")
-    # assemble block-diagonal DistMatrix from the (B, nm, nm) batch
-    Qb = Q
-
-    def qfill(i, j):
-        bi, ri = i // nm, i % nm
-        bj, cj = j // nm, j % nm
-        val = Qb[jnp.clip(bi, 0, B - 1), ri, cj]
-        return jnp.where(bi == bj, val, 0.0).astype(odt)
-
-    from ..core.distmatrix import zeros as dm_zeros
     with _TM.phase("fill", level):
-        Qd = index_dependent_fill(
-            dm_zeros(npad, npad, MC, MR, grid, dtype=odt), qfill)
+        Qd = _place_blocks(Q, grid)
     lam_full = lam.reshape(-1)
 
     while B > 1:

@@ -70,8 +70,11 @@ time by them.  A path is made of:
                            equation, the Gu-Eisenstat weights, the fill
                            of V), ``k<level>/fill`` (once, between the
                            replicated levels and the distributed ones:
-                           the batch of eigenvector blocks laid out
-                           block-diagonally in [MC,MR]) and
+                           the zeros of the [MC,MR] eigenvector matrix
+                           and, for each block of the batch, its
+                           ``[STAR,STAR] -> [MC,MR]`` hand-over and the
+                           ``interior_update`` that places it whole on
+                           the diagonal: dense copies, no gather) and
                            ``k<level>/merge`` (the eigenvector
                            products and their stores), ``level`` counting
                            merges from the leaves up;
@@ -146,6 +149,10 @@ not tick again).  Read them under ``metrics_scope()``:
                            ``distributed`` (unrolled on the [MC,MR]
                            eigenvector matrix: 31 at n = 16384 with the
                            defaults, 32 subproblems of 512 up to one)
+  ``dc_fill_block``        one eigenvector block placed on the diagonal of
+                           the [MC,MR] matrix at ``tridiag_eig``'s hand-off
+                           (32 at n = 16384 with the defaults; none where
+                           every merge is replicated or ``vectors`` is off)
   ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
 
 Counters of the compile log (:mod:`.compile_log`), ticked on the current

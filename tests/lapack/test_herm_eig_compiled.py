@@ -13,6 +13,7 @@ the [MC,MR] eigenvector matrix), which the defaults reach only above 512.
 import contextlib
 import functools
 import importlib.util
+import math
 import os
 import re
 
@@ -87,7 +88,7 @@ def compiled(grid_name, n):
     counts = {name: {labels: v for (_name, labels), v
                      in reg.counters(name).items()}
               for name in ("herm_tridiag_panel", "herm_tridiag_symmetrize",
-                           "dc_merge", "apply_q_panel")}
+                           "dc_merge", "dc_fill_block", "apply_q_panel")}
     return exe, counts
 
 
@@ -168,7 +169,8 @@ def test_compiled_program_carries_every_scope_and_classifies(grid_name):
 
 def test_counters_read_the_panels_and_the_merges():
     """n = 320, nb = 64: five panels each way; four leaves of 80, so one
-    level of two replicated merges, then one distributed merge."""
+    level of two replicated merges, the two blocks of 160 placed on the
+    [MC,MR] matrix's diagonal, then one distributed merge."""
     for grid_name in GRIDS:
         _exe, counts = compiled(grid_name, 320)
         assert counts["herm_tridiag_panel"] == {(): 5}
@@ -176,11 +178,63 @@ def test_counters_read_the_panels_and_the_merges():
         assert counts["apply_q_panel"] == {(): 5}
         assert counts["dc_merge"] == {(("kind", "replicated"),): 2,
                                       (("kind", "distributed"),): 1}
+        assert counts["dc_fill_block"] == {(): 2}
 
 
 def test_phases_are_in_the_canonical_list():
     for phases in STAGES.values():
         assert set(phases) <= set(obs.PHASES)
+
+
+# --------------------------------------- the blocks are placed, not gathered
+
+_GATHER = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+                     r"gather\((.*)$", re.M)
+
+
+def big_gathers(text, least):
+    """``[(entries, op_name)]`` of the ``gather`` instructions of the
+    optimized HLO, on their own or inside a fusion's computation, whose
+    result has ``least`` entries or more."""
+    found = []
+    for dims, rest in _GATHER.findall(text):
+        entries = math.prod(int(d) for d in dims.split(",") if d)
+        name = re.search(r'op_name="([^"]*)"', rest)
+        if entries >= least:
+            found.append((entries, name.group(1) if name else ""))
+    return found
+
+
+def test_the_gather_reader_finds_a_fused_gather():
+    text = """
+%fused_computation.38 (param_0.116: f32[2,160,160], param_1: s32[320,320,3]) -> f32[320,320] {
+  ROOT %gather.330 = f32[320,320]{1,0:T(8,128)} gather(%param_0.116, %param_1), offset_dims={}, metadata={op_name="jit(f)/k03/fill/gather" stack_frame_id=260}
+}
+ENTRY %main {
+  %gather.2 = f32[320]{0} gather(%a, %b), offset_dims={}
+  %g = f32[102400]{0} gather(%a, %b), offset_dims={}
+}"""
+    assert big_gathers(text, 320 * 320) == [
+        (102400, "jit(f)/k03/fill/gather"), (102400, "")]
+    assert [size for size, _ in big_gathers(text, 320)] == [
+        102400, 320, 102400]
+
+
+@pytest.mark.parametrize("grid_name", GRIDS)
+def test_hand_off_gathers_no_entry_of_the_eigenvector_matrix(grid_name):
+    """ISSUE 42: the batch of eigenvector blocks reaches the [MC,MR] matrix
+    as dense block copies.  Laid out by a function of (i, j) the compiler
+    made ONE gather over all npad^2 entries, 22.7 ns an entry on the chip
+    (6.1 of ``heig.1x1.b2b``'s 15.8 s); no gather of the drivers' own may
+    have a device's share of the matrix, npad^2 / chips entries, or more.
+    (The ENGINE unpacks a gathered block with one on this backend, under
+    its ``el.redist.`` name: on 2x2 the merge's ``[MC,MR] -> [STAR,STAR]``
+    of a 160-block is 160^2 = 320^2 / 4 entries, a shard's size by
+    coincidence of B = 2 on four chips.)"""
+    n, chips = 320, 1 if grid_name == "1x1" else 4
+    text = compiled(grid_name, n)[0].as_text()
+    assert not [found for found in big_gathers(text, n * n // chips)
+                if "el.redist." not in found[1]]
 
 
 # ------------------------------------- one read of the trailing view a column

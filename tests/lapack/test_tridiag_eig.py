@@ -8,10 +8,12 @@ checks around upstream ``external/pmrrr`` in
 phase (n <= repl_max) and the distributed [MC,MR] phase (n > repl_max), and
 the herm_eig wiring end-to-end.
 """
+import jax
 import numpy as np
+import pytest
 
 import elemental_tpu as el
-from elemental_tpu.lapack.tridiag_eig import tridiag_eig
+from elemental_tpu.lapack.tridiag_eig import _place_blocks, tridiag_eig
 
 
 def _trid(d, e):
@@ -87,6 +89,27 @@ def test_distributed_phase(any_grid):
     w, Zd = tridiag_eig(d, e, grid=any_grid, vectors=True,
                         leaf_max=48, repl_max=128)
     _check(d, e, w, Zd, tol=1e-9)
+
+
+@pytest.mark.parametrize("B,nm", [(4, 88), (3, 63)])
+def test_hand_off_places_the_blocks_bit_for_bit(any_grid, B, nm):
+    """The hand-off between the replicated levels and the distributed ones
+    (ISSUE 42): the (B, nm, nm) batch comes out as numpy's block diagonal,
+    bit for bit, at block sizes that are multiples of no grid stride (88 is
+    n = 350's own at the hand-off), eagerly and as a compiled program."""
+    Qb = np.random.default_rng(42).standard_normal(
+        (B, nm, nm)).astype(np.float32)
+    want = np.zeros((B * nm, B * nm), np.float32)
+    for b in range(B):
+        want[b * nm:(b + 1) * nm, b * nm:(b + 1) * nm] = Qb[b]
+    for place in (_place_blocks,
+                  jax.jit(_place_blocks, static_argnums=1)):
+        Qd = place(Qb, any_grid)
+        assert (Qd.cdist, Qd.rdist) == (el.MC, el.MR)
+        assert Qd.gshape == (B * nm, B * nm)
+        got = np.asarray(el.to_global(Qd))
+        assert got.dtype == np.float32
+        assert np.array_equal(got, want)
 
 
 def test_herm_eig_dc_path(grid24):

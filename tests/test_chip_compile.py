@@ -426,3 +426,32 @@ def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
             [(c, line) for c, line in lines if "fused_computation" in c],
             nt, ("parameter",))
         assert len(readers) == 1, (k, readers)
+
+
+def test_divide_and_conquer_hand_off_places_blocks_and_gathers_nothing(topo,
+                                                                      one_chip):
+    """``tridiag_eig`` with vectors at n = 1024 (ISSUE 42; 15 s), the
+    divide and conquer of the program above on its own.  Between the
+    replicated levels and the distributed ones the two 512-blocks of
+    eigenvectors are PLACED on the diagonal of the [MC,MR] matrix, under
+    ``k03/fill``: laid out by a function of (i, j), the TPU compiler made
+    one gather over all n^2 entries (``fusion.38 f32[268435456]`` at
+    n = 16384: 6.1 of ``heig.1x1.b2b``'s 15.8 s, for 33.5 MB of dense
+    copies).  No gather, on its own or inside a fusion, has n^2 entries or
+    more, and the hand-off's ops still carry the scope.  (Not the whole
+    ``herm_eig``: two tests of one file may go to two workers, and each
+    would compile it.)"""
+    import elemental_tpu as el
+    from elemental_tpu.lapack.tridiag_eig import tridiag_eig
+    from .lapack.test_herm_eig_compiled import big_gathers
+    n = 1024
+    grid = el.Grid([topo.devices[0]])
+    d = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+    e = jax.ShapeDtypeStruct((n - 1,), jnp.float32, sharding=one_chip)
+    # float32 throughout, as on the chip (the tests' x64 would put the
+    # secular stage in float64, which the TPU emulates: 90 s of compile)
+    with jax.enable_x64(False):
+        text = jax.jit(lambda d, e: tridiag_eig(d, e, grid=grid)).lower(
+            d, e).compile().as_text()
+    assert not big_gathers(text, n * n)
+    assert re.search(r'op_name="[^"]*/el\.tridiag_eig/[^"]*k03/fill/', text)
