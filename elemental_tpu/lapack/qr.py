@@ -25,13 +25,15 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec
 
-from ..core.dist import MC, MR, VC, STAR
+from ..core.dist import MC, MR, VC, STAR, rank_of
 from ..core.distmatrix import DistMatrix
 from ..core.view import view, update_view
 from ..core.compat import shard_map
 from ..redist.engine import apply_fault, redistribute
 from ..blas.level3 import _blocksize, _check_mcmr, trsm
+from ..obs import metrics as _metrics
 from .lu import (_update_cols_lt, _update_cols_ge, _hi, _phase_hook,
                  _nopiv_panel, _scoped)
 
@@ -439,13 +441,29 @@ def explicit_q(Ap: DistMatrix, tau, nb: int | None = None,
     return apply_q(Ap, tau, I, orient="N", nb=nb, precision=_hi(precision))
 
 
+@_scoped("el.least_squares")
 def least_squares(A: DistMatrix, B: DistMatrix, nb: int | None = None,
                   precision=None, abft=None) -> DistMatrix:
     """Minimize ||A X - B||_F for m >= n via QR (``El::LeastSquares``,
     dense path of ``src/lapack_like/euclidean_min/LeastSquares.cpp``).
 
-    Fully distributed: Q^H B via packed reflectors, then a distributed
-    triangular solve against the interior-extracted R (no replication).
+    Two routes, chosen from the shapes and the grid
+    (:func:`_takes_tall_route`; the counter ``lstsq_route{kind}`` says
+    which):
+
+    * ``tall`` (``qr::TS``): on a grid of p > 1 chips, where every
+      chip's share of the rows, m / p, is at least 8192 n and n <= 256
+      (the aspect and width the route was measured at), B is no wider
+      than A, and the entries are real, the alignments zero and no
+      ``abft`` is asked for: A and B go to
+      [VC,STAR] once, each chip factors its own rows by Householder QR,
+      the p small R factors are gathered once and their stack factored
+      the same on every chip, and R X = Y is solved there.  No chip ever
+      holds more of A than its m / p rows.  ``nb`` is not used.
+    * ``blocked``, everything else (square and moderately tall
+      problems): the blocked Householder QR below, Q^H B via packed
+      reflectors, then a distributed triangular solve against the
+      interior-extracted R.
 
     ``abft`` threads through to :func:`qr` (ISSUE 15): the factorization
     -- the solve's whole O(m n^2) fault surface -- runs checksum-guarded
@@ -457,6 +475,12 @@ def least_squares(A: DistMatrix, B: DistMatrix, nb: int | None = None,
     m, n = A.gshape
     if m < n:
         raise ValueError("least_squares requires m >= n (tall)")
+    if B.gshape[0] != m:
+        raise ValueError(f"B height {B.gshape[0]} != {m}")
+    tall = _takes_tall_route(A, B, abft)
+    _metrics.inc("lstsq_route", kind="tall" if tall else "blocked")
+    if tall:
+        return _least_squares_tall(A, B, precision)
     Ap, tau = qr(A, nb=nb, precision=_hi(precision), abft=abft)
     Y = apply_q(Ap, tau, B, orient="C", nb=nb, precision=_hi(precision))
     R = make_trapezoidal(interior_view(Ap, (0, n), (0, n)), "U")
@@ -693,42 +717,308 @@ def rq(A: DistMatrix, nb: int | None = None, precision=None):
 
 
 # ---------------------------------------------------------------------
-# TSQR (tall-skinny)
+# TSQR (tall-skinny): every chip factors the rows it holds
 # ---------------------------------------------------------------------
+#
+# The route of ``least_squares`` and ``tsqr`` for m >> n (``qr::TS``).
+# A chip's rows are all of [VC,STAR]'s local block, (lr, n).  It is
+# factored by Householder QR where it lies, in TRANSPOSED storage: a
+# column of A is a row of sublanes, and an unblocked panel of
+# ``_TALL_PANEL`` columns is one row of whole float32 tiles, so nothing
+# the column loop touches is padded (a panel stored (lr, 8) would pad
+# its 8 lanes to 128).  The first n rows (the ``head``, n x n, where R
+# and the reflectors' unit triangle live) are kept apart from the rest
+# (the ``body``, n x (lr - n) transposed), so that no op on the body needs
+# a mask.  Panels are joined by recursion on the column range (Elmroth &
+# Gustavson): factor the left half, apply its block reflector
+# I - V T V^T to the right half, factor that, and merge the two T.  All
+# products against the body carry the caller's ``precision``.
 
-def tsqr(A: DistMatrix):
-    """Tall-skinny QR of a [VC,STAR] matrix (``qr::TS``): per-device local
-    QR + one all-gather of the p small R factors + a redundant stacked QR.
-    Returns (Q [VC,STAR] with orthonormal columns, R [STAR,STAR])."""
+#: columns of an unblocked panel: the sublanes of one float32 tile
+_TALL_PANEL = 8
+#: the widest operand that takes the tall route: the benchmark's cell's.
+#: The route unrolls its panels (n / 8 column loops and as many block
+#: reflectors, 82,036 optimized HLO lines at 256 columns) and was
+#: compiled and timed at no other width (PERF.md 6 and 7, PR 43)
+_TALL_MAX_COLS = 256
+#: rows a chip must hold for each column of A: the cell's 2^21 rows a chip
+#: for 256 columns, the one aspect the route was timed at on a grid
+_TALL_ASPECT = 8192
+#: rows of the body one partial sum runs over (below)
+_TALL_CHUNK = 8192
+
+# Every inner product of the factorization runs over a chip's rows, 2^21 of
+# them in the benchmark's cell, and a float32 accumulator that long loses
+# five digits; on columns that are nearly parallel the loss is what decides
+# the answer (the rounding of the PRODUCTS averages out over so many terms,
+# whatever their precision: PERF.md 6, PR 43).  So the body is kept in
+# chunks of ``_TALL_CHUNK`` rows, (C, n, L): a product over the rows is C
+# partial products, each accumulated over one chunk, summed by halves.
+
+
+def _pair_sum(x):
+    """Sum over the leading axis by halves: log2(C) roundings a term, where
+    a running sum makes C."""
+    while x.shape[0] > 1:
+        if x.shape[0] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[:1])])
+        x = x[0::2] + x[1::2]
+    return x[0]
+
+
+def _tall_chunks(rows):
+    """(mb, k) rows of a chip's block -> (C, k, L) chunks, transposed;
+    zero rows fill the last chunk (they change no product)."""
+    mb, k = rows.shape
+    L = max(min(_TALL_CHUNK, mb), 1)
+    C = max(-(-mb // L), 1)
+    rows = jnp.pad(rows, ((0, C * L - mb), (0, 0)))
+    return rows.reshape(C, L, k).transpose(0, 2, 1)
+
+
+def _tall_rows(chunks, mb):
+    """The inverse of :func:`_tall_chunks`: (C, k, L) -> (mb, k)."""
+    C, k, L = chunks.shape
+    return chunks.transpose(0, 2, 1).reshape(C * L, k)[:mb]
+
+
+def _rows_dot(X, Y, precision):
+    """``X Y^T`` over the rows of two chunked bodies (C, k, L), (C, j, L):
+    (k, j), a partial product a chunk, summed by halves."""
+    return _pair_sum(jnp.einsum("ckl,cjl->ckj", X, Y, precision=precision))
+
+
+def _rows_mix(M, Y, precision):
+    """``M Y`` for (k, j) ``M`` and a chunked body ``Y`` (C, j, L)."""
+    return jnp.einsum("kj,cjl->ckl", M, Y, precision=precision)
+
+
+def _tall_panel(Ph, Pb, c0):
+    """Unblocked Householder QR of the ``w <= _TALL_PANEL`` columns
+    ``c0 .. c0 + w`` of a chip's block, transposed: ``Ph`` (w, n) their
+    head rows, ``Pb`` (C, w, L) their body.  LAPACK larfg conventions, as
+    :func:`_panel_qr`.  Returns ``(packed head, Vh, Vb, T)``: R's columns
+    on and left of the diagonal with the reflectors' tails right of it,
+    the reflectors' heads alone (unit at the diagonal), their bodies, and
+    the larft triangle of the w reflectors (``Q = I - V T V^T``).
+
+    A column costs the body two passes: the dots of every row with row j
+    (its norm, the other columns' products with it and, from the rows
+    already reduced, the new column of V^T V for T), then one fused
+    update."""
+    w, n = Ph.shape
+    dt = Ph.dtype
+    lane = jnp.arange(n)
+    row = jnp.arange(w)
+
+    def column(j, state):
+        Ph, Vh, Pb, T = state
+        c = c0 + j
+        hrow = Ph[j]
+        alpha = hrow[c]
+        htail = jnp.where(lane > c, hrow, 0)
+        brow = lax.dynamic_index_in_dim(Pb, j, axis=1)          # (C, 1, L)
+        dots = _pair_sum(jnp.sum(Pb * brow, axis=2))            # (w,)
+        sigma = jnp.sum(htail * htail) + dots[j]
+        anorm = jnp.sqrt(alpha * alpha + sigma)
+        beta = jnp.where(alpha < 0, anorm, -anorm)
+        degenerate = anorm == 0
+        safe_beta = jnp.where(degenerate, 1.0, beta).astype(dt)
+        tau = jnp.where(degenerate, 0.0,
+                        (safe_beta - alpha) / safe_beta).astype(dt)
+        denom = alpha - safe_beta
+        scale = (1.0 / jnp.where(denom == 0, 1.0, denom)).astype(dt)
+        vh = jnp.where(lane == c, jnp.where(degenerate, 0.0, 1.0),
+                       htail * scale).astype(dt)
+        # v_j against every row: rows k > j still hold columns of A
+        # (w_k = v_j^T a_k), rows k < j hold reflectors (V^T V for T)
+        d = jnp.where(row < j, Vh @ vh, Ph @ vh) + dots * scale
+        wk = jnp.where(row > j, tau * d, 0)
+        packed = jnp.where(lane > c, vh, jnp.where(lane == c, beta, hrow))
+        Ph = jnp.where((row == j)[:, None], packed[None, :],
+                       Ph - wk[:, None] * vh[None, :])
+        # body: rows k > j lose wk * v_j, row j becomes v_j, rows k < j stay
+        Pb = jnp.where((row == j)[None, :, None], brow * scale,
+                       Pb - (wk * scale)[None, :, None] * brow)
+        Vh = jnp.where((row == j)[:, None], vh[None, :], Vh)
+        tcol = jnp.where(row < j, -tau * (T @ jnp.where(row < j, d, 0)),
+                         jnp.where(row == j, tau, 0))
+        T = jnp.where((row == j)[None, :], tcol[:, None], T)
+        return Ph, Vh, Pb, T
+
+    return lax.fori_loop(
+        0, w, column, (Ph, jnp.zeros_like(Ph), Pb, jnp.zeros((w, w), dt)))
+
+
+def _tall_factor(H, Ab, r0, h, precision):
+    """Householder QR of columns ``r0 .. r0 + h`` of a chip's block in
+    place: ``H`` (h, n) are their head rows, ``Ab`` (C, n, L) the WHOLE
+    body, of which rows ``r0 .. r0 + h`` are read and rewritten (columns
+    left of ``r0`` already hold reflectors, right of ``r0 + h`` are not
+    touched).  Returns ``(packed head, Vh, Ab, T)`` as
+    :func:`_tall_panel`, T the larft triangle of all h reflectors."""
+    if h <= _TALL_PANEL:
+        Ph, Vh, Pb, T = _tall_panel(H, Ab[:, r0:r0 + h], r0)
+        return Ph, Vh, Ab.at[:, r0:r0 + h].set(Pb), T
+    h1 = -(-(h // 2) // _TALL_PANEL) * _TALL_PANEL
+    mm = partial(jnp.matmul, precision=precision)
+    P1, V1, Ab, T1 = _tall_factor(H[:h1], Ab, r0, h1, precision)
+    lo, mid, hi = r0, r0 + h1, r0 + h
+    # right half <- (I - V1 T1 V1^T)^T right half, rows being columns:
+    # X -= ((X V1) T1) V1^T
+    M = mm(mm(H[h1:], V1.T)
+           + _rows_dot(Ab[:, mid:hi], Ab[:, lo:mid], precision), T1)
+    H2 = H[h1:] - mm(M, V1)
+    Ab = Ab.at[:, mid:hi].set(
+        Ab[:, mid:hi] - _rows_mix(M, Ab[:, lo:mid], precision))
+    P2, V2, Ab, T2 = _tall_factor(H2, Ab, mid, h - h1, precision)
+    # larft by halves: T12 = -T1 (V1^T V2) T2
+    S = mm(V1, V2.T) + _rows_dot(Ab[:, lo:mid], Ab[:, mid:hi], precision)
+    T12 = -mm(mm(T1, S), T2)
+    T = jnp.concatenate([
+        jnp.concatenate([T1, T12], axis=1),
+        jnp.concatenate([jnp.zeros((h - h1, h1), T1.dtype), T2], axis=1)])
+    return (jnp.concatenate([P1, P2]), jnp.concatenate([V1, V2]), Ab, T)
+
+
+def _tall_qr(a, precision):
+    """Householder QR of a chip's (lr, n) block, lr >= n, where it lies.
+    Returns ``(R, Vh, Vb, T)``: R (n, n) upper triangular and the block
+    reflector ``Q = I - V T V^T`` with ``V^T = [Vh | Vb]`` ((n, n) unit
+    upper triangular | the body's (C, n, L) chunks)."""
+    n = a.shape[1]
+    Pk, Vh, Vb, T = _tall_factor(a[:n].T, _tall_chunks(a[n:]), 0, n,
+                                 precision)
+    return jnp.tril(Pk).T, Vh, Vb, T
+
+
+def _tall_apply_qt(Vh, Vb, T, b, precision):
+    """The first n rows of ``Q^T b`` for a chip's (lr, k) block ``b`` and
+    the block reflector of :func:`_tall_qr`; the rows below them are not
+    formed.  Returns (n, k)."""
+    n = Vh.shape[0]
+    mm = partial(jnp.matmul, precision=precision)
+    bh = b[:n].T                                       # (k, n)
+    M = mm(mm(bh, Vh.T) + _rows_dot(_tall_chunks(b[n:]), Vb, precision), T)
+    return (bh - mm(M, Vh)).T
+
+
+def _tall_explicit_q(Vh, Vb, T, C, rows, precision):
+    """``Q [C; 0]`` for an (n, k) block ``C``: (rows, k), head rows
+    first."""
+    mm = partial(jnp.matmul, precision=precision)
+    M = mm(mm(C.T, Vh.T), T.T)                         # (k, n)
+    body = _tall_rows(-_rows_mix(M, Vb, precision), rows - Vh.shape[0])
+    return jnp.concatenate([(C.T - mm(M, Vh)).T, body])
+
+
+def _tsqr_tree_stage(R1, grid, precision):
+    """One all-gather of the p chips' R factors in VC rank order and the
+    QR of their stack, the same on every chip: ``(R, Vh, Vb, T)`` of the
+    (p n, n) stack as :func:`_tall_qr`."""
+    n = R1.shape[0]
+    p = grid.size
+    _metrics.inc("tsqr_tree_bytes", p * n * n * R1.dtype.itemsize)
+    Rs = lax.all_gather(R1, ("mr", "mc"), axis=0)       # VC rank order
+    return _tall_qr(Rs.reshape(p * n, n), precision)
+
+
+@_scoped("el.tsqr")
+def tsqr(A: DistMatrix, precision=None):
+    """Tall-skinny QR of a [VC,STAR] matrix (``qr::TS``): each chip's
+    Householder QR of its own rows, one all-gather of the p small R
+    factors and the QR of their stack, the same on every chip.
+    Returns (Q [VC,STAR] with orthonormal columns, R [STAR,STAR]).
+    Real dtypes only (a complex A is refused: the reflectors here take no
+    conjugates); every product runs at ``precision`` (None: HIGHEST)."""
     if A.dist != (VC, STAR) or (A.calign, A.ralign) != (0, 0):
         raise ValueError(f"tsqr expects zero-aligned [VC,STAR], got {A}")
+    if not jnp.issubdtype(A.dtype, jnp.floating):
+        raise ValueError(
+            f"tsqr factors real floating-point matrices, got {A.dtype}")
     m, k = A.gshape
     g = A.grid
-    r, c = g.height, g.width
-    p = r * c
     if m < k:
         raise ValueError("tsqr needs m >= k")
-
-    import jax
-    from jax.sharding import PartitionSpec as P
+    if A.local_rows < k:
+        raise ValueError(
+            f"tsqr: each of the {g.size} chips must hold at least as many "
+            f"rows as A has columns; {m} rows give a chip {A.local_rows}, "
+            f"A has {k} columns")
+    prec = _hi(precision)
 
     def f(a):
-        q1, r1 = jnp.linalg.qr(a, mode="reduced")        # (lr,kk),(kk,k)
-        rs = lax.all_gather(r1, ("mr", "mc"), axis=0)    # VC rank order
-        kk = r1.shape[0]
-        stacked = rs.reshape(p * kk, k)
-        q2, R = jnp.linalg.qr(stacked, mode="reduced")   # (p*kk,k),(k,k)
-        vc = lax.axis_index("mc") + r * lax.axis_index("mr")
-        q2b = lax.dynamic_slice_in_dim(q2, vc * kk, kk, axis=0)
-        return q1 @ q2b, R
+        with jax.named_scope("k00/local"):
+            _metrics.inc("tsqr_leaf")
+            R, Vh, Vb, T = _tall_qr(a, prec)
+        C = jnp.eye(k, dtype=a.dtype)       # this chip's block of the stack's Q
+        if g.size > 1:
+            with jax.named_scope("k00/tree"):
+                R, Vh2, Vb2, T2 = _tsqr_tree_stage(R, g, prec)
+            with jax.named_scope("k00/applyq"):
+                Q2 = _tall_explicit_q(Vh2, Vb2, T2, C, g.size * k, prec)
+                C = lax.dynamic_slice_in_dim(
+                    Q2, rank_of(VC, g.height, g.width) * k, k, axis=0)
+        with jax.named_scope("k00/applyq"):
+            return _tall_explicit_q(Vh, Vb, T, C, a.shape[0], prec), R
 
-    # float32-accurate dots: the TPU default would run the local QRs' and the
-    # Q1*Q2 product's matmuls in bf16
-    with jax.default_matmul_precision("highest"):
-        Qs, Rs = shard_map(
-            f, mesh=g.mesh, in_specs=(A.spec,),
-            out_specs=(A.spec, P(None, None)), check_vma=False,
-        )(A.local)
+    Qs, Rs = shard_map(
+        f, mesh=g.mesh, in_specs=(A.spec,),
+        out_specs=(A.spec, PartitionSpec(None, None)), check_vma=False,
+    )(A.local)
     Q = DistMatrix(Qs, (m, k), VC, STAR, 0, 0, g)
     R = DistMatrix(Rs, (k, k), STAR, STAR, 0, 0, g)
     return Q, R
+
+
+def _takes_tall_route(A: DistMatrix, B: DistMatrix, abft) -> bool:
+    """The rule of :func:`least_squares`, held to what was measured on the
+    chip (PERF.md 6, PR 43: 8,388,608 x 256 on 2x2): a grid of several
+    chips (on one chip nothing is gathered by the blocked route either),
+    every chip's slab of A, m / p rows, at least ``_TALL_ASPECT`` times
+    taller than A is wide, at most ``_TALL_MAX_COLS`` columns, B no wider
+    than A, real entries, and no checksum guard asked for (the guard is
+    the blocked route's)."""
+    m, n = A.gshape
+    return (not abft and A.grid.size > 1 and 0 < n <= _TALL_MAX_COLS
+            and m >= _TALL_ASPECT * n * A.grid.size
+            and B.gshape[1] <= n
+            and jnp.issubdtype(A.dtype, jnp.floating)
+            and A.dtype == B.dtype
+            and (A.calign, A.ralign, B.calign, B.ralign) == (0, 0, 0, 0))
+
+
+@_scoped("el.tsqr")
+def _least_squares_tall(A: DistMatrix, B: DistMatrix, precision):
+    """The tall-skinny route of :func:`least_squares`, on a grid of
+    several chips: rows to the chips
+    ([MC,MR] -> [VC,STAR], once for A and once for B), each chip's
+    Householder QR of its own rows and its n rows of Q^T B, one
+    all-gather of the p R factors (and one of the p blocks of Q^T B), the
+    QR of the stack the same on every chip, and R X = Y solved there."""
+    g = A.grid
+    n, nrhs = A.gshape[1], B.gshape[1]
+    prec = _hi(precision)
+    Av = redistribute(A, VC, STAR)
+    Bv = redistribute(B, VC, STAR)
+
+    def f(a, b):
+        with jax.named_scope("k00/local"):
+            _metrics.inc("tsqr_leaf")
+            R, Vh, Vb, T = _tall_qr(a, prec)
+        with jax.named_scope("k00/applyq"):
+            Y = _tall_apply_qt(Vh, Vb, T, b, prec)
+        with jax.named_scope("k00/tree"):
+            R, Vh, Vb, T = _tsqr_tree_stage(R, g, prec)
+        with jax.named_scope("k00/applyq"):
+            Ys = lax.all_gather(Y, ("mr", "mc"), axis=0)
+            Y = _tall_apply_qt(Vh, Vb, T, Ys.reshape(g.size * n, nrhs), prec)
+        with jax.named_scope("k00/solve"):
+            return lax.linalg.triangular_solve(
+                R, Y, left_side=True, lower=False)
+
+    X = shard_map(f, mesh=g.mesh, in_specs=(Av.spec, Bv.spec),
+                  out_specs=PartitionSpec(None, None), check_vma=False)(
+                      Av.local, Bv.local)
+    return redistribute(DistMatrix(X, (n, nrhs), STAR, STAR, 0, 0, g),
+                        MC, MR)

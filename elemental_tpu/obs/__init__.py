@@ -46,9 +46,10 @@ time by them.  A path is made of:
   ``el.<driver>``          a public driver: ``cholesky``, ``lu``, ``qr``,
                            ``gemm``, ``herk``, ``trsm``, ``herm_eig`` and
                            its three stages ``hermitian_tridiag``,
-                           ``tridiag_eig``, ``apply_q_herm_tridiag``
-                           (:func:`scoped`); the one-device paths carry
-                           the same names
+                           ``tridiag_eig``, ``apply_q_herm_tridiag``,
+                           ``least_squares`` and, inside it or alone,
+                           ``tsqr`` (:func:`scoped`); the one-device
+                           paths carry the same names
   ``k<step>/<phase>``      inside a driver: the step, two digits or more
                            (``k03``, ``k117``), then a phase of
                            :data:`PHASES` -- ``diag``, ``panel``,
@@ -79,7 +80,17 @@ time by them.  A path is made of:
                            products and their stores), ``level`` counting
                            merges from the leaves up;
                            ``apply_q_herm_tridiag`` names
-                           ``k<panel>/apply``.  A nested driver or local
+                           ``k<panel>/apply``.  The tall-skinny route
+                           (``el.least_squares/el.tsqr`` and ``el.tsqr``
+                           alone) has one step, ``k00``, and four phases:
+                           ``local`` (the chip's Householder QR of the
+                           rows it holds), ``tree`` (the all-gather of
+                           the p R factors and the QR of their stack),
+                           ``applyq`` (Q^T B from the reflectors, the
+                           chip's own and then the stack's; in ``tsqr``
+                           the explicit Q) and ``solve`` (R X = Y); the
+                           two hops beside them read
+                           ``el.redist.MC_MR.to.VC_STAR``.  A nested driver or local
                            finish nests its own (``k14/tail/k00/diag``,
                            ``k05/merge/el.gemm/k00/panel``):
                            the FIRST ``k<step>`` gives an op its phase
@@ -154,6 +165,15 @@ not tick again).  Read them under ``metrics_scope()``:
                            (32 at n = 16384 with the defaults; none where
                            every merge is replicated or ``vectors`` is off)
   ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
+  ``lstsq_route{kind}``    one ``least_squares``: ``kind`` ``tall`` (every
+                           chip factors its own rows: ``lapack/qr.py:
+                           _takes_tall_route``) | ``blocked`` (``qr``,
+                           ``apply_q``, ``trsm``)
+  ``tsqr_leaf``            one chip-local QR of the tall route (the
+                           ``shard_map`` body is traced once: one a solve)
+  ``tsqr_tree_bytes``      bytes of the R factors a chip receives in the
+                           tree's all-gather: p n^2 itemsize (1,048,576 at
+                           n = 256 on 2x2); nothing on one chip
 
 Counters of the compile log (:mod:`.compile_log`), ticked on the current
 registry whenever JAX compiles, in any mode:

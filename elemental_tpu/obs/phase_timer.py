@@ -53,7 +53,8 @@ SCHEMA = "phase_timings/v1"
 #: leaf/secular/fill/merge in the divide and conquer, apply in the
 #: back-transform)
 PHASES = ("diag", "panel", "swap", "solve", "spread", "update", "tail",
-          "hemv", "leaf", "secular", "fill", "merge", "apply")
+          "hemv", "leaf", "secular", "fill", "merge", "apply",
+          "local", "tree", "applyq")
 
 
 class PhaseTimer(PhaseHook):

@@ -14,6 +14,8 @@ _CASES = [
     ("cholesky.py", ["--n", "96"], ["factor_resid", "solve_resid"]),
     ("lu.py", ["--n", "96"], ["factor_resid"]),
     ("qr_least_squares.py", ["--m", "120", "--n", "40"], ["lstsq_err"]),
+    ("tall_least_squares.py", ["--m", "262144", "--n", "4"],
+     ["lstsq_err", "tall"]),
     ("herm_eig.py", ["--n", "80"], ["resid", "orth"]),
     ("svd.py", ["--m", "90", "--n", "40"], ["reconstruct", "sv_err"]),
     ("lp.py", ["--m", "10", "--n", "24"], ["rel_gap"]),
@@ -52,5 +54,7 @@ def test_example(script, argv, metrics, capsys):
         val = out.split(f"{key}=")[1].split()[0].rstrip(")")
         if val not in ("True", "False"):
             assert abs(float(val)) < 1e-3, (key, val, out)
+        if key == "tall":
+            assert val == "True", out
     if "converged=" in out:
         assert "converged=True" in out, out
