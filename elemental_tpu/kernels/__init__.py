@@ -1,5 +1,6 @@
-"""Fused Pallas panel kernels for the factorization critical path
-(ISSUE 17).
+"""Pallas kernels: the fused panel kernels of the factorization critical
+path (ISSUE 17) and the one-pass triangle ``symv`` of the
+tridiagonalization's column loop (ISSUE 44).
 
 Panel factorization is the serial spine of every blocked schedule: the
 LU chunk ladder, the Cholesky diagonal-block factor/inverse pair, and
@@ -16,8 +17,15 @@ resident in VMEM:
 * :func:`qr_panel` -- larfg reflector chain + larft T build, twin of
   ``lapack.qr._panel_qr`` + ``_larft`` (residual-bounded).
 
-Selection is driven by the ``panel_impl='xla'|'pallas'|'auto'`` knob on
-``lu`` / ``cholesky`` / ``qr``: :func:`resolve_panel` turns the
+Beside them, :func:`symv_lower` -- ``(tril(A) + stril(A)^T) x`` from one
+read of the stored lower triangle, a grid over its tiles alone -- is what
+``lapack.condense.hermitian_tridiag`` multiplies by once a column on one
+TPU chip (no knob: the driver decides from its input,
+``condense._reads_triangle_once``), and the first kernel of this package
+a benchmark cell runs (``heig.1x1.b2b``).
+
+The panel kernels are selected by the ``panel_impl='xla'|'pallas'|'auto'``
+knob on ``lu`` / ``cholesky`` / ``qr``: :func:`resolve_panel` turns the
 resolved knob into a :class:`PanelPlan`, and each call site asks
 ``plan.use_pallas(shape, dtype)`` -- a STATIC trace-time gate that
 falls back to the XLA twin for complex dtypes and for panels whose
@@ -43,6 +51,7 @@ from .common import (LANE, PANEL_VMEM_BUDGET, PANEL_VMEM_LIMIT, SUBLANE,
 from .lu_panel import lu_panel
 from .chol_panel import potrf_inv
 from .qr_panel import qr_panel
+from .symv import symv_lower
 
 #: implementations the ``panel_impl`` knob enumerates ('auto' resolves
 #: to one of these); 'xla' first, so ties in the tuner's cost ranking

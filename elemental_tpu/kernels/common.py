@@ -91,11 +91,14 @@ def kernel_trace(interpret: bool):
     return contextlib.nullcontext() if interpret else jax.enable_x64(False)
 
 
-def compiler_params():
-    """Mosaic parameters every panel kernel is compiled with: the scoped
-    VMEM limit is raised from the compiler's default to
-    :data:`PANEL_VMEM_LIMIT`."""
-    return pltpu.CompilerParams(vmem_limit_bytes=PANEL_VMEM_LIMIT)
+def compiler_params(dimension_semantics=None):
+    """Mosaic parameters every kernel is compiled with: the scoped VMEM
+    limit is raised from the compiler's default to
+    :data:`PANEL_VMEM_LIMIT`.  A kernel with a grid names each grid
+    dimension's semantics (``arbitrary``: the steps run in order and may
+    carry state from one to the next)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=PANEL_VMEM_LIMIT,
+                                dimension_semantics=dimension_semantics)
 
 
 def pad_tiles(x):

@@ -6,14 +6,27 @@ panel, then a Her2k-style two-sided trailing update -- SURVEY.md §4.5) and
 ``condense/Hessenberg/**`` (``El::Hessenberg``).
 
 TPU-first design: the reduction panel loop is ONE jitted ``lax.fori_loop``
-per panel (LAPACK ``latrd`` semantics).  Once a panel the fixed trailing
-view is made Hermitian-full (its stored lower triangle mirrored above the
-diagonal: one transpose exchange); per column the only distributed work is
-then a single plain :func:`~elemental_tpu.blas.level2.gemv` against that
-full view, ONE read of it a column (the reference's distributed Hemv reads
-one triangle through two accumulators, [MC,STAR] and [MR,STAR]; here the
-second is paid once a panel instead of once a column); the V/W panels live
-replicated (n x nb -- small).  The
+per panel (LAPACK ``latrd`` semantics); per column the only work against
+the trailing matrix is one matvec with the panel's FIXED trailing view,
+and it takes one of two forms, chosen once from the input
+(:func:`_reads_triangle_once`):
+
+* on ONE TPU chip, real float32: the view stays as stored and the
+  one-pass triangle ``symv`` kernel (:mod:`~elemental_tpu.kernels.symv`)
+  reads its lower triangle ONCE a column, using every tile it loads for
+  ``A_ij x_j`` and for ``A_ij^T x_i`` (the reference's Hemv reads one
+  triangle through two accumulators, [MC,STAR] and [MR,STAR]: the same
+  two products).  The kernel reads the view through its TRANSPOSE: the
+  TPU compiler holds the working matrix column-major, so the transpose is
+  the same bytes row-major, the layout a kernel's operand has;
+* everywhere else (grids of several chips, complex entries, the CPU):
+  once a panel the view is made Hermitian-full (its stored lower triangle
+  mirrored above the diagonal: one transpose exchange) and per column a
+  single plain :func:`~elemental_tpu.blas.level2.gemv` reads that full
+  view, ONE read of the square a column; the second accumulator is paid
+  once a panel instead of once a column.
+
+The V/W panels live replicated (n x nb -- small).  The
 trailing update ``A22 -= V W^H + W V^H`` is one masked storage matmul on
 the MXU (exactly the reference's rank-2k update), so all O(n^3/MXU-friendly)
 FLOPs are large matmuls and all latency-bound work is batched into one
@@ -44,6 +57,7 @@ from ..blas.level1 import _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle
 from ..obs import metrics as _metrics
 from ..obs.tracer import NULL_HOOK
+from ..kernels.symv import symv_lower
 from .lu import _update_cols_lt, _hi, _phase_hook, _scoped
 from .qr import _larft
 
@@ -100,23 +114,41 @@ def _hermitian_full(Atrail: DistMatrix) -> DistMatrix:
                                        Atrail.local, mirror.local))
 
 
-@partial(jax.jit, static_argnums=(2, 3, 4, 5))
-def _tridiag_panel(Afull: DistMatrix, P, nbw: int, extract_last: bool,
-                   precision, step: int):
+def _reads_triangle_once(A: DistMatrix) -> bool:
+    """The rule of :func:`hermitian_tridiag`'s matvec, from what the input
+    shows: the grid is ONE chip (an element-cyclic shard of a Hermitian
+    matrix is not locally symmetric), the chip is a TPU (true of a described
+    topology too, so a rehearsal takes the path; on the CPU the kernel would
+    be interpreted once a column) and the entries are real float32 (Mosaic
+    has no complex type; the kernel's products and sums are float32 on the
+    VPU, no lower than any ``precision``).  Then the column loop's matvec is
+    the one-pass triangle ``symv`` kernel on the trailing view AS STORED and
+    nothing is mirrored (measured, PERF.md 6, PR 44); everything else
+    mirrors the view once a panel and reads the full square."""
+    return (A.grid.size == 1 and A.grid.devices[0].platform == "tpu"
+            and A.dtype == jnp.float32)
+
+
+@partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
+                   precision, step: int, symv: bool):
     """latrd: reduce ``nbw`` columns of the trailing matrix.
 
-    ``Afull`` is the fixed (nt, nt) [MC,MR] trailing view made Hermitian
-    (:func:`_hermitian_full`); ``P`` the replicated panel columns.  Returns
-    (V, W, d, e, tau) with V/W the (nt, nbw) replicated reflector/update
-    panels.
+    ``Atrail`` is the panel's fixed (nt, nt) [MC,MR] trailing view in the
+    form its matvec reads: with ``symv`` AS STORED, the one chip's array
+    whose lower triangle the kernel reads once a column (what lies above
+    the diagonal is never read); without, made Hermitian-full
+    (:func:`_hermitian_full`) and read whole by one ``gemv`` a column.
+    ``P`` the replicated panel columns.  Returns (V, W, d, e, tau) with
+    V/W the (nt, nbw) replicated reflector/update panels.
 
     The column loop names its ops ``k<step>/hemv`` (the one matvec against
-    the full trailing view) and ``k<step>/panel`` (all the rest), side by
+    the trailing view) and ``k<step>/panel`` (all the rest), side by
     side: this is one jitted loop, so the names are all a phase is here.
     """
     tm = NULL_HOOK
-    nt = Afull.gshape[0]
-    g = Afull.grid
+    nt = Atrail.gshape[0]
+    g = Atrail.grid
     dtype = P.dtype
     rdtype = _real_dtype(dtype)
     ridx = jnp.arange(nt)
@@ -132,12 +164,17 @@ def _tridiag_panel(Afull: DistMatrix, P, nbw: int, extract_last: bool,
             d = d.at[jj].set(jnp.real(col[jj]).astype(rdtype))
             v, tau_j, beta = _larfg_tail(col, jj, ridx, dtype)
             e = e.at[jj].set(beta.astype(rdtype))
-        # the one distributed op per column: u = A_trail v, one read of the
-        # full view (v's leading zeros make this the reference's A22*v on
-        # the true subproblem)
+        # the one op per column against the trailing matrix: u = A_trail v,
+        # one read of the view (v's leading zeros make this the reference's
+        # A22*v on the true subproblem)
         with tm.phase("hemv", step):
-            u = _unwrap_vec(gemv(Afull, _wrap_vec(v, g),
-                                 precision=_hi(precision)))
+            if symv:
+                # compiled wherever the chip is a TPU, a described one too
+                u = symv_lower(Atrail.local, v,
+                               interpret=g.devices[0].platform != "tpu")
+            else:
+                u = _unwrap_vec(gemv(Atrail, _wrap_vec(v, g),
+                                     precision=_hi(precision)))
         with tm.phase("panel", step):
             u = u - V @ (jnp.conj(W).T @ v) - W @ (jnp.conj(V).T @ v)
             w = tau_j * u
@@ -182,12 +219,16 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     and ``Q = H_0 H_1 ... H_{n-2}`` packed in ``Ap``'s lower triangle
     (``El::HermitianTridiag``).
 
-    Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (once a
-    panel the trailing view's mirror into a full Hermitian matrix, then
-    the column loop's one matvec against it), ``panel`` (the rest of the
-    column loop and the packed panel's store), ``update`` (the rank-2k
-    trailing update and its four hops); ``herm_tridiag_panel`` counts the
-    panels and ``herm_tridiag_symmetrize`` the mirrors (one a panel).
+    Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (the matvec's
+    operand, made once a panel outside the column loop, and the loop's one
+    matvec against it: on one TPU chip the slice of the trailing view as
+    stored and the ``el_symv_lower`` kernel; elsewhere the view's mirror
+    into a full Hermitian matrix and a ``gemv``), ``panel``
+    (the rest of the column loop and the packed panel's store), ``update``
+    (the rank-2k trailing update and its hops); ``herm_tridiag_panel`` counts
+    the panels, ``herm_tridiag_hemv{impl}`` which matvec each took (``symv`` |
+    ``mirror``) and ``herm_tridiag_symmetrize`` the mirrors (one a panel
+    on the mirror path, none on the other).
     """
     _check_mcmr(A)
     n = A.gshape[0]
@@ -209,6 +250,7 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     ib = _blocksize(nb, math.lcm(r, c), n)
     kend = n - 1                          # reflector columns 0 .. n-2
     tm = _phase_hook("hermitian_tridiag")
+    symv = _reads_triangle_once(A)
     Ap = A
     d_parts, e_parts, tau_parts = [], [], []
     s = 0
@@ -220,14 +262,17 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
         final = e_col == kend
         wp_end = n if final else min(round_up(e_col, c), n)
         # once a panel, never once a column: the matvec's operand
-        _metrics.inc("herm_tridiag_symmetrize")
+        _metrics.inc("herm_tridiag_hemv", impl="symv" if symv else "mirror")
         with tm.phase("hemv", k) as ph:
-            Afull = _hermitian_full(view(Ap, rows=(s, n), cols=(s, n)))
-            ph.done(Afull.local)
+            Atrail = view(Ap, rows=(s, n), cols=(s, n))
+            if not symv:
+                _metrics.inc("herm_tridiag_symmetrize")
+                Atrail = _hermitian_full(Atrail)
+            ph.done(Atrail.local)
         P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)), STAR, STAR).local
         # the column loop names its own phases (hemv beside panel)
-        V, W, dpan, epan, taupan = _tridiag_panel(Afull, P, nbw, final,
-                                                  precision, k)
+        V, W, dpan, epan, taupan = _tridiag_panel(Atrail, P, nbw, final,
+                                                  precision, k, symv)
         d_parts.append(dpan)
         e_parts.append(epan)
         tau_parts.append(taupan)

@@ -103,10 +103,14 @@ def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     ``el.tridiag_eig/k<level>/{leaf,secular,fill,merge}`` (n above ``dc_min``;
     ``fill`` above ``repl_max``) and
     ``el.apply_q_herm_tridiag/k<panel>/apply``; the trace-time counters
-    ``herm_tridiag_panel``, ``herm_tridiag_symmetrize``, ``dc_merge{kind}``,
-    ``dc_fill_block`` and ``apply_q_panel`` count the panels, the mirrors of
-    the trailing view (one a panel), the merges and the eigenvector blocks
-    placed on the [MC,MR] matrix's diagonal between the two kinds of merge.
+    ``herm_tridiag_panel``, ``herm_tridiag_hemv{impl}``,
+    ``herm_tridiag_symmetrize``, ``dc_merge{kind}``, ``dc_fill_block`` and
+    ``apply_q_panel`` count the panels, which matvec each took (on one TPU
+    chip, real float32, the one-pass triangle ``symv`` kernel:
+    ``impl=symv``, nothing mirrored; elsewhere ``impl=mirror``), the
+    mirrors of the trailing view (one a panel on that path), the merges
+    and the eigenvector blocks placed on the [MC,MR] matrix's diagonal
+    between the two kinds of merge.
     """
     _check_mcmr(A)
     n = A.gshape[0]

@@ -59,9 +59,13 @@ time by them.  A path is made of:
                            the masked copy of the operand it factors
                            in).  The Hermitian eigensolve adds six:
                            ``hermitian_tridiag`` names ``k<panel>/hemv``
-                           (once a panel the trailing view's mirror
-                           into a full Hermitian matrix, then the column
-                           loop's one matvec against it) BESIDE
+                           (the matvec's operand, made once a panel,
+                           then the column loop's one matvec against it:
+                           on one TPU chip the slice of the trailing
+                           view as stored and the ``el_symv_lower``
+                           kernel, which reads its lower triangle once;
+                           elsewhere the view's mirror into a full
+                           Hermitian matrix and a ``gemv``) BESIDE
                            ``k<panel>/panel`` (the
                            rest of the column loop, the packed panel's
                            store) and ``k<panel>/update`` (the rank-2k
@@ -103,9 +107,13 @@ time by them.  A path is made of:
                            unpack / reshape / copy beside them;
                            ``el.redist.panel_spread`` and
                            ``el.redist.row_permute`` likewise
-  ``el_potrf_inv_panel`` / the ``name=`` of the three Pallas panel
-  ``el_lu_panel`` /        kernels (``kernels/``), which is how a trace
-  ``el_qr_panel``          shows a ``pallas_call``
+  ``el_potrf_inv_panel`` / the ``name=`` of the Pallas kernels
+  ``el_lu_panel`` /        (``kernels/``: the three panel kernels and the
+  ``el_qr_panel`` /        one-pass triangle ``symv``), which is how a
+  ``el_symv_lower``        trace shows a ``pallas_call``: the optimized
+                           HLO's instruction is ``%el_symv_lower.<n>``,
+                           a ``tpu_custom_call`` whose ``op_name`` ends
+                           ``.../k<panel>/hemv/el_symv_lower/pallas_call``
 
 An op in an ``el.`` scope but outside any phase (a driver's final
 assembly or mask) belongs to the driver; an op with no ``el.`` segment
@@ -150,10 +158,16 @@ not tick again).  Read them under ``metrics_scope()``:
                            device receives: every row crossing chips)
   ``herm_tridiag_panel``   one panel of ``hermitian_tridiag`` (a column
                            loop and, but for the last, a rank-2k update)
+  ``herm_tridiag_hemv{impl}``   one panel's choice of matvec: ``impl``
+                           ``symv`` (one TPU chip, real float32: the
+                           ``el_symv_lower`` kernel on the view as
+                           stored; 64 at n = 16384, nb 256) | ``mirror``
+                           (everything else: ``lapack/condense.py:
+                           _reads_triangle_once``)
   ``herm_tridiag_symmetrize``   one mirror of a panel's trailing view
                            into a full Hermitian matrix, the operand of
-                           the column loop's matvec: one a panel (64 at
-                           n = 16384, nb 256), never one a column
+                           the mirror path's ``gemv``: one a panel there,
+                           never one a column; none on the ``symv`` path
   ``dc_merge{kind}``       one merge of ``tridiag_eig``: ``kind``
                            ``replicated`` (a level of the vmapped batch
                            ticks once for each of its merges) |

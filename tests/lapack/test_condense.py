@@ -124,10 +124,63 @@ def test_mirror_keeps_the_stored_diagonal(grid_name, monkeypatch):
     # a NEW function object: jax keys its trace cache by the function
     panel = condense._tridiag_panel.__wrapped__
     monkeypatch.setattr(condense, "_tridiag_panel", jax.jit(
-        lambda *args: panel(*args), static_argnums=(2, 3, 4, 5)))
+        lambda *args: panel(*args), static_argnums=(2, 3, 4, 5, 6)))
     want = hermitian_tridiag(Ad, nb=8)
     for w, g in zip(_stored(want), _stored(got)):
         np.testing.assert_allclose(g, w, rtol=0, atol=1e-12 * np.abs(w).max())
+
+
+# the one-chip path through the one-pass triangle symv kernel (ISSUE 44)
+
+@pytest.mark.parametrize("n,nb", [(320, 64), (257, 64)])
+def test_symv_path_gives_the_mirror_paths_reduction(n, nb, monkeypatch):
+    """Nothing mirrored, the kernel on the view as stored: ``d``, ``e``,
+    ``tau`` and the packed lower triangle are the mirror path's to
+    rounding.  Float64, because the reduction amplifies a rounding along
+    the columns (float32 moves the last ``d`` by 1e-3 here, and both
+    tridiagonal matrices have ``A``'s eigenvalues to 1e-6: the test
+    below); garbage above the diagonal must not matter on either path."""
+    A = _herm(n, jnp.float64, seed=n)
+    A[np.triu_indices(n, 1)] = np.nan
+    Ad = from_global(A, MC, MR, _grid("1x1"))
+    want = _stored(hermitian_tridiag(Ad, nb=nb))
+    # the path of one TPU chip; the grid is the CPU's, so the kernel is
+    # interpreted.  (The choice is a static argument of the jitted panel:
+    # nothing traced for the mirror path is handed back.)
+    monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
+    with obs.metrics_scope() as reg:
+        got = _stored(hermitian_tridiag(Ad, nb=nb))
+    panels = -(-(n - 1) // nb)
+    assert dict(reg.counters("herm_tridiag_hemv")) == {
+        ("herm_tridiag_hemv", (("impl", "symv"),)): panels}
+    assert not reg.counters("herm_tridiag_symmetrize")
+    for w, g in zip(want, got):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-9 * np.abs(w).max())
+
+
+def test_herm_eig_through_the_symv_path_agrees_with_numpy(monkeypatch):
+    from elemental_tpu.lapack.spectral import herm_eig
+    monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
+    n = 129
+    A = _herm(n, jnp.float32, seed=44)
+    w = herm_eig(from_global(A, MC, MR, _grid("1x1")), nb=64, vectors=False)
+    want = np.linalg.eigvalsh(A.astype(np.float64))
+    assert np.abs(np.asarray(w, np.float64) - want).max() <= (
+        50 * np.finfo(np.float32).eps * np.abs(want).max())
+
+
+@pytest.mark.parametrize("chips,platform,dtype,want", [
+    (1, "tpu", jnp.float32, True),
+    (4, "tpu", jnp.float32, False),     # a shard is not locally symmetric
+    (1, "cpu", jnp.float32, False),     # an interpreted kernel a column
+    (1, "tpu", jnp.float64, False),
+    (1, "tpu", jnp.complex64, False),   # Mosaic has no complex type
+    (1, "tpu", jnp.bfloat16, False)])
+def test_the_rule_reads_the_grid_and_the_dtype(chips, platform, dtype, want):
+    from types import SimpleNamespace as NS
+    A = NS(grid=NS(size=chips, devices=[NS(platform=platform)] * chips),
+           dtype=jnp.dtype(dtype))
+    assert condense._reads_triangle_once(A) is want
 
 
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.complex128])

@@ -64,7 +64,7 @@ def _fresh_inner_jits():
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(condense, "_tridiag_panel", jax.jit(
             anew(condense._tridiag_panel.__wrapped__),
-            static_argnums=(2, 3, 4, 5)))
+            static_argnums=(2, 3, 4, 5, 6)))
         patch.setattr(tridiag_eig, "_tridiag_eig_jit", jax.jit(
             anew(tridiag_eig._tridiag_eig_jit.__wrapped__),
             static_argnums=(2, 3, 4, 5, 6, 7)))
@@ -88,7 +88,8 @@ def compiled(grid_name, n):
     counts = {name: {labels: v for (_name, labels), v
                      in reg.counters(name).items()}
               for name in ("herm_tridiag_panel", "herm_tridiag_symmetrize",
-                           "dc_merge", "dc_fill_block", "apply_q_panel")}
+                           "herm_tridiag_hemv", "dc_merge", "dc_fill_block",
+                           "apply_q_panel")}
     return exe, counts
 
 
@@ -179,6 +180,17 @@ def test_counters_read_the_panels_and_the_merges():
         assert counts["dc_merge"] == {(("kind", "replicated"),): 2,
                                       (("kind", "distributed"),): 1}
         assert counts["dc_fill_block"] == {(): 2}
+
+
+def test_cpu_backend_and_grids_take_the_mirror_path():
+    """ISSUE 44: the one-pass triangle ``symv`` kernel is the matvec of ONE
+    TPU chip; on the CPU backend (an interpreted kernel a column) and on a
+    grid of several chips (a shard is not locally symmetric) every panel
+    says ``impl=mirror`` and the program holds no kernel."""
+    for grid_name in GRIDS:
+        exe, counts = compiled(grid_name, 320)
+        assert counts["herm_tridiag_hemv"] == {(("impl", "mirror"),): 5}
+        assert "el_symv_lower" not in exe.as_text()
 
 
 def test_phases_are_in_the_canonical_list():

@@ -1,7 +1,8 @@
 """The three fused panel kernels, compiled by the TPU's own compiler for
 a DESCRIBED v5e chip (no chip attached, nothing runs): at the widths
 ``chip_smoke.py`` gives them and at the largest shapes their VMEM gate
-admits.  A kernel body that Mosaic refuses -- a primitive with no
+admits; and the one-pass triangle ``symv`` at the eigensolve cell's
+views.  A kernel body that Mosaic refuses -- a primitive with no
 lowering, a slice off the (8, 128) tiling, more VMEM than the compiler
 grants -- fails here, at no chip time.  And the redistribution engine's
 local unpacks at the 2x2 benchmark cell's shapes, for the described 2x2:
@@ -20,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from elemental_tpu.kernels import (PANEL_VMEM_BUDGET, PanelPlan, lu_panel,
-                                   potrf_inv, qr_panel)
+                                   potrf_inv, qr_panel, symv_lower)
 
 PLAN = PanelPlan(impl="pallas")
 
@@ -54,9 +55,10 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _compile(fn, shape, one_chip):
-    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
-    text = jax.jit(fn).lower(x).compile().as_text()
+def _compile(fn, *shapes, one_chip):
+    args = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            for shape in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
 
 
@@ -86,7 +88,16 @@ _QR = lambda p: qr_panel(p, interpret=False)                         # noqa: E73
 def test_kernel_compiles_for_v5e(fn, shape, copies, one_chip):
     assert PLAN.use_pallas(shape, jnp.float32, copies=copies), \
         "the gate sends this shape to XLA; the case no longer tests a kernel"
-    _compile(fn, shape, one_chip)
+    _compile(fn, shape, one_chip=one_chip)
+
+
+@pytest.mark.parametrize("nt", [16384, 768, 257])
+def test_symv_kernel_compiles_for_v5e(nt, one_chip):
+    """The eigensolve cell's first view and a late one (768 is no multiple
+    of the 512-tile: a ragged edge), and an order that is no multiple of
+    anything (one edge block past both extents)."""
+    _compile(lambda a, x: symv_lower(a, x, interpret=False), (nt, nt), (nt,),
+             one_chip=one_chip)
 
 
 @pytest.mark.parametrize("shape,copies", [
@@ -108,7 +119,7 @@ def test_compilers_default_vmem_grant_refuses_a_gate_corner(one_chip,
     with pytest.raises(Exception, match="(?i)vmem"):
         # a fresh function: jit would hand back the earlier compile of _CHOL
         _compile(lambda d: potrf_inv(d, interpret=False), (1024, 1024),
-                 one_chip)
+                 one_chip=one_chip)
 
 
 # ---------------------------------------------------------------------
@@ -399,33 +410,57 @@ def test_move_rows_plans_half_a_shard_and_one_all_reduce(grid22):
 
 
 def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
-    """The whole donated ``jit(herm_eig)`` at n = 1024, nb = 256 (ISSUE 38;
-    10 s).  For the column loop's transposed matvec the TPU compiler
-    re-laid the panel's FIXED trailing view out inside the ``while`` body,
-    ``copy f32[nt,nt]{1,0} -> {0,1}`` once a COLUMN, and two fusions read
-    the view: 16 nt^2 bytes a column where 4 nt^2 do the work, 34.2 of the
-    41.9 s of ``heig.1x1.b2b`` (the CPU backend assigns layouts otherwise
-    and never showed it).  With the view made Hermitian-full once a panel
-    every column loop holds ONE fusion that reads an nt x nt array and no
-    ``copy``, ``transpose`` or ``select`` that makes one."""
+    """The whole donated ``jit(herm_eig)`` at n = 1024, nb = 256, for ONE
+    described v5e chip (ISSUE 38, ISSUE 44; a minute).  Before PR 38 the TPU
+    compiler re-laid the panel's FIXED trailing view out inside the
+    ``while`` body, ``copy f32[nt,nt]{1,0} -> {0,1}`` once a COLUMN (34.2 of
+    the 41.9 s of ``heig.1x1.b2b``; the CPU backend assigns layouts
+    otherwise and never showed it); PR 38's one ``gemv`` against a mirrored
+    view read the full square, twice the stored triangle's bytes (7.86 of
+    9.69 s).  On one TPU chip every column loop now holds exactly ONE
+    ``tpu_custom_call``, the one-pass triangle ``symv`` kernel under
+    ``k<panel>/hemv``, which is the view's only reader: no ``copy``,
+    ``transpose``, ``select`` or ``slice`` makes an nt x nt array there, no
+    fusion takes one as a parameter, no mirror's transposing exchange is
+    left in the reduction, and the kernel's row-major operand costs no
+    relayout anywhere: the compiler holds the working matrix COLUMN-major,
+    the kernel reads its operand through the transpose, and the program has
+    no ``copy`` or ``transpose`` of a panel's view at all (read as stored it
+    had one a panel, ``copy f32[nt,nt]{1,0}``, with no name)."""
     import elemental_tpu as el
+    from elemental_tpu import obs
     from .lapack.test_herm_eig_compiled import column_loops, square_ops
     n, nb = 1024, 256
     grid = el.Grid([topo.devices[0]])
     A = _abstract(grid, n, n, el.MC, el.MR)
-    text = jax.jit(lambda a: el.herm_eig(a, nb=nb),
-                   donate_argnums=0).lower(A).compile().as_text()
+    with obs.metrics_scope() as reg:
+        text = jax.jit(lambda a: el.herm_eig(a, nb=nb),
+                       donate_argnums=0).lower(A).compile().as_text()
+    assert dict(reg.counters("herm_tridiag_hemv")) == {
+        ("herm_tridiag_hemv", (("impl", "symv"),)): 4}
+    assert not reg.counters("herm_tridiag_symmetrize")
+    assert not re.search(
+        r'op_name="[^"]*/el\.hermitian_tridiag/[^"]*el\.redist\.MR_MC\.to\.MC_MR',
+        text)
+    everything = [(None, line.strip()) for line in text.splitlines()]
     loops = column_loops(text)
     assert sorted(loops) == [0, 1, 2, 3]
     for k, lines in loops.items():
         nt = n - k * nb
+        kernels = [line for _c, line in lines if "tpu_custom_call" in line]
+        assert len(kernels) == 1, (k, kernels)
+        assert re.match(r"%?el_symv_lower[.\d]* = ", kernels[0]), kernels[0]
+        assert re.search(rf'op_name="[^"]*/k{k:02d}/hemv/', kernels[0])
+        assert f"f32[{nt},{nt}]{{1,0}}" in kernels[0]      # row-major operand
         if nt == nb:                    # the panel's own blocks are nt x nt
             continue
-        assert not square_ops(lines, nt, ("copy", "transpose", "select")), k
-        readers = square_ops(
+        assert not square_ops(
+            lines, nt, ("copy", "transpose", "select", "slice")), k
+        assert not square_ops(
             [(c, line) for c, line in lines if "fused_computation" in c],
-            nt, ("parameter",))
-        assert len(readers) == 1, (k, readers)
+            nt, ("parameter",)), k
+        if k:               # (nt = n is also the order of A, Z and their copies)
+            assert not square_ops(everything, nt, ("copy", "transpose")), k
 
 
 def test_divide_and_conquer_hand_off_places_blocks_and_gathers_nothing(topo,
