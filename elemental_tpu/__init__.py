@@ -46,6 +46,7 @@ from .lapack import (cholesky, hpd_solve, cholesky_solve_after,
                      cholesky_pivoted, cholesky_mod)
 from .lapack import (lu, lu_solve, lu_solve_after, permute_rows,
                      permute_cols, lu_full_pivot)
+from .lapack import mixed_solve
 from .lapack import (qr, apply_q, explicit_q, least_squares, tsqr, lq,
                      apply_q_lq, explicit_l, qr_col_piv, rq)
 from .lapack import ridge, tikhonov, lse, glm

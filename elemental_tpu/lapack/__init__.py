@@ -4,6 +4,7 @@ from .cholesky import (cholesky, hpd_solve, cholesky_solve_after,
                        cholesky_pivoted, cholesky_mod)
 from .lu import (lu, lu_solve, lu_solve_after, permute_rows, permute_cols,
                  lu_full_pivot)
+from .mixed import mixed_solve
 from .qr import (qr, apply_q, explicit_q, least_squares, tsqr, lq,
                  apply_q_lq, explicit_l, qr_col_piv, rq)
 from .euclidean_min import ridge, tikhonov, lse, glm

@@ -646,7 +646,12 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
     docstring); ``update_precision`` optionally lowers ONLY the trailing
     ``L21 @ U12``
     updates (e.g. ``lax.Precision.DEFAULT`` for bf16-MXU throughput at a
-    documented ~1e-3 residual cost); ``timer`` enables eager per-phase
+    documented ~1e-3 residual cost, and nothing repairs it afterwards:
+    where the operand needs no pivoting, ``mixed_solve`` runs the same
+    one-pass updates with EXPLICITLY rounded operands, the same arithmetic
+    on every backend, and refines the answer on the device back to the
+    float32 level, 6e-9 where the unrefined one reads 4e-6 at n = 384;
+    ``lapack/mixed.py``); ``timer`` enables eager per-phase
     wall-clock attribution (``elemental_tpu.obs.PhaseTimer``).
 
     ``panel`` selects the panel strategy:

@@ -48,7 +48,7 @@ time by them.  A path is made of:
                            its three stages ``hermitian_tridiag``,
                            ``tridiag_eig``, ``apply_q_herm_tridiag``,
                            ``least_squares`` and, inside it or alone,
-                           ``tsqr`` (:func:`scoped`); the one-device
+                           ``tsqr``, ``lu_nopiv`` (:func:`scoped`); the one-device
                            paths carry the same names
   ``k<step>/<phase>``      inside a driver: the step, two digits or more
                            (``k03``, ``k117``), then a phase of
@@ -101,6 +101,25 @@ time by them.  A path is made of:
   ``el.hpd_solve`` /       the public solves, which open ``factor`` and
   ``el.lu_solve``          ``sweeps`` around their two stages, so a sweep
                            reads ``el.hpd_solve/sweeps/el.trsm/k02/solve``
+  ``el.mixed_solve``       the mixed-precision solve (``lapack/mixed.py``):
+                           ``factor`` around ``el.lu_nopiv`` (the LU
+                           without pivoting: ``k<step>/diag``, ``/panel``,
+                           ``/update`` on one chip, ``/panel``, ``/solve``,
+                           ``/update`` on a grid), ``sweeps`` around the
+                           FIRST solve's two ``el.trsm``, and ``el.refine``
+                           around the refinement, whose two phases are
+                           ``residual`` (``B - A X`` through a stationary-A
+                           ``el.gemm``, and the norms) and ``correct`` (the
+                           correction's two ``el.trsm`` and ``X += D``):
+                           ``el.refine/k00/residual`` once before the
+                           ``lax.while_loop`` and, opened INSIDE its body,
+                           ``el.refine/while/body/k01/correct`` and
+                           ``.../k01/residual``.  The first ``k<step>``
+                           gives an op its phase, so the nested ``trsm``
+                           and ``gemm`` read ``refine/correct`` and
+                           ``refine/residual``: not ``sweep`` (no
+                           ``sweeps`` segment stands over them), not
+                           ``update`` or ``panel``
   ``el.redist.<SRC>.to.<DST>``  every public ``redistribute`` entry
                            (``el.redist.MC_MR.to.VC_STAR``), around ALL
                            it emits: the collectives and the local pack /
@@ -179,6 +198,15 @@ not tick again).  Read them under ``metrics_scope()``:
                            (32 at n = 16384 with the defaults; none where
                            every merge is replicated or ``vectors`` is off)
   ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
+  ``lu_nopiv_step``        one step of the LU without pivoting
+                           (``lapack/mixed.py``: 16 at n = 32768, nb 2048)
+  ``mixed_update{dtype}``  one trailing update of it, with the dtype its
+                           operands were ROUNDED to before the product
+                           (``bfloat16`` in ``mixed_solve``: 15 there;
+                           ``float32`` where ``lu_nopiv(low=None)`` keeps
+                           them); the refinement's step count is not a
+                           counter: it is decided on the device and comes
+                           back as ``info["steps"]``
   ``lstsq_route{kind}``    one ``least_squares``: ``kind`` ``tall`` (every
                            chip factors its own rows: ``lapack/qr.py:
                            _takes_tall_route``) | ``blocked`` (``qr``,

@@ -51,10 +51,11 @@ SCHEMA = "phase_timings/v1"
 #: QR panel/update, gemm panel, trsm solve/update, herk spread/update;
 #: the Hermitian eigensolve names hemv/panel/update in the reduction,
 #: leaf/secular/fill/merge in the divide and conquer, apply in the
-#: back-transform)
+#: back-transform; the mixed-precision solve's refinement names
+#: residual/correct)
 PHASES = ("diag", "panel", "swap", "solve", "spread", "update", "tail",
           "hemv", "leaf", "secular", "fill", "merge", "apply",
-          "local", "tree", "applyq")
+          "local", "tree", "applyq", "residual", "correct")
 
 
 class PhaseTimer(PhaseHook):

@@ -13,6 +13,8 @@ _EX = os.path.join(os.path.dirname(__file__), os.pardir, "examples")
 _CASES = [
     ("cholesky.py", ["--n", "96"], ["factor_resid", "solve_resid"]),
     ("lu.py", ["--n", "96"], ["factor_resid"]),
+    ("mixed_solve.py", ["--n", "192", "--nb", "64"],
+     ["backward_error", "unrefined"]),
     ("qr_least_squares.py", ["--m", "120", "--n", "40"], ["lstsq_err"]),
     ("tall_least_squares.py", ["--m", "262144", "--n", "4"],
      ["lstsq_err", "tall"]),

@@ -27,6 +27,7 @@ CENSUS = {
              "comm_precision", "redist_path"},
     "hpd_solve": {"uplo", "nb", "precision", "info", "health"},
     "lu_solve": {"nb", "precision", "panel", "info", "health"},
+    "mixed_solve": {"nb", "precision", "max_steps"},
     "least_squares": {"nb", "precision", "abft"},
     "redistribute": {"calign", "ralign", "comm_precision", "path"},
 }
