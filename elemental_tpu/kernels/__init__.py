@@ -1,6 +1,7 @@
 """Pallas kernels: the fused panel kernels of the factorization critical
-path (ISSUE 17) and the one-pass triangle ``symv`` of the
-tridiagonalization's column loop (ISSUE 44).
+path (ISSUE 17), the one-pass triangle ``symv`` of the
+tridiagonalization's column loop (ISSUE 44) and the unpivoted block LU of
+the mixed-precision factor's diagonal blocks (ISSUE 46).
 
 Panel factorization is the serial spine of every blocked schedule: the
 LU chunk ladder, the Cholesky diagonal-block factor/inverse pair, and
@@ -22,7 +23,13 @@ read of the stored lower triangle, a grid over its tiles alone -- is what
 ``lapack.condense.hermitian_tridiag`` multiplies by once a column on one
 TPU chip (no knob: the driver decides from its input,
 ``condense._reads_triangle_once``), and the first kernel of this package
-a benchmark cell runs (``heig.1x1.b2b``).
+a benchmark cell runs (``heig.1x1.b2b``).  And :func:`lu_nopiv_block` --
+the unpivoted column recurrence of one square block, resident in VMEM:
+``lu_panel``'s unblocked mode without its pivot search, row swap and
+pivot output, twin of the ``fori_loop`` inside ``lapack.lu._lu_nopiv`` --
+is what ``lapack.mixed.lu_nopiv`` factors each sub-block of a diagonal
+block with on one TPU chip (no knob either:
+``mixed._diag_blocks_in_vmem``; cell ``hplmxp.1x1.b2b``).
 
 The panel kernels are selected by the ``panel_impl='xla'|'pallas'|'auto'``
 knob on ``lu`` / ``cholesky`` / ``qr``: :func:`resolve_panel` turns the
@@ -52,6 +59,7 @@ from .lu_panel import lu_panel
 from .chol_panel import potrf_inv
 from .qr_panel import qr_panel
 from .symv import symv_lower
+from .lu_nopiv_block import lu_nopiv_block
 
 #: implementations the ``panel_impl`` knob enumerates ('auto' resolves
 #: to one of these); 'xla' first, so ties in the tuner's cost ranking

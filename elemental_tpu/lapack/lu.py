@@ -337,12 +337,27 @@ def _tournament_pivots(P, nbw: int, r: int):
     return perm
 
 
-def _lu_nopiv(W, precision=None, bs: int = 256):
+def _lu_nopiv(W, precision=None, bs: int = 256, *, block_kernel=None):
     """Unpivoted blocked LU of a square block (packed L\\U, unit-lower L).
     The CALU diagonal factorization: the tournament already fixed the
     pivot order, so no argmax / row motion remains -- diagonal blocks run
     the plain recurrence, off-diagonal blocks are triangular solves and
-    one MXU matmul per step."""
+    one MXU matmul per step.
+
+    The recurrence of one ``bs x bs`` sub-block has two lowerings of ONE
+    algorithm.  ``unb`` is a ``lax.fori_loop`` of XLA ops: a divide, a
+    column store and an outer-product subtract a column, whose launches
+    and re-laid carry cost 4.2 us a column on a v5e.  With
+    ``block_kernel`` (the caller's static choice, from what ITS input
+    shows: ``lapack/mixed.py:_diag_blocks_in_vmem``; real blocks only) it
+    is that callable, ``kernels.lu_nopiv_block`` as the caller's chips run
+    it: the Pallas kernel ``el_lu_nopiv_block``, the sub-block resident in
+    VMEM for all its columns.  The blocked outer loop and the sub-block
+    order ``bs`` are the same in both: a kernel step costs the sub-block's
+    area in tile passes, the triangular solves and the matmul between
+    sub-blocks fall as 1 / bs, and the whole HPL-MxP solve on the chip
+    reads 0.36746 s at 128, 0.36744 at 256 and 0.36972 at 512 (PERF.md 6,
+    PR 46)."""
     b = W.shape[0]
 
     def unb(B):
@@ -357,6 +372,8 @@ def _lu_nopiv(W, precision=None, bs: int = 256):
 
         return lax.fori_loop(0, n, body, B)
 
+    if block_kernel is not None:
+        unb = block_kernel
     if b <= bs:
         return unb(W)
     for s in range(0, b, bs):

@@ -22,10 +22,15 @@ Two precisions, kept apart:
 
 The factor (:func:`lu_nopiv`) is a blocked right-looking loop over a COPY
 of A (the residual needs A): per step the diagonal block's unpivoted LU
-(``lu._lu_nopiv``), the two panels as one matmul each against the block's
-triangular inverses (``L21 = A21 U11^-1``, ``U12 = L11^-1 A12``), and the
-trailing update.  On one chip it is built as ``cholesky._local_chol_array``
-is: ONE n x n working buffer addressed by static offsets and written in
+(``lu._lu_nopiv``: sub-blocks joined by two triangular solves and a
+matmul; a sub-block's column recurrence is, on ONE TPU chip with real
+float32, ONE Pallas kernel with the sub-block resident in VMEM,
+``kernels/lu_nopiv_block.py``, and a ``fori_loop`` of XLA ops everywhere
+else: :func:`_diag_blocks_in_vmem`, one algorithm in two lowerings), the
+two panels as one matmul each against the block's triangular inverses
+(``L21 = A21 U11^-1``, ``U12 = L11^-1 A12``), and the trailing update.
+On one chip it is built as ``cholesky._local_chol_array`` is: ONE n x n
+working buffer addressed by static offsets and written in
 place, the update in column stripes.  On a grid it is ``lu``'s distributed
 loop with the panel factored unpivoted (CALU's refactorization without its
 tournament and without ``move_rows``): the panel's columns gathered to
@@ -42,19 +47,23 @@ that does not lower the residual at all is not applied.
 
 Scopes (grammar: :mod:`elemental_tpu.obs`): ``el.mixed_solve`` opens
 ``factor`` (under it ``el.lu_nopiv/k<step>/diag``, ``/panel``,
-``/update``), ``sweeps`` (the first solve's two ``el.trsm``) and
+``/update``; the kernel's launches, ``el_lu_nopiv_block``, sit under the
+step's ``diag``), ``sweeps`` (the first solve's two ``el.trsm``) and
 ``el.refine``, whose phases are ``k00/residual`` (the first residual and
 A's norm) and, INSIDE the loop's body, ``k01/correct`` and
 ``k01/residual``: the first ``k<step>`` gives an op its phase, so the
 correction's ``trsm`` and the residual's ``gemm`` read ``refine/correct``
 and ``refine/residual``, not ``sweep``, ``update`` or ``panel``.
-Counters: ``lu_nopiv_step`` (one a step of the factor) and
+Counters: ``lu_nopiv_step`` (one a step of the factor),
+``lu_nopiv_diag{impl}`` (one a diagonal block, beside it: ``kernel`` |
+``xla``, the lowering its column recurrence took) and
 ``mixed_update{dtype}`` (one a trailing update, with the dtype its
 operands were rounded to).
 """
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +75,7 @@ from ..core.view import view, update_view
 from ..redist.engine import redistribute
 from ..blas.level1 import frobenius_norm as _norm
 from ..blas.level3 import _blocksize, _check_mcmr, gemm, trsm
+from ..kernels import lu_nopiv_block
 from ..obs import metrics as _metrics
 from ..obs.tracer import phase_hook as _phase_hook, scoped as _scoped
 from .lu import _hi, _lu_nopiv, _nopiv_panel, _unit_lower_inv, _upper_inv
@@ -99,12 +109,31 @@ def _unit_lower(Wf):
     return jnp.tril(Wf, -1) + jnp.eye(Wf.shape[0], dtype=Wf.dtype)
 
 
-def _lu_nopiv_array(a, n: int, ib: int, precision, low, tm):
+def _diag_blocks_in_vmem(A: DistMatrix) -> bool:
+    """The rule of the factor's diagonal blocks, from what the input shows:
+    the grid is ONE chip (the grid loop factors its panel whole through
+    ``lu._nopiv_panel``, on every chip the same replicated block; a Mosaic
+    kernel there wants a ``shard_map`` of its own, and no cell has timed
+    that loop), the chip is a TPU (true of a described topology too, so a
+    rehearsal takes the path; on the CPU the kernel would be interpreted)
+    and the entries are real float32 (Mosaic has no complex type and no
+    float64; the kernel's divide and multiply-subtract are float32 on the
+    VPU, no lower than any ``precision``).  Then the unblocked column
+    recurrence of each sub-block of ``lu._lu_nopiv`` is the Pallas kernel
+    ``el_lu_nopiv_block``, the sub-block resident in VMEM (measured,
+    PERF.md 6, PR 46); everything else keeps the ``fori_loop`` of XLA
+    ops."""
+    return (A.grid.size == 1 and A.grid.devices[0].platform == "tpu"
+            and A.dtype == jnp.float32)
+
+
+def _lu_nopiv_array(a, n: int, ib: int, precision, low, tm, block_kernel):
     """Packed unpivoted LU of an (n, n) array in one working buffer: step
     k reads its diagonal block, its two panels and its trailing window of
     ``T`` by static offsets and writes each back where it was read.  The
     update goes by column stripes ``2 ib`` wide, each one matmul of the
-    rounded panels (rounded once a step) written into its own window."""
+    rounded panels (rounded once a step) written into its own window.
+    ``block_kernel`` is the diagonal blocks' lowering (:func:`lu_nopiv`)."""
     dt = a.dtype
     q = 2 * ib
     T = a
@@ -112,10 +141,13 @@ def _lu_nopiv_array(a, n: int, ib: int, precision, low, tm):
         w = min(ib, n - s)
         o = s + w
         _metrics.inc("lu_nopiv_step")
+        _metrics.inc("lu_nopiv_diag",
+                     impl="xla" if block_kernel is None else "kernel")
         # every read is of the LATEST value of T: a read of an older one
         # after a write costs a copy of the whole buffer (PERF.md 6, PR 34)
         with tm.phase("diag", k) as ph:
-            Wf = _lu_nopiv(T[s:o, s:o], precision)
+            Wf = _lu_nopiv(T[s:o, s:o], precision,
+                           block_kernel=block_kernel)
             ph.done(Wf)
         if o == n:
             with tm.phase("diag", k):
@@ -152,6 +184,7 @@ def _lu_nopiv_grid(A: DistMatrix, ib: int, precision, low, tm) -> DistMatrix:
         e = min(s + ib, n)
         w = e - s
         _metrics.inc("lu_nopiv_step")
+        _metrics.inc("lu_nopiv_diag", impl="xla")
         with tm.phase("panel", k) as ph:
             pan = redistribute(view(A, rows=(s, n), cols=(s, e)), STAR, STAR)
             Pf = _nopiv_panel(pan.local, w, precision)
@@ -205,8 +238,17 @@ def lu_nopiv(A: DistMatrix, nb: int | None = None, precision=None,
     tm.start()
     g = A.grid
     if g.size == 1:
+        # the diagonal blocks' lowering, decided once from the input and
+        # static from here down (None: lu._lu_nopiv's own loop of XLA
+        # ops); compiled on a TPU, a described one too, and interpreted
+        # where a test patches the rule on the CPU
+        block_kernel = None
+        if _diag_blocks_in_vmem(A):
+            block_kernel = partial(lu_nopiv_block,
+                                   interpret=g.devices[0].platform != "tpu")
         return A.with_local(_lu_nopiv_array(
-            A.local, n, max(nb or 2048, 1), precision, low, tm))
+            A.local, n, max(nb or 2048, 1), precision, low, tm,
+            block_kernel))
     ib = _blocksize(nb, math.lcm(g.height, g.width), n)
     return _lu_nopiv_grid(A, ib, precision, low, tm)
 

@@ -127,12 +127,17 @@ time by them.  A path is made of:
                            ``el.redist.panel_spread`` and
                            ``el.redist.row_permute`` likewise
   ``el_potrf_inv_panel`` / the ``name=`` of the Pallas kernels
-  ``el_lu_panel`` /        (``kernels/``: the three panel kernels and the
-  ``el_qr_panel`` /        one-pass triangle ``symv``), which is how a
-  ``el_symv_lower``        trace shows a ``pallas_call``: the optimized
-                           HLO's instruction is ``%el_symv_lower.<n>``,
-                           a ``tpu_custom_call`` whose ``op_name`` ends
+  ``el_lu_panel`` /        (``kernels/``: the three panel kernels, the
+  ``el_qr_panel`` /        one-pass triangle ``symv`` and the unpivoted
+  ``el_symv_lower`` /      block LU), which is how a trace shows a
+  ``el_lu_nopiv_block``    ``pallas_call``: the optimized HLO's
+                           instruction is ``%el_symv_lower.<n>``, a
+                           ``tpu_custom_call`` whose ``op_name`` ends
                            ``.../k<panel>/hemv/el_symv_lower/pallas_call``
+                           (``%el_lu_nopiv_block.<n>`` under
+                           ``el.lu_nopiv/k<step>/diag``: one a sub-block
+                           of a diagonal block, so ``panel_share`` reads
+                           it as it read the loop it replaced)
 
 An op in an ``el.`` scope but outside any phase (a driver's final
 assembly or mask) belongs to the driver; an op with no ``el.`` segment
@@ -200,6 +205,14 @@ not tick again).  Read them under ``metrics_scope()``:
   ``apply_q_panel``        one panel of ``apply_q_herm_tridiag``
   ``lu_nopiv_step``        one step of the LU without pivoting
                            (``lapack/mixed.py``: 16 at n = 32768, nb 2048)
+  ``lu_nopiv_diag{impl}``  one diagonal block of it, ticked beside
+                           ``lu_nopiv_step``, with the lowering its
+                           sub-blocks' column recurrence took: ``impl``
+                           ``kernel`` (ONE TPU chip, real float32: the
+                           ``el_lu_nopiv_block`` kernel, the sub-block
+                           in VMEM; 16 at n = 32768, nb 2048, 128
+                           launches) | ``xla`` (everything else:
+                           ``lapack/mixed.py:_diag_blocks_in_vmem``)
   ``mixed_update{dtype}``  one trailing update of it, with the dtype its
                            operands were ROUNDED to before the product
                            (``bfloat16`` in ``mixed_solve``: 15 there;

@@ -8,6 +8,9 @@ operand: the refined answer at the float32 level, the unrefined one at
 least 100 times worse, the step count, a ``perm``-free factor, an operand
 that needs pivoting reported through ``info``, the counters, the scopes
 the compiled program carries and how ``benchmark/scopes.py`` classes them.
+And the diagonal blocks' two lowerings (ISSUE 46): with the rule patched
+true the Pallas kernel (interpreted here) gives the XLA loop's factor and
+answer, and ``lu_nopiv_diag{impl}`` says which ran.
 """
 import importlib
 import re
@@ -56,12 +59,20 @@ def _backward_error(A, B, X):
         np.linalg.norm(A) * np.linalg.norm(X) + np.linalg.norm(B))
 
 
-def _program(**kw):
+def _program(nb=NB, **kw):
     """The jitted program of these keywords, named as the benchmark names
     it, so the scope tests' compile is the solves' (a compile-cache hit)."""
     def bench_solve(a, b):
-        return el.mixed_solve(a, b, nb=NB, **kw)
+        return el.mixed_solve(a, b, nb=nb, **kw)
     return jax.jit(bench_solve)
+
+
+def _factor(grid_name, A, low=mixed.LOW, nb=NB):
+    """The packed factor alone, as one array."""
+    LU = jax.jit(lambda a: mixed.lu_nopiv(a, nb=nb, low=low))(
+        _dist(_grid(grid_name), A))
+    assert isinstance(LU, el.DistMatrix)
+    return np.asarray(el.to_global(LU))
 
 
 def _solve(grid_name, A, B, **kw):
@@ -124,13 +135,9 @@ def test_factor_has_no_permutation(grid_name):
     operands, to the float32 level without; and the bf16 factor is NOT at
     the float32 level (the rounding is really there on the CPU)."""
     A, _B = _operands()
-    grid = _grid(grid_name)
 
     def residual(low):
-        LU = jax.jit(lambda a: mixed.lu_nopiv(a, nb=NB, low=low))(
-            _dist(grid, A))
-        assert isinstance(LU, el.DistMatrix)
-        LU = np.asarray(el.to_global(LU), np.float64)
+        LU = _factor(grid_name, A, low).astype(np.float64)
         L, U = np.tril(LU, -1) + np.eye(N), np.triu(LU)
         return np.linalg.norm(L @ U - A) / np.linalg.norm(A)
     low, high = residual(jnp.bfloat16), residual(None)
@@ -177,6 +184,119 @@ def test_counters_tick_once_a_step_and_name_the_update_dtype():
         assert counters.counter_value(
             "mixed_update", dtype="float32") == N // NB - 1
         assert counters.counter_value("mixed_update", dtype="bfloat16") == 0
+
+
+# ------------------------------- the diagonal blocks in VMEM (ISSUE 46)
+
+#: the factor's block: at NB a diagonal block is under ``_lu_nopiv``'s
+#: sub-block order, ONE launch; at N the one diagonal block is cut in two
+#: sub-blocks (256 and 128) and ``_lu_nopiv``'s blocked outer loop runs
+#: around the kernel
+KERNEL_NBS = [NB, N]
+
+
+def _through_the_kernel(monkeypatch):
+    """What one TPU chip runs; the grid is the CPU's, so the kernel is
+    interpreted.  (The choice is static under each new ``jit``: nothing
+    traced for the XLA loop is handed back.)"""
+    monkeypatch.setattr(mixed, "_diag_blocks_in_vmem", lambda A: True)
+
+
+@pytest.mark.parametrize("nb", KERNEL_NBS)
+def test_kernel_path_gives_the_xla_paths_factor(monkeypatch, nb):
+    """One algorithm, two lowerings.  With float32 updates nothing else
+    differs: the packed factors agree to 32 ulps of the diagonal's scale,
+    2 sqrt(n) (a fused multiply-subtract may round once where the twin
+    rounds twice; to the bit here, where it does not).  With bf16 updates a
+    float32 ulp in a panel may flip an entry's bf16 rounding: one bf16 ulp
+    of an entry of L21, which is under 2 / sqrt(n)."""
+    A, _B = _operands()
+    xla, xla_f32 = _factor("1x1", A, nb=nb), _factor("1x1", A, None, nb)
+    _through_the_kernel(monkeypatch)
+    with obs.metrics_scope() as counters:
+        got, got_f32 = _factor("1x1", A, nb=nb), _factor("1x1", A, None, nb)
+    assert counters.counter_value("lu_nopiv_diag", impl="kernel") \
+        == 2 * (N // nb)
+    assert counters.counter_value("lu_nopiv_diag", impl="xla") == 0
+    assert np.abs(got_f32 - xla_f32).max() <= (
+        32 * np.finfo(np.float32).eps * 2 * np.sqrt(N))
+    assert np.abs(got - xla).max() <= 2.0 ** -8 * 2 / np.sqrt(N)
+    L, U = np.tril(got_f32, -1) + np.eye(N), np.triu(got_f32)
+    assert np.linalg.norm(L.astype(np.float64) @ U - A) \
+        < 1e-6 * np.linalg.norm(A)
+
+
+@pytest.mark.parametrize("nb", KERNEL_NBS)
+def test_kernel_path_solves_as_the_xla_path_does(monkeypatch, nb):
+    """The same ``steps`` and ``converged``, and the refined answer under
+    the same limits against float64 numpy as the XLA path's
+    (``test_refined_answer_is_at_the_float32_level``)."""
+    A, B = _operands()
+    X_xla, info_xla = _solve("1x1", A, B, nb=nb)
+    _through_the_kernel(monkeypatch)
+    X, info = _solve("1x1", A, B, nb=nb)
+    want = np.linalg.solve(A.astype(np.float64), B.astype(np.float64))
+    assert info["steps"] == info_xla["steps"]
+    assert info["converged"] and info_xla["converged"]
+    assert _backward_error(A, B, X) < 3 * max(
+        _backward_error(A, B, np.linalg.solve(A, B)), 1e-8)
+    assert np.linalg.norm(X - want) < 1e-6 * np.linalg.norm(want)
+    assert np.linalg.norm(X - X_xla) < 1e-6 * np.linalg.norm(want)
+    assert info["backward_error"] == pytest.approx(
+        _backward_error(A, B, X), rel=0.5)
+
+
+@pytest.mark.parametrize("grid_name", sorted(GRIDS))
+def test_diag_counter_says_which_lowering_ran(monkeypatch, grid_name):
+    """One tick a diagonal block at trace time, beside ``lu_nopiv_step``:
+    all ``xla`` on the CPU; all ``kernel`` where the rule says so, which it
+    is asked on one chip only (the grid loop has the XLA loop alone)."""
+    A, B = _operands()
+    grid = _grid(grid_name)
+    xla, kernel = {"kernel": 0, "xla": N // NB}, {"kernel": N // NB, "xla": 0}
+
+    def ticks():
+        with obs.metrics_scope() as counters:
+            jax.jit(lambda a, b: el.mixed_solve(a, b, nb=NB)[0].local
+                    ).lower(_dist(grid, A), _dist(grid, B))
+        assert counters.counter_value("lu_nopiv_step") == N // NB
+        return {impl: counters.counter_value("lu_nopiv_diag", impl=impl)
+                for impl in ("kernel", "xla")}
+    assert ticks() == xla
+    _through_the_kernel(monkeypatch)
+    assert ticks() == (kernel if grid_name == "1x1" else xla)
+
+
+@pytest.mark.parametrize("chips,platform,dtype,want", [
+    (1, "tpu", jnp.float32, True),
+    (4, "tpu", jnp.float32, False),     # the grid loop: never timed
+    (1, "cpu", jnp.float32, False),     # an interpreted kernel a sub-block
+    (4, "cpu", jnp.float32, False),
+    (1, "tpu", jnp.float64, False),     # Mosaic has no 64-bit type
+    (1, "tpu", jnp.complex64, False),   # nor a complex one
+    (1, "tpu", jnp.bfloat16, False)])   # the high side is float32
+def test_the_rule_reads_the_grid_the_chip_and_the_dtype(chips, platform,
+                                                        dtype, want):
+    from types import SimpleNamespace as NS
+    A = NS(grid=NS(size=chips, devices=[NS(platform=platform)] * chips),
+           dtype=jnp.dtype(dtype))
+    assert mixed._diag_blocks_in_vmem(A) is want
+
+
+@pytest.mark.parametrize("dtype", [np.float64, np.complex64])
+def test_what_the_kernel_cannot_hold_keeps_the_xla_loop(dtype):
+    """float64 and complex operands factor as before (the rule says no on
+    any chip): ``L U = A`` at the operand's own precision, no kernel."""
+    A, _B = _operands()
+    A = A.astype(dtype)
+    with obs.metrics_scope() as counters:
+        LU = np.asarray(el.to_global(jax.jit(
+            lambda a: mixed.lu_nopiv(a, nb=NB, low=None))(
+                _dist(_grid("1x1"), A))))
+    assert counters.counter_value("lu_nopiv_diag", impl="kernel") == 0
+    L, U = np.tril(LU, -1) + np.eye(N), np.triu(LU)
+    assert np.linalg.norm(L @ U - A) < 50 * np.finfo(dtype).eps \
+        * np.linalg.norm(A)
 
 
 # ------------------------------------------------- the compiled program

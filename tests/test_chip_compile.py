@@ -1,10 +1,10 @@
 """The three fused panel kernels, compiled by the TPU's own compiler for
 a DESCRIBED v5e chip (no chip attached, nothing runs): at the widths
 ``chip_smoke.py`` gives them and at the largest shapes their VMEM gate
-admits; and the one-pass triangle ``symv`` at the eigensolve cell's
-views.  A kernel body that Mosaic refuses -- a primitive with no
-lowering, a slice off the (8, 128) tiling, more VMEM than the compiler
-grants -- fails here, at no chip time.  And the redistribution engine's
+admits; the one-pass triangle ``symv`` at the eigensolve cell's views;
+and the unpivoted block LU at the sub-block order ``lu._lu_nopiv`` ships.
+A kernel body that Mosaic refuses -- a primitive with no lowering, a
+slice off the (8, 128) tiling, more VMEM than the compiler grants -- fails here, at no chip time.  And the redistribution engine's
 local unpacks at the 2x2 benchmark cell's shapes, for the described 2x2:
 a relayout the compiler pads 64-fold shows as temporary bytes.
 
@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from elemental_tpu.kernels import (PANEL_VMEM_BUDGET, PanelPlan, lu_panel,
-                                   potrf_inv, qr_panel, symv_lower)
+from elemental_tpu.kernels import (PANEL_VMEM_BUDGET, PanelPlan,
+                                   lu_nopiv_block, lu_panel, potrf_inv,
+                                   qr_panel, symv_lower)
 
 PLAN = PanelPlan(impl="pallas")
 
@@ -97,6 +98,19 @@ def test_symv_kernel_compiles_for_v5e(nt, one_chip):
     of the 512-tile: a ragged edge), and an order that is no multiple of
     anything (one edge block past both extents)."""
     _compile(lambda a, x: symv_lower(a, x, interpret=False), (nt, nt), (nt,),
+             one_chip=one_chip)
+
+
+#: the sub-block order ``lu._lu_nopiv`` ships (its ``bs`` default)
+NOPIV_BS = 256
+
+
+@pytest.mark.parametrize("n", [NOPIV_BS, 200])
+def test_lu_nopiv_block_kernel_compiles_for_v5e(n, one_chip):
+    """The sub-block order ``lu._lu_nopiv`` hands it (the HPL-MxP cell's
+    128 launches) and a ragged one (200 = 25 sublane tiles, 72 columns
+    past a lane tile: padded)."""
+    _compile(lambda b: lu_nopiv_block(b, interpret=False), (n, n),
              one_chip=one_chip)
 
 
@@ -461,6 +475,73 @@ def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
             nt, ("parameter",)), k
         if k:               # (nt = n is also the order of A, Z and their copies)
             assert not square_ops(everything, nt, ("copy", "transpose")), k
+
+
+def test_mixed_solve_factors_its_diagonal_blocks_in_vmem(topo):
+    """The whole ``jit(mixed_solve)`` at n = 1024, nb = 512 (two diagonal
+    blocks of two sub-blocks each), for ONE described v5e chip (ISSUE 46;
+    15 s).  Before, each sub-block's column recurrence was a ``while`` of
+    XLA ops under ``k<step>/diag`` whose carry the TPU compiler re-laid
+    once a column (``copy f32[256,256]``, 256 events a loop, with no name:
+    0.138 of the 0.4915 s of ``hplmxp.1x1.b2b``).  On a TPU chip with real
+    float32 it is ONE ``tpu_custom_call`` a sub-block, the Pallas kernel
+    ``el_lu_nopiv_block`` under the step's ``diag`` scope: the recurrence's
+    ``while`` is gone (``triangular_solve``'s own loops, between sub-blocks,
+    stay under their own name), and nothing in the program copies a
+    sub-block."""
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    from .lapack.test_herm_eig_compiled import square_ops
+    n, nb, bs = 1024, 512, NOPIV_BS
+    grid = el.Grid([topo.devices[0]])
+    A, B = (_abstract(grid, n, k, el.MC, el.MR) for k in (n, 1))
+    with obs.metrics_scope() as reg:
+        text = jax.jit(lambda a, b: el.mixed_solve(a, b, nb=nb)).lower(
+            A, B).compile().as_text()
+    assert dict(reg.counters("lu_nopiv_diag")) == {
+        ("lu_nopiv_diag", (("impl", "kernel"),)): n // nb}
+    lines = [line.strip() for line in text.splitlines()]
+    kernels = [line for line in lines if "tpu_custom_call" in line]
+    assert len(kernels) == (n // nb) * (nb // bs)
+    for line in kernels:
+        assert re.match(r"%?el_lu_nopiv_block[.\d]* = ", line), line[:200]
+        assert f"f32[{bs},{bs}]" in line.split(" custom-call(")[0]
+    steps = [re.search(
+        r'op_name="[^"]*/factor/el\.lu_nopiv/k(\d\d)/diag/el_lu_nopiv_block/',
+        line).group(1) for line in kernels]
+    assert sorted(steps) == sorted(f"{k:02d}" for k in range(n // nb)
+                                   for _ in range(nb // bs))
+    # the unblocked loop carried the scope's own ``while``; what is left
+    # under ``diag`` is named by the op that made it
+    under_diag = [line for line in lines
+                  if re.search(r'/el\.lu_nopiv/k\d\d/diag[/"]', line)]
+    assert under_diag
+    assert not [line for line in under_diag
+                if re.search(r'/diag/while[/"]', line)]
+    whiles = [line for line in under_diag if " while(" in line]
+    assert all(re.search(r'/diag/triangular_solve"', line)
+               for line in whiles), whiles[:2]
+    assert not square_ops([(None, line) for line in lines], bs, ("copy",))
+
+
+def test_grid_mixed_solve_keeps_the_xla_loop(grid22):
+    """The same program on the described 2x2 (n = 1024, nb = 256; 10 s): the
+    rule has a one-chip clause (``mixed._diag_blocks_in_vmem``), so the grid
+    loop, which no cell or chip run has timed, lowers as it did: no
+    ``tpu_custom_call``, every diagonal block ticked ``xla``.  (A Mosaic
+    kernel is not partitioned automatically: handed the panel's replicated
+    block under GSPMD the lowering raises "cannot be automatically
+    partitioned"; a launch there wants a ``shard_map`` of its own.)"""
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    n, nb = 1024, 256
+    A, B = (_abstract(grid22, n, k, el.MC, el.MR) for k in (n, 8))
+    with obs.metrics_scope() as reg:
+        text = jax.jit(lambda a, b: el.mixed_solve(a, b, nb=nb)).lower(
+            A, B).compile().as_text()
+    assert dict(reg.counters("lu_nopiv_diag")) == {
+        ("lu_nopiv_diag", (("impl", "xla"),)): n // nb}
+    assert "tpu_custom_call" not in text
 
 
 def test_divide_and_conquer_hand_off_places_blocks_and_gathers_nothing(topo,
