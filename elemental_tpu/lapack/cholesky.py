@@ -64,6 +64,7 @@ trace is split by.  Pass an ``elemental_tpu.obs.PhaseTimer`` and call
 from __future__ import annotations
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +81,7 @@ from ..blas.level1 import make_trapezoidal, _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, trsm
 from ..obs import metrics as _metrics
 from ..obs.tracer import NULL_HOOK, scoped as _scoped
-from .lu import _hi, _phase_hook
+from .lu import _hi, _phase_hook, _tri_matmul
 
 #: Trailing-matrix size at which the distributed loop gathers the tail and
 #: finishes locally (look-ahead schedule only, unless overridden).  The
@@ -160,7 +161,7 @@ def _potrf_inv_impl(D, precision, bs: int = 512):
 
 
 def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
-                      timer=None, plan=None):
+                      timer=None, plan=None, panels_row_major: bool = False):
     """Blocked lower Cholesky of an (n, n) array (lower triangle valid),
     returning the lower-triangular factor (zeros above the diagonal).
     Shared by the p == 1 driver and the distributed tail crossover (where
@@ -170,7 +171,10 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
     Schedule:
       * diagonal blocks factored by :func:`_potrf_inv` (small-base potrf +
         matmul inverse assembly) and the panel solve L21 = A21 L11^{-H}
-        done as ONE matmul -- XLA's potrf/trsm at nb=2048 are latency-bound;
+        done as a product with the block's inverse over its NON-ZERO
+        blocks (``lu._tri_matmul``: ``L11^{-H}`` is upper-triangular and
+        the dense product spent half its flops on exact zeros) -- XLA's
+        potrf/trsm at nb=2048 are latency-bound;
       * ONE n x n buffer from the first step to the last.  Step k
         addresses its trailing window ``T[o:, o:]`` by static offsets and
         writes its finished panel, zeros above it, into the same buffer's
@@ -187,48 +191,68 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
         the FLOPs of the full product -- the MXU answer to the reference's
         recursive ``Trrk``);
       * ``lookahead=True`` additionally computes the next panel's column
-        strip first and factors diag block k+1 + its panel solve from it,
-        so the latency-bound ``_potrf_inv`` inner loop is data-independent
-        of the wide remainder stripes and XLA may overlap them (the same
-        pipeline as ``lu._local_lu``).  ``strip`` and ``L21`` stay values
-        of their own: the matmuls never re-read ``T`` after a write."""
+        strip first and factors diag block k+1 from it, so the
+        latency-bound ``_potrf_inv`` inner loop is data-independent of the
+        wide remainder stripes and XLA may overlap them (the same pipeline
+        as ``lu._local_lu``).  Panel k+1's product WAITS for the stripes
+        (an ``optimization_barrier`` with the working buffer): one core
+        runs them one after the other anyway, and L21 of step k+1 then
+        takes the room L21 of step k leaves.  Left to the scheduler, the
+        TPU compiler ran the four block products before the stripes and
+        the plan held a panel more (PERF.md 6, PR 47).  ``strip`` and
+        ``L21`` stay values of their own: the matmuls never re-read ``T``
+        after a write;
+      * ``panels_row_major`` (the caller's, on a TPU) pins L21 row-major,
+        the layout the TPU compiler gave the panel's one dense matmul
+        while the buffer around it is column-major: the update's matmuls
+        read it so.  Unpinned, the blocks inherit the buffer's layout, the
+        scheduler reorders the loop around them and the plan reads 0.68 GB
+        more at N = 32768 (PERF.md 6, PR 47)."""
     tm = timer if timer is not None else NULL_HOOK
     dt = a.dtype
     q = 2 * ib
     T = a
     nxt = None
 
-    def diag_and_panel(step, src, w, below):
-        # diag block ``step`` from src[:w, :w]; its panel solve, one
-        # matmul, from the rows under it
+    def diag_and_panel(step, src, w, below, after=None):
+        # diag block ``step`` from src[:w, :w]; its panel solve, a
+        # product with the triangular inverse, from the rows under it,
+        # once ``after`` (the working buffer, handed back) is computed
         with tm.phase("diag", step) as ph:
             L11, Li11 = _potrf_inv(src[:w, :w], precision, plan=plan)
             ph.done(L11)
         L21 = None
         if below:
             with tm.phase("panel", step) as ph:
-                L21 = jnp.matmul(src[w:, :w], jnp.conj(Li11).T,
-                                 precision=_hi(precision)).astype(dt)
+                X = src[w:, :w]
+                if after is not None:
+                    X, after = lax.optimization_barrier((X, after))
+                L21 = _tri_matmul(X, jnp.conj(Li11).T, "right",
+                                  _hi(precision)).astype(dt)
+                if panels_row_major:
+                    L21 = _pin_layout(L21, (0, 1))
                 ph.done(L21)
-        return L11, Li11, L21
+        return L11, Li11, L21, after
 
     if lookahead:
         w0 = min(ib, n)
-        nxt = diag_and_panel(0, T[:, :w0], w0, w0 < n)
+        *nxt, _ = diag_and_panel(0, T[:, :w0], w0, w0 < n)
     for k, s in enumerate(range(0, n, ib)):
         w = min(ib, n - s)
         o = s + w                   # where the trailing window starts
         if lookahead:
             L11, Li11, L21 = nxt
         else:
-            L11, Li11, L21 = diag_and_panel(k, T[s:, s:o], w, o < n)
+            L11, Li11, L21, _ = diag_and_panel(k, T[s:, s:o], w, o < n)
         # the finished panel goes into its own columns with zeros above it,
         # so the buffer leaves the loop lower-triangular and no caller has
-        # to mask (and copy) the whole of it
+        # to mask (and copy) the whole of it.  A plain update-slice: the
+        # ``.at[].set`` of a static window goes through a bounds select
+        # that the TPU compiler gives a panel-sized value of its own
         with tm.phase("panel", k):
-            T = T.at[:, s:o].set(jnp.concatenate(
+            T = lax.dynamic_update_slice(T, jnp.concatenate(
                 [jnp.zeros((s, w), dt), jnp.tril(L11)]
-                + ([] if L21 is None else [L21]), axis=0))
+                + ([] if L21 is None else [L21]), axis=0), (0, s))
         if o == n:
             break
         _metrics.inc("chol_update")
@@ -244,16 +268,15 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
                 ph.done(T)
             continue
         # look-ahead: the next panel's column strip updates first (one tall
-        # narrow matmul), diag block k+1 factors + panel k+1 solves from it;
-        # the wide remainder stripes read L21 and their own window of T,
-        # never the strip, so the replicated _potrf_inv and the MXU stripes
-        # can overlap.  The strip is not written back: panel k+1 overwrites
-        # its columns.
+        # narrow matmul), diag block k+1 factors from it; the wide remainder
+        # stripes read L21 and their own window of T, never the strip, so
+        # the replicated _potrf_inv and the MXU stripes can overlap; panel
+        # k+1 solves after them.  The strip is not written back: panel k+1
+        # overwrites its columns.
         with tm.phase("update", k):
             w2 = min(ib, mt)
             strip = T[o:, o:o + w2] - jnp.matmul(
                 L21, jnp.conj(L21[:w2, :]).T, precision=precision).astype(dt)
-        nxt = diag_and_panel(k + 1, strip, w2, w2 < mt)
         with tm.phase("update", k) as ph:
             for i in range(w2, mt, q):
                 iq = min(i + q, mt)
@@ -262,16 +285,14 @@ def _local_chol_array(a, n: int, ib: int, precision, lookahead: bool = True,
                 T = T.at[o + i:o + iq, o + w2:o + iq].set(
                     T[o + i:o + iq, o + w2:o + iq] - upd.astype(dt))
             ph.done(T)
+        *nxt, T = diag_and_panel(k + 1, strip, w2, w2 < mt, after=T)
     return T
 
 
-@jax.jit
-def _pin_column_major(x):
-    """``x`` held column-major where it is an intermediate of a compiled
-    program: the layout the TPU compiler gives the one-buffer loop.
-    Without it the compiler re-lays the whole factor out row-major for
-    whoever reads it next (the sweeps of ``hpd_solve``): a second n x n
-    buffer beside the working one (PERF.md 6, PR 34).
+@partial(jax.jit, static_argnums=1)
+def _pin_layout(x, major_to_minor):
+    """``x`` held in the given layout where it is an intermediate of a
+    compiled program.
 
     A ``jit`` of its own so that the constraint is always INSIDE a program,
     under whatever transformation the caller runs: inlined into an
@@ -279,7 +300,23 @@ def _pin_column_major(x):
     when called eagerly.  An eager ``with_layout_constraint`` makes an
     executable whose RESULT has the layout, and jax 0.9.0 hands that one
     back from the persistent compile cache without it (transposed data)."""
-    return with_layout_constraint(x, Layout(major_to_minor=(1, 0)))
+    return with_layout_constraint(x, Layout(major_to_minor=major_to_minor))
+
+
+def _pin_column_major(x):
+    """``x`` held column-major: the layout the TPU compiler gives the
+    one-buffer loop.  Without it the compiler re-lays the whole factor out
+    row-major for whoever reads it next (the sweeps of ``hpd_solve``): a
+    second n x n buffer beside the working one (PERF.md 6, PR 34)."""
+    return _pin_layout(x, (1, 0))
+
+
+def _pins_layouts(grid) -> bool:
+    """The layouts this module pins were read from the TPU compiler;
+    elsewhere nothing asks for them.  Under ``jax.disable_jit()`` the pin's
+    own jit would run it eagerly."""
+    return (grid.devices[0].platform == "tpu"
+            and not jax.config.jax_disable_jit)
 
 
 def _local_cholesky(A: DistMatrix, nb: int | None, precision,
@@ -290,11 +327,11 @@ def _local_cholesky(A: DistMatrix, nb: int | None, precision,
     array IS the global matrix, so the whole blocked loop is one fused XLA
     program with no shard_map/redistribute sub-computation boundaries."""
     ib = max(nb or 2048, 1)
+    on_tpu = _pins_layouts(A.grid)
     out = _local_chol_array(A.local, A.gshape[0], ib, precision,
-                            lookahead=lookahead, timer=timer, plan=plan)
-    # the layout was read from the TPU compiler; elsewhere nothing asks for
-    # it.  Under jax.disable_jit() the pin's own jit would run it eagerly
-    if A.grid.devices[0].platform == "tpu" and not jax.config.jax_disable_jit:
+                            lookahead=lookahead, timer=timer, plan=plan,
+                            panels_row_major=on_tpu)
+    if on_tpu:
         out = _pin_column_major(out)
     return A.with_local(out)
 
@@ -433,6 +470,8 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
     with tm.phase("mask", 0):
         L = make_trapezoidal(A, "L")
 
+    panels_row_major = _pins_layouts(g)
+
     def factor_diag(step, src, lo, hi):
         # replicated diagonal-block factor + inverse: every device runs
         # the same deterministic _potrf_inv, so the panel Trsm is a matmul
@@ -444,12 +483,19 @@ def cholesky(A: DistMatrix, uplo: str = "L", nb: int | str | None = None,
         return L11, Li11
 
     def solve_panel(step, src, rows, cols, Li11):
-        # L21 = A21 L11^{-H} on the [VC,STAR] panel
+        # L21 = A21 L11^{-H} on the [VC,STAR] panel, pinned row-major on
+        # a TPU as in _local_chol_array: the compiler gave the one dense
+        # matmul that layout and the panel spread reads it so; the block
+        # products come column-major, and the spread then re-laid every
+        # panel (0.6 ms a step at N = 32768 on 2x2, more than the products
+        # save: PERF.md 6, PR 47)
         with tm.phase("panel", step) as ph:
             A21_vc = redistribute(view(src, rows=rows, cols=cols), VC, STAR,
                                   comm_precision=cp, path=rp)
-            x21 = jnp.matmul(A21_vc.local, jnp.conj(Li11).T,
-                             precision=_hi(precision)).astype(A.dtype)
+            x21 = _tri_matmul(A21_vc.local, jnp.conj(Li11).T, "right",
+                              _hi(precision)).astype(A.dtype)
+            if panels_row_major:
+                x21 = _pin_layout(x21, (0, 1))
             L21_vc = DistMatrix(x21, (rows[1] - rows[0], cols[1] - cols[0]),
                                 VC, STAR, 0, 0, g)
             ph.done(L21_vc)

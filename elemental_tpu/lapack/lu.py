@@ -114,6 +114,7 @@ def _hi(precision):
 # import ``_phase_hook`` from here.
 from ..obs.tracer import (NULL_HOOK, phase_hook as _phase_hook,
                           scoped as _scoped)
+from ..obs import metrics as _metrics
 
 
 # ---------------------------------------------------------------------
@@ -396,7 +397,10 @@ def _lu_nopiv(W, precision=None, bs: int = 256, *, block_kernel=None):
 def _upper_inv(U, nbw: int, precision=None, bs: int = 256):
     """Inverse of a non-unit upper-triangular block with matmul assembly
     (the upper sibling of :func:`_unit_lower_inv`) -- turns the CALU
-    ``L21 := A21 U11^{-1}`` panel solve into one MXU matmul."""
+    ``L21 := A21 U11^{-1}`` panel solve into MXU matmuls
+    (:func:`_tri_matmul`: one a block column of the inverse, which is
+    built into zeros, so what lies under a ``bs`` block's diagonal is an
+    exact zero)."""
     dt = U.dtype
     if nbw <= bs:
         return lax.linalg.triangular_solve(
@@ -416,14 +420,62 @@ def _upper_inv(U, nbw: int, precision=None, bs: int = 256):
     return Ui
 
 
+#: Block width of a panel's product against a block-triangular inverse
+#: (:func:`_tri_matmul`): a multiple of every builder's own block
+#: (``_upper_inv`` / ``_unit_lower_inv`` 256, ``cholesky._potrf_inv_impl``
+#: 512), so what lies past a block's diagonal is zero by construction.
+TRI_BLOCK = 512
+
+
+def _tri_matmul(A, B, side: str, precision):
+    """``A @ B`` where one operand is the (w, w) inverse of a triangular
+    block as :func:`_upper_inv`, :func:`_unit_lower_inv` and
+    ``cholesky._potrf_inv_impl`` build it: assembled block by block into
+    zeros, so everything on the other side of the block diagonal is an
+    EXACT zero, which the compiler cannot know.  The dense product
+    multiplies those zeros (``2 m w^2`` flops for a triangle's
+    ``m w^2``); here the contraction STOPS at each block's diagonal:
+
+    * ``side='right'``: B upper-triangular (``X @ Ui``, ``X @ Li^H``);
+      column block j is ``A[:, :e_j] @ B[:e_j, s_j:e_j]``;
+    * ``side='left'``: A lower-triangular (``Li @ Y``); row block i is
+      ``A[s_i:e_i, :e_i] @ B[:e_i, :]``.
+
+    With ``w = t TRI_BLOCK`` that is ``(1 + 1/t) / 2`` of the dense
+    product's flops (62.5 % at the cells' w = 2048).  Only terms that are
+    exactly zero leave the sums: the result differs from the dense one by
+    a float32 dot's summation order.  The rule is in the shape:
+    ``w < 2 TRI_BLOCK`` is the one dense matmul.  Ticks the trace-time
+    counter ``panel_tri_product{kind}``, ``blocked`` | ``dense``."""
+    w = B.shape[0]
+    c = TRI_BLOCK
+    if w < 2 * c:
+        _metrics.inc("panel_tri_product", kind="dense")
+        return jnp.matmul(A, B, precision=precision)
+    _metrics.inc("panel_tri_product", kind="blocked")
+    edges = [(s, min(s + c, w)) for s in range(0, w, c)]
+    if side == "right":
+        return jnp.concatenate(
+            [jnp.matmul(A[:, :e], B[:e, s:e], precision=precision)
+             for s, e in edges], axis=1)
+    return jnp.concatenate(
+        [jnp.matmul(A[s:e, :e], B[:e, :], precision=precision)
+         for s, e in edges], axis=0)
+
+
 def _nopiv_panel(Pp, nbw: int, precision=None):
     """Unpivoted factorization of an already-permuted (M, nbw) panel:
-    packed ``[L11\\U11; L21]`` with ``L21 = A21 U11^{-1}`` as one matmul.
+    packed ``[L11\\U11; L21]`` with ``L21 = A21 U11^{-1}`` as a product
+    with the block's inverse over its non-zero blocks (:func:`_tri_matmul`;
+    one dense matmul at a TSQR width).
     Shared by the CALU panel (winners on top) and the TSQR Householder
     reconstruction in ``qr.py`` (LU of ``Q1 - S``)."""
     Wf = _lu_nopiv(Pp[:nbw], precision)
+    if Pp.shape[0] == nbw:          # the last panel: nothing under its block
+        return Wf
     Ui = _upper_inv(jnp.triu(Wf), nbw, precision)
-    L21 = jnp.matmul(Pp[nbw:], Ui, precision=_hi(precision)).astype(Pp.dtype)
+    L21 = _tri_matmul(Pp[nbw:], Ui, "right", _hi(precision)
+                      ).astype(Pp.dtype)
     return jnp.concatenate([Wf, L21], axis=0)
 
 
@@ -446,7 +498,9 @@ def _calu_panel(P, nbw: int, r: int, precision=None):
 def _unit_lower_inv(L11, nbw: int, precision=None, bs: int = 256):
     """Inverse of a unit-lower (nbw, nbw) panel block with matmul assembly
     (small triangular_solve only at ``bs`` diagonal blocks) -- turns the
-    U12 := L11^{-1} A12 panel solve into one MXU matmul."""
+    U12 := L11^{-1} A12 panel solve into MXU matmuls (:func:`_tri_matmul`:
+    one a block row of the inverse, which is built into zeros, so what
+    lies right of a ``bs`` block's diagonal is an exact zero)."""
     dt = L11.dtype
     if nbw <= bs:
         return lax.linalg.triangular_solve(
@@ -593,8 +647,14 @@ def _local_lu_array(a, m: int, n: int, ib: int, precision,
             Li11 = _unit_lower_inv(jnp.tril(Pf[:nbw], -1)
                                    + jnp.eye(nbw, dtype=a.dtype),
                                    nbw, precision)
-            U1n = jnp.matmul(Li11, a[s:e, e:], precision=_hi(precision)
-                             ).astype(a.dtype)
+            # the joined row block is a value of its own BEFORE the
+            # write-back below: fused into the write-back's fusion (a second
+            # output of it) it made the strip update, which reads the
+            # pre-writeback ``a``, wait for the write, and the TPU compiler
+            # copied the whole buffer every step (PERF.md 6, PR 47)
+            U1n = lax.optimization_barrier(
+                _tri_matmul(Li11, a[s:e, e:], "left", _hi(precision)
+                            ).astype(a.dtype))
             ph.done(U1n)
         if not lookahead or e >= kend:
             with tm.phase("solve", k):
@@ -898,8 +958,8 @@ def lu(A: DistMatrix, nb: int | str | None = None, precision=None,
             else:
                 A1n = redistribute(view(A, rows=(s, e), cols=(s, n)),
                                    STAR, VR, comm_precision=cp, path=rp)
-                u1n = jnp.matmul(Li11, A1n.local, precision=_hi(precision)
-                                 ).astype(Pf.dtype)
+                u1n = _tri_matmul(Li11, A1n.local, "left", _hi(precision)
+                                  ).astype(Pf.dtype)
                 U1n = DistMatrix(u1n, (nbw, n - s), STAR, VR, 0, 0, g)
                 U1n_mr = redistribute(U1n, STAR, MR, comm_precision=cp,
                                       path=rp)

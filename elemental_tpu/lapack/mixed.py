@@ -27,8 +27,10 @@ matmul; a sub-block's column recurrence is, on ONE TPU chip with real
 float32, ONE Pallas kernel with the sub-block resident in VMEM,
 ``kernels/lu_nopiv_block.py``, and a ``fori_loop`` of XLA ops everywhere
 else: :func:`_diag_blocks_in_vmem`, one algorithm in two lowerings), the
-two panels as one matmul each against the block's triangular inverses
-(``L21 = A21 U11^-1``, ``U12 = L11^-1 A12``), and the trailing update.
+two panels as products with the block's triangular inverses
+(``L21 = A21 U11^-1``, ``U12 = L11^-1 A12``; ``lu._tri_matmul``: over the
+inverses' non-zero blocks only, 5/8 of the dense product's flops at nb
+2048), and the trailing update.
 On one chip it is built as ``cholesky._local_chol_array`` is: ONE n x n
 working buffer addressed by static offsets and written in
 place, the update in column stripes.  On a grid it is ``lu``'s distributed
@@ -78,7 +80,8 @@ from ..blas.level3 import _blocksize, _check_mcmr, gemm, trsm
 from ..kernels import lu_nopiv_block
 from ..obs import metrics as _metrics
 from ..obs.tracer import phase_hook as _phase_hook, scoped as _scoped
-from .lu import _hi, _lu_nopiv, _nopiv_panel, _unit_lower_inv, _upper_inv
+from .lu import (_hi, _lu_nopiv, _nopiv_panel, _tri_matmul, _unit_lower_inv,
+                 _upper_inv)
 
 #: what the trailing updates' operands are rounded to: the low side
 LOW = jnp.bfloat16
@@ -144,7 +147,10 @@ def _lu_nopiv_array(a, n: int, ib: int, precision, low, tm, block_kernel):
         _metrics.inc("lu_nopiv_diag",
                      impl="xla" if block_kernel is None else "kernel")
         # every read is of the LATEST value of T: a read of an older one
-        # after a write costs a copy of the whole buffer (PERF.md 6, PR 34)
+        # after a write costs a copy of the whole buffer (PERF.md 6, PR 34);
+        # the panels go back by plain update-slices (``.at[].set`` goes
+        # through a bounds select, which at step 0 cost a panel-sized
+        # float32 value beside the blocks: PERF.md 6, PR 47)
         with tm.phase("diag", k) as ph:
             Wf = _lu_nopiv(T[s:o, s:o], precision,
                            block_kernel=block_kernel)
@@ -155,15 +161,18 @@ def _lu_nopiv_array(a, n: int, ib: int, precision, low, tm, block_kernel):
             break
         with tm.phase("panel", k) as ph:
             Ui = _upper_inv(jnp.triu(Wf), w, precision)
-            L21 = jnp.matmul(T[o:, s:o], Ui, precision=precision).astype(dt)
-            T = T.at[s:, s:o].set(jnp.concatenate([Wf, L21], axis=0))
+            L21 = _tri_matmul(T[o:, s:o], Ui, "right", precision).astype(dt)
+            T = lax.dynamic_update_slice(
+                T, jnp.concatenate([Wf, L21], axis=0), (s, s))
             Li = _unit_lower_inv(_unit_lower(Wf), w, precision)
-            U12 = jnp.matmul(Li, T[s:o, o:], precision=precision).astype(dt)
-            T = T.at[s:o, o:].set(U12)
+            U12 = _tri_matmul(Li, T[s:o, o:], "left", precision).astype(dt)
+            T = lax.dynamic_update_slice(T, U12, (s, o))
+            # rounded once a step, and under the panels' name: the pass
+            # that joins a panel's blocks and rounds them is the panel's
+            Lb, Ub = _round(L21, low), _round(U12, low)
             ph.done(T)
         _tick_update(low, dt)
         with tm.phase("update", k) as ph:
-            Lb, Ub = _round(L21, low), _round(U12, low)
             for i in range(0, n - o, q):
                 j = min(i + q, n - o)
                 upd = _low_product(Lb, Ub[:, i:j], low, precision)
@@ -197,8 +206,8 @@ def _lu_nopiv_grid(A: DistMatrix, ib: int, precision, low, tm) -> DistMatrix:
         with tm.phase("solve", k) as ph:
             Li = _unit_lower_inv(_unit_lower(Pf[:w]), w, precision)
             A12 = redistribute(view(A, rows=(s, e), cols=(e, n)), STAR, VR)
-            u12 = jnp.matmul(Li, A12.local, precision=precision
-                             ).astype(A.dtype)
+            u12 = _tri_matmul(Li, A12.local, "left", precision
+                              ).astype(A.dtype)
             U12 = redistribute(A12.with_local(u12), STAR, MR)
             A = update_view(A, redistribute(U12, MC, MR), rows=(s, e),
                             cols=(e, n))

@@ -220,6 +220,16 @@ not tick again).  Read them under ``metrics_scope()``:
                            them); the refinement's step count is not a
                            counter: it is decided on the device and comes
                            back as ``info["steps"]``
+  ``panel_tri_product{kind}``   one product of a panel with a
+                           triangular block's inverse (``lapack/lu.py:
+                           _tri_matmul``: ``L21 = A21 U11^-1`` or
+                           ``A21 L11^-H``, ``U12 = L11^-1 A12``):
+                           ``kind`` ``blocked`` (the inverse is at least
+                           two blocks of ``lu.TRI_BLOCK`` wide: the
+                           contraction stops at each block's diagonal;
+                           30 in ``mixed_solve`` and 15 in ``hpd_solve``
+                           at n = 32768, nb 2048, one chip) | ``dense``
+                           (narrower: the one matmul)
   ``lstsq_route{kind}``    one ``least_squares``: ``kind`` ``tall`` (every
                            chip factors its own rows: ``lapack/qr.py:
                            _takes_tall_route``) | ``blocked`` (``qr``,

@@ -334,6 +334,52 @@ def test_one_chip_cholesky_factors_in_one_buffer(topo):
     assert _plan_bytes(compiled) <= 2_520_000_000, _plan_bytes(compiled)
 
 
+_ENTRY_F32 = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = f32\[(\d+),(\d+)\]\{",
+                        re.M)
+
+
+@pytest.mark.parametrize("driver,nrhs,donate,bound,joined", [
+    pytest.param("hpd_solve", 8, (0,), 642_300_000, True, id="hpd_solve"),
+    pytest.param("mixed_solve", 1, (), 617_400_000, False,
+                 id="mixed_solve")])
+def test_blocked_panel_products_leave_no_second_panel(driver, nrhs, donate,
+                                                      bound, joined, topo):
+    """The one-chip cells' programs at N = 8192, nb = 2048 (three steps with
+    a panel under their diagonal block; 45 s each), ISSUE 47: a panel's
+    product with a triangular inverse is four block matmuls now
+    (``lu._tri_matmul``), and four values joined can leave a SECOND
+    panel-sized temporary beside the panel (6144 x 2048 float32 is 50 MB
+    here, 8 % of either plan; 252 MB at the cells' N = 32768, where the
+    benchmark's ``plan_gb`` has a 1 % bound).  The bounds are this tree's
+    readings and 1 %: 635,844,608 bytes for ``hpd_solve`` (L21 joined ONCE
+    a step, row-major, after the step's stripes: ``cholesky.
+    _local_chol_array``; its N = 16384 reading is held by the test above),
+    611,203,072 for ``mixed_solve`` (648,960,000 before: the panels go back
+    by plain update-slices, and step 0's bounds select with a float32
+    panel of its own went).  In ``mixed_solve`` the join never exists in
+    float32: the working buffer's write and the bfloat16 rounding read the
+    four blocks, so NO float32 value of a panel's size is left in the
+    program but the blocks' own."""
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    n, nb = 8192, 2048
+    grid = el.Grid([topo.devices[0]])
+    A = _abstract(grid, n, n, el.MC, el.MR)
+    B = _abstract(grid, n, nrhs, el.MC, el.MR)
+    solve = getattr(el, driver)
+    with obs.metrics_scope() as reg:
+        compiled = jax.jit(lambda a, b: solve(a, b, nb=nb),
+                           donate_argnums=donate).lower(A, B).compile()
+    ticks = dict(reg.counters("panel_tri_product"))
+    assert set(ticks) == {("panel_tri_product", (("kind", "blocked"),))}
+    assert _plan_bytes(compiled) <= bound, _plan_bytes(compiled)
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    panels = [(r, c) for r, c in
+              ((int(r), int(c)) for r, c in _ENTRY_F32.findall(entry))
+              if r * c >= (n - 2 * nb) * nb and (r, c) != (n, n)]
+    assert bool(panels) == joined, panels
+
+
 def _donated_hpd_solve_on_2x2(grid22, n, nb=2048, nrhs=8):
     """The whole ``hpd_solve`` as the 2x2 benchmark cells run it, A
     donated, compiled for the described ``v5e:2x2``."""
@@ -373,6 +419,31 @@ def test_grid_cholesky_holds_one_working_shard(grid22):
     assert len(moves) <= 4, moves
     assert not _padded_small_minor(text)
     assert _plan_bytes(compiled) <= 853_000_000, _plan_bytes(compiled)
+
+
+_ENTRY_OP = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[[\d,]*\]\{([\d,]*)"
+                       r"[^ ]* ([\w\-]+)\(.*op_name=\"([^\"]*)\"", re.M)
+
+
+def test_grid_cholesky_panel_reaches_the_spread_row_major(grid22):
+    """The 2x2 ``hpd_solve`` at N = 8192, nb = 2048 (two panels on the
+    grid before the crossover tail; 30 s), ISSUE 47.  The TPU compiler gave
+    the panel's ONE dense matmul a row-major result and the panel spread
+    reads it so; ``lu._tri_matmul``'s block products come column-major,
+    and the spread then re-laid every panel: two ``copy`` a step under the
+    spread's name (28 at N = 32768), 0.6 ms a step on the chip,
+    ``hpd32k.2x2.b2b`` 0.24516 -> 0.24734 s with the products' own 0.0076 s
+    saved (PERF.md 6, PR 47).  The grid loop pins L21 row-major on a TPU as
+    the one-chip loop does: every panel product is row-major and no
+    ``copy`` stands under a spread."""
+    compiled = _donated_hpd_solve_on_2x2(grid22, 8192)
+    entry = compiled.as_text().split("\nENTRY ")[1]
+    ops = _ENTRY_OP.findall(entry)
+    products = [layout for layout, _op, name in ops
+                if "/panel/" in name and name.endswith("/dot_general")]
+    assert len(products) >= 8 and set(products) == {"1,0"}, products
+    assert not [name for _layout, op, name in ops
+                if op == "copy" and "/spread/" in name]
 
 
 @pytest.mark.slow
