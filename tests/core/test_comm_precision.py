@@ -22,6 +22,8 @@ from elemental_tpu.redist.quantize import (COMM_PRECISIONS, QUANT_TILE,
                                            q8_decode, q8_encode, q8_pack,
                                            q8_unpack)
 
+from ..conftest import compiled
+
 RNG = np.random.default_rng(1234)
 
 
@@ -187,11 +189,11 @@ def test_none_is_bit_identical_and_count_equal(grid24, redist_counter):
     S = from_global(spd, MC, MR, grid=grid24)
 
     with engine.redist_counts() as c0:
-        LU0, p0 = el.lu(A, nb=nb)
-        L0 = el.cholesky(S, nb=nb)
+        LU0, p0 = compiled(el.lu, nb=nb)(A)
+        L0 = compiled(el.cholesky, nb=nb)(S)
     with engine.redist_counts() as c1:
-        LU1, p1 = el.lu(A, nb=nb, comm_precision=None)
-        L1 = el.cholesky(S, nb=nb, comm_precision=None)
+        LU1, p1 = compiled(el.lu, nb=nb, comm_precision=None)(A)
+        L1 = compiled(el.cholesky, nb=nb, comm_precision=None)(S)
     assert dict(c0) == dict(c1)
     assert (np.asarray(LU0.local) == np.asarray(LU1.local)).all()
     assert (np.asarray(p0) == np.asarray(p1)).all()
@@ -207,7 +209,7 @@ def test_lu_quantized_residual_class(grid24, mode):
     n, nb = 48, 8
     m = (RNG.normal(size=(n, n)) + n * np.eye(n)).astype(np.float32)
     A = from_global(m, MC, MR, grid=grid24)
-    LU, perm = el.lu(A, nb=nb, comm_precision=mode)
+    LU, perm = compiled(el.lu, nb=nb, comm_precision=mode)(A)
     lu_g = np.asarray(to_global(LU), dtype=np.float64)
     L = np.tril(lu_g, -1) + np.eye(n)
     U = np.triu(lu_g)
@@ -223,7 +225,8 @@ def test_cholesky_quantized_residual_class(grid24, mode):
     F = RNG.normal(size=(n, n))
     spd = (F @ F.T / n + n * np.eye(n)).astype(np.float32)
     S = from_global(spd, MC, MR, grid=grid24)
-    L = np.asarray(to_global(el.cholesky(S, nb=nb, comm_precision=mode)),
+    L = np.asarray(to_global(compiled(el.cholesky, nb=nb,
+                                      comm_precision=mode)(S)),
                    dtype=np.float64)
     resid = np.linalg.norm(spd - L @ L.T) / np.linalg.norm(spd)
     assert resid <= 5e-2, resid
@@ -238,7 +241,7 @@ def test_qr_trsm_herk_gemm_accept_the_knob(grid24):
     A = from_global(m, MC, MR, grid=grid24)
     B = from_global(RNG.normal(size=(n, n)).astype(np.float32), MC, MR,
                     grid=grid24)
-    packed, tau = el.qr(A, nb=nb, comm_precision="bf16")
+    packed, tau = compiled(el.qr, nb=nb, comm_precision="bf16")(A)
     R = np.triu(np.asarray(to_global(packed), dtype=np.float64))[:n]
     # |R| diag magnitudes match numpy's to the quantized class
     Rn = np.linalg.qr(m.astype(np.float64))[1]

@@ -11,6 +11,8 @@ from elemental_tpu import MC, MR, from_global, to_global
 from elemental_tpu.kernels import (DEFAULT_INNERS, PANEL_IMPLS, PanelPlan,
                                    default_inners, panel_fits, resolve_panel)
 
+from ..conftest import compiled
+
 
 def _dist(g, arr):
     return from_global(arr, MC, MR, grid=g)
@@ -116,8 +118,8 @@ def test_lu_pallas_matches_xla_pivots(two_grids):
     rng = np.random.default_rng(17)
     F = rng.normal(size=(32, 32))
     A = _dist(two_grids, F)
-    LUp, permp = el.lu(A, nb=8, panel_impl="pallas")
-    LUx, permx = el.lu(A, nb=8, panel_impl="xla")
+    LUp, permp = compiled(el.lu, nb=8, panel_impl="pallas")(A)
+    LUx, permx = compiled(el.lu, nb=8, panel_impl="xla")(A)
     np.testing.assert_array_equal(np.asarray(permp), np.asarray(permx))
     lu_ = np.asarray(to_global(LUp))
     L = np.tril(lu_, -1) + np.eye(32)
@@ -130,7 +132,7 @@ def test_cholesky_pallas_residual(two_grids):
     rng = np.random.default_rng(18)
     G = rng.normal(size=(32, 32))
     S = G @ G.T / 32 + 32 * np.eye(32)
-    L = el.cholesky(_dist(two_grids, S), nb=8, panel_impl="pallas")
+    L = compiled(el.cholesky, nb=8, panel_impl="pallas")(_dist(two_grids, S))
     lg = np.asarray(to_global(L))
     assert np.linalg.norm(lg @ lg.T - S) / np.linalg.norm(S) < 1e-12
 
@@ -139,8 +141,8 @@ def test_qr_pallas_matches_xla(two_grids):
     rng = np.random.default_rng(19)
     F = rng.normal(size=(32, 32))
     A = _dist(two_grids, F)
-    pp, taup = el.qr(A, nb=8, panel_impl="pallas")
-    px, taux = el.qr(A, nb=8, panel_impl="xla")
+    pp, taup = compiled(el.qr, nb=8, panel_impl="pallas")(A)
+    px, taux = compiled(el.qr, nb=8, panel_impl="xla")(A)
     np.testing.assert_allclose(np.asarray(to_global(pp)),
                                np.asarray(to_global(px)),
                                rtol=0, atol=1e-11)
@@ -155,8 +157,8 @@ def test_complex_driver_falls_back_bitwise(grid24):
     rng = np.random.default_rng(20)
     F = (rng.normal(size=(24, 24)) + 1j * rng.normal(size=(24, 24)))
     A = _dist(grid24, F)
-    LUp, permp = el.lu(A, nb=8, panel_impl="pallas")
-    LUx, permx = el.lu(A, nb=8, panel_impl="xla")
+    LUp, permp = compiled(el.lu, nb=8, panel_impl="pallas")(A)
+    LUx, permx = compiled(el.lu, nb=8, panel_impl="xla")(A)
     np.testing.assert_array_equal(np.asarray(permp), np.asarray(permx))
     assert np.array_equal(np.asarray(to_global(LUp)),
                           np.asarray(to_global(LUx)))
@@ -165,7 +167,7 @@ def test_complex_driver_falls_back_bitwise(grid24):
 def test_driver_accepts_panel_impl_auto(grid24):
     rng = np.random.default_rng(21)
     F = rng.normal(size=(24, 24))
-    LU, perm = el.lu(_dist(grid24, F), nb=8, panel_impl="auto")
+    LU, perm = compiled(el.lu, nb=8, panel_impl="auto")(_dist(grid24, F))
     lu_ = np.asarray(to_global(LU))
     L = np.tril(lu_, -1) + np.eye(24)
     U = np.triu(lu_)

@@ -11,6 +11,8 @@ import elemental_tpu as el
 from elemental_tpu import MC, MR, from_global, to_global
 from elemental_tpu.matrices import hermitian_uniform_spectrum
 
+from ..conftest import compiled
+
 
 @pytest.mark.parametrize("dtype", [np.float64, np.complex128])
 @pytest.mark.parametrize("uplo", ["L", "U"])
@@ -18,7 +20,7 @@ def test_cholesky_residual(grid24, uplo, dtype):
     n = 28
     A = hermitian_uniform_spectrum(n, 1, 10, grid24, dtype=dtype, seed=3)
     F = np.asarray(to_global(A))
-    L = el.cholesky(A, uplo=uplo, nb=8)
+    L = compiled(el.cholesky, uplo=uplo, nb=8)(A)
     Lh = np.asarray(to_global(L))
     if uplo == "L":
         assert np.allclose(np.triu(Lh, 1), 0)
@@ -34,7 +36,8 @@ def test_cholesky_reads_only_triangle(grid42):
     A = hermitian_uniform_spectrum(n, 1, 5, grid42, dtype=np.float64, seed=4)
     F = np.asarray(to_global(A))
     garbage = F + np.triu(np.random.default_rng(0).normal(size=(n, n)), 1)
-    Ld = el.cholesky(from_global(garbage, MC, MR, grid42), "L", nb=8)
+    Ld = compiled(el.cholesky, uplo="L", nb=8)(
+        from_global(garbage, MC, MR, grid42))
     want = np.linalg.cholesky(F)
     np.testing.assert_allclose(np.asarray(to_global(Ld)), want, rtol=1e-10)
 
@@ -43,7 +46,7 @@ def test_cholesky_two_grids_ragged(two_grids):
     n = 19     # deliberately not a multiple of any grid dim
     A = hermitian_uniform_spectrum(n, 1, 4, two_grids, dtype=np.float64, seed=5)
     F = np.asarray(to_global(A))
-    L = np.asarray(to_global(el.cholesky(A, nb=8)))
+    L = np.asarray(to_global(compiled(el.cholesky, nb=8)(A)))
     assert np.linalg.norm(F - L @ L.T) / np.linalg.norm(F) < 1e-13
 
 
@@ -54,7 +57,8 @@ def test_hpd_solve(grid24, uplo):
     F = np.asarray(to_global(A))
     rng = np.random.default_rng(7)
     B = rng.normal(size=(n, nrhs)) + 1j * rng.normal(size=(n, nrhs))
-    X = el.hpd_solve(A, from_global(B, MC, MR, grid24), uplo=uplo, nb=8)
+    X = compiled(el.hpd_solve, uplo=uplo, nb=8)(
+        A, from_global(B, MC, MR, grid24))
     Xh = np.asarray(to_global(X))
     assert np.linalg.norm(F @ Xh - B) / np.linalg.norm(B) < 1e-12
 
@@ -63,9 +67,10 @@ def test_cholesky_solve_after(grid24):
     n, nrhs = 20, 3
     A = hermitian_uniform_spectrum(n, 1, 6, grid24, dtype=np.float64, seed=8)
     F = np.asarray(to_global(A))
-    L = el.cholesky(A, nb=8)
+    L = compiled(el.cholesky, nb=8)(A)
     B = np.random.default_rng(9).normal(size=(n, nrhs))
-    X = el.cholesky_solve_after(L, from_global(B, MC, MR, grid24), nb=8)
+    X = compiled(el.cholesky_solve_after, nb=8)(
+        L, from_global(B, MC, MR, grid24))
     assert np.linalg.norm(F @ np.asarray(to_global(X)) - B) < 1e-11 * np.linalg.norm(B)
 
 
@@ -81,7 +86,7 @@ def test_cholesky_upper_multigrid(two_grids, dtype):
     n = 21
     A = hermitian_uniform_spectrum(n, 1, 9, two_grids, dtype=dtype, seed=13)
     F = np.asarray(to_global(A))
-    U = np.asarray(to_global(el.cholesky(A, uplo="U", nb=8)))
+    U = np.asarray(to_global(compiled(el.cholesky, uplo="U", nb=8)(A)))
     assert np.allclose(np.tril(U, -1), 0)
     assert np.linalg.norm(F - U.conj().T @ U) / np.linalg.norm(F) < 1e-13
 
@@ -91,7 +96,7 @@ def test_cholesky_upper_2x2_grid():
     g = _grid22()
     A = hermitian_uniform_spectrum(n, 1, 10, g, dtype=np.complex128, seed=14)
     F = np.asarray(to_global(A))
-    U = np.asarray(to_global(el.cholesky(A, uplo="U", nb=8)))
+    U = np.asarray(to_global(compiled(el.cholesky, uplo="U", nb=8)(A)))
     assert np.allclose(np.tril(U, -1), 0)
     assert np.linalg.norm(F - U.conj().T @ U) / np.linalg.norm(F) < 1e-13
 
@@ -103,7 +108,7 @@ def test_hpd_solve_2x2_grid(uplo):
     A = hermitian_uniform_spectrum(n, 1, 8, g, dtype=np.float64, seed=15)
     F = np.asarray(to_global(A))
     B = np.random.default_rng(16).normal(size=(n, nrhs))
-    X = el.hpd_solve(A, from_global(B, MC, MR, g), uplo=uplo, nb=8)
+    X = compiled(el.hpd_solve, uplo=uplo, nb=8)(A, from_global(B, MC, MR, g))
     assert np.linalg.norm(F @ np.asarray(to_global(X)) - B) \
         < 1e-12 * np.linalg.norm(B)
 
@@ -115,8 +120,8 @@ def test_cholesky_lookahead_matches_classic(grid24, n, dtype):
     right-looking driver to roundoff (crossover disabled so both run the
     full distributed loop)."""
     A = hermitian_uniform_spectrum(n, 1, 10, grid24, dtype=dtype, seed=17)
-    La = el.cholesky(A, nb=8, lookahead=True, crossover=0)
-    Lb = el.cholesky(A, nb=8, lookahead=False)
+    La = compiled(el.cholesky, nb=8, lookahead=True, crossover=0)(A)
+    Lb = compiled(el.cholesky, nb=8, lookahead=False)(A)
     np.testing.assert_allclose(np.asarray(to_global(La)),
                                np.asarray(to_global(Lb)),
                                rtol=1e-12, atol=1e-13)
@@ -129,19 +134,20 @@ def test_cholesky_lookahead_matches_classic_local():
     for n in (40, 37):
         A = hermitian_uniform_spectrum(n, 1, 10, g1, dtype=np.float64,
                                        seed=18)
-        La = el.cholesky(A, nb=16, lookahead=True)
-        Lb = el.cholesky(A, nb=16, lookahead=False)
+        La = compiled(el.cholesky, nb=16, lookahead=True)(A)
+        Lb = compiled(el.cholesky, nb=16, lookahead=False)(A)
         np.testing.assert_allclose(np.asarray(La.local),
                                    np.asarray(Lb.local),
                                    rtol=1e-12, atol=1e-13)
 
 
-def _shrinking_chol_reference(a, n, ib, precision, lookahead):
+def _shrinking_chol_panels(a, n, ib, precision, lookahead):
     """``_local_chol_array`` as it stood before ISSUE 34, plain: the
     trailing matrix copied into a smaller array at every step
-    (``T = T[w:, w:]``), the finished panels kept in a list and assembled
-    at the end.  The same matmuls on the same operands in the same order
-    as the one-buffer loop, so the lower triangles agree to the bit."""
+    (``T = T[w:, w:]``), the finished panels kept in a list (assembled by
+    ``_shrinking_chol_reference``).  The same matmuls on the same operands
+    in the same order as the one-buffer loop, so the lower triangles agree
+    to the bit."""
     import jax.numpy as jnp
     from elemental_tpu.lapack.cholesky import _potrf_inv
     dt, q, panels, T = a.dtype, 2 * ib, [], a
@@ -173,7 +179,15 @@ def _shrinking_chol_reference(a, n, ib, precision, lookahead):
             upd = jnp.matmul(L21[i:iq, :], jnp.conj(L21[w2:iq, :]).T,
                              precision=precision)
             T = T.at[i:iq, w2:iq].set(T[i:iq, w2:iq] - upd.astype(dt))
-    out = np.zeros((n, n), dt)
+    return panels
+
+
+def _shrinking_chol_reference(a, n, ib, precision, lookahead):
+    """The panels of the loop above, run as one compiled program, put where
+    they belong in an n x n matrix on the host."""
+    panels = compiled(_shrinking_chol_panels, n=n, ib=ib, precision=precision,
+                      lookahead=lookahead)(a)
+    out = np.zeros((n, n), a.dtype)
     for s, P in zip(range(0, n, ib), panels):
         out[s:, s:s + P.shape[1]] = np.asarray(P)
     return out
@@ -201,7 +215,8 @@ def test_local_chol_array_one_buffer(n, lookahead, dtype):
     # only the lower triangle is valid input: NaN above the diagonal
     a = jax.numpy.asarray(
         (np.tril(F) + np.triu(np.full((n, n), np.nan), 1)).astype(dtype))
-    got = np.asarray(_local_chol_array(a, n, ib, hi, lookahead=lookahead))
+    got = np.asarray(compiled(_local_chol_array, n=n, ib=ib, precision=hi,
+                              lookahead=lookahead)(a))
     assert not np.triu(got, 1).any()
     want = np.linalg.cholesky(F)
     assert got.dtype == dtype
@@ -327,9 +342,11 @@ def test_cholesky_crossover_boundary(grid24):
     A = hermitian_uniform_spectrum(n, 1, 10, grid24, dtype=np.float64,
                                    seed=19)
     F = np.asarray(to_global(A))
-    ref = np.asarray(to_global(el.cholesky(A, nb=8, lookahead=False)))
+    ref = np.asarray(to_global(compiled(el.cholesky, nb=8,
+                                        lookahead=False)(A)))
     for xo in (7, 8, 16, n):
-        L = np.asarray(to_global(el.cholesky(A, nb=8, crossover=xo)))
+        L = np.asarray(to_global(compiled(el.cholesky, nb=8,
+                                          crossover=xo)(A)))
         np.testing.assert_allclose(L, ref, rtol=1e-12, atol=1e-13)
         assert np.linalg.norm(F - L @ L.T) / np.linalg.norm(F) < 1e-13
 

@@ -6,6 +6,11 @@ windows back together and multiplied the whole square of every trailing
 window (kept below as the reference).  Since ISSUE 36 the update walks
 stripes of the lower trapezoid: the sizes from ``9ib`` on have windows of
 more than one stripe, a ragged last stripe and a ragged last row.
+
+The two result tests run the program, and the reference, each as ONE
+compiled program (``conftest.compiled``, ISSUE 48): walked eagerly a
+nine-step case dispatched five hundred programs of its own shapes, and the
+file took over a quarter of tier-1's clock.
 """
 import math
 
@@ -16,6 +21,8 @@ import pytest
 
 import elemental_tpu as el
 from elemental_tpu import MC, MR, STAR, VC, from_global, to_global
+
+from ..conftest import compiled
 
 IB = 8
 HI = jax.lax.Precision.HIGHEST
@@ -160,25 +167,31 @@ def test_grid_cholesky_masks_at_the_entry(grid, size, schedule, dtype):
     the bit, or, where the CPU backend's dot gives a stripe's product other
     last bits than the same columns of the full product (it picks its kernel
     by shape), within the rounding of one update's dot products of length
-    ``ib``, ``ib eps |L| |L|^H`` elementwise (40 of the 224 cases; the
-    largest read: 1.21 eps)."""
+    ``ib``, ``ib eps |L| |L|^H`` elementwise (38 of the 224 cases, all of
+    five steps or more; the largest read: 0.97 eps).  Program and reference
+    each run as one compiled program; at ``3ib`` on 2x2 the eager walk of
+    the program is run as well and equals the compiled one to the bit, in
+    every schedule."""
     g, n = _grid(grid), _SIZES[size]
     lookahead, crossover = _SCHEDULES[schedule]
     F, a = _operand(n, dtype, seed=35 + n)
     A = from_global(a, MC, MR, grid=g)
-    got = np.asarray(to_global(el.cholesky(
-        A, nb=IB, lookahead=lookahead, crossover=crossover)))
+    opts = dict(nb=IB, lookahead=lookahead, crossover=crossover)
+    got = np.asarray(to_global(compiled(el.cholesky, **opts)(A)))
     assert got.dtype == dtype
     assert not np.triu(got, 1).any()
     assert np.isfinite(got).all()
     want = np.linalg.cholesky(F)
     assert np.linalg.norm(got - want) < 50 * np.finfo(dtype).eps * n \
         * np.linalg.norm(want)
-    ref = np.asarray(to_global(_exit_masked_cholesky_reference(
-        A, IB, lookahead, crossover)))
+    ref = np.asarray(to_global(compiled(
+        _exit_masked_cholesky_reference, **opts)(A)))
     if not np.array_equal(got, ref):
         bound = IB * np.finfo(dtype).eps * (np.abs(ref) @ np.abs(ref).conj().T)
         assert (np.abs(got - ref) <= bound).all()
+    if (grid, size) == ("2x2", "3ib"):
+        assert np.array_equal(
+            got, np.asarray(to_global(el.cholesky(A, **opts))))
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.complex64])
@@ -194,9 +207,9 @@ def test_grid_hpd_solve_against_numpy(grid, size, dtype):
     B = rng.normal(size=(n, nrhs))
     if np.issubdtype(dtype, np.complexfloating):
         B = B + 1j * rng.normal(size=(n, nrhs))
-    X = np.asarray(to_global(el.hpd_solve(
+    X = np.asarray(to_global(compiled(el.hpd_solve, nb=IB)(
         from_global(a, MC, MR, grid=g),
-        from_global(B.astype(dtype), MC, MR, grid=g), nb=IB)))
+        from_global(B.astype(dtype), MC, MR, grid=g))))
     assert X.dtype == dtype
     want = np.linalg.solve(F, B.astype(dtype))
     eps = np.finfo(dtype).eps

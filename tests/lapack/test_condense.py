@@ -15,6 +15,8 @@ from elemental_tpu.lapack.condense import (
     hermitian_tridiag, apply_q_herm_tridiag, hessenberg, apply_q_hessenberg)
 from elemental_tpu.matrices.basic import identity
 
+from ..conftest import compiled
+
 
 def _herm(n, dtype, seed=0):
     rng = np.random.default_rng(seed)
@@ -34,10 +36,11 @@ def _tridiag_full(d, e):
 def test_hermitian_tridiag(grid24, dtype, n):
     A = _herm(n, dtype)
     Ad = from_global(A, MC, MR, grid24)
-    Ap, d, e, tau = hermitian_tridiag(Ad, nb=8)
+    Ap, d, e, tau = compiled(hermitian_tridiag, nb=8)(Ad)
     T = _tridiag_full(d, e)
     # Q explicit via back-transform of the identity
-    Q = apply_q_herm_tridiag(Ap, tau, identity(n, grid=grid24, dtype=dtype), nb=8)
+    Q = compiled(apply_q_herm_tridiag, nb=8)(
+        Ap, tau, identity(n, grid=grid24, dtype=dtype))
     Qg = np.asarray(to_global(Q))
     resid = np.linalg.norm(A - Qg @ T @ Qg.conj().T) / max(np.linalg.norm(A), 1)
     orth = np.linalg.norm(np.eye(n) - Qg.conj().T @ Qg)
@@ -55,7 +58,7 @@ def test_hermitian_tridiag_uplo_upper(grid24):
     Abad = A.copy()
     Abad[np.tril_indices(n, -1)] = 99.0
     Ad = from_global(Abad, MC, MR, grid24)
-    Ap, d, e, tau = hermitian_tridiag(Ad, uplo="U", nb=8)
+    Ap, d, e, tau = compiled(hermitian_tridiag, uplo="U", nb=8)(Ad)
     T = _tridiag_full(d, e)
     np.testing.assert_allclose(np.linalg.eigvalsh(T), np.linalg.eigvalsh(A),
                                rtol=1e-10, atol=1e-10)
@@ -80,8 +83,8 @@ def _stored(result):
 def test_one_mirror_a_panel(grid24, n, nb):
     """``herm_tridiag_symmetrize`` ticks once a panel, never once a column."""
     with obs.metrics_scope() as reg:
-        hermitian_tridiag(from_global(_herm(n, jnp.float64), MC, MR, grid24),
-                          nb=nb)
+        compiled(hermitian_tridiag, nb=nb)(
+            from_global(_herm(n, jnp.float64), MC, MR, grid24))
     (panels,) = reg.counters("herm_tridiag_panel").values()
     (mirrors,) = reg.counters("herm_tridiag_symmetrize").values()
     assert mirrors == panels == -(-(n - 1) // nb)
@@ -100,8 +103,9 @@ def test_hermitian_tridiag_lower_never_reads_the_upper_triangle(
     A = _herm(n, dtype, seed=5)
     Abad = A.copy()
     Abad[np.triu_indices(n, 1)] = poison
-    clean = hermitian_tridiag(from_global(A, MC, MR, grid), nb=8)
-    dirty = hermitian_tridiag(from_global(Abad, MC, MR, grid), nb=8)
+    reduce = compiled(hermitian_tridiag, nb=8)
+    clean = reduce(from_global(A, MC, MR, grid))
+    dirty = reduce(from_global(Abad, MC, MR, grid))
     for want, got in zip(_stored(clean), _stored(dirty)):
         assert np.all(np.isfinite(got))
         assert np.array_equal(want, got)
@@ -143,13 +147,13 @@ def test_symv_path_gives_the_mirror_paths_reduction(n, nb, monkeypatch):
     A = _herm(n, jnp.float64, seed=n)
     A[np.triu_indices(n, 1)] = np.nan
     Ad = from_global(A, MC, MR, _grid("1x1"))
-    want = _stored(hermitian_tridiag(Ad, nb=nb))
+    want = _stored(compiled(hermitian_tridiag, nb=nb)(Ad))
     # the path of one TPU chip; the grid is the CPU's, so the kernel is
     # interpreted.  (The choice is a static argument of the jitted panel:
     # nothing traced for the mirror path is handed back.)
     monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
     with obs.metrics_scope() as reg:
-        got = _stored(hermitian_tridiag(Ad, nb=nb))
+        got = _stored(compiled(hermitian_tridiag, nb=nb)(Ad))
     panels = -(-(n - 1) // nb)
     assert dict(reg.counters("herm_tridiag_hemv")) == {
         ("herm_tridiag_hemv", (("impl", "symv"),)): panels}
@@ -163,7 +167,8 @@ def test_herm_eig_through_the_symv_path_agrees_with_numpy(monkeypatch):
     monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
     n = 129
     A = _herm(n, jnp.float32, seed=44)
-    w = herm_eig(from_global(A, MC, MR, _grid("1x1")), nb=64, vectors=False)
+    w = compiled(herm_eig, nb=64, vectors=False)(
+        from_global(A, MC, MR, _grid("1x1")))
     want = np.linalg.eigvalsh(A.astype(np.float64))
     assert np.abs(np.asarray(w, np.float64) - want).max() <= (
         50 * np.finfo(np.float32).eps * np.abs(want).max())
@@ -192,10 +197,11 @@ def test_hessenberg(grid24, dtype):
         A = A + 1j * rng.standard_normal((n, n))
     A = A.astype(dtype)
     Ad = from_global(A, MC, MR, grid24)
-    H, Qp, tau = hessenberg(Ad)
+    H, Qp, tau = compiled(hessenberg)(Ad)
     Hg = np.asarray(to_global(H))
     assert np.abs(np.tril(Hg, -2)).max() < 1e-12
-    Q = apply_q_hessenberg(Qp, tau, identity(n, grid=grid24, dtype=dtype))
+    Q = compiled(apply_q_hessenberg)(
+        Qp, tau, identity(n, grid=grid24, dtype=dtype))
     Qg = np.asarray(to_global(Q))
     resid = np.linalg.norm(A - Qg @ Hg @ Qg.conj().T) / np.linalg.norm(A)
     orth = np.linalg.norm(np.eye(n) - Qg.conj().T @ Qg)

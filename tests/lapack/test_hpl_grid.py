@@ -18,6 +18,8 @@ from elemental_tpu.lapack.lu import lu, lu_solve, lu_solve_after
 from elemental_tpu.obs import metrics_scope
 from elemental_tpu.redist import engine
 
+from ..conftest import compiled
+
 N, NB = 256, 64
 EPS32 = float(np.finfo(np.float32).eps)
 
@@ -90,11 +92,11 @@ def _solve(grid, A, B, crossover):
     a number is the same two stages with that crossover."""
     Ad, Bd = (from_global(v, MC, MR, grid=grid) for v in (A, B))
     kwargs = {} if crossover == "driver" else {"crossover": crossover}
-    LU_, perm = lu(Ad, nb=NB, **kwargs)
+    LU_, perm = compiled(lu, nb=NB, **kwargs)(Ad)
     if crossover == "driver":
-        X = lu_solve(Ad, Bd, nb=NB)
+        X = compiled(lu_solve, nb=NB)(Ad, Bd)
     else:
-        X = lu_solve_after(LU_, perm, Bd, nb=NB)
+        X = compiled(lu_solve_after, nb=NB)(LU_, perm, Bd)
     return np.asarray(perm), np.asarray(to_global(X))
 
 
@@ -239,8 +241,8 @@ def test_hpd_solve_with_many_right_hand_sides(reference, r, c, nrhs):
     A = _operand(reference, "hpd_shifted", N, N, 0)
     B = _operand(reference, "uniform_pm1", N, nrhs, 1)
     grid = _grid(r, c)
-    X = el.hpd_solve(from_global(A, MC, MR, grid=grid),
-                     from_global(B, MC, MR, grid=grid), nb=NB)
+    X = compiled(el.hpd_solve, nb=NB)(from_global(A, MC, MR, grid=grid),
+                                      from_global(B, MC, MR, grid=grid))
     X = np.asarray(to_global(X))
     want = np.linalg.solve(A.astype(np.float64), B.astype(np.float64))
     assert X.dtype == np.float32 and X.shape == (N, nrhs)

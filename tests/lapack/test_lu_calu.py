@@ -18,6 +18,8 @@ import elemental_tpu as el
 from elemental_tpu import MC, MR, from_global, to_global
 from elemental_tpu.lapack.lu import lu, lu_solve, lu_solve_after, permute_rows
 
+from ..conftest import compiled
+
 #: documented stability bound: calu residual may exceed classic's by at
 #: most this factor (plus an absolute roundoff floor) on the suite below.
 #: The theoretical growth ratio is 2^{b(log2 r)} worst-case; on these
@@ -57,7 +59,7 @@ def test_calu_residual(grid24, shape):
     m, n = shape
     rng = np.random.default_rng(61)
     F = rng.normal(size=(m, n))
-    LUd, perm = lu(_dist(grid24, F), nb=8, panel="calu")
+    LUd, perm = compiled(lu, nb=8, panel="calu")(_dist(grid24, F))
     assert _resid(F, LUd, perm) < 1e-13
 
 
@@ -67,9 +69,10 @@ def test_calu_lookahead_matches_classic_schedule(grid24):
     disabled so both run the full distributed loop)."""
     rng = np.random.default_rng(62)
     F = rng.normal(size=(32, 32))
-    LUa, pa = lu(_dist(grid24, F), nb=8, panel="calu", lookahead=True,
-                 crossover=0)
-    LUb, pb = lu(_dist(grid24, F), nb=8, panel="calu", lookahead=False)
+    A = _dist(grid24, F)
+    LUa, pa = compiled(lu, nb=8, panel="calu", lookahead=True,
+                       crossover=0)(A)
+    LUb, pb = compiled(lu, nb=8, panel="calu", lookahead=False)(A)
     np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
     np.testing.assert_allclose(np.asarray(to_global(LUa)),
                                np.asarray(to_global(LUb)),
@@ -83,8 +86,8 @@ def test_calu_crossover_tail_valid(grid24, xo):
     factorization must stay residual-exact at every threshold."""
     rng = np.random.default_rng(63)
     F = rng.normal(size=(48, 48))
-    LUd, perm = lu(_dist(grid24, F), nb=8, panel="calu", lookahead=True,
-                   crossover=xo)
+    LUd, perm = compiled(lu, nb=8, panel="calu", lookahead=True,
+                         crossover=xo)(_dist(grid24, F))
     assert _resid(F, LUd, perm) < 1e-13
 
 
@@ -95,8 +98,9 @@ def test_calu_degenerates_to_classic_on_single_row_grid():
     g18 = el.Grid(jax.devices(), height=1)
     rng = np.random.default_rng(64)
     F = rng.normal(size=(24, 24))
-    LUa, pa = lu(_dist(g18, F), nb=8, panel="calu", lookahead=False)
-    LUb, pb = lu(_dist(g18, F), nb=8, panel="classic", lookahead=False)
+    A = _dist(g18, F)
+    LUa, pa = compiled(lu, nb=8, panel="calu", lookahead=False)(A)
+    LUb, pb = compiled(lu, nb=8, panel="classic", lookahead=False)(A)
     np.testing.assert_array_equal(np.asarray(pa), np.asarray(pb))
     np.testing.assert_allclose(np.asarray(to_global(LUa)),
                                np.asarray(to_global(LUb)),
@@ -125,8 +129,9 @@ def _stability_cases(n):
 def test_calu_stability_vs_classic(grid24, case):
     n = 32
     F = dict(_stability_cases(n))[case]
-    LUc, pc = lu(_dist(grid24, F), nb=8, panel="classic", lookahead=False)
-    LUt, pt = lu(_dist(grid24, F), nb=8, panel="calu", lookahead=False)
+    A = _dist(grid24, F)
+    LUc, pc = compiled(lu, nb=8, panel="classic", lookahead=False)(A)
+    LUt, pt = compiled(lu, nb=8, panel="calu", lookahead=False)(A)
     r_classic = _resid(F, LUc, pc)
     r_calu = _resid(F, LUt, pt)
     assert r_calu <= CALU_RESIDUAL_FACTOR * r_classic + _FLOOR, (
@@ -142,7 +147,8 @@ def test_calu_lu_solve(grid24):
     rng = np.random.default_rng(66)
     F = rng.normal(size=(n, n)) + n * np.eye(n)
     B = rng.normal(size=(n, nrhs))
-    X = lu_solve(_dist(grid24, F), _dist(grid24, B), nb=8, panel="calu")
+    X = compiled(lu_solve, nb=8, panel="calu")(_dist(grid24, F),
+                                               _dist(grid24, B))
     Xh = np.asarray(to_global(X))
     assert np.linalg.norm(F @ Xh - B) / np.linalg.norm(B) < 1e-12
 
@@ -151,10 +157,11 @@ def test_calu_lu_solve_after_reuse(grid24):
     n = 24
     rng = np.random.default_rng(67)
     F = rng.normal(size=(n, n)) + n * np.eye(n)
-    LUd, perm = lu(_dist(grid24, F), nb=8, panel="calu")
+    LUd, perm = compiled(lu, nb=8, panel="calu")(_dist(grid24, F))
+    solve_after = compiled(lu_solve_after, nb=8)
     for seed in (1, 2):
         B = np.random.default_rng(seed).normal(size=(n, 2))
-        X = lu_solve_after(LUd, perm, _dist(grid24, B), nb=8)
+        X = solve_after(LUd, perm, _dist(grid24, B))
         assert np.linalg.norm(F @ np.asarray(to_global(X)) - B) \
             < 1e-12 * np.linalg.norm(B)
 
@@ -166,7 +173,7 @@ def test_calu_permute_rows_inverse_roundtrip(grid24):
     rng = np.random.default_rng(68)
     F = rng.normal(size=(n, n))
     B = rng.normal(size=(n, 5))
-    _, perm = lu(_dist(grid24, F), nb=8, panel="calu")
+    _, perm = compiled(lu, nb=8, panel="calu")(_dist(grid24, F))
     Bd = _dist(grid24, B)
     Bp = permute_rows(Bd, perm)
     np.testing.assert_allclose(np.asarray(to_global(Bp)),

@@ -10,6 +10,8 @@ import elemental_tpu as el
 from elemental_tpu import MC, MR, VC, STAR, from_global, to_global
 from elemental_tpu.lapack.qr import qr, apply_q, explicit_q, least_squares, tsqr
 
+from ..conftest import compiled
+
 
 def _dist(g, arr):
     return from_global(arr, MC, MR, grid=g)
@@ -23,8 +25,8 @@ def test_qr_residual_orthogonality(grid24, shape, dtype):
     F = rng.normal(size=(m, n)).astype(dtype)
     if np.issubdtype(dtype, np.complexfloating):
         F = F + 1j * rng.normal(size=(m, n))
-    Ap, tau = qr(_dist(grid24, F), nb=8)
-    Q = np.asarray(to_global(explicit_q(Ap, tau, nb=8)))
+    Ap, tau = compiled(qr, nb=8)(_dist(grid24, F))
+    Q = np.asarray(to_global(compiled(explicit_q, nb=8)(Ap, tau)))
     k = min(m, n)
     R = np.triu(np.asarray(to_global(Ap)))[:k, :]
     assert np.linalg.norm(np.eye(m) - Q.conj().T @ Q) < 1e-12 * m
@@ -35,7 +37,7 @@ def test_qr_vs_numpy_R(grid42):
     m, n = 20, 12
     rng = np.random.default_rng(22)
     F = rng.normal(size=(m, n))
-    Ap, tau = qr(_dist(grid42, F), nb=8)
+    Ap, tau = compiled(qr, nb=8)(_dist(grid42, F))
     R = np.triu(np.asarray(to_global(Ap)))[:n, :]
     Rnp = np.linalg.qr(F, mode="r")
     np.testing.assert_allclose(np.abs(R), np.abs(Rnp), atol=1e-12)
@@ -46,10 +48,10 @@ def test_apply_q_adjoint_roundtrip(grid24):
     rng = np.random.default_rng(23)
     F = rng.normal(size=(m, n)) + 1j * rng.normal(size=(m, n))
     B = rng.normal(size=(m, nrhs)) + 1j * rng.normal(size=(m, nrhs))
-    Ap, tau = qr(_dist(grid24, F), nb=8)
+    Ap, tau = compiled(qr, nb=8)(_dist(grid24, F))
     Bd = _dist(grid24, B)
-    out = apply_q(Ap, tau, apply_q(Ap, tau, Bd, orient="C", nb=8),
-                  orient="N", nb=8)
+    out = compiled(apply_q, orient="N", nb=8)(
+        Ap, tau, compiled(apply_q, orient="C", nb=8)(Ap, tau, Bd))
     np.testing.assert_allclose(np.asarray(to_global(out)), B, atol=1e-12)
 
 
@@ -59,7 +61,7 @@ def test_least_squares(grid24, shape):
     rng = np.random.default_rng(24)
     F = rng.normal(size=(m, n))
     B = rng.normal(size=(m, 3))
-    X = least_squares(_dist(grid24, F), _dist(grid24, B), nb=8)
+    X = compiled(least_squares, nb=8)(_dist(grid24, F), _dist(grid24, B))
     Xnp, *_ = np.linalg.lstsq(F, B, rcond=None)
     np.testing.assert_allclose(np.asarray(to_global(X)), Xnp, atol=1e-10)
 
@@ -69,7 +71,8 @@ def test_least_squares_complex_two_grids(two_grids):
     rng = np.random.default_rng(25)
     F = rng.normal(size=(m, n)) + 1j * rng.normal(size=(m, n))
     B = rng.normal(size=(m, 2)) + 1j * rng.normal(size=(m, 2))
-    X = least_squares(_dist(two_grids, F), _dist(two_grids, B), nb=4)
+    X = compiled(least_squares, nb=4)(_dist(two_grids, F),
+                                      _dist(two_grids, B))
     Xnp, *_ = np.linalg.lstsq(F, B, rcond=None)
     np.testing.assert_allclose(np.asarray(to_global(X)), Xnp, atol=1e-10)
 
@@ -79,7 +82,7 @@ def test_tsqr(grid24):
     rng = np.random.default_rng(26)
     F = rng.normal(size=(m, k))
     A = from_global(F, VC, STAR, grid24)
-    Q, R = tsqr(A)
+    Q, R = compiled(tsqr)(A)
     Qh = np.asarray(to_global(Q))
     Rh = np.asarray(to_global(R))
     assert np.linalg.norm(Qh.T @ Qh - np.eye(k)) < 1e-13

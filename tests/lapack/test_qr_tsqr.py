@@ -13,9 +13,18 @@ import pytest
 from elemental_tpu import MC, MR, from_global, to_global
 from elemental_tpu.lapack.qr import qr, apply_q, explicit_q
 
+from ..conftest import compiled
+
 
 def _dist(g, arr):
     return from_global(arr, MC, MR, grid=g)
+
+
+def _factor_and_q(A, nb):
+    """One program: the blocking ``qr`` records on its factor (``_qr_nb``,
+    which ``explicit_q`` reads) does not cross a ``jit`` boundary."""
+    Ap, tau = qr(A, nb=nb, panel="tsqr")
+    return Ap, explicit_q(Ap, tau)
 
 
 @pytest.mark.parametrize("shape", [(24, 16), (32, 32), (19, 13), (30, 18)])
@@ -23,8 +32,8 @@ def test_tsqr_residual_orthogonality(grid24, shape):
     m, n = shape
     rng = np.random.default_rng(71)
     F = rng.normal(size=(m, n))
-    Ap, tau = qr(_dist(grid24, F), nb=8, panel="tsqr")
-    Q = np.asarray(to_global(explicit_q(Ap, tau)))
+    Ap, Q = compiled(_factor_and_q, nb=8)(_dist(grid24, F))
+    Q = np.asarray(to_global(Q))
     k = min(m, n)
     R = np.triu(np.asarray(to_global(Ap)))[:k, :]
     assert np.linalg.norm(Q.T @ Q - np.eye(m)) < 1e-12
@@ -34,7 +43,7 @@ def test_tsqr_residual_orthogonality(grid24, shape):
 def test_tsqr_R_matches_numpy_abs(grid42):
     rng = np.random.default_rng(72)
     F = rng.normal(size=(28, 12))
-    Ap, _ = qr(_dist(grid42, F), nb=4, panel="tsqr")
+    Ap, _ = compiled(qr, nb=4, panel="tsqr")(_dist(grid42, F))
     R = np.triu(np.asarray(to_global(Ap)))[:12, :]
     np.testing.assert_allclose(np.abs(R), np.abs(np.linalg.qr(F, mode="r")),
                                atol=1e-11)
@@ -43,8 +52,8 @@ def test_tsqr_R_matches_numpy_abs(grid42):
 def test_tsqr_complex(grid24):
     rng = np.random.default_rng(73)
     F = rng.normal(size=(20, 12)) + 1j * rng.normal(size=(20, 12))
-    Ap, tau = qr(_dist(grid24, F), nb=4, panel="tsqr")
-    Q = np.asarray(to_global(explicit_q(Ap, tau)))
+    Ap, Q = compiled(_factor_and_q, nb=4)(_dist(grid24, F))
+    Q = np.asarray(to_global(Q))
     R = np.triu(np.asarray(to_global(Ap)))[:12, :]
     assert np.linalg.norm(Q.conj().T @ Q - np.eye(20)) < 1e-11
     assert np.linalg.norm(Q[:, :12] @ R - F) < 1e-11 * np.linalg.norm(F)
@@ -77,11 +86,15 @@ def test_tsqr_least_squares_path(grid24):
     F = rng.normal(size=(30, 10))
     B = rng.normal(size=(30, 2))
     X_np, *_ = np.linalg.lstsq(F, B, rcond=None)
-    Ap, tau = qr(_dist(grid24, F), nb=4, panel="tsqr")
-    Y = apply_q(Ap, tau, _dist(grid24, B), orient="C")
     from elemental_tpu.redist.interior import interior_view
     from elemental_tpu.blas.level1 import make_trapezoidal
     from elemental_tpu.blas.level3 import trsm
-    R = make_trapezoidal(interior_view(Ap, (0, 10), (0, 10)), "U")
-    X = trsm("L", "U", "N", R, interior_view(Y, (0, 10), (0, 2)), nb=4)
+
+    def minimizer(A, B):
+        Ap, tau = qr(A, nb=4, panel="tsqr")
+        Y = apply_q(Ap, tau, B, orient="C")
+        R = make_trapezoidal(interior_view(Ap, (0, 10), (0, 10)), "U")
+        return trsm("L", "U", "N", R, interior_view(Y, (0, 10), (0, 2)), nb=4)
+
+    X = compiled(minimizer)(_dist(grid24, F), _dist(grid24, B))
     np.testing.assert_allclose(np.asarray(to_global(X)), X_np, atol=1e-10)

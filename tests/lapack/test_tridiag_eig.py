@@ -15,6 +15,8 @@ import pytest
 import elemental_tpu as el
 from elemental_tpu.lapack.tridiag_eig import _place_blocks, tridiag_eig
 
+from ..conftest import compiled
+
 
 def _trid(d, e):
     return np.diag(d) + np.diag(e, 1) + np.diag(e, -1)
@@ -120,7 +122,7 @@ def test_herm_eig_dc_path(grid24):
     G = rng.standard_normal((n, n))
     F = (G + G.T) / 2
     A = el.from_global(F, el.MC, el.MR, grid=grid24)
-    w, Z = el.herm_eig(A, dc_min=0, repl_max=96)
+    w, Z = compiled(el.herm_eig, dc_min=0, repl_max=96)(A)
     wref = np.linalg.eigvalsh(F)
     assert np.abs(np.asarray(w) - wref).max() < 1e-9
     Zg = np.asarray(el.to_global(Z))
@@ -135,7 +137,8 @@ def test_herm_eig_dc_subset(grid24):
     G = rng.standard_normal((n, n))
     F = (G + G.T) / 2
     A = el.from_global(F, el.MC, el.MR, grid=grid24)
-    w, Z = el.herm_eig(A, subset=("index", 10, 29), dc_min=0, repl_max=64)
+    w, Z = compiled(el.herm_eig, subset=("index", 10, 29), dc_min=0,
+                    repl_max=64)(A)
     wref = np.linalg.eigvalsh(F)[10:30]
     assert np.abs(np.asarray(w) - wref).max() < 1e-9
     Zg = np.asarray(el.to_global(Z))
@@ -150,6 +153,6 @@ def test_herm_eig_dc_values_only(grid24):
     G = rng.standard_normal((n, n))
     F = (G + G.T) / 2
     A = el.from_global(F, el.MC, el.MR, grid=grid24)
-    w = el.herm_eig(A, vectors=False, dc_min=0, repl_max=64)
+    w = compiled(el.herm_eig, vectors=False, dc_min=0, repl_max=64)(A)
     wref = np.linalg.eigvalsh(F)
     assert np.abs(np.asarray(w) - wref).max() < 1e-9

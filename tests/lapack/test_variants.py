@@ -9,6 +9,8 @@ import pytest
 
 import elemental_tpu as el
 
+from ..conftest import compiled
+
 
 def _g(F, grid):
     return el.from_global(np.asarray(F, np.float64), el.MC, el.MR, grid=grid)
@@ -209,7 +211,7 @@ def test_rq(two_grids, shape):
     rng = np.random.default_rng(7)
     m, n = shape
     F = rng.normal(size=(m, n))
-    R, Q = el.rq(_g(F, two_grids))
+    R, Q = compiled(el.rq)(_g(F, two_grids))
     Rg, Qg = _t(R), _t(Q)
     k = min(m, n)
     assert Rg.shape == (m, k) and Qg.shape == (k, n)
