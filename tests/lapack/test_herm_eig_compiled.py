@@ -8,8 +8,12 @@ scopes and counters its three stages carry into the compiled program
 names cost the program nothing.  ``dc_min=64, repl_max=64`` put the test
 sizes through the divide and conquer, n = 320 through a distributed merge
 (four leaves of 80: one level of two replicated merges, then one merge on
-the [MC,MR] eigenvector matrix), which the defaults reach only above 512.
+the [MC,MR] eigenvector matrix), which the defaults reach only above 512;
+n = 398 on 2x2 (ISSUE 50) through TWO distributed levels, a padded tree and
+a ragged last panel, and through the pin of what one column of the grid
+reduction exchanges.
 """
+import collections
 import contextlib
 import functools
 import importlib.util
@@ -95,9 +99,7 @@ def compiled(grid_name, n):
 
 # ------------------------------------------------------------- the answer
 
-@pytest.mark.parametrize("n", [192, 320])
-@pytest.mark.parametrize("grid_name", GRIDS)
-def test_compiled_herm_eig_agrees_with_float64_numpy(grid_name, n):
+def _agrees_with_float64_numpy(grid_name, n):
     F = _symmetric(n)
     exe, _ = compiled(grid_name, n)
     A = el.from_global(F, el.MC, el.MR, grid=_grid(grid_name))
@@ -119,6 +121,36 @@ def test_compiled_herm_eig_agrees_with_float64_numpy(grid_name, n):
     orthogonality = np.linalg.norm(Zg.T @ Zg - np.eye(n)) / np.sqrt(n)
     assert residual <= 10 * EPS, residual
     assert orthogonality <= 20 * EPS, orthogonality
+
+
+@pytest.mark.parametrize("n", [192, 320])
+@pytest.mark.parametrize("grid_name", GRIDS)
+def test_compiled_herm_eig_agrees_with_float64_numpy(grid_name, n):
+    _agrees_with_float64_numpy(grid_name, n)
+
+
+#: an order whose divide and conquer has two DISTRIBUTED levels on 2x2 and
+#: whose reduction ends in a ragged panel (ISSUE 50)
+N_DEEP = 398
+
+
+def test_two_distributed_merge_levels_and_a_ragged_last_panel_on_2x2():
+    """What n = 192 (no distributed merge) and n = 320 (ONE, between two
+    blocks of 160, every panel 64 wide but the last of 63) could not show:
+    at n = 398 the tree is eight leaves of 50 over a PADDED order of 400,
+    one replicated level (four merges to 100), the four blocks placed on
+    the [MC,MR] matrix's diagonal, then two distributed levels (two merges
+    to 200 whose operands are blocks of the matrix, one to 400): a merge
+    whose INPUT was a distributed merge's output; the reduction's seventh
+    panel is 13 columns wide and every local view (199, 167, ... 7 rows)
+    is of odd order.  Against float64 numpy, as the orders above."""
+    _exe, counts = compiled("2x2", N_DEEP)
+    assert counts["herm_tridiag_panel"] == {(): 7}
+    assert (N_DEEP - 1) % NB == 13
+    assert counts["dc_merge"] == {(("kind", "replicated"),): 4,
+                                  (("kind", "distributed"),): 3}
+    assert counts["dc_fill_block"] == {(): 4}
+    _agrees_with_float64_numpy("2x2", N_DEEP)
 
 
 # -------------------------------------------------------------- the names
@@ -326,6 +358,50 @@ def test_column_loop_reads_the_trailing_view_once(grid_name):
                          made), (k, made)
         if nt > NB:         # (at nt == nb the panel's own blocks are square)
             assert not square_ops(lines, nt, ("copy", "transpose", "select")), k
+
+
+#: a collective's opcode in a line of optimized HLO (an ``op_name`` spells
+#: ``all_to_all`` with underscores, so only the instruction matches)
+COLLECTIVE = re.compile(
+    r" (all-gather|all-to-all|all-reduce|collective-permute|reduce-scatter)"
+    r"(?:-start)?\(")
+
+
+def test_a_column_of_the_grid_reduction_exchanges_five_times_and_writes_no_shard():
+    """ISSUE 50: what ONE column of the reduction costs on a grid, pinned in
+    the compiled loop body (the orders above held the product to one read of
+    the view; nothing counted the exchanges beside it).  The vector goes to
+    ``[MR,STAR]`` in three collectives (an all-to-all, a collective-permute,
+    an all-gather), the product's partial sums are joined by the compiler's
+    own all-reduce, which carries the product's name and reads ``hemv``, and
+    the result is replicated again by one all-gather: FIVE dependent
+    collectives a column, 81,920 a solve at n = 16384, each on a vector.  A
+    sixth is a regression of the engine's route, not noise.  And nothing in
+    the body makes an array of the trailing shard's size: the local view is
+    the loop's invariant, read by the one product."""
+    loops = column_loops(compiled("2x2", N_DEEP)[0].as_text())
+    assert sorted(loops) == list(range(7))
+    for k, lines in loops.items():
+        found = collections.Counter()
+        for _c, line in lines:
+            opcode = COLLECTIVE.search(line)
+            if opcode:
+                name = re.search(r'op_name="([^"]*)"', line).group(1)
+                hop = [s for s in name.split("/") if s.startswith("el.redist.")]
+                assert f"/k{k:02d}/hemv/" in name, (k, name)
+                found[opcode.group(1), hop[0] if hop else "product"] += 1
+        assert found == {
+            ("all-to-all", "el.redist.MC_MR.to.MR_STAR"): 1,
+            ("collective-permute", "el.redist.MC_MR.to.MR_STAR"): 1,
+            ("all-gather", "el.redist.MC_MR.to.MR_STAR"): 1,
+            ("all-reduce", "product"): 1,
+            ("all-gather", "el.redist.MC_MR.to.STAR_STAR"): 1}, (k, found)
+        nt = (N_DEEP - k * NB) // 2              # the local view's order
+        made = [line for _c, line in lines
+                if re.match(rf"(ROOT )?%?[\w.\-]+ = f32\[{nt},{nt}\]", line)
+                and " get-tuple-element(" not in line
+                and " parameter(" not in line]
+        assert not made, (k, made)
 
 
 # ------------------------------------------------------- what the names cost

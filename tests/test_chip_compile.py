@@ -548,6 +548,75 @@ def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
             assert not square_ops(everything, nt, ("copy", "transpose")), k
 
 
+def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
+        grid22):
+    """The grid twin of the test above (ISSUE 50): the whole donated
+    ``jit(herm_eig)`` at n = 2048, nb = 256 for the described ``v5e:2x2``,
+    float32 throughout as on the chip (a minute).  On a grid every panel
+    takes PR 38's mirror path (the kernel reads a stored triangle, and an
+    element-cyclic shard of a Hermitian matrix is not locally symmetric):
+    the view is mirrored ONCE a panel, outside the loop, and a column is
+    one product against the (nt/2, nt/2) local view, the loop's invariant
+    as it stands, its partial sums joined across the grid's row by the
+    compiler's all-reduce, with the vector's hop to ``[MR,STAR]`` before it
+    and the result's gather after: at most five collectives a column, every
+    one on a vector.  Before PR 38 the TPU compiler re-laid the FIXED view
+    inside the ``while`` body once a COLUMN (18.66 of one chip's 41.9 s),
+    which only the optimized HLO for the described chip showed; here no
+    ``copy``, ``transpose``, ``select``, ``slice`` or update makes a local
+    view's worth of data in any loop body, and exactly one fusion, the
+    product's, takes the view as a parameter.  The plan beside the shard:
+    10.38 shards at the cell's n = 16384 (ISSUE 50's rehearsal), 12.33 at
+    4096 and 14.74 here, where panels and vectors weigh more: under 20."""
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    from .lapack.test_herm_eig_compiled import (COLLECTIVE, column_loops,
+                                                square_ops)
+    n, nb = 2048, 256
+    A = _abstract(grid22, n, n, el.MC, el.MR)
+    with jax.enable_x64(False), obs.metrics_scope() as reg:
+        compiled = jax.jit(lambda a: el.herm_eig(a, nb=nb),
+                           donate_argnums=0).lower(A).compile()
+    assert dict(reg.counters("herm_tridiag_hemv")) == {
+        ("herm_tridiag_hemv", (("impl", "mirror"),)): n // nb}
+    assert dict(reg.counters("herm_tridiag_symmetrize")) == {
+        ("herm_tridiag_symmetrize", ()): n // nb}
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    loops = column_loops(text)
+    assert sorted(loops) == list(range(n // nb))
+    for k, lines in loops.items():
+        nt = (n - k * nb) // 2                   # the local view's order
+        found = [(m.group(1), line) for _c, line in lines
+                 for m in [COLLECTIVE.search(line)] if m]
+        assert 4 <= len(found) <= 5, (k, [op for op, _line in found])
+        named = [line for _op, line in found if 'op_name="' in line]
+        assert all(f"/k{k:02d}/hemv/" in line for line in named), k
+        # in every other panel the compiler makes the vector's gather an
+        # all-reduce of an update-slice and gives it NO name: on the chip
+        # that one reads ``unscoped``, not ``redist``
+        assert [op for op, line in found if 'op_name="' not in line] in (
+            [], ["all-reduce"]), k
+        (join,) = [line for line in named if "el.redist." not in line]
+        assert " all-reduce(" in join and "/hemv/dot_general" in join, k
+        # every exchanged array is a vector
+        assert not any(re.search(rf"\[{nt},\d\d+\]", line.split(" = ")[1]
+                                 .split("(")[0]) for _op, line in found), k
+        readers = {c for c, line in lines if "fused_computation" in c
+                   and re.search(rf"= f32\[{nt},{nt}\]\S* parameter\(", line)}
+        assert len(readers) == 1, (k, readers)
+        if nt == nb:                    # the panel's own blocks are nt x nt
+            continue
+        assert not square_ops(
+            [(c, line) for c, line in lines if "fused_computation" not in c],
+            nt, ("copy", "transpose", "select", "slice", "fusion",
+                 "dynamic-update-slice", "all-to-all", "all-gather")), k
+    mem = compiled.memory_analysis()
+    plan = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert plan < 20 * (4 * n * n // 4), plan
+
+
 def test_mixed_solve_factors_its_diagonal_blocks_in_vmem(topo):
     """The whole ``jit(mixed_solve)`` at n = 1024, nb = 512 (two diagonal
     blocks of two sub-blocks each), for ONE described v5e chip (ISSUE 46;
