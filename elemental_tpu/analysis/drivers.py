@@ -382,11 +382,10 @@ def _registry() -> dict:
 #: per-driver EL006 overrides above the 4.0x default, each a DECLARED
 #: memory cost the variant is known to pay (measured on 1x1+2x2, pinned
 #: by the memory_plan goldens + tests/analysis/test_mem_lint.py):
-#: the slice gather one-shots whole operand slabs, `[CIRC,CIRC]` and
-#: `[MD,*]` forms concentrate the operand on few devices, and the
-#: direct one-shot plans stage full send+recv buffers at once.
+#: `[CIRC,CIRC]` and `[MD,*]` forms concentrate the operand on few
+#: devices, and the direct one-shot plans stage full send+recv buffers
+#: at once.
 MEM_BUDGET_FACTORS = {
-    "gemm_slice": 6.5,        # one-shot row/col slab gathers (by design)
     "gemm_dot_direct": 5.0,   # replicated-form staging, direct plans
     "herk_direct": 6.0,
     "qr_lq_direct": 5.0,

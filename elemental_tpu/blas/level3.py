@@ -32,6 +32,7 @@ from jax import lax
 from ..core.dist import MC, MR, VC, VR, STAR
 from ..core.distmatrix import DistMatrix, zeros as dm_zeros
 from ..core.view import view, update_view
+from ..obs import metrics as _metrics
 from ..obs.tracer import (NULL_HOOK as _NULL_HOOK, phase_hook as _phase_hook,
                           scoped as _scoped)
 from ..redist.engine import redistribute, transpose_dist, panel_spread
@@ -99,10 +100,14 @@ def gemm(A: DistMatrix, B: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None
     largest-operand-stationary heuristic in ``Gemm.cpp``), or one of
     'A' / 'B' / 'C' / 'dot' / 'gspmd' / 'slice' explicitly ('gspmd' =
     single storage matmul, XLA chooses the schedule; 'slice' = the
-    one-sided slicing schedule of :func:`_summa_slice` -- three one-shot
-    compiled plans, no ring, the tall-skinny/rectangular winner).
+    one-sided slicing schedule of :func:`_summa_slice` -- three hops of
+    one collective each through the engine's fused kernels (an
+    all-gather and two all-to-alls over one mesh axis, unpacked in whole
+    blocks), no ring, the tall-skinny/rectangular winner).
     ``nb='auto'`` likewise asks the tuner for the panel width; an
     explicit value always wins ('dot', 'gspmd' and 'slice' ignore it).
+    On a grid of more than one device the resolved ``alg`` ticks the
+    trace-time counter ``gemm_route{alg}``.
 
     ``comm_precision`` (``None`` | ``'bf16'`` | ``'int8'`` | ``'auto'``)
     selects the wire precision of the SUMMA panel moves (the per-panel
@@ -160,6 +165,8 @@ def gemm(A: DistMatrix, B: DistMatrix, alpha=1.0, beta=0.0, C: DistMatrix | None
     from ..redist.quantize import check_comm_precision
     check_comm_precision(comm_precision)
     cp, rp = comm_precision, redist_path
+    if A.grid.size > 1:
+        _metrics.inc("gemm_route", alg=alg)
     tm = _phase_hook("gemm", alg=alg)
     tm.start()
     if alg == "C":
@@ -296,29 +303,35 @@ def _summa_dot(alpha, A, B, beta, C, precision, tm=_NULL_HOOK, cp=None,
 def _summa_slice(alpha, A, B, beta, C, precision, tm=_NULL_HOOK, cp=None):
     """Slicing-based one-sided gemm (``alg='slice'``, the arXiv 2510.08874
     direction): every device owns one contiguous-cyclic SLICE of C's rows
-    (or columns) and gathers, in ONE compiled one-shot plan per operand,
-    exactly the A rows (B columns) that slice needs plus the shared small
-    operand -- no k-panel ring, no per-panel barrier.
+    (or columns) and gathers, in ONE collective per operand, exactly the
+    A rows (B columns) that slice needs plus the shared small operand --
+    no k-panel ring, no per-panel barrier.
 
     Row mode (``m >= n`` or an Nx1 grid): A -> [VC,STAR] (each device
-    takes its 1-D cyclic row slice -- a single ragged FFD-packed a2a over
-    mr), B -> [STAR,STAR] (the small operand, one exchange), then a fully
-    LOCAL contraction (k is unsharded on both sides, so no hidden psum)
-    lands D = A_slice @ B as [VC,STAR] storage, filtered back onto
-    [MC,MR] by a third one-shot plan.  Column mode mirrors with
-    [STAR,STAR] x [STAR,VR].  Degeneracies: 1x1 grids early-out to one
-    local matmul with ZERO redistributes (pinned); on Nx1 (row mode) and
-    1xN (column mode) grids two of the three plans are pure local
-    filters, leaving a single collective.
+    takes its 1-D cyclic row slice -- one all-to-all over mr), B ->
+    [STAR,STAR] (the small operand, one all-gather over the whole grid),
+    then a fully LOCAL contraction (k is unsharded on both sides, so no
+    hidden psum) lands D = A_slice @ B as [VC,STAR] storage, filtered
+    back onto [MC,MR] by a third hop (one all-to-all over mr).  Column
+    mode mirrors with [STAR,STAR] x [STAR,VR] over mc.  Degeneracies:
+    1x1 grids early-out to one local matmul with ZERO redistributes
+    (pinned); on Nx1 (row mode) and 1xN (column mode) grids two of the
+    three hops are pure local filters, leaving a single collective.
 
-    The slice gathers ride the plan compiler natively
-    (``path='direct'``), so ``comm_precision`` composes PER SLOT -- bf16
-    cast or int8 block-scale-pack on every packed a2a slot -- and the
-    ``redist_path`` knob is moot: the gather IS a one-shot plan.  The
-    tuner prices the three plans with the same ``compile_plan`` byte
-    math (``tune.cost_model``), which is what makes ``alg='auto'`` pick
-    'slice' on tall-skinny / non-square-grid geometry and keep the SUMMA
-    twins elsewhere."""
+    The three hops take the engine's default route, which for each of
+    the six pairs is a fused single-collective kernel of
+    ``redist.engine._fused_dispatch`` unpacked by whole-block
+    interleaves (``redist_unpack{impl,dim}``); never ``path='direct'``,
+    whose dense index tables run on a TPU as a gather and a scatter of
+    one entry at a time.  ``comm_precision`` passes through to every
+    operand hop: ``'bf16'`` casts each payload, ``'int8'`` block-scales
+    the [STAR,STAR] leg and degrades to ``'bf16'`` on the [V] leg, as
+    the engine documents.  The route takes no ``redist_path``.  The
+    fused hops ship the wire bytes of the compiled plan of the same
+    pair, to the byte, so the tuner prices them with the same
+    ``compile_plan`` byte math (``tune.cost_model``), which is what
+    makes ``alg='auto'`` pick 'slice' on tall-skinny / non-square-grid
+    geometry and keep the SUMMA twins elsewhere."""
     m, n = C.gshape
     g = A.grid
     with tm.phase("panel", 0) as ph:
@@ -327,20 +340,16 @@ def _summa_slice(alpha, A, B, beta, C, precision, tm=_NULL_HOOK, cp=None):
         else:
             from ..redist.plan import slice_row_mode
             if slice_row_mode(m, n, (g.height, g.width)):
-                As = redistribute(A, VC, STAR, comm_precision=cp,
-                                  path="direct")
-                Bs = redistribute(B, STAR, STAR, comm_precision=cp,
-                                  path="direct")
+                As = redistribute(A, VC, STAR, comm_precision=cp)
+                Bs = redistribute(B, STAR, STAR, comm_precision=cp)
                 dl = jnp.matmul(As.local, Bs.local, precision=precision)
                 D = DistMatrix(dl, (m, n), VC, STAR, 0, 0, g)
             else:
-                As = redistribute(A, STAR, STAR, comm_precision=cp,
-                                  path="direct")
-                Bs = redistribute(B, STAR, VR, comm_precision=cp,
-                                  path="direct")
+                As = redistribute(A, STAR, STAR, comm_precision=cp)
+                Bs = redistribute(B, STAR, VR, comm_precision=cp)
                 dl = jnp.matmul(As.local, Bs.local, precision=precision)
                 D = DistMatrix(dl, (m, n), STAR, VR, 0, 0, g)
-            d = redistribute(D, MC, MR, path="direct").local
+            d = redistribute(D, MC, MR).local
         res = C.with_local(_safe_astype(
             alpha * d + (beta * C.local if _nonzero(beta) else 0),
             C.dtype))

@@ -36,7 +36,11 @@ Design (SURVEY.md §8.1 item 4, VERDICT r3 item 3):
     eigenvector matrix as a block-diagonal [MC,MR] ``DistMatrix`` and do
     the two half-height updates as distributed SUMMA gemms with the secular
     eigenvector matrix V filled TILE-LOCALLY from O(n) replicated vectors
-    -- no replicated n x n array ever exists above ``repl_max``.
+    -- no replicated n x n array ever exists above ``repl_max``.  On a
+    grid of several devices whose strides divide the subproblems' order,
+    a level's merges are ONE merge in a ``lax.fori_loop`` over the block
+    offset (``redist.interior.grain_view`` / ``grain_update``): the
+    program holds a merge a level, not a merge a subproblem.
 
 The secular stage runs in float64 when x64 is enabled (CPU mesh tests) and
 float32 otherwise (TPU), independent of the storage dtype.
@@ -53,7 +57,8 @@ from jax import lax
 from ..core.dist import MC, MR, STAR
 from ..core.distmatrix import DistMatrix, zeros as dm_zeros
 from ..redist.engine import redistribute
-from ..redist.interior import interior_view, interior_update
+from ..redist.interior import (interior_view, interior_update, grain_view,
+                               grain_update)
 from ..blas.level1 import index_dependent_fill
 from ..blas.level3 import gemm
 from ..obs import metrics as _metrics
@@ -383,9 +388,9 @@ def tridiag_eig(d, e, grid=None, vectors: bool = True,
     ``fill`` (once, at the last replicated level's number: the hand-off of
     the batch of eigenvector blocks to the [MC,MR] matrix, each block
     placed whole on the diagonal), ``merge`` (the eigenvector products and
-    their stores).  ``dc_merge`` counts the merges by ``kind``
-    (``replicated`` | ``distributed``), ``dc_fill_block`` the blocks the
-    hand-off places.
+    their stores); a rolled level's read ``while/body/closed_call/k<level>/
+    ...``.  ``dc_merge`` counts the merges by ``kind`` (``replicated`` |
+    ``distributed``), ``dc_fill_block`` the blocks the hand-off places.
     """
     d = jnp.asarray(d)
     e = jnp.asarray(e)
@@ -477,18 +482,44 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
         Qd = _place_blocks(Q, grid)
     lam_full = lam.reshape(-1)
 
-    while B > 1:
-        level += 1
-        for p in range(B // 2):
-            _metrics.inc("dc_merge", kind="distributed")
+    # a level's merges differ in their offset alone.  Where nm is a
+    # multiple of both strides the two blocks of a merge are the same
+    # local window on every device, so the offset may be a loop counter:
+    # the level is ONE compiled merge in a ``fori_loop``, not B / 2 of
+    # them (at n = 16384 on 2x2 five bodies for 31 merges).  On other
+    # grids the blocks are cut at static offsets, a merge at a time; so
+    # they are on ONE device, whose program is left as it was measured.
+    on_grain = grid.size > 1 and nm % math.lcm(grid.height, grid.width) == 0
+
+    def merge_of(nm, level):
+        """``merge(p, (Qd, lam_full))`` of the level that joins blocks of
+        order ``nm``.  A NEW function a level: ``fori_loop`` keeps the
+        traced body of a function it has seen for the same carry, and
+        every level's carry is the same two arrays."""
+        def block(Qd, o):
+            if on_grain:
+                return grain_view(Qd, (o, o), (nm, nm))
+            return interior_view(Qd, (o, o + nm), (o, o + nm))
+
+        def half(lam, o):
+            if isinstance(o, int):
+                return lam[o:o + nm]
+            return lax.dynamic_slice_in_dim(lam, o, nm)
+
+        def store(Qd, Z, i0, j0):
+            if on_grain:
+                return grain_update(Qd, Z, (i0, j0))
+            return interior_update(Qd, Z, (i0, j0))
+
+        def merge(p, state):
+            Qd, lam_full = state
             o = p * 2 * nm
             with _TM.phase("secular", level):
                 beta = ep[o + nm - 1]
-                lam1 = lam_full[o:o + nm]
-                lam2 = lam_full[o + nm:o + 2 * nm]
-                Q1 = interior_view(Qd, (o, o + nm), (o, o + nm))
-                Q2 = interior_view(Qd, (o + nm, o + 2 * nm),
-                                   (o + nm, o + 2 * nm))
+                lam1 = half(lam_full, o)
+                lam2 = half(lam_full, o + nm)
+                Q1 = block(Qd, o)
+                Q2 = block(Qd, o + nm)
                 z1 = redistribute(interior_view(Q1, (nm - 1, nm), (0, nm)),
                                   STAR, STAR).local[0]
                 z2 = redistribute(interior_view(Q2, (0, 1), (0, nm)),
@@ -498,10 +529,9 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
                 lamn, perm, ds, tau, aidx, zhat, cninv, flip = _secular(
                     D, z, beta, scale, n_iters, chunk)
 
-                def vfill(i, j, _p=perm, _ds=ds, _tau=tau, _ai=aidx,
-                          _zh=zhat, _cn=cninv, _fl=flip):
-                    return _v_entries(i, j, _p, _ds, _tau, _ai, _zh, _cn,
-                                      _fl, odt)
+                def vfill(i, j):
+                    return _v_entries(i, j, perm, ds, tau, aidx, zhat,
+                                      cninv, flip, odt)
 
                 V = index_dependent_fill(
                     dm_zeros(2 * nm, 2 * nm, MC, MR, grid, dtype=odt), vfill)
@@ -510,9 +540,23 @@ def _tridiag_eig_jit(d, e, grid, vectors, leaf_max, repl_max, chunk,
                 Vbot = interior_view(V, (nm, 2 * nm), (0, 2 * nm))
                 Ztop = gemm(Q1, Vtop, precision=_hi(precision))
                 Zbot = gemm(Q2, Vbot, precision=_hi(precision))
-                Qd = interior_update(Qd, Ztop, (o, o))
-                Qd = interior_update(Qd, Zbot, (o + nm, o))
+                Qd = store(Qd, Ztop, o, o)
+                Qd = store(Qd, Zbot, o + nm, o)
                 lam_full = lax.dynamic_update_slice(lam_full, lamn, (o,))
+            return Qd, lam_full
+        return merge
+
+    while B > 1:
+        level += 1
+        merge = merge_of(nm, level)
+        _metrics.inc("dc_merge", B // 2, kind="distributed")
+        if on_grain and B > 2:
+            with _metrics.repeated(B // 2):
+                Qd, lam_full = lax.fori_loop(0, B // 2, merge,
+                                             (Qd, lam_full))
+        else:
+            for p in range(B // 2):
+                Qd, lam_full = merge(p, (Qd, lam_full))
         B //= 2
         nm *= 2
 

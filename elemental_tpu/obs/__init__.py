@@ -195,9 +195,20 @@ not tick again).  Read them under ``metrics_scope()``:
   ``dc_merge{kind}``       one merge of ``tridiag_eig``: ``kind``
                            ``replicated`` (a level of the vmapped batch
                            ticks once for each of its merges) |
-                           ``distributed`` (unrolled on the [MC,MR]
-                           eigenvector matrix: 31 at n = 16384 with the
-                           defaults, 32 subproblems of 512 up to one)
+                           ``distributed`` (on the [MC,MR] eigenvector
+                           matrix: 31 at n = 16384 with the defaults,
+                           32 subproblems of 512 up to one; a level on
+                           the grid's grain is one merge in a
+                           ``fori_loop`` and ticks once for each trip,
+                           as every counter under it does:
+                           ``metrics.repeated``)
+  ``gemm_route{alg}``      one ``gemm`` on a grid of more than one
+                           device, with the RESOLVED ``alg`` (``'auto'``
+                           through the tuner): ``slice`` 56 and ``gspmd``
+                           6 at n = 16384 on 2x2, two products for each
+                           of the 31 distributed merges (levels of 512,
+                           1024, 2048 take ``slice``; 4096, 8192
+                           ``gspmd``)
   ``dc_fill_block``        one eigenvector block placed on the diagonal of
                            the [MC,MR] matrix at ``tridiag_eig``'s hand-off
                            (32 at n = 16384 with the defaults; none where

@@ -212,8 +212,28 @@ def scoped(registry: MetricsRegistry | None = None):
         _CURRENT = prev
 
 
+#: what one module-level tick is worth (see :func:`repeated`); threads
+#: trace on their own, so it is theirs
+_WEIGHT = threading.local()
+
+
+@contextlib.contextmanager
+def repeated(trips: int):
+    """Every module-level :func:`inc` in the block counts ``trips``
+    times.  For the TRACE-TIME counters of a rolled loop: jax traces a
+    ``lax.fori_loop`` body once, the device runs it ``trips`` times, and
+    a counter that says how often the compiled program does a thing has
+    to read what the unrolled loop read.  Nests by product."""
+    prev = getattr(_WEIGHT, "value", 1)
+    _WEIGHT.value = prev * trips
+    try:
+        yield
+    finally:
+        _WEIGHT.value = prev
+
+
 def inc(name: str, value: float = 1, **labels) -> None:
-    _CURRENT.inc(name, value, **labels)
+    _CURRENT.inc(name, value * getattr(_WEIGHT, "value", 1), **labels)
 
 
 def set_gauge(name: str, value: float, **labels) -> None:

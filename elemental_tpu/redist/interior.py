@@ -11,6 +11,9 @@ module supplies the general case as a standalone op:
     DistMatrix with the same distribution pair.
   * :func:`interior_update` -- functionally write ``B`` into ``A`` at an
     arbitrary ``(i0, j0)`` offset.
+  * :func:`grain_view` / :func:`grain_update` -- the same two at a
+    stride-grain offset that may be TRACED (a loop's counter): one local
+    ``dynamic_slice`` / ``dynamic_update_slice`` a device, no exchange.
 
 TPU-native cost model: a global range whose start ``s`` is NOT a stride
 multiple shifts every row's owner by the fixed rotation ``s mod S`` -- so
@@ -27,6 +30,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ..core import indexing as ix
 from ..core.compat import shard_map
@@ -166,6 +170,54 @@ def interior_update(A: DistMatrix, B: DistMatrix, at=(0, 0)) -> DistMatrix:
 
     return shard_map(f, mesh=g.mesh, in_specs=(A.spec, B.spec),
                          out_specs=A.spec, check_vma=False)(A, B)
+
+
+def _grain_block(A: DistMatrix, at, shape):
+    """Local offsets and extents of the stride-grain block ``shape`` at
+    ``at`` of ``A``; a static offset or extent off the grain raises, a
+    traced offset is the caller's promise."""
+    _check_zero_aligned(A)
+    strides = (A.col_stride, A.row_stride)
+    for x, S in zip(tuple(shape) + tuple(at), strides + strides):
+        if isinstance(x, int) and x % S:
+            raise ValueError(f"block {shape} at {at} is off the grain "
+                             f"{strides} of {A}")
+    offs = tuple(jnp.asarray(o, jnp.int32) // S for o, S in zip(at, strides))
+    return offs, tuple(x // S for x, S in zip(shape, strides))
+
+
+def grain_view(A: DistMatrix, at, shape) -> DistMatrix:
+    """``A[i0:i0+h, j0:j0+w]`` for ``at=(i0, j0)``, ``shape=(h, w)`` all
+    multiples of their dim's stride, as a new zero-aligned DistMatrix.
+    ``at`` may be TRACED (``shape`` is static), which is what
+    :func:`interior_view` cannot take: every device owns the same local
+    window of such a block, so the view is one ``dynamic_slice`` of the
+    local block and a loop can walk a matrix by blocks with ONE compiled
+    body."""
+    offs, ext = _grain_block(A, at, shape)
+    out_meta = DistMatrix(None, tuple(shape), A.cdist, A.rdist, 0, 0, A.grid)
+
+    def f(a, i, j):
+        return out_meta.with_local(lax.dynamic_slice(a.local, (i, j), ext))
+
+    return shard_map(f, mesh=A.grid.mesh, in_specs=(A.spec, P(), P()),
+                     out_specs=out_meta.spec, check_vma=False)(A, *offs)
+
+
+def grain_update(A: DistMatrix, B: DistMatrix, at) -> DistMatrix:
+    """Functionally write ``B`` into ``A`` at ``at=(i0, j0)``, offsets and
+    B's extents multiples of their dim's stride; ``at`` may be TRACED
+    (:func:`grain_view`'s mirror: one local ``dynamic_update_slice``)."""
+    if B.dist != A.dist or B.grid != A.grid:
+        raise ValueError(f"grain_update needs matching layout: {A} vs {B}")
+    _check_zero_aligned(B)
+    offs, _ = _grain_block(A, at, B.gshape)
+
+    def f(a, b, i, j):
+        return a.with_local(lax.dynamic_update_slice(a.local, b.local, (i, j)))
+
+    return shard_map(f, mesh=A.grid.mesh, in_specs=(A.spec, B.spec, P(), P()),
+                     out_specs=A.spec, check_vma=False)(A, B, *offs)
 
 
 # ---------------------------------------------------------------------

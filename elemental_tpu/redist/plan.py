@@ -475,7 +475,7 @@ def slice_row_mode(m: int, n: int, grid_shape: tuple) -> bool:
     slices ([STAR,VR]) otherwise -- symmetrically free on 1xN grids.
     One rule shared by the executor (``blas.level3._summa_slice``), the
     cost model and the analysis drivers, so the tuner prices exactly the
-    plans the executor runs."""
+    hops the executor runs."""
     r, c = grid_shape
     return c == 1 or (r != 1 and m >= n)
 
@@ -514,7 +514,13 @@ def compile_slice_plan(src: tuple, dst: tuple, gshape: tuple,
 
 
 def gemm_slice_plans(m: int, k: int, n: int, grid_shape: tuple):
-    """The compiled plan set of the slicing gemm at one geometry.
+    """The compiled plans of the slicing gemm's three pairs at one
+    geometry.  The gemm's hops run the engine's fused kernels, not these
+    plans (``blas.level3._summa_slice``: index tables run as a gather and
+    a scatter of one entry at a time on a TPU); the plans are the BYTE
+    MATH of the route: a fused hop ships its plan's ``wire_bytes`` to the
+    byte, ragged padding included (``tests/analysis/
+    test_gemm_slice_plan.py``).
 
     Returns ``(mode, plans)`` where mode is ``'local'`` (1x1: zero
     collectives), ``'rows'`` or ``'cols'``, and plans is a tuple of

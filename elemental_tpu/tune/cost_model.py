@@ -477,8 +477,7 @@ def _gemm_sites(alg: str, m: int, k: int, n: int, r: int, c: int,
         sites.append((tag, prim, plan.wire_bytes(z)))
 
     use_direct = redist_path == "direct" and p > 1
-    if use_direct:
-        from ..core.dist import MC, MR, VC, STAR  # jax-free taxonomy
+    from ..core.dist import MC, MR, VC, STAR  # jax-free taxonomy
 
     if alg == "C":
         kb = blocksize_policy(nb, grain_lcm, k)
@@ -525,18 +524,22 @@ def _gemm_sites(alg: str, m: int, k: int, n: int, r: int, c: int,
         ps("D psum(mr)", (m / r) * n, c)
         ag("D->[MC,MR]", (m / r) * (n / c), 1 if c == 1 else 2)
     elif alg == "slice":
-        # Slicing gemm (ISSUE 16): three one-shot plans, priced off the
-        # SAME compiled RedistPlan byte math the executor runs --
-        # regardless of redist_path (the slice gathers ARE direct plans,
-        # so the knob crossing prices identically and the tie-break
-        # keeps the default).  No hidden psum: k is unsharded on both
-        # sides of the local contraction.
+        # Slicing gemm (ISSUE 16): three single-collective hops.  They
+        # run the engine's fused kernels (one all-gather to [STAR,STAR],
+        # one all-to-all over one mesh axis for each [V] leg), which ship
+        # the wire bytes of the compiled RedistPlan of the same pair to
+        # the byte, ragged extents included (tests/analysis pins it), so
+        # the plan's byte math prices them -- regardless of redist_path
+        # (the route takes no such knob, so the crossing prices
+        # identically and the tie-break keeps the default).  No hidden
+        # psum: k is unsharded on both sides of the local contraction.
         if p > 1:
             from ..redist.plan import gemm_slice_plans
             for tag, plan in gemm_slice_plans(m, k, n, (r, c))[1]:
                 if plan is None or plan.kind == "local":
                     continue                # degenerate relabeling leg
-                prim = "all_to_all" if plan.kind == "a2a" else "ppermute"
+                prim = "all_gather" if plan.dst == (STAR, STAR) \
+                    else "all_to_all"
                 sites.append((tag, prim, plan.wire_bytes(z)))
     else:
         raise KeyError(f"unknown gemm alg {alg!r}")

@@ -1,0 +1,181 @@
+"""What a compiled program costs the persistent compile cache, without a chip.
+
+``heig.2x2.b2b``'s executable is the largest a cell compiles, and the chip
+machine's cache takes no entry over ``CACHE_ENTRY_LIMIT`` bytes: a program
+over it is compiled by EVERY run (``setup_s`` 794 s for 92 s warm, PERF.md
+6, PR 51).  jax stores ``compress_executable(serialize(compiled))``; on the
+rehearsal's executable that gave the chip's entry to 0.02 %.
+
+    python -m perf.program_size eig --n 16384            # ten minutes, 20 GB
+    python -m perf.program_size eig --n 16384 --stage dc # the stages alone
+    python -m perf.program_size drivers                  # CPU, seconds
+
+``eig`` compiles the donated ``jit(herm_eig)`` (nb 256, float32) for a
+described ``v5e:2x2`` and prints the entry's bytes beside the plan, the
+lines and the trace-time counters.  ``drivers`` prints a hash of the
+stripped optimized HLO (CPU backend, n = 256, one device and 2x2) of every
+driver a cell of the benchmark compiles.  ``--root <checkout>`` imports
+``elemental_tpu`` from another tree: run both on two trees to see which
+programs a change moved (equal hashes: the program is the other tree's)
+and by how much.  ONE n = 16384 rehearsal at a time (two at most, with
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1`` in the shell): three took 83 GiB.
+"""
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import re
+import sys
+import time
+
+#: the chip machine's limit on one entry of its compile cache (192 MiB)
+CACHE_ENTRY_LIMIT = 192 << 20
+
+STAGES = ("whole", "tridiag", "dc", "applyq")
+
+
+def entry_bytes(compiled):
+    """``(serialized, cache entry)`` bytes of a compiled executable: what
+    ``serialize_executable`` gives and what jax's compile cache stores."""
+    from jax._src import compilation_cache
+    from jax.experimental.serialize_executable import serialize
+    payload = serialize(compiled)[0]
+    return len(payload), len(compilation_cache.compress_executable(payload))
+
+
+def _eig(args):
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec
+    jax.config.update("jax_enable_compilation_cache", False)
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    from elemental_tpu.core.distmatrix import DistMatrix
+    from elemental_tpu.lapack.condense import (apply_q_herm_tridiag,
+                                               hermitian_tridiag)
+    from elemental_tpu.lapack.tridiag_eig import tridiag_eig
+
+    n, nb, hi = args.n, 256, jax.lax.Precision.HIGHEST
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    grid = el.Grid(list(topo.devices))
+
+    def matrix():
+        meta = DistMatrix(None, (n, n), el.MC, el.MR, 0, 0, grid)
+        return meta.with_local(jax.ShapeDtypeStruct(
+            (n, n), jnp.float32, sharding=grid.sharding(meta.spec)))
+
+    def vector(k):
+        return jax.ShapeDtypeStruct(
+            (k,), jnp.float32,
+            sharding=NamedSharding(grid.mesh, PartitionSpec()))
+
+    fn, operands, donate = {
+        "whole": (lambda a: el.herm_eig(a, nb=nb), (matrix(),), (0,)),
+        "tridiag": (lambda a: hermitian_tridiag(a, "L", nb=nb, precision=hi),
+                    (matrix(),), (0,)),
+        "dc": (lambda d, e: tridiag_eig(d, e, grid=grid, precision=hi),
+               (vector(n), vector(n - 1)), ()),
+        "applyq": (lambda ap, tau, z: apply_q_herm_tridiag(
+            ap, tau, z, orient="N", nb=nb, precision=hi),
+            (matrix(), vector(n - 1), matrix()), (2,)),
+    }[args.stage]
+    start = time.time()
+    with obs.metrics_scope() as reg:
+        lowered = jax.jit(fn, donate_argnums=donate).lower(*operands)
+        lowered_at = time.time()
+        compiled = lowered.compile()
+    compiled_at = time.time()
+    text = compiled.as_text()
+    if args.hlo:
+        with open(args.hlo, "w") as out:
+            out.write(text)
+    mem = compiled.memory_analysis()
+    serialized, entry = entry_bytes(compiled)
+    print(json.dumps({
+        "stage": args.stage, "n": n, "grid": [grid.height, grid.width],
+        "trace_lower_s": round(lowered_at - start, 1),
+        "compile_s": round(compiled_at - lowered_at, 1),
+        "plan_bytes": (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                       + mem.temp_size_in_bytes - mem.alias_size_in_bytes),
+        "hlo_lines": text.count("\n") + 1,
+        "serialized_bytes": serialized, "cache_entry_bytes": entry,
+        "cache_entry_limit": CACHE_ENTRY_LIMIT,
+        "fits": entry <= CACHE_ENTRY_LIMIT,
+        "counters": {name: {",".join(f"{k}={v}" for k, v in labels): count
+                            for (_n, labels), count
+                            in reg.counters(name).items()}
+                     for name in ("gemm_route", "dc_merge")}}), flush=True)
+    return 0 if entry <= CACHE_ENTRY_LIMIT else 1
+
+
+def _stripped(text):
+    """Optimized HLO less what moves with a source line or a module's
+    number: the metadata and the header's layout line."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    head, _, rest = text.partition("\n")
+    starts = [i for i in (rest.find("\n%"), rest.find("\nENTRY")) if i >= 0]
+    return head.split(",")[0] + rest[min(starts):]
+
+
+def _drivers(_args):
+    import jax
+    jax.config.update("jax_num_cpu_devices", 8)
+    jax.config.update("jax_enable_compilation_cache", False)
+    import numpy as np
+    import elemental_tpu as el
+
+    def line(grid_name, driver, fn, *operands):
+        text = jax.jit(fn).lower(*operands).compile().as_text()
+        print(grid_name, driver,
+              hashlib.sha256(_stripped(text).encode()).hexdigest()[:16],
+              text.count("\n") + 1, flush=True)
+
+    rng = np.random.default_rng(0)
+    n = 256
+    for grid_name, chips in (("1x1", 1), ("2x2", 4)):
+        g = el.Grid(list(jax.devices()[:chips]))
+
+        def dist(F):
+            return el.from_global(F.astype(np.float32), el.MC, el.MR, grid=g)
+        F = rng.normal(size=(n, n))
+        S, G = dist(F @ F.T + n * np.eye(n)), dist(F)
+        B = dist(rng.normal(size=(n, 8)))
+        line(grid_name, "hpd_solve", lambda a, b: el.hpd_solve(a, b, nb=64),
+             S, B)
+        line(grid_name, "lu_solve", lambda a, b: el.lu_solve(a, b, nb=64),
+             G, B)
+        line(grid_name, "mixed_solve",
+             lambda a, b: el.mixed_solve(a, b, nb=64), S, B)
+        line(grid_name, "herm_eig",
+             lambda a: el.herm_eig(a, nb=64, dc_min=32, repl_max=32), S)
+        line(grid_name, "least_squares", el.least_squares,
+             dist(rng.normal(size=(4096, 16))),
+             dist(rng.normal(size=(4096, 4))))
+    return 0
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--root", help="import elemental_tpu from this tree")
+    sub = parser.add_subparsers(dest="what", required=True)
+    eig = sub.add_parser("eig")
+    eig.add_argument("--n", type=int, default=16384)
+    eig.add_argument("--stage", choices=STAGES, default="whole")
+    eig.add_argument("--hlo", help="write the optimized HLO here")
+    sub.add_parser("drivers")
+    args = parser.parse_args(argv)
+    # a rehearsal describes a chip and a hash needs the virtual CPU mesh:
+    # both want the CPU client, set before jax starts
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.root:
+        sys.path.insert(0, os.path.abspath(args.root))
+    return {"eig": _eig, "drivers": _drivers}[args.what](args)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

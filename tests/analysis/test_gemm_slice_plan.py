@@ -21,7 +21,7 @@ from elemental_tpu.analysis.drivers import (DEFAULT_N, DEFAULT_NB,
                                             _mcmr_input,
                                             gemm_slice_extents)
 from elemental_tpu.core.distmatrix import DistMatrix
-from elemental_tpu.core.dist import MC, MR
+from elemental_tpu.core.dist import MC, MR, STAR
 from elemental_tpu.redist.plan import gemm_slice_plans
 from elemental_tpu.tune import TuneContext
 from elemental_tpu.tune import cost_model as cm
@@ -69,7 +69,7 @@ def _psums(alg, grid_shape):
 def test_slice_strictly_fewer_rounds_than_every_twin(grid_shape):
     g = _grid(*grid_shape)
     s_rounds, _ = _rounds_bytes(_trace_alg("slice", g))
-    assert s_rounds == 3                    # the three one-shot plans
+    assert s_rounds == 3                    # one collective a hop
     assert _psums("slice", grid_shape) == 0  # k unsharded: NO hidden psum
     for alg in TWINS:
         t_rounds, _ = _rounds_bytes(_trace_alg(alg, g))
@@ -105,18 +105,38 @@ def test_slice_closed_form_beats_every_twin_on_2x4():
     assert all(s.rounds <= score(a).rounds for a in TWINS)
 
 
+@pytest.mark.parametrize("extents,mode", [((M, K, N), "rows"),
+                                          ((37, 23, 11), "rows"),
+                                          ((11, 23, 37), "cols")],
+                         ids=["golden", "ragged_rows", "ragged_cols"])
 @pytest.mark.parametrize("grid_shape", [(2, 2), (2, 4)],
                          ids=["2x2", "2x4"])
-def test_traced_bytes_equal_compiled_plan_bytes(grid_shape):
-    """The trace and the plan compiler agree EXACTLY: what the tuner
-    prices is what the executor ships (no hidden psum on the slice path)."""
+def test_traced_bytes_equal_compiled_plan_bytes(grid_shape, extents, mode):
+    """What the tuner prices is what the executor ships (no hidden psum
+    on the slice path).  Since ISSUE 51 the three hops run the engine's
+    fused kernels: ONE all-gather over the whole grid (the small operand
+    to [STAR,STAR]) and TWO all-to-alls over one mesh axis (the slice in,
+    the product out) -- and they ship the wire bytes of the compiled
+    plans of the same three pairs to the byte, ragged extents and their
+    padding included, so ``tune.cost_model`` keeps the plans' byte math
+    (``chain_cost``'s closed form leaves the ragged padding out)."""
     g = _grid(*grid_shape)
-    _, s_bytes = _rounds_bytes(_trace_alg("slice", g))
-    mode, plans = gemm_slice_plans(M, K, N, grid_shape)
-    assert mode == "rows"                   # m >= n: row slices
-    compiled = sum(p.wire_bytes(4) for _, p in plans
-                   if p is not None and p.kind != "local")
-    assert s_bytes == compiled, (s_bytes, compiled)
+    m, k, n = extents
+    totals = _trace_alg("slice", g, m, k, n).totals()
+    assert {p: t["count"] for p, t in totals.items()} == {
+        "all_gather": 1, "all_to_all": 2}
+    got_mode, plans = gemm_slice_plans(m, k, n, grid_shape)
+    assert got_mode == mode
+    star = [p.wire_bytes(4) for _, p in plans if p.dst == (STAR, STAR)]
+    rest = [p.wire_bytes(4) for _, p in plans if p.dst != (STAR, STAR)]
+    assert totals["all_gather"]["bytes"] == sum(star) and len(star) == 1
+    assert totals["all_to_all"]["bytes"] == sum(rest) and len(rest) == 2
+    # and the cost model labels its sites with the collectives that run
+    ctx = TuneContext("gemm", extents, "float32", grid_shape, "cpu")
+    b = cm.score_config("gemm", {"alg": "slice", "nb": None}, ctx=ctx,
+                        grid=None, dtype=jnp.float32)
+    assert b.prim_counts == {"all_gather": 1, "all_to_all": 2}
+    assert b.comm_bytes == sum(star) + sum(rest)
 
 
 @pytest.mark.parametrize("grid_shape,mode", [((4, 1), "rows"),

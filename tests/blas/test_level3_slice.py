@@ -151,6 +151,132 @@ def test_slice_ignores_nb():
 
 
 # ---------------------------------------------------------------------
+# ISSUE 51: the three hops move blocks through the engine's fused
+# single-collective kernels, not entries through the plan executor's
+# index tables -- the same bits, no gather and no scatter, and a counter
+# ---------------------------------------------------------------------
+
+def _through_the_plan_executor(monkeypatch):
+    """The route of every commit before PR 51: the slicing gemm's hops
+    asked :func:`redistribute` for ``path='direct'``."""
+    real = l3.redistribute
+    monkeypatch.setattr(
+        l3, "redistribute",
+        lambda A, cdist, rdist, **kw: real(A, cdist, rdist, path="direct",
+                                           **kw))
+
+
+#: row mode (m >= n), column mode, ragged extents each way, a complex
+#: product, an accumulating C, a narrow wire: (m, k, n), dtype, keywords
+ROUTE_CASES = {
+    "row_mode": ((96, 40, 24), np.float32, {}),
+    "column_mode": ((24, 40, 96), np.float32, {}),
+    "ragged_rows": ((37, 23, 11), np.float32, {}),
+    "ragged_columns": ((11, 23, 37), np.float64, {}),
+    "complex": ((45, 17, 29), np.complex64, {}),
+    "beta": ((50, 21, 70), np.float32, {"alpha": 1.25, "beta": -0.5}),
+    "bf16_wire": ((64, 32, 48), np.float32, {"comm_precision": "bf16"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+@pytest.mark.parametrize("r,c", [(2, 2), (2, 4)], ids=["2x2", "2x4"])
+def test_fused_hops_give_the_plan_executors_bits(r, c, case, monkeypatch):
+    """Pure data movement either way: the result through the engine's
+    fused kernels is the plan executor's, bit for bit."""
+    g = el.Grid(jax.devices()[: r * c], height=r)
+    (m, k, n), dtype, kw = ROUTE_CASES[case]
+    rng = _rng(51)
+
+    def draw(*shape):
+        x = rng.normal(size=shape)
+        if np.issubdtype(dtype, np.complexfloating):
+            x = x + 1j * rng.normal(size=shape)
+        return x.astype(dtype)
+    A, B, C0 = draw(m, k), draw(k, n), draw(m, n)
+
+    def product():
+        C = _dist(g, C0) if "beta" in kw else None
+        return np.asarray(to_global(l3.gemm(
+            _dist(g, A), _dist(g, B), C=C, alg="slice",
+            precision=jax.lax.Precision.HIGHEST, **kw)))
+    fused = product()
+    _through_the_plan_executor(monkeypatch)
+    assert np.array_equal(fused, product())
+    want = kw.get("alpha", 1.0) * A.astype(np.complex128) @ B \
+        + kw.get("beta", 0.0) * C0
+    tol = 2e-2 if "comm_precision" in kw else 1e-5
+    assert np.linalg.norm(fused - want) <= tol * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("route", ["fused", "plan_executor"])
+@pytest.mark.parametrize("shape", [(256, 64, 32), (32, 64, 256)],
+                         ids=["row_mode", "column_mode"])
+@pytest.mark.parametrize("r,c", [(2, 2), (2, 4)], ids=["2x2", "2x4"])
+def test_compiled_slice_gemm_gathers_and_scatters_no_block(r, c, shape,
+                                                           route,
+                                                           monkeypatch):
+    """The compiled program of ONE ``gemm(alg='slice')``: through the
+    fused kernels no ``gather`` and no ``scatter`` instruction moves a
+    device's share of the smallest operand or more (here: none at all);
+    through the plan executor every hop packs with a gather and two of
+    them unpack with a scatter, an entry at a time (15 ns each on a
+    v5e: PERF.md 6, PR 51) -- which also shows the reader reads."""
+    from ..lapack.test_herm_eig_compiled import big_moves
+    g = el.Grid(jax.devices()[: r * c], height=r)
+    m, k, n = shape
+    if route == "plan_executor":
+        _through_the_plan_executor(monkeypatch)
+    A = _dist(g, np.zeros((m, k), np.float32))
+    B = _dist(g, np.zeros((k, n), np.float32))
+    text = jax.jit(lambda a, b: l3.gemm(a, b, alg="slice").local).lower(
+        A, B).compile().as_text()
+    share = min(m * k, k * n, m * n) // (r * c)
+    moves = big_moves(text, share)
+    if route == "fused":
+        assert not moves
+        assert not big_moves(text, 1)
+    else:
+        assert {opcode for _n, opcode, _name in moves} == {"gather",
+                                                           "scatter"}
+        assert all("_redistribute_direct_jit" in name
+                   for _n, _opcode, name in moves)
+
+
+def test_gemm_route_reads_the_resolved_alg(grid24):
+    """``gemm_route{alg}``: one tick a ``gemm`` on a grid of more than one
+    device, with what ``'auto'`` RESOLVED to; nothing on 1x1, where every
+    schedule is the one local matmul."""
+    from elemental_tpu import obs
+    from elemental_tpu import tune
+
+    def auto_pick(gshape, g):
+        return tune.resolve_knobs(
+            "gemm", gshape=gshape, dtype=jnp.float32, grid=g,
+            knobs={"alg": "auto", "nb": None, "comm_precision": None,
+                   "redist_path": None})["alg"]
+    tall, square = (1024, 128, 64), (256, 256, 256)
+    assert auto_pick(tall, grid24) == "slice"
+    assert auto_pick(square, grid24) == "gspmd"
+
+    def run(g, shape, **kw):
+        m, k, n = shape
+        return l3.gemm(_dist(g, np.ones((m, k), np.float32)),
+                       _dist(g, np.ones((k, n), np.float32)), **kw)
+    with obs.metrics_scope() as reg:
+        run(grid24, tall)
+        run(grid24, tall, alg="auto")
+        run(grid24, square)
+        run(grid24, square, alg="C", nb=16)
+        run(grid24, square, alg="slice")
+        run(el.Grid(jax.devices()[:1], height=1), tall)
+        run(el.Grid(jax.devices()[:1], height=1), tall, alg="slice")
+    assert {labels: v for (_name, labels), v
+            in reg.counters("gemm_route").items()} == {
+        (("alg", "slice"),): 3, (("alg", "gspmd"),): 1, (("alg", "C"),): 1}
+
+
+# ---------------------------------------------------------------------
 # bugfix sweep: beta accumulation on the stationary-A/B + gspmd paths
 # (mirror of the PR 2 _summa_dot complex-beta fix)
 # ---------------------------------------------------------------------

@@ -35,7 +35,8 @@ GRIDS = ["1x1", "2x2"]
 
 
 def _grid(name):
-    return el.Grid(list(jax.devices()[:1 if name == "1x1" else 4]))
+    r, c = (int(d) for d in name.split("x"))
+    return el.Grid(list(jax.devices()[:r * c]), height=r)
 
 
 def _symmetric(n):
@@ -93,7 +94,7 @@ def compiled(grid_name, n):
                      in reg.counters(name).items()}
               for name in ("herm_tridiag_panel", "herm_tridiag_symmetrize",
                            "herm_tridiag_hemv", "dc_merge", "dc_fill_block",
-                           "apply_q_panel")}
+                           "gemm_route", "apply_q_panel")}
     return exe, counts
 
 
@@ -151,6 +152,40 @@ def test_two_distributed_merge_levels_and_a_ragged_last_panel_on_2x2():
                                   (("kind", "distributed"),): 3}
     assert counts["dc_fill_block"] == {(): 4}
     _agrees_with_float64_numpy("2x2", N_DEEP)
+
+
+@pytest.mark.parametrize("grid_name", ["2x2", "2x4"])
+def test_a_level_of_merges_on_the_grain_is_one_loop(grid_name):
+    """n = 398: the level of two merges to 200 (blocks of 100, a multiple
+    of both strides on 2x2 and 2x4) is ONE merge compiled in a
+    ``fori_loop`` whose counter gives the blocks' offset, so a level costs
+    the program one merge whatever its width (31 merges, five bodies at
+    n = 16384 on 2x2: the unrolled program's cache entry, 214 MB, was over
+    the chip machine's 192 MiB an entry and every run compiled; PERF.md 6,
+    PR 51).  The last level's one merge is no loop, and the counters read
+    what the device runs: three merges, two products each."""
+    exe, counts = compiled(grid_name, N_DEEP)
+    names = op_names(exe.as_text())
+    k02 = [n for n in names if re.search(r"/el\.tridiag_eig/.*k02/merge/", n)]
+    k03 = [n for n in names if re.search(r"/el\.tridiag_eig/.*k03/merge/", n)]
+    before = [n.split("/el.tridiag_eig/")[1].split("/k0")[0]
+              for n in k02 + k03]
+    assert k02 and k03 and (
+        [b.endswith("/while/body/closed_call") for b in before]
+        == [True] * len(k02) + [False] * len(k03))
+    for level in (k02, k03):
+        assert any("/el.gemm/" in n for n in level)
+    assert counts["dc_merge"][(("kind", "distributed"),)] == 3
+    assert counts["gemm_route"] == {(("alg", "slice"),): 6}
+
+
+def test_one_device_keeps_its_merges_unrolled():
+    """The one-chip program is left as it was measured
+    (``heig.1x1.b2b``): no merge of its divide and conquer is in a loop."""
+    names = op_names(compiled("1x1", N_DEEP)[0].as_text())
+    merges = [n for n in names if re.search(r"/el\.tridiag_eig/.*/merge", n)]
+    assert merges and not any("/while/body/closed_call/k0" in n
+                              for n in merges)
 
 
 # -------------------------------------------------------------- the names
@@ -232,21 +267,38 @@ def test_phases_are_in_the_canonical_list():
 
 # --------------------------------------- the blocks are placed, not gathered
 
-_GATHER = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\](?:\{[^}]*\})? "
-                     r"gather\((.*)$", re.M)
+_MOVE = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\](?:\{[^}]*\})? "
+                   r"(gather|scatter)\(([^)]*)\)(.*)$", re.M)
+_ARRAY = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]", re.M)
+
+
+def _entries(dims):
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def big_moves(text, least):
+    """``[(entries, opcode, op_name)]`` of the ``gather`` and ``scatter``
+    instructions of the optimized HLO, on their own or inside a fusion's
+    computation, that move ``least`` entries or more: a gather's result,
+    a scatter's UPDATES (its last operand; its result is the whole array
+    written into, however few entries land there)."""
+    arrays = {name: _entries(dims) for name, dims in _ARRAY.findall(text)}
+    found = []
+    for dims, opcode, operands, rest in _MOVE.findall(text):
+        entries = _entries(dims)
+        if opcode == "scatter":
+            updates = re.findall(r"%?([A-Za-z_][\w.\-]*)\s*(?:,|$)", operands)
+            entries = arrays[updates[-1]]
+        name = re.search(r'op_name="([^"]*)"', rest)
+        if entries >= least:
+            found.append((entries, opcode, name.group(1) if name else ""))
+    return found
 
 
 def big_gathers(text, least):
-    """``[(entries, op_name)]`` of the ``gather`` instructions of the
-    optimized HLO, on their own or inside a fusion's computation, whose
-    result has ``least`` entries or more."""
-    found = []
-    for dims, rest in _GATHER.findall(text):
-        entries = math.prod(int(d) for d in dims.split(",") if d)
-        name = re.search(r'op_name="([^"]*)"', rest)
-        if entries >= least:
-            found.append((entries, name.group(1) if name else ""))
-    return found
+    """``[(entries, op_name)]`` of :func:`big_moves`' gathers."""
+    return [(entries, name) for entries, opcode, name
+            in big_moves(text, least) if opcode == "gather"]
 
 
 def test_the_gather_reader_finds_a_fused_gather():
@@ -254,31 +306,51 @@ def test_the_gather_reader_finds_a_fused_gather():
 %fused_computation.38 (param_0.116: f32[2,160,160], param_1: s32[320,320,3]) -> f32[320,320] {
   ROOT %gather.330 = f32[320,320]{1,0:T(8,128)} gather(%param_0.116, %param_1), offset_dims={}, metadata={op_name="jit(f)/k03/fill/gather" stack_frame_id=260}
 }
+%fused_computation.39 (param_0.117: f32[4,80,80], param_1.2: s32[316,3], param_2.3: f32[316]) -> f32[4,80,80] {
+  %param_0.117 = f32[4,80,80]{2,1,0} parameter(0)
+  %param_1.2 = s32[316,3]{1,0} parameter(1)
+  %param_2.3 = f32[316]{0} parameter(2)
+  ROOT %scatter.7 = f32[4,80,80]{2,1,0} scatter(%param_0.117, %param_1.2, %param_2.3), update_window_dims={}, to_apply=%add, metadata={op_name="jit(f)/k00/leaf/scatter-add"}
+}
 ENTRY %main {
   %gather.2 = f32[320]{0} gather(%a, %b), offset_dims={}
   %g = f32[102400]{0} gather(%a, %b), offset_dims={}
+  %u = f32[160,160]{1,0} fusion(%x), kind=kLoop, calls=%f
+  %s = f32[320,320]{1,0} scatter(%z, %i, %u), update_window_dims={}, to_apply=%set, metadata={op_name="jit(f)/el.redist.VC_STAR.to.MC_MR/scatter"}
 }"""
     assert big_gathers(text, 320 * 320) == [
         (102400, "jit(f)/k03/fill/gather"), (102400, "")]
     assert [size for size, _ in big_gathers(text, 320)] == [
         102400, 320, 102400]
+    # a scatter counts the entries it WRITES: the leaves' 316 couplings,
+    # not the 25,600 entries of the batch they are added to
+    assert [found for found in big_moves(text, 300)
+            if found[1] == "scatter"] == [
+        (316, "scatter", "jit(f)/k00/leaf/scatter-add"),
+        (25600, "scatter", "jit(f)/el.redist.VC_STAR.to.MC_MR/scatter")]
 
 
-@pytest.mark.parametrize("grid_name", GRIDS)
-def test_hand_off_gathers_no_entry_of_the_eigenvector_matrix(grid_name):
+@pytest.mark.parametrize("grid_name,n", [("1x1", 320), ("2x2", 320),
+                                         ("2x2", N_DEEP), ("2x4", N_DEEP)])
+def test_hand_off_gathers_no_entry_of_the_eigenvector_matrix(grid_name, n):
     """ISSUE 42: the batch of eigenvector blocks reaches the [MC,MR] matrix
     as dense block copies.  Laid out by a function of (i, j) the compiler
     made ONE gather over all npad^2 entries, 22.7 ns an entry on the chip
-    (6.1 of ``heig.1x1.b2b``'s 15.8 s); no gather of the drivers' own may
-    have a device's share of the matrix, npad^2 / chips entries, or more.
-    (The ENGINE unpacks a gathered block with one on this backend, under
-    its ``el.redist.`` name: on 2x2 the merge's ``[MC,MR] -> [STAR,STAR]``
-    of a 160-block is 160^2 = 320^2 / 4 entries, a shard's size by
-    coincidence of B = 2 on four chips.)"""
-    n, chips = 320, 1 if grid_name == "1x1" else 4
-    text = compiled(grid_name, n)[0].as_text()
-    assert not [found for found in big_gathers(text, n * n // chips)
-                if "el.redist." not in found[1]]
+    (6.1 of ``heig.1x1.b2b``'s 15.8 s).  ISSUE 51: the distributed merges'
+    products moved their operands the same way, through the plan
+    executor's index tables (a gather in, a scatter out, 15 ns an entry:
+    1.94 of ``heig.2x2.b2b``'s 5.58 s), which this test let pass under
+    their ``el.redist.`` name.  Under ANY name, no gather and no scatter of
+    the compiled eigensolve moves a device's share of the matrix, n^2 /
+    chips entries, or more: every product of a distributed merge (two a
+    merge, all of them ``slice`` at these sizes) moves blocks."""
+    chips = _grid(grid_name).size
+    exe, counts = compiled(grid_name, n)
+    assert not big_moves(exe.as_text(), n * n // chips)
+    merges = counts["dc_merge"].get((("kind", "distributed"),), 0)
+    assert merges == {320: 1, N_DEEP: 3}[n]
+    assert counts["gemm_route"] == (
+        {(("alg", "slice"),): 2 * merges} if chips > 1 else {})
 
 
 # ------------------------------------- one read of the trailing view a column

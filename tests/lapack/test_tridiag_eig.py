@@ -93,6 +93,30 @@ def test_distributed_phase(any_grid):
     _check(d, e, w, Zd, tol=1e-9)
 
 
+@pytest.mark.parametrize("shape", [(2, 2), (2, 4)],
+                         ids=lambda rc: f"grid{rc[0]}x{rc[1]}")
+def test_every_rolled_level_runs_its_own_merge(shape):
+    """Sixteen blocks of 32 at the hand-off: three levels of merges (8, 4,
+    2) whose blocks lie on the grid's grain, each ONE merge in a
+    ``fori_loop``, then the last merge alone.  The levels' loops carry the
+    same two arrays, and ``fori_loop`` keeps the traced body of a function
+    it has seen for a carry: handed ONE merge function for all levels it
+    ran the first level's body, blocks of 32, at every level (residual 1;
+    only a tree with two rolled levels or more shows it).  The counters
+    read what the device runs: fifteen merges, two products each."""
+    from elemental_tpu import obs
+    grid = el.Grid(jax.devices()[:shape[0] * shape[1]], height=shape[0])
+    rng = np.random.default_rng(7)
+    n = 512
+    d, e = rng.standard_normal(n), rng.standard_normal(n - 1)
+    with obs.metrics_scope() as reg:
+        w, Zd = jax.jit(lambda d, e: tridiag_eig(
+            d, e, grid=grid, vectors=True, leaf_max=16, repl_max=32))(d, e)
+    _check(d, e, w, Zd, tol=1e-9)
+    assert reg.counter_value("dc_merge", kind="distributed") == 15
+    assert sum(reg.counters("gemm_route").values()) == 30
+
+
 @pytest.mark.parametrize("B,nm", [(4, 88), (3, 63)])
 def test_hand_off_places_the_blocks_bit_for_bit(any_grid, B, nm):
     """The hand-off between the replicated levels and the distributed ones

@@ -68,6 +68,20 @@ def test_scoped_isolation():
     assert m.current().counter_value("inner_counter") == 0
 
 
+def test_repeated_weighs_every_tick_in_the_block():
+    """A rolled loop's body is traced once and run ``trips`` times."""
+    with m.scoped() as reg:
+        m.inc("tick")
+        with m.repeated(4):
+            m.inc("tick")
+            m.inc("tick", 2, kind="x")
+            with m.repeated(3):
+                m.inc("tick")
+        m.inc("tick")
+        assert reg.counter_value("tick") == 1 + 4 + 12 + 1
+        assert reg.counter_value("tick", kind="x") == 8
+
+
 def test_label_coercion_keeps_json_safe():
     reg = m.MetricsRegistry()
     reg.inc("c", label=(1, 2))              # non-scalar label -> str()

@@ -566,12 +566,23 @@ def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
     ``copy``, ``transpose``, ``select``, ``slice`` or update makes a local
     view's worth of data in any loop body, and exactly one fusion, the
     product's, takes the view as a parameter.  The plan beside the shard:
-    10.38 shards at the cell's n = 16384 (ISSUE 50's rehearsal), 12.33 at
-    4096 and 14.74 here, where panels and vectors weigh more: under 20."""
+    10.08 shards at the cell's n = 16384 and 4.14 here (PR 51's
+    rehearsals; 10.38 and 14.74 while the merges' hops carried the plan
+    executor's index tables as constants of the program): under 6.
+
+    ISSUE 51, the same program: its three distributed merges (two of 512,
+    one of 1024) are six ``gemm`` products, every one ``slice``, whose
+    hops move blocks through the engine's fused kernels.  Under ANY name
+    no ``gather`` and no ``scatter`` moves a device's share of the matrix,
+    n^2 / 4 entries, or more (through the plan executor's index tables
+    each product packed and unpacked an entry at a time: 1.94 of
+    ``heig.2x2.b2b``'s 5.58 s), and nothing under a merge holds a minor
+    dimension of 2 on 128 lanes (an interleave merged with a neighbour's
+    reshape: the local matmul, ``interior_view``, ``interior_update``)."""
     import elemental_tpu as el
     from elemental_tpu import obs
-    from .lapack.test_herm_eig_compiled import (COLLECTIVE, column_loops,
-                                                square_ops)
+    from .lapack.test_herm_eig_compiled import (COLLECTIVE, big_moves,
+                                                column_loops, square_ops)
     n, nb = 2048, 256
     A = _abstract(grid22, n, n, el.MC, el.MR)
     with jax.enable_x64(False), obs.metrics_scope() as reg:
@@ -583,6 +594,15 @@ def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
         ("herm_tridiag_symmetrize", ()): n // nb}
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
+    assert dict(reg.counters("dc_merge"))[
+        "dc_merge", (("kind", "distributed"),)] == 3
+    assert dict(reg.counters("gemm_route")) == {
+        ("gemm_route", (("alg", "slice"),)): 6}
+    assert not big_moves(text, n * n // 4)
+    merges = "\n".join(line for line in text.splitlines()
+                       if re.search(r'op_name="[^"]*/merge/', line))
+    assert merges.count("/el.gemm/") > 100
+    assert not _padded_small_minor(merges, over=1 << 20)
     loops = column_loops(text)
     assert sorted(loops) == list(range(n // nb))
     for k, lines in loops.items():
@@ -614,7 +634,14 @@ def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
     mem = compiled.memory_analysis()
     plan = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert plan < 20 * (4 * n * n // 4), plan
+    assert plan < 6 * (4 * n * n // 4), plan
+    # what the program costs the persistent compile cache (the chip
+    # machine takes no entry over 192 MiB, and with every merge unrolled
+    # the cell's program, 214 MB, was compiled by every run): 16.3 MB
+    # here with the two merges of 512 unrolled, 154.6 MB at the cell's n
+    # with a level rolled into one loop (perf/program_size.py)
+    from perf.program_size import CACHE_ENTRY_LIMIT, entry_bytes
+    assert entry_bytes(compiled)[1] < CACHE_ENTRY_LIMIT // 10
 
 
 def test_mixed_solve_factors_its_diagonal_blocks_in_vmem(topo):

@@ -1,7 +1,7 @@
 """ISSUE 16: 'slice' in the gemm alg space -- cost-model ranking pins.
 
-``alg='auto'`` must pick 'slice' exactly where its three one-shot plans
-win (tall-skinny / non-square-grid geometry) and keep every existing
+``alg='auto'`` must pick 'slice' exactly where its three single-collective
+hops win (tall-skinny / non-square-grid geometry) and keep every existing
 winner elsewhere: gspmd on square and long-k grids, the pinned dot
 early-out on 1x1 (candidate-order tie-break, byte-identical)."""
 import math
@@ -64,8 +64,10 @@ def test_auto_keeps_existing_winners_elsewhere():
 
 
 def test_slice_priced_identically_across_redist_path():
-    """The slice gathers ARE one-shot plans: the redist_path crossing
-    must not change its score (deterministic resolution)."""
+    """The slicing route takes no ``redist_path`` (its hops are the
+    engine's fused single-collective kernels, priced by the compiled
+    plans' byte math): the knob's crossing must not change its score
+    (deterministic resolution)."""
     from elemental_tpu.tune import cost_model as cm
     ctx = TuneContext("gemm", (8192, 512, 256), "float32", (2, 4), "cpu")
     scores = [cm.score_config("gemm", {"alg": "slice", "nb": None,
