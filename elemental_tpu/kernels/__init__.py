@@ -21,9 +21,11 @@ resident in VMEM:
 Beside them, :func:`symv_lower` -- ``(tril(A) + stril(A)^T) x`` from one
 read of the stored lower triangle, a grid over its tiles alone -- is what
 ``lapack.condense.hermitian_tridiag`` multiplies by once a column on one
-TPU chip (no knob: the driver decides from its input,
+TPU chip and, each chip on its own shard through the kernel's shard form
+(``symv.symv_lower_shard``, inside a ``shard_map``), on a square grid of
+them (no knob: the driver decides from its input,
 ``condense._reads_triangle_once``), and the first kernel of this package
-a benchmark cell runs (``heig.1x1.b2b``).  And :func:`lu_nopiv_block` --
+a benchmark cell runs (``heig.1x1.b2b``, since PR 52 ``heig.2x2.b2b``).  And :func:`lu_nopiv_block` --
 the unpivoted column recurrence of one square block, resident in VMEM:
 ``lu_panel``'s unblocked mode without its pivot search, row swap and
 pivot output, twin of the ``fori_loop`` inside ``lapack.lu._lu_nopiv`` --

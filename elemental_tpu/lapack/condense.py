@@ -19,7 +19,18 @@ and it takes one of two forms, chosen once from the input
   two products).  The kernel reads the view through its TRANSPOSE: the
   TPU compiler holds the working matrix column-major, so the transpose is
   the same bytes row-major, the layout a kernel's operand has;
-* everywhere else (grids of several chips, complex entries, the CPU):
+* on a SQUARE grid of TPU chips (``r == c``), real float32: the same, in
+  ONE ``shard_map`` a column (:func:`_symv_grid`).  Panels start on
+  multiples of ``r``, so chip ``(p, q)`` holds ``A[p + r i, q + r j]`` of
+  the zero-aligned view and its stored part is a LOCAL lower triangle; the
+  kernel's shard form reads it once, as stored (the compiler holds the
+  grid's working shard row-major), for the chip's two products (against
+  ``v[q::r]`` for the rows it owns and, transposed, against ``v[p::r]``
+  for the columns it owns: the two accumulators), and ONE all-reduce of a
+  replicated vector joins the partial results.  The loop keeps its
+  vectors in residue-major order there, so cuts and places are blocks;
+* everywhere else (non-square grids, where a shard's stored part is a
+  trapezoid; complex entries; the CPU):
   once a panel the view is made Hermitian-full (its stored lower triangle
   mirrored above the diagonal: one transpose exchange) and per column a
   single plain :func:`~elemental_tpu.blas.level2.gemv` reads that full
@@ -48,6 +59,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from jax.sharding import PartitionSpec
+
+from ..core.compat import shard_map
 from ..core.dist import MC, MR, STAR
 from ..core.distmatrix import DistMatrix
 from ..core.view import view, update_view, round_up
@@ -57,7 +71,7 @@ from ..blas.level1 import _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle
 from ..obs import metrics as _metrics
 from ..obs.tracer import NULL_HOOK
-from ..kernels.symv import symv_lower
+from ..kernels.symv import shard_block, symv_lower, symv_lower_shard
 from .lu import _update_cols_lt, _hi, _phase_hook, _scoped
 from .qr import _larft
 
@@ -76,10 +90,12 @@ def _unwrap_vec(x: DistMatrix):
     return redistribute(x, STAR, STAR).local[:, 0]
 
 
-def _larfg_at(col, piv, ridx, dtype):
+def _larfg_at(col, piv, ridx, dtype, at=None):
     """Householder reflector pivoting at row ``piv`` (zeroes rows > piv):
-    real beta, H = I - tau v v^H, implicit v[piv] = 1."""
-    alpha = col[piv]
+    real beta, H = I - tau v v^H, implicit v[piv] = 1.  ``ridx`` holds the
+    row each entry of ``col`` is; ``at`` is where row ``piv`` lies in it
+    (``piv`` itself unless the rows are kept in another order)."""
+    alpha = col[piv if at is None else at]
     tail2 = jnp.where(ridx > piv, col, 0)
     sigma = jnp.sum(jnp.abs(tail2) ** 2)
     anorm = jnp.sqrt(jnp.abs(alpha) ** 2 + sigma)
@@ -95,10 +111,11 @@ def _larfg_at(col, piv, ridx, dtype):
     return v.astype(dtype), jnp.asarray(tau, dtype), beta
 
 
-def _larfg_tail(col, jj, ridx, dtype):
+def _larfg_tail(col, jj, ridx, dtype, at=None):
     """Householder reflector zeroing rows > jj+1 of ``col`` (LAPACK larfg:
-    real beta, H = I - tau v v^H with implicit v[jj+1] = 1)."""
-    return _larfg_at(col, jj + 1, ridx, dtype)
+    real beta, H = I - tau v v^H with implicit v[jj+1] = 1); ``at`` as in
+    :func:`_larfg_at`, the place of row ``jj + 1``."""
+    return _larfg_at(col, jj + 1, ridx, dtype, at)
 
 
 def _hermitian_full(Atrail: DistMatrix) -> DistMatrix:
@@ -116,31 +133,99 @@ def _hermitian_full(Atrail: DistMatrix) -> DistMatrix:
 
 def _reads_triangle_once(A: DistMatrix) -> bool:
     """The rule of :func:`hermitian_tridiag`'s matvec, from what the input
-    shows: the grid is ONE chip (an element-cyclic shard of a Hermitian
-    matrix is not locally symmetric), the chip is a TPU (true of a described
-    topology too, so a rehearsal takes the path; on the CPU the kernel would
-    be interpreted once a column) and the entries are real float32 (Mosaic
-    has no complex type; the kernel's products and sums are float32 on the
-    VPU, no lower than any ``precision``).  Then the column loop's matvec is
-    the one-pass triangle ``symv`` kernel on the trailing view AS STORED and
-    nothing is mirrored (measured, PERF.md 6, PR 44); everything else
-    mirrors the view once a panel and reads the full square."""
-    return (A.grid.size == 1 and A.grid.devices[0].platform == "tpu"
+    shows: the grid is ONE chip or SQUARE (``r == c``: with panels on
+    multiples of ``r`` a chip's shard of the zero-aligned trailing view
+    stores a local lower triangle, its diagonal kept or not by the chip's
+    place; on ``r != c`` the stored part is a trapezoid), the chip is a TPU
+    (true of a described topology too, so a rehearsal takes the path; on
+    the CPU the kernel would be interpreted once a column) and the entries
+    are real float32 (Mosaic has no complex type; the kernel's products
+    and sums are float32 on the VPU, no lower than any ``precision``).
+    Then the column loop's matvec is the one-pass triangle ``symv`` kernel
+    on the trailing view AS STORED, each chip on its own shard, and nothing
+    is mirrored (measured, PERF.md 6, PR 44 and PR 52); everything else
+    (non-square grids, complex entries, the CPU) mirrors the view once a
+    panel and reads the full square."""
+    g = A.grid
+    return (g.height == g.width and g.devices[0].platform == "tpu"
             and A.dtype == jnp.float32)
+
+
+def _hemv_impl(A: DistMatrix) -> str:
+    """``herm_tridiag_hemv``'s label: which matvec the column loops take."""
+    if not _reads_triangle_once(A):
+        return "mirror"
+    return "symv" if A.grid.size == 1 else "symv_grid"
+
+
+def _residue_major(x, r: int, block: int):
+    """The rows of a replicated ``(nt, ...)`` array in the STORAGE order of
+    an ``r``-cyclic distribution: rows ``p, p + r, ...`` together, residue
+    after residue, each residue's rows padded with zero rows to ``block``
+    (at least ``ceil(nt / r)``).  Row ``i`` goes to ``(i % r) block + i //
+    r``; the two cuts the grid's matvec needs of a vector (``v[p::r]``,
+    ``v[q::r]``) are contiguous blocks there."""
+    x = jnp.pad(x, [(0, r * block - x.shape[0])] + [(0, 0)] * (x.ndim - 1))
+    return jnp.swapaxes(x.reshape((block, r) + x.shape[1:]), 0, 1).reshape(
+        x.shape)
+
+
+def _natural(x, r: int, nt: int):
+    """:func:`_residue_major`'s inverse: the ``nt`` rows in their order."""
+    block = x.shape[0] // r
+    return jnp.swapaxes(x.reshape((r, block) + x.shape[1:]), 0, 1).reshape(
+        x.shape)[:nt]
+
+
+def _symv_grid(Atrail: DistMatrix, v, interpret: bool):
+    """``(tril(A) + stril(A)^T) v`` on a square ``r x r`` grid, from ONE
+    read of each chip's shard as stored and ONE all-reduce.  ``v`` and the
+    result are replicated vectors in residue-major order
+    (:func:`_residue_major`, blocks of :func:`~elemental_tpu.kernels.symv.
+    shard_block`).  Chip ``(p, q)`` reads blocks ``q`` and ``p`` of ``v``
+    (``v[q::r]``, ``v[p::r]``), multiplies its stored part by the one and,
+    transposed, by the other (:func:`~elemental_tpu.kernels.symv.
+    symv_lower_shard`: the reference's two accumulators, ``[MC,STAR]`` and
+    ``[MR,STAR]``) and leaves the two partial results in blocks ``p`` and
+    ``q`` of a zero vector, whose sum over all chips IS the product,
+    replicated: what the column loop wants next.  Cuts and places are block
+    rows the kernel indexes, so a column is the kernel and the sum (the
+    reference's ``Contract`` to ``[STAR,STAR]``: ``el.redist.hemv_join``)."""
+    g = Atrail.grid
+    r, nt = g.height, Atrail.gshape[0]
+
+    def f(a, v):
+        part = symv_lower_shard(a, v, lax.axis_index("mc"),
+                                lax.axis_index("mr"), stride=r, nt=nt,
+                                interpret=interpret)
+        with jax.named_scope("el.redist.hemv_join"):
+            return lax.psum(part, ("mc", "mr"))
+
+    return shard_map(f, mesh=g.mesh,
+                     in_specs=(Atrail.spec, PartitionSpec()),
+                     out_specs=PartitionSpec())(Atrail.local, v)
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
-                   precision, step: int, symv: bool):
+                   precision, step: int, impl: str):
     """latrd: reduce ``nbw`` columns of the trailing matrix.
 
     ``Atrail`` is the panel's fixed (nt, nt) [MC,MR] trailing view in the
-    form its matvec reads: with ``symv`` AS STORED, the one chip's array
-    whose lower triangle the kernel reads once a column (what lies above
-    the diagonal is never read); without, made Hermitian-full
+    form its matvec reads (``impl``, :func:`_hemv_impl`): ``symv`` AS
+    STORED, the one chip's array whose lower triangle the kernel reads once
+    a column (what lies above the diagonal is never read); ``symv_grid`` AS
+    STORED too, each chip of a square grid reading its own shard's stored
+    part once a column (:func:`_symv_grid`); ``mirror`` made Hermitian-full
     (:func:`_hermitian_full`) and read whole by one ``gemv`` a column.
     ``P`` the replicated panel columns.  Returns (V, W, d, e, tau) with
     V/W the (nt, nbw) replicated reflector/update panels.
+
+    With ``symv_grid`` the loop keeps its vectors (the rows of ``P``, ``V``,
+    ``W``) in residue-major order, so that a chip's two cuts of ``v`` and
+    the two places of its results are contiguous blocks: ``ridx`` is then
+    the map from storage to row, ``at`` the map back, and the panels are
+    put in their natural order once, after the loop.
 
     The column loop names its ops ``k<step>/hemv`` (the one matvec against
     the trailing view) and ``k<step>/panel`` (all the rest), side by
@@ -152,26 +237,43 @@ def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
     dtype = P.dtype
     rdtype = _real_dtype(dtype)
     ridx = jnp.arange(nt)
+    at = lambda j: j                                          # noqa: E731
     nd = nbw + 1 if extract_last else nbw
+    interpret = g.devices[0].platform != "tpu"
+    reordered = impl == "symv_grid"
+    if reordered:
+        r = g.height
+        block, _tile = shard_block(nt, r)
+        P = _residue_major(P, r, block)
+        # the row each place holds (a place of padding reads nt or more).
+        # Held as ONE array the loop reads: computed inside its consumers
+        # the map makes every vector of the loop two-dimensional
+        place = jnp.arange(r * block)
+        ridx = lax.optimization_barrier(place // block + r * (place % block))
+        at = lambda j: lax.rem(j, r) * block + lax.div(j, r)  # noqa: E731
+    rows = P.shape[0]
 
     def corrected_col(P, V, W, jj):
-        return P[:, jj] - V @ jnp.conj(W[jj, :]) - W @ jnp.conj(V[jj, :])
+        return (P[:, jj] - V @ jnp.conj(W[at(jj), :])
+                - W @ jnp.conj(V[at(jj), :]))
 
     def body(jj, carry):
         V, W, d, e, tau = carry
         with tm.phase("panel", step):
             col = corrected_col(P, V, W, jj)
-            d = d.at[jj].set(jnp.real(col[jj]).astype(rdtype))
-            v, tau_j, beta = _larfg_tail(col, jj, ridx, dtype)
+            d = d.at[jj].set(jnp.real(col[at(jj)]).astype(rdtype))
+            v, tau_j, beta = _larfg_tail(
+                col, jj, ridx, dtype, at(jj + 1) if reordered else None)
             e = e.at[jj].set(beta.astype(rdtype))
         # the one op per column against the trailing matrix: u = A_trail v,
         # one read of the view (v's leading zeros make this the reference's
         # A22*v on the true subproblem)
         with tm.phase("hemv", step):
-            if symv:
+            if impl == "symv":
                 # compiled wherever the chip is a TPU, a described one too
-                u = symv_lower(Atrail.local, v,
-                               interpret=g.devices[0].platform != "tpu")
+                u = symv_lower(Atrail.local, v, interpret=interpret)
+            elif impl == "symv_grid":
+                u = _symv_grid(Atrail, v, interpret)
             else:
                 u = _unwrap_vec(gemv(Atrail, _wrap_vec(v, g),
                                      precision=_hi(precision)))
@@ -186,7 +288,7 @@ def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
         return V, W, d, e, tau
 
     with tm.phase("panel", step):
-        init = (jnp.zeros((nt, nbw), dtype), jnp.zeros((nt, nbw), dtype),
+        init = (jnp.zeros((rows, nbw), dtype), jnp.zeros((rows, nbw), dtype),
                 jnp.zeros((nd,), rdtype), jnp.zeros((nbw,), rdtype),
                 jnp.zeros((nbw,), dtype))
     # the loop itself stays outside a phase: a scope around it would come
@@ -195,7 +297,10 @@ def _tridiag_panel(Atrail: DistMatrix, P, nbw: int, extract_last: bool,
     if extract_last:
         with tm.phase("panel", step):
             col = corrected_col(P, V, W, nbw)
-            d = d.at[nbw].set(jnp.real(col[nbw]).astype(rdtype))
+            d = d.at[nbw].set(jnp.real(col[at(nbw)]).astype(rdtype))
+    if reordered:
+        with tm.phase("panel", step):
+            V, W = _natural(V, r, nt), _natural(W, r, nt)
     return V, W, d, e, tau
 
 
@@ -222,13 +327,17 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     Scopes (``el.hermitian_tridiag/k<panel>/...``): ``hemv`` (the matvec's
     operand, made once a panel outside the column loop, and the loop's one
     matvec against it: on one TPU chip the slice of the trailing view as
-    stored and the ``el_symv_lower`` kernel; elsewhere the view's mirror
-    into a full Hermitian matrix and a ``gemv``), ``panel``
+    stored and the ``el_symv_lower`` kernel; on a square grid of them each
+    chip's kernel on its shard of that slice and, under
+    ``hemv/shard_map/el.redist.hemv_join``, the one all-reduce that joins
+    them; elsewhere the view's mirror into a full Hermitian matrix and a
+    ``gemv``), ``panel``
     (the rest of the column loop and the packed panel's store), ``update``
     (the rank-2k trailing update and its hops); ``herm_tridiag_panel`` counts
     the panels, ``herm_tridiag_hemv{impl}`` which matvec each took (``symv`` |
-    ``mirror``) and ``herm_tridiag_symmetrize`` the mirrors (one a panel
-    on the mirror path, none on the other).
+    ``symv_grid`` | ``mirror``: :func:`_hemv_impl`) and
+    ``herm_tridiag_symmetrize`` the mirrors (one a panel on the mirror
+    path, none on the others).
     """
     _check_mcmr(A)
     n = A.gshape[0]
@@ -250,7 +359,7 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
     ib = _blocksize(nb, math.lcm(r, c), n)
     kend = n - 1                          # reflector columns 0 .. n-2
     tm = _phase_hook("hermitian_tridiag")
-    symv = _reads_triangle_once(A)
+    impl = _hemv_impl(A)
     Ap = A
     d_parts, e_parts, tau_parts = [], [], []
     s = 0
@@ -262,17 +371,17 @@ def hermitian_tridiag(A: DistMatrix, uplo: str = "L", nb: int | None = None,
         final = e_col == kend
         wp_end = n if final else min(round_up(e_col, c), n)
         # once a panel, never once a column: the matvec's operand
-        _metrics.inc("herm_tridiag_hemv", impl="symv" if symv else "mirror")
+        _metrics.inc("herm_tridiag_hemv", impl=impl)
         with tm.phase("hemv", k) as ph:
             Atrail = view(Ap, rows=(s, n), cols=(s, n))
-            if not symv:
+            if impl == "mirror":
                 _metrics.inc("herm_tridiag_symmetrize")
                 Atrail = _hermitian_full(Atrail)
             ph.done(Atrail.local)
         P = redistribute(view(Ap, rows=(s, n), cols=(s, wp_end)), STAR, STAR).local
         # the column loop names its own phases (hemv beside panel)
         V, W, dpan, epan, taupan = _tridiag_panel(Atrail, P, nbw, final,
-                                                  precision, k, symv)
+                                                  precision, k, impl)
         d_parts.append(dpan)
         e_parts.append(epan)
         tau_parts.append(taupan)

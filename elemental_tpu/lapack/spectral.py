@@ -105,9 +105,9 @@ def herm_eig(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     ``el.apply_q_herm_tridiag/k<panel>/apply``; the trace-time counters
     ``herm_tridiag_panel``, ``herm_tridiag_hemv{impl}``,
     ``herm_tridiag_symmetrize``, ``dc_merge{kind}``, ``dc_fill_block`` and
-    ``apply_q_panel`` count the panels, which matvec each took (on one TPU
-    chip, real float32, the one-pass triangle ``symv`` kernel:
-    ``impl=symv``, nothing mirrored; elsewhere ``impl=mirror``), the
+    ``apply_q_panel`` count the panels, which matvec each took (real float32
+    on one TPU chip or a square grid of them, the one-pass triangle kernel:
+    ``impl=symv`` | ``symv_grid``, no mirror; elsewhere ``impl=mirror``), the
     mirrors of the trailing view (one a panel on that path), the merges
     and the eigenvector blocks placed on the [MC,MR] matrix's diagonal
     between the two kinds of merge.

@@ -64,8 +64,14 @@ time by them.  A path is made of:
                            on one TPU chip the slice of the trailing
                            view as stored and the ``el_symv_lower``
                            kernel, which reads its lower triangle once;
-                           elsewhere the view's mirror into a full
-                           Hermitian matrix and a ``gemv``) BESIDE
+                           on a square grid of TPU chips the same, each
+                           chip's kernel on its own shard inside a
+                           ``shard_map``, and the ONE all-reduce that
+                           joins the partial results, which reads
+                           ``k<panel>/hemv/shard_map/el.redist.hemv_join``
+                           and so ``redist``; elsewhere the view's mirror
+                           into a full Hermitian matrix and a ``gemv``)
+                           BESIDE
                            ``k<panel>/panel`` (the
                            rest of the column loop, the packed panel's
                            store) and ``k<panel>/update`` (the rank-2k
@@ -125,7 +131,12 @@ time by them.  A path is made of:
                            it emits: the collectives and the local pack /
                            unpack / reshape / copy beside them;
                            ``el.redist.panel_spread`` and
-                           ``el.redist.row_permute`` likewise
+                           ``el.redist.row_permute`` likewise, and
+                           ``el.redist.hemv_join`` (the grid
+                           tridiagonalization's sum of a column's
+                           partial matvecs over all chips: one ``psum``
+                           of a replicated vector, the reference's
+                           ``Contract`` to ``[STAR,STAR]``)
   ``el_potrf_inv_panel`` / the ``name=`` of the Pallas kernels
   ``el_lu_panel`` /        (``kernels/``: the three panel kernels, the
   ``el_qr_panel`` /        one-pass triangle ``symv`` and the unpivoted
@@ -185,13 +196,19 @@ not tick again).  Read them under ``metrics_scope()``:
   ``herm_tridiag_hemv{impl}``   one panel's choice of matvec: ``impl``
                            ``symv`` (one TPU chip, real float32: the
                            ``el_symv_lower`` kernel on the view as
-                           stored; 64 at n = 16384, nb 256) | ``mirror``
-                           (everything else: ``lapack/condense.py:
-                           _reads_triangle_once``)
+                           stored; 64 at n = 16384, nb 256) |
+                           ``symv_grid`` (a SQUARE grid of TPU chips,
+                           real float32: the kernel's shard form on
+                           each chip's shard as stored and one
+                           all-reduce; 64 there too) | ``mirror``
+                           (everything else, non-square grids, complex
+                           entries and the CPU among it:
+                           ``lapack/condense.py:_reads_triangle_once``)
   ``herm_tridiag_symmetrize``   one mirror of a panel's trailing view
                            into a full Hermitian matrix, the operand of
                            the mirror path's ``gemv``: one a panel there,
-                           never one a column; none on the ``symv`` path
+                           never one a column; none on the ``symv`` and
+                           ``symv_grid`` paths
   ``dc_merge{kind}``       one merge of ``tridiag_eig``: ``kind``
                            ``replicated`` (a level of the vmapped batch
                            ticks once for each of its merges) |

@@ -68,6 +68,7 @@ def test_hermitian_tridiag_uplo_upper(grid24):
 
 def _grid(name):
     return (Grid(jax.devices()[:1]) if name == "1x1"
+            else Grid(jax.devices()[:4], height=2) if name == "2x2"
             else Grid(jax.devices(), height=2))
 
 
@@ -134,21 +135,29 @@ def test_mirror_keeps_the_stored_diagonal(grid_name, monkeypatch):
         np.testing.assert_allclose(g, w, rtol=0, atol=1e-12 * np.abs(w).max())
 
 
-# the one-chip path through the one-pass triangle symv kernel (ISSUE 44)
+# the paths through the one-pass triangle symv kernel: one chip (ISSUE 44)
+# and a square grid, each chip on its own shard (ISSUE 52)
 
-@pytest.mark.parametrize("n,nb", [(320, 64), (257, 64)])
-def test_symv_path_gives_the_mirror_paths_reduction(n, nb, monkeypatch):
-    """Nothing mirrored, the kernel on the view as stored: ``d``, ``e``,
-    ``tau`` and the packed lower triangle are the mirror path's to
-    rounding.  Float64, because the reduction amplifies a rounding along
+@pytest.mark.parametrize("n,nb,grid_name,impl", [
+    (320, 64, "1x1", "symv"), (257, 64, "1x1", "symv"),
+    (320, 64, "2x2", "symv_grid"), (257, 64, "2x2", "symv_grid"),
+    (37, 8, "2x2", "symv_grid")])
+def test_symv_path_gives_the_mirror_paths_reduction(n, nb, grid_name, impl,
+                                                    monkeypatch):
+    """Nothing mirrored, the kernel on the view as stored (on 2x2 each
+    chip's on its own shard, the vectors of the loop in residue-major
+    order, one ``psum`` a column): ``d``, ``e``, ``tau`` and the packed
+    lower triangle are the mirror path's to rounding, odd orders (a chip of
+    the last residue holds a line less) and a ragged last panel included.
+    Float64, because the reduction amplifies a rounding along
     the columns (float32 moves the last ``d`` by 1e-3 here, and both
     tridiagonal matrices have ``A``'s eigenvalues to 1e-6: the test
     below); garbage above the diagonal must not matter on either path."""
     A = _herm(n, jnp.float64, seed=n)
     A[np.triu_indices(n, 1)] = np.nan
-    Ad = from_global(A, MC, MR, _grid("1x1"))
+    Ad = from_global(A, MC, MR, _grid(grid_name))
     want = _stored(compiled(hermitian_tridiag, nb=nb)(Ad))
-    # the path of one TPU chip; the grid is the CPU's, so the kernel is
+    # the path of a TPU; the grid is the CPU's, so the kernel is
     # interpreted.  (The choice is a static argument of the jitted panel:
     # nothing traced for the mirror path is handed back.)
     monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
@@ -156,36 +165,61 @@ def test_symv_path_gives_the_mirror_paths_reduction(n, nb, monkeypatch):
         got = _stored(compiled(hermitian_tridiag, nb=nb)(Ad))
     panels = -(-(n - 1) // nb)
     assert dict(reg.counters("herm_tridiag_hemv")) == {
-        ("herm_tridiag_hemv", (("impl", "symv"),)): panels}
+        ("herm_tridiag_hemv", (("impl", impl),)): panels}
     assert not reg.counters("herm_tridiag_symmetrize")
     for w, g in zip(want, got):
         np.testing.assert_allclose(g, w, rtol=0, atol=1e-9 * np.abs(w).max())
 
 
-def test_herm_eig_through_the_symv_path_agrees_with_numpy(monkeypatch):
+def test_the_grid_path_reads_the_upper_triangle_too(monkeypatch):
+    """``uplo='U'`` is transposed to lower first, on every path."""
+    n = 44
+    A = _herm(n, jnp.float64, seed=5)
+    Abad = A.copy()
+    Abad[np.tril_indices(n, -1)] = np.nan
+    Ad = from_global(Abad, MC, MR, _grid("2x2"))
+    monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
+    _Ap, d, e, _tau = compiled(hermitian_tridiag, uplo="U", nb=8)(Ad)
+    np.testing.assert_allclose(np.linalg.eigvalsh(_tridiag_full(d, e)),
+                               np.linalg.eigvalsh(A), rtol=1e-10, atol=1e-10)
+
+
+@pytest.mark.parametrize("grid_name", ["1x1", "2x2"])
+def test_herm_eig_through_the_symv_path_agrees_with_numpy(grid_name,
+                                                          monkeypatch):
     from elemental_tpu.lapack.spectral import herm_eig
     monkeypatch.setattr(condense, "_reads_triangle_once", lambda A: True)
     n = 129
     A = _herm(n, jnp.float32, seed=44)
     w = compiled(herm_eig, nb=64, vectors=False)(
-        from_global(A, MC, MR, _grid("1x1")))
+        from_global(A, MC, MR, _grid(grid_name)))
     want = np.linalg.eigvalsh(A.astype(np.float64))
     assert np.abs(np.asarray(w, np.float64) - want).max() <= (
         50 * np.finfo(np.float32).eps * np.abs(want).max())
 
 
-@pytest.mark.parametrize("chips,platform,dtype,want", [
-    (1, "tpu", jnp.float32, True),
-    (4, "tpu", jnp.float32, False),     # a shard is not locally symmetric
-    (1, "cpu", jnp.float32, False),     # an interpreted kernel a column
-    (1, "tpu", jnp.float64, False),
-    (1, "tpu", jnp.complex64, False),   # Mosaic has no complex type
-    (1, "tpu", jnp.bfloat16, False)])
-def test_the_rule_reads_the_grid_and_the_dtype(chips, platform, dtype, want):
+@pytest.mark.parametrize("shape,platform,dtype,want", [
+    ((1, 1), "tpu", jnp.float32, "symv"),
+    ((2, 2), "tpu", jnp.float32, "symv_grid"),   # a shard's stored part is
+    ((4, 4), "tpu", jnp.float32, "symv_grid"),   # a local lower triangle
+    ((2, 4), "tpu", jnp.float32, "mirror"),      # ... a trapezoid
+    ((1, 4), "tpu", jnp.float32, "mirror"),
+    ((4, 1), "tpu", jnp.float32, "mirror"),
+    ((1, 1), "cpu", jnp.float32, "mirror"),  # an interpreted kernel a column
+    ((2, 2), "cpu", jnp.float32, "mirror"),
+    ((1, 1), "tpu", jnp.float64, "mirror"),
+    ((2, 2), "tpu", jnp.float64, "mirror"),
+    ((1, 1), "tpu", jnp.complex64, "mirror"),    # Mosaic has no complex type
+    ((2, 2), "tpu", jnp.complex64, "mirror"),
+    ((1, 1), "tpu", jnp.bfloat16, "mirror")])
+def test_the_rule_reads_the_grid_and_the_dtype(shape, platform, dtype, want):
     from types import SimpleNamespace as NS
-    A = NS(grid=NS(size=chips, devices=[NS(platform=platform)] * chips),
+    chips = shape[0] * shape[1]
+    A = NS(grid=NS(size=chips, height=shape[0], width=shape[1],
+                   devices=[NS(platform=platform)] * chips),
            dtype=jnp.dtype(dtype))
-    assert condense._reads_triangle_once(A) is want
+    assert condense._reads_triangle_once(A) is (want != "mirror")
+    assert condense._hemv_impl(A) == want
 
 
 @pytest.mark.parametrize("dtype", [jnp.float64, jnp.complex128])

@@ -250,10 +250,13 @@ def test_counters_read_the_panels_and_the_merges():
 
 
 def test_cpu_backend_and_grids_take_the_mirror_path():
-    """ISSUE 44: the one-pass triangle ``symv`` kernel is the matvec of ONE
-    TPU chip; on the CPU backend (an interpreted kernel a column) and on a
-    grid of several chips (a shard is not locally symmetric) every panel
-    says ``impl=mirror`` and the program holds no kernel."""
+    """ISSUE 44, ISSUE 52: the one-pass triangle ``symv`` kernel is the
+    matvec of ONE TPU chip and, each chip on its own shard, of a SQUARE
+    grid of TPU chips.  This file compiles for the CPU backend (an
+    interpreted kernel a column), which keeps the mirror path on every
+    grid, as non-square grids and complex entries do anywhere: every panel
+    says ``impl=mirror`` and the program holds no kernel
+    (``tests/test_chip_compile.py`` reads the TPU's programs)."""
     for grid_name in GRIDS:
         exe, counts = compiled(grid_name, 320)
         assert counts["herm_tridiag_hemv"] == {(("impl", "mirror"),): 5}
@@ -440,9 +443,13 @@ COLLECTIVE = re.compile(
 
 
 def test_a_column_of_the_grid_reduction_exchanges_five_times_and_writes_no_shard():
-    """ISSUE 50: what ONE column of the reduction costs on a grid, pinned in
-    the compiled loop body (the orders above held the product to one read of
-    the view; nothing counted the exchanges beside it).  The vector goes to
+    """ISSUE 50: what ONE column of the reduction costs on a grid ON THE
+    MIRROR PATH, pinned in the compiled loop body (the orders above held
+    the product to one read of the view; nothing counted the exchanges
+    beside it).  Since ISSUE 52 this is the path of the CPU (what this file
+    compiles for), of non-square grids and of complex entries; a square
+    grid of TPU chips runs one kernel and ONE all-reduce a column
+    (``tests/test_chip_compile.py``).  The vector goes to
     ``[MR,STAR]`` in three collectives (an all-to-all, a collective-permute,
     an all-gather), the product's partial sums are joined by the compiler's
     own all-reduce, which carries the product's name and reads ``hemv``, and

@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from elemental_tpu.kernels import (PANEL_VMEM_BUDGET, PanelPlan,
                                    lu_nopiv_block, lu_panel, potrf_inv,
                                    qr_panel, symv_lower)
+from elemental_tpu.kernels.symv import shard_block, symv_lower_shard
 
 PLAN = PanelPlan(impl="pallas")
 
@@ -99,6 +100,26 @@ def test_symv_kernel_compiles_for_v5e(nt, one_chip):
     anything (one edge block past both extents)."""
     _compile(lambda a, x: symv_lower(a, x, interpret=False), (nt, nt), (nt,),
              one_chip=one_chip)
+
+
+@pytest.mark.parametrize("nt", [16384, 1792, 256, 399])
+def test_symv_shard_kernel_compiles_for_v5e(nt, one_chip):
+    """The shard form of the same body (ISSUE 52), for one chip of a 2x2
+    grid, ``(p, q)`` traced scalars as under ``shard_map``: the grid cell's
+    first local view (8192) and its last (128), a local order that is no
+    multiple of the 512-tile (896), and an odd global order (a local order
+    of 200 with a line past the matrix on the chips of residue 1)."""
+    m, (block, _tile) = -(-nt // 2), shard_block(nt, 2)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in (((m, m), jnp.float32),
+                                 ((2 * block,), jnp.float32),
+                                 ((), jnp.int32), ((), jnp.int32))]
+    text = jax.jit(lambda a, x, p, q: symv_lower_shard(
+        a, x, p, q, stride=2, nt=nt, interpret=False)).lower(
+            *args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # read as stored: no transposing copy of the shard before the kernel
+    assert not re.search(rf"f32\[{m},{m}\]\S* (copy|transpose)\(", text)
 
 
 #: the sub-block order ``lu._lu_nopiv`` ships (its ``bs`` default)
@@ -537,6 +558,10 @@ def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
         assert re.match(r"%?el_symv_lower[.\d]* = ", kernels[0]), kernels[0]
         assert re.search(rf'op_name="[^"]*/k{k:02d}/hemv/', kernels[0])
         assert f"f32[{nt},{nt}]{{1,0}}" in kernels[0]      # row-major operand
+        # the one-chip form of the body (ISSUE 52 gave it a shard form with
+        # two more scalar words): two tile tables, the view, the vector
+        assert len(re.search(r"custom-call\(([^)]*)\)", kernels[0])
+                   .group(1).split(", ")) == 4, kernels[0]
         if nt == nb:                    # the panel's own blocks are nt x nt
             continue
         assert not square_ops(
@@ -550,25 +575,27 @@ def test_eigensolve_column_loop_reads_the_view_once_and_moves_nothing(topo):
 
 def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
         grid22):
-    """The grid twin of the test above (ISSUE 50): the whole donated
-    ``jit(herm_eig)`` at n = 2048, nb = 256 for the described ``v5e:2x2``,
-    float32 throughout as on the chip (a minute).  On a grid every panel
-    takes PR 38's mirror path (the kernel reads a stored triangle, and an
-    element-cyclic shard of a Hermitian matrix is not locally symmetric):
-    the view is mirrored ONCE a panel, outside the loop, and a column is
-    one product against the (nt/2, nt/2) local view, the loop's invariant
-    as it stands, its partial sums joined across the grid's row by the
-    compiler's all-reduce, with the vector's hop to ``[MR,STAR]`` before it
-    and the result's gather after: at most five collectives a column, every
-    one on a vector.  Before PR 38 the TPU compiler re-laid the FIXED view
-    inside the ``while`` body once a COLUMN (18.66 of one chip's 41.9 s),
-    which only the optimized HLO for the described chip showed; here no
-    ``copy``, ``transpose``, ``select``, ``slice`` or update makes a local
-    view's worth of data in any loop body, and exactly one fusion, the
-    product's, takes the view as a parameter.  The plan beside the shard:
-    10.08 shards at the cell's n = 16384 and 4.14 here (PR 51's
-    rehearsals; 10.38 and 14.74 while the merges' hops carried the plan
-    executor's index tables as constants of the program): under 6.
+    """The grid twin of the test above (ISSUE 50, ISSUE 52): the whole
+    donated ``jit(herm_eig)`` at n = 2048, nb = 256 for the described
+    ``v5e:2x2``, float32 throughout as on the chip (a minute).  On a SQUARE
+    grid of TPU chips every panel takes the grid form of the one-pass
+    triangle ``symv`` (``herm_tridiag_hemv{impl=symv_grid}``): nothing is
+    mirrored (no ``herm_tridiag_symmetrize``, no ``MR_MC.to.MC_MR``
+    exchange under the reduction), and a column is ONE ``tpu_custom_call``
+    under ``k<panel>/hemv``, each chip's kernel on its (nt/2, nt/2) shard
+    of the view AS STORED (the compiler holds the grid's working shard
+    ROW-major, so the kernel walks the tiles on or below the shard's own
+    diagonal; through the transpose, as on one chip, every panel paid a
+    transposing ``copy f32[nt/2,nt/2]``, which only this program showed),
+    and ONE collective, the all-reduce of the replicated vector, under
+    ``el.redist.hemv_join`` and ``k<panel>/hemv``.  Before (PR 38's mirror
+    path on every grid): a multiply-reduce over the full local square and
+    five dependent collectives a column, 1.54 + 0.33 + 0.44 of
+    ``heig.2x2.b2b``'s 3.41 s.  Still no ``copy``, ``transpose``,
+    ``select``, ``slice`` or update makes a local view's worth of data in
+    any loop body, and NO fusion takes the view as a parameter: the kernel
+    is its only reader.  The plan beside the shard: under 6 shards as
+    before (3.6 here).
 
     ISSUE 51, the same program: its three distributed merges (two of 512,
     one of 1024) are six ``gemm`` products, every one ``slice``, whose
@@ -589,11 +616,13 @@ def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
         compiled = jax.jit(lambda a: el.herm_eig(a, nb=nb),
                            donate_argnums=0).lower(A).compile()
     assert dict(reg.counters("herm_tridiag_hemv")) == {
-        ("herm_tridiag_hemv", (("impl", "mirror"),)): n // nb}
-    assert dict(reg.counters("herm_tridiag_symmetrize")) == {
-        ("herm_tridiag_symmetrize", ()): n // nb}
+        ("herm_tridiag_hemv", (("impl", "symv_grid"),)): n // nb}
+    assert not reg.counters("herm_tridiag_symmetrize")
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
+    assert not re.search(
+        r'op_name="[^"]*/el\.hermitian_tridiag/[^"]*el\.redist\.MR_MC\.to\.MC_MR',
+        text)
+    assert text.count('custom_call_target="tpu_custom_call"') == n // nb
     assert dict(reg.counters("dc_merge"))[
         "dc_merge", (("kind", "distributed"),)] == 3
     assert dict(reg.counters("gemm_route")) == {
@@ -609,28 +638,35 @@ def test_grid_eigensolve_column_reads_the_local_view_once_and_copies_no_shard(
         nt = (n - k * nb) // 2                   # the local view's order
         found = [(m.group(1), line) for _c, line in lines
                  for m in [COLLECTIVE.search(line)] if m]
-        assert 4 <= len(found) <= 5, (k, [op for op, _line in found])
-        named = [line for _op, line in found if 'op_name="' in line]
-        assert all(f"/k{k:02d}/hemv/" in line for line in named), k
-        # in every other panel the compiler makes the vector's gather an
-        # all-reduce of an update-slice and gives it NO name: on the chip
-        # that one reads ``unscoped``, not ``redist``
-        assert [op for op, line in found if 'op_name="' not in line] in (
-            [], ["all-reduce"]), k
-        (join,) = [line for line in named if "el.redist." not in line]
-        assert " all-reduce(" in join and "/hemv/dot_general" in join, k
-        # every exchanged array is a vector
-        assert not any(re.search(rf"\[{nt},\d\d+\]", line.split(" = ")[1]
-                                 .split("(")[0]) for _op, line in found), k
+        # ONE collective a column: the all-reduce of a vector, named
+        ((op, join),) = found
+        assert op == "all-reduce", (k, op)
+        assert re.search(rf'op_name="[^"]*/k{k:02d}/hemv/[^"]*'
+                         r'el\.redist\.hemv_join/', join), join
+        assert re.match(r"%?[\w.\-]+ = f32\[\d+\]", join), join
+        # ONE kernel a column, the view's only reader, on the shard as
+        # stored (row-major, no transpose before it)
+        kernels = [line for _c, line in lines if "tpu_custom_call" in line]
+        assert len(kernels) == 1, (k, kernels)
+        assert re.match(r"%?el_symv_lower[.\d]* = ", kernels[0]), kernels[0]
+        assert re.search(rf'op_name="[^"]*/k{k:02d}/hemv/', kernels[0])
+        assert f"f32[{nt},{nt}]{{1,0}}" in kernels[0]
         readers = {c for c, line in lines if "fused_computation" in c
                    and re.search(rf"= f32\[{nt},{nt}\]\S* parameter\(", line)}
-        assert len(readers) == 1, (k, readers)
+        assert not readers, (k, readers)
         if nt == nb:                    # the panel's own blocks are nt x nt
             continue
         assert not square_ops(
             [(c, line) for c, line in lines if "fused_computation" not in c],
             nt, ("copy", "transpose", "select", "slice", "fusion",
                  "dynamic-update-slice", "all-to-all", "all-gather")), k
+        # nor anywhere in the program: the kernel's operand costs no
+        # relayout of a panel's view (nt = n/2 is also the order of A's
+        # shard, Z's and their copies)
+        if k:
+            assert not square_ops([(None, line.strip())
+                                   for line in text.splitlines()],
+                                  nt, ("copy", "transpose")), k
     mem = compiled.memory_analysis()
     plan = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
