@@ -21,10 +21,13 @@ flow on device.
 """
 from __future__ import annotations
 
+import itertools
 import math
 
 import numpy as np
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core.dist import MC, MR, STAR
 from ..core.distmatrix import DistMatrix
@@ -34,6 +37,9 @@ from ..blas.level1 import (frobenius_norm, one_norm, infinity_norm,
                            shift_diagonal, get_diagonal, make_symmetric,
                            trace as dm_trace)
 from ..blas.level3 import _check_mcmr, gemm, trsm, herk
+from ..obs import metrics as _metrics
+from ..obs.tracer import scoped as _scoped
+from ..tune.policy import stage_blocksize
 from .cholesky import cholesky, hpd_solve
 from .lu import lu_solve, _hi
 from .qr import qr, apply_q
@@ -85,41 +91,112 @@ def _qdwh_schedule(l0: float, tol: float, maxiter: int = 32):
     return params
 
 
-def _qdwh_step_chol(X: DistMatrix, a, b, c, nb, precision) -> DistMatrix:
+def _qdwh_step_chol(X: DistMatrix, c, keep, gain, blocks,
+                    precision) -> DistMatrix:
     """Cholesky-variant step (safe once c is moderate): Z = I + c X^H X,
-    Z = W W^H, X' = (b/c) X + (a - b/c) X W^{-H} W^{-1}."""
-    n = X.gshape[1]
+    Z = W W^H, X' = keep X + gain X W^{-H} W^{-1} with keep = b / c,
+    gain = a - b / c."""
+    nb = blocks["chol"]
     Z = herk("L", X, alpha=c, orient="C", nb=nb, precision=_hi(precision))
     Z = shift_diagonal(Z, 1)
     W = cholesky(Z, "L", nb=nb, precision=_hi(precision))
     B = trsm("R", "L", "C", W, X, nb=nb, precision=_hi(precision))   # X W^{-H}
     B = trsm("R", "L", "N", W, B, nb=nb, precision=_hi(precision))   # ... W^{-1}
-    return X.with_local((b / c) * X.local + (a - b / c) * B.local)
+    return X.with_local(keep * X.local + gain * B.local)
 
 
-def _qdwh_step_qr(X: DistMatrix, a, b, c, nb, precision) -> DistMatrix:
-    """QR-variant step (numerically safe for huge c):
-    [sqrt(c) X; I] = Q R, X' = (b/c) X + (a - b/c)/sqrt(c) Q1 Q2^H."""
+def _qdwh_step_qr(X: DistMatrix, sc, keep, gain, blocks,
+                  precision) -> DistMatrix:
+    """QR-variant step (numerically safe for huge c): with sc = sqrt(c),
+    [sc X; I] = Q R, X' = keep X + gain Q1 Q2^H with keep = b / c,
+    gain = (a - b / c) / sc."""
     m, n = X.gshape
-    sc = math.sqrt(c)
     S = vstack(X.with_local(sc * X.local), _identity_like(X, n, n))
-    Ap, tau = qr(S, nb=nb, precision=_hi(precision))
+    Ap, tau = qr(S, nb=blocks["qr"], precision=_hi(precision))
     # thin Q = Q [I; 0]
     E = _identity_like(X, m + n, n)
-    Qthin = apply_q(Ap, tau, E, orient="N", nb=nb, precision=_hi(precision))
+    Qthin = apply_q(Ap, tau, E, orient="N", nb=blocks["qr"],
+                    precision=_hi(precision))
     Q1 = interior_view(Qthin, (0, m), (0, n))
     Q2 = interior_view(Qthin, (m, m + n), (0, n))
-    G = gemm(Q1, Q2, orient_b="C", nb=nb, precision=_hi(precision))
-    return X.with_local((b / c) * X.local + ((a - b / c) / sc) * G.local)
+    G = gemm(Q1, Q2, orient_b="C", nb=blocks["chol"],
+             precision=_hi(precision))
+    return X.with_local(keep * X.local + gain * G.local)
 
 
+def _qdwh_coefficients(kind: str, a: float, b: float, c: float):
+    """A step's three scalars, in double on the host: the weight of
+    ``X^H X`` (its root in the QR form), and the weights of X and of the
+    step's product in the update."""
+    keep = b / c
+    if kind == "qr":
+        return math.sqrt(c), keep, (a - keep) / math.sqrt(c)
+    return c, keep, a - keep
+
+
+def _qdwh_run(X: DistMatrix, kind: str, first: int, params, blocks,
+              precision) -> DistMatrix:
+    """Consecutive steps ``first .. first + len(params) - 1`` of ONE
+    variant: one step inline, more as ONE ``lax.fori_loop`` body over the
+    table of their scalars (a step's program is tens of thousands of
+    lines at size, and the compile cache takes no entry over 192 MiB:
+    ``perf/program_size.py``).  The ops carry ``qdwh_<kind><first>`` or
+    ``qdwh_<kind><first>_<last>``, opened inside the body."""
+    step = {"qr": _qdwh_step_qr, "chol": _qdwh_step_chol}[kind]
+    last = first + len(params) - 1
+    name = f"qdwh_{kind}{first:02d}" + (f"_{last:02d}" if last > first else "")
+    table = [_qdwh_coefficients(kind, *abc) for abc in params]
+    _metrics.inc("qdwh_step", len(table), kind=kind)
+    if len(table) == 1:
+        with jax.named_scope(name):
+            return step(X, *table[0], blocks, precision)
+    scalars = jnp.asarray(table, _real_dtype(X.dtype))
+
+    def body(k, X):          # a new function a loop: jax keys its trace by it
+        with jax.named_scope(name):
+            return step(X, scalars[k, 0], scalars[k, 1], scalars[k, 2],
+                        blocks, precision)
+
+    with _metrics.repeated(len(table)):
+        return lax.fori_loop(0, len(table), body, X)
+
+
+def _polar_blocks(nb, m: int, n: int, grid, dtype) -> dict:
+    """``{stage: nb}`` of :func:`polar` and ``svd``'s polar route on an
+    (m, n) operand, m >= n: an explicit ``nb`` goes to every stage; with
+    ``nb=None`` each is picked from its shape, the grid and the dtype
+    (:func:`~elemental_tpu.tune.policy.stage_blocksize`): ``qr`` for the
+    QR-based steps' ``qr`` and ``apply_q`` of the (m + n, n) stack (their
+    panels are column loops), ``chol`` for the Cholesky-based steps'
+    ``herk``, ``cholesky`` and ``trsm`` and for the outer ``gemm``s, ``eig``
+    for ``svd``'s inner ``herm_eig``.  Whoever runs a stage ticks
+    ``polar_block{stage,nb}`` for it."""
+    if nb is not None:
+        return {"qr": nb, "chol": nb, "eig": nb}
+    return {"qr": stage_blocksize("qr", n, grid, dtype),
+            "chol": stage_blocksize("block", n, grid, dtype),
+            "eig": stage_blocksize("reduce", n, grid, dtype)}
+
+
+@_scoped("el.polar")
 def polar(A: DistMatrix, nb: int | None = None, precision=None,
           l_min: float | None = None, qr_c_switch: float = 100.0):
     """Polar decomposition ``A = U H`` with U a partial isometry (m >= n:
     U^H U = I) and H Hermitian PSD (Elemental ``El::Polar``, QDWH variant).
 
     ``l_min``: lower bound on sigma_min(A)/sigma_max(A) (defaults to ~eps of
-    the dtype -- an underestimate only adds iterations)."""
+    the dtype -- an underestimate only adds iterations).
+
+    ONE traceable program: the scale ``alpha`` and the degenerate case (a
+    zero or non-finite operand: ``U = I``, ``H = 0``) are values on the
+    device, the (a, b, c) schedule is static, and every step of it runs.
+    With ``nb=None`` each stage's block is :func:`_polar_blocks`'s.  Its ops
+    carry ``el.polar/<segment>``: ``qdwh_qr<first>_<last>`` |
+    ``qdwh_chol<first>_<last>`` around the consecutive steps of one variant
+    (numbered from 01, ONE loop body over their scalars: :func:`_qdwh_run`;
+    not a ``k<step>``, so the nested drivers' ops keep their own phase),
+    ``polar_h`` around ``H = U^H A``; ``qdwh_step{kind=qr|chol}`` counts
+    the steps the device runs."""
     _check_mcmr(A)
     m, n = A.gshape
     if m < n:
@@ -131,21 +208,33 @@ def polar(A: DistMatrix, nb: int | None = None, precision=None,
                  nb=nb, precision=_hi(precision))
         return U, _hermitianize(H)
 
-    alpha = float(jnp.sqrt(jnp.maximum(one_norm(A) * infinity_norm(A),
-                                       jnp.finfo(_real_dtype(A.dtype)).tiny)))
-    if not np.isfinite(alpha) or alpha == 0.0:
-        return _identity_like(A, m, n), A.with_local(jnp.zeros_like(A.local))
+    blocks = _polar_blocks(nb, m, n, A.grid, A.dtype)
+    for stage in ("qr", "chol"):
+        _metrics.inc("polar_block", stage=stage, nb=str(blocks[stage]))
+    rdtype = _real_dtype(A.dtype)
+    # sqrt(|A|_1 |A|_inf) >= |A|_2, as the product of the roots: the
+    # product of the norms leaves the dtype's range where neither root does
+    n1, ni = one_norm(A), infinity_norm(A)
+    degenerate = ~(jnp.isfinite(n1) & jnp.isfinite(ni) & (n1 > 0))
+    alpha = jnp.where(degenerate, 1,
+                      jnp.sqrt(n1) * jnp.sqrt(ni)).astype(rdtype)
     X = A.with_local((A.local / alpha).astype(A.dtype))
     eps = _eps_of(A.dtype)
     l0 = l_min if l_min is not None else eps
-    for (a, b, c) in _qdwh_schedule(l0, tol=10 * eps):
-        if c > qr_c_switch:
-            X = _qdwh_step_qr(X, a, b, c, nb, precision)
-        else:
-            X = _qdwh_step_chol(X, a, b, c, nb, precision)
-    U = X
-    H = gemm(U, A, orient_a="C", nb=nb, precision=_hi(precision))
-    return U, _hermitianize(H)
+    schedule = _qdwh_schedule(l0, tol=10 * eps)
+    kinds = ["qr" if c > qr_c_switch else "chol" for _a, _b, c in schedule]
+    first = 0
+    for kind, run in itertools.groupby(kinds):
+        count = len(list(run))
+        X = _qdwh_run(X, kind, first + 1, schedule[first:first + count],
+                      blocks, precision)
+        first += count
+    with jax.named_scope("polar_h"):
+        H = _hermitianize(gemm(X, A, orient_a="C", nb=blocks["chol"],
+                               precision=_hi(precision)))
+    U = X.with_local(jnp.where(degenerate, _identity_like(A, m, n).local,
+                               X.local))
+    return U, H.with_local(jnp.where(degenerate, 0, H.local))
 
 
 # ---------------------------------------------------------------------

@@ -20,6 +20,7 @@ columns BEFORE the back-transform, so a k-subset costs an (n, k) apply-Q.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -35,6 +36,7 @@ from .condense import hermitian_tridiag, apply_q_herm_tridiag, _real_dtype
 from .lu import permute_cols, _hi, _scoped
 from .qr import qr, apply_q
 from .tridiag_eig import tridiag_eig
+from ..obs import metrics as _metrics
 
 # Above this order the tridiagonal EVP switches from the replicated
 # jnp.linalg.eigh fallback to the scalable Cuppen D&C (:mod:`.tridiag_eig`,
@@ -249,6 +251,7 @@ def hermitian_svd(A: DistMatrix, uplo: str = "L", vectors: bool = True,
     return U, s, V
 
 
+@_scoped("el.svd")
 def svd(A: DistMatrix, vectors: bool = True, approach: str = "auto",
         nb: int | None = None, precision=None, eig_approach: str = "tridiag"):
     """Singular value decomposition ``A = U diag(s) V^H`` (``El::SVD``).
@@ -265,6 +268,15 @@ def svd(A: DistMatrix, vectors: bool = True, approach: str = "auto",
     ``eig_approach`` is forwarded to the inner :func:`herm_eig` ('qdwh'
     selects the fully-scalable spectral D&C).
     Returns (U, s, V) with s descending (replicated real vector).
+
+    ONE traceable program on every route (``jax.jit(el.svd)``: no host
+    read).  With ``nb=None`` the polar route picks each stage's block from
+    its shape, the grid and the dtype (``funcs._polar_blocks``); an
+    explicit ``nb`` goes to every stage.  Its ops carry ``el.svd/...``:
+    ``el.polar/{qdwh_qr<steps>,qdwh_chol<steps>,polar_h}`` (``funcs.polar``),
+    the inner ``el.herm_eig`` as it is, ``svd_u`` around ``U = U_p V``;
+    the trace-time counter ``svd_route{approach}`` reads the RESOLVED
+    approach of each (nested) call.
     """
     _check_mcmr(A)
     m, n = A.gshape
@@ -278,8 +290,13 @@ def svd(A: DistMatrix, vectors: bool = True, approach: str = "auto",
         return V, s, U
     if approach == "auto":
         approach = "chan" if m >= max(int(1.5 * n), n + 1) else "polar"
+    if approach == "chan" and m == n:
+        approach = "local"
+    if approach not in ("chan", "polar", "golub", "local"):
+        raise ValueError(f"unknown svd approach {approach!r}")
+    _metrics.inc("svd_route", approach=approach)
 
-    if approach == "chan" and m > n:
+    if approach == "chan":
         Ap, tau = qr(A, nb=nb, precision=_hi(precision))
         Rd = make_trapezoidal(interior_view(Ap, (0, n), (0, n)), "U")
         out = svd(Rd, vectors, "polar" if n > 128 else "local", nb, precision,
@@ -295,8 +312,6 @@ def svd(A: DistMatrix, vectors: bool = True, approach: str = "auto",
     if approach == "golub":
         return _svd_golub_kahan(A, vectors, nb, precision, eig_approach)
 
-    if approach == "local" or (approach in ("chan",) and m == n):
-        approach = "local"
     if approach == "local":
         # replicated fallback for small blocks (the redundant-LAPACK analog)
         Ag = redistribute(A, STAR, STAR).local
@@ -308,9 +323,7 @@ def svd(A: DistMatrix, vectors: bool = True, approach: str = "auto",
                           MC, MR)
         return Ud, s.astype(_real_dtype(A.dtype)), Vd
 
-    if approach == "polar":
-        return _svd_polar(A, vectors, nb, precision, eig_approach)
-    raise ValueError(f"unknown svd approach {approach!r}")
+    return _svd_polar(A, vectors, nb, precision, eig_approach)
 
 
 def _svd_golub_kahan(A: DistMatrix, vectors: bool, nb, precision,
@@ -376,20 +389,29 @@ def _svd_golub_kahan(A: DistMatrix, vectors: bool, nb, precision,
     return U, s, V
 
 
+def _reversed_cols(V: DistMatrix) -> DistMatrix:
+    """The columns in reverse order: a static flip on one device (no
+    gather), ``permute_cols`` by a constant order on a grid."""
+    if V.grid.size == 1:
+        return V.with_local(V.local[:, ::-1])
+    return permute_cols(V, jnp.arange(V.gshape[1])[::-1])
+
+
 def _svd_polar(A: DistMatrix, vectors: bool, nb, precision,
                eig_approach: str):
     # polar path: A = Up H; H = V diag(w) V^H; s = w desc; U = Up V
-    from .funcs import polar
+    from .funcs import polar, _polar_blocks
+    m, n = A.gshape
+    blocks = _polar_blocks(nb, m, n, A.grid, A.dtype)
+    _metrics.inc("polar_block", stage="eig", nb=str(blocks["eig"]))
     Up, H = polar(A, nb=nb, precision=_hi(precision))
-    if not vectors:
-        w = herm_eig(H, "L", vectors=False, nb=nb, approach=eig_approach,
-                     precision=_hi(precision))
-        return jnp.clip(jnp.sort(w)[::-1], 0, None)
-    w, V = herm_eig(H, "L", True, nb=nb, approach=eig_approach,
-                    precision=_hi(precision))
+    out = herm_eig(H, "L", vectors, nb=blocks["eig"], approach=eig_approach,
+                   precision=_hi(precision))
     # H is PSD: w ascending >= 0 (up to rounding); descending order
-    order = jnp.argsort(-w)
-    s = jnp.clip(w[order], 0, None)
-    Vd = permute_cols(V, order)
-    U = gemm(Up, Vd, precision=_hi(precision))
-    return U, s, Vd
+    if not vectors:
+        return jnp.clip(out[::-1], 0, None)
+    w, V = out
+    Vd = _reversed_cols(V)
+    with jax.named_scope("svd_u"):
+        U = gemm(Up, Vd, nb=blocks["chol"], precision=_hi(precision))
+    return U, jnp.clip(w[::-1], 0, None), Vd

@@ -44,8 +44,8 @@ where xprof / Perfetto show them and ``benchmark/scopes.py`` sums device
 time by them.  A path is made of:
 
   ``el.<driver>``          a public driver: ``cholesky``, ``lu``, ``qr``,
-                           ``gemm``, ``herk``, ``trsm``, ``herm_eig`` and
-                           its three stages ``hermitian_tridiag``,
+                           ``gemm``, ``herk``, ``trsm``, ``svd``, ``polar``,
+                           ``herm_eig`` and its three stages ``hermitian_tridiag``,
                            ``tridiag_eig``, ``apply_q_herm_tridiag``,
                            ``least_squares`` and, inside it or alone,
                            ``tsqr``, ``lu_nopiv`` (:func:`scoped`); the one-device
@@ -126,6 +126,34 @@ time by them.  A path is made of:
                            ``refine/residual``: not ``sweep`` (no
                            ``sweeps`` segment stands over them), not
                            ``update`` or ``panel``
+  ``el.svd`` / ``el.polar``  the singular value decomposition and the polar
+                           decomposition it rests on (``lapack/spectral.py``,
+                           ``lapack/funcs.py``).  ``el.svd`` stands around
+                           everything; inside it ``el.polar`` around the
+                           QDWH iteration, the consecutive steps of
+                           one variant under a segment that says the
+                           variant and their numbers from 01,
+                           ``<steps>`` = ``<first>`` or
+                           ``<first>_<last>`` (two steps or more are ONE
+                           ``lax.fori_loop`` body over their scalars,
+                           the segment opened inside it:
+                           ``el.polar/while/body/qdwh_chol03_06/...``):
+                           ``qdwh_qr<steps>`` (the QR-based form:
+                           ``el.qr`` of the stack ``[sqrt(c) X; I]``,
+                           ``apply_q``, ``el.gemm``) |
+                           ``qdwh_chol<steps>`` (the Cholesky-based
+                           form: ``el.herk``, ``el.cholesky``, two
+                           ``el.trsm``), and
+                           ``polar_h`` around ``H = U_p^H A``; then the
+                           inner ``el.herm_eig`` as it is, and ``svd_u``
+                           around ``U = U_p V``.  None of these segments
+                           is a ``k<step>``, so the nested drivers' ops
+                           keep their own phase
+                           (``.../qdwh_qr01_02/el.qr/k03/panel``
+                           reads ``qr/panel``), and
+                           ``benchmark/svd_share.py`` reads what stands
+                           over them.  ``apply_q`` opens no scope: its ops
+                           read ``polar/-``
   ``el.redist.<SRC>.to.<DST>``  every public ``redistribute`` entry
                            (``el.redist.MC_MR.to.VC_STAR``), around ALL
                            it emits: the collectives and the local pack /
@@ -258,6 +286,25 @@ not tick again).  Read them under ``metrics_scope()``:
                            30 in ``mixed_solve`` and 15 in ``hpd_solve``
                            at n = 32768, nb 2048, one chip) | ``dense``
                            (narrower: the one matmul)
+  ``svd_route{approach}``  one ``svd`` (a nested call ticks for itself:
+                           the transpose of a wide operand, ``'chan'``'s
+                           SVD of R) with the RESOLVED ``approach``:
+                           ``polar`` | ``chan`` | ``golub`` | ``local``
+                           (``'auto'`` on a square operand: ``polar``)
+  ``qdwh_step{kind}``      one step of ``polar``'s QDWH iteration: ``kind``
+                           ``qr`` | ``chol``; the schedule is static, from
+                           the dtype's eps: 2 and 4 in float32, 2 and 6
+                           in float64 (a loop body is traced once and
+                           ticks for each of its trips)
+  ``polar_block{stage,nb}``  the block one stage of ``polar`` / ``svd``'s
+                           polar route runs with: ``stage`` ``qr`` (the
+                           QR-based steps' ``qr`` and ``apply_q``) |
+                           ``chol`` (``herk``, ``cholesky``, ``trsm`` and
+                           the ``gemm``s) | ``eig`` (``svd``'s inner
+                           ``herm_eig``), ``nb`` the explicit one or, with
+                           ``nb=None``, what ``tune.policy.stage_blocksize``
+                           picks from the shape, the grid and the dtype
+                           (512, 2048, 256 at n = 16384 in float32)
   ``lstsq_route{kind}``    one ``least_squares``: ``kind`` ``tall`` (every
                            chip factors its own rows: ``lapack/qr.py:
                            _takes_tall_route``) | ``blocked`` (``qr``,

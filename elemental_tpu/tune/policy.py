@@ -21,6 +21,9 @@ extent-clamping rule every blocked driver shares (re-exported as
 from __future__ import annotations
 
 import dataclasses
+import math
+
+import numpy as np
 
 from . import cache as _cache
 from .knobs import OPS, TuneContext, candidate_configs
@@ -47,6 +50,34 @@ def blocksize_policy(nb, grain: int, extent: int) -> int:
         nb = blocksize()
     nb = round_up(max(nb, 1), grain)
     return min(nb, round_up(max(extent, 1), grain))
+
+
+#: float32 block of a stage at size on one chip (PERF.md 4).  ``reduce``:
+#: the Hermitian reduction and its back-transform, whose panel is a
+#: column-at-a-time loop, latency- and HBM-bound: narrow, what
+#: ``heig.1x1.b2b`` measured.  ``block``: the stages whose panel is itself
+#: blocked (Cholesky, the triangular sweeps, the rank-k accumulations): the
+#: MXU's width, what the ``hpd`` cells measured.  ``qr``: the blocked
+#: Householder QR and the reflector sweep that must share its blocking; its
+#: panel is a column loop too, but each panel is unrolled into the program
+#: twice (``qr``, ``apply_q``), and at 256 the SVD cell's program passed the
+#: compile cache's limit on one entry (``perf/program_size.py``).
+STAGE_BLOCKS = {"reduce": 256, "qr": 512, "block": 2048}
+
+
+def stage_blocksize(stage: str, extent: int, grid, dtype) -> int:
+    """The block a COMPOSED driver (``svd``, ``polar``) hands one of its
+    stages when its caller passed ``nb=None``: the stages of one call want
+    different blocks, and one global default (128) serves none of them at
+    size.  ``stage`` is a key of :data:`STAGE_BLOCKS`; the float32 value is
+    scaled by the dtype's width (the same bytes a panel), kept to at most
+    an eighth of the stage's ``extent`` (eight steps or more) and to 128
+    at least, and rounded up to the grid's grain.  The stage's own
+    :func:`blocksize_policy` still clamps it to the extent."""
+    from ..core.view import round_up
+    target = STAGE_BLOCKS[stage] * 4 // max(np.dtype(dtype).itemsize, 4)
+    nb = max(128, min(target, int(extent) // 8))
+    return round_up(nb, math.lcm(grid.height, grid.width))
 
 
 # ---------------------------------------------------------------------

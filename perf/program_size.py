@@ -8,11 +8,14 @@ rehearsal's executable that gave the chip's entry to 0.02 %.
 
     python -m perf.program_size eig --n 16384            # ten minutes, 20 GB
     python -m perf.program_size eig --n 16384 --stage dc # the stages alone
+    python -m perf.program_size svd --n 16384            # a quarter of an hour
     python -m perf.program_size drivers                  # CPU, seconds
 
 ``eig`` compiles the donated ``jit(herm_eig)`` (nb 256, float32) for a
 described ``v5e:2x2`` and prints the entry's bytes beside the plan, the
-lines and the trace-time counters.  ``drivers`` prints a hash of the
+lines and the trace-time counters; ``svd`` the donated ``jit(el.svd)`` of
+``svd.1x1.b2b`` (no ``nb``) for ONE described v5e chip, the same way.
+``drivers`` prints a hash of the
 stripped optimized HLO (CPU backend, n = 256, one device and 2x2) of every
 driver a cell of the benchmark compiles.  ``--root <checkout>`` imports
 ``elemental_tpu`` from another tree: run both on two trees to see which
@@ -53,7 +56,6 @@ def _eig(args):
     from jax.sharding import NamedSharding, PartitionSpec
     jax.config.update("jax_enable_compilation_cache", False)
     import elemental_tpu as el
-    from elemental_tpu import obs
     from elemental_tpu.core.distmatrix import DistMatrix
     from elemental_tpu.lapack.condense import (apply_q_herm_tridiag,
                                                hermitian_tridiag)
@@ -84,33 +86,75 @@ def _eig(args):
             ap, tau, z, orient="N", nb=nb, precision=hi),
             (matrix(), vector(n - 1), matrix()), (2,)),
     }[args.stage]
+    return _rehearse(jax.jit(fn, donate_argnums=donate), operands, args.hlo,
+                     ("gemm_route", "dc_merge"),
+                     stage=args.stage, n=n, grid=[grid.height, grid.width])
+
+
+def _rehearse(jitted, operands, hlo, counters, **head):
+    """Lower and compile ``jitted`` for the described chip, print one JSON
+    line (``head``, the seconds, the plan, the lines, the entry's bytes,
+    the named trace-time counters) and return 1 if the entry is over the
+    cache's limit."""
+    from elemental_tpu import obs
     start = time.time()
     with obs.metrics_scope() as reg:
-        lowered = jax.jit(fn, donate_argnums=donate).lower(*operands)
+        lowered = jitted.lower(*operands)
         lowered_at = time.time()
         compiled = lowered.compile()
     compiled_at = time.time()
     text = compiled.as_text()
-    if args.hlo:
-        with open(args.hlo, "w") as out:
+    if hlo:
+        with open(hlo, "w") as out:
             out.write(text)
     mem = compiled.memory_analysis()
+    parts = {"argument": mem.argument_size_in_bytes,
+             "output": mem.output_size_in_bytes,
+             "temp": mem.temp_size_in_bytes,
+             "alias": mem.alias_size_in_bytes}
     serialized, entry = entry_bytes(compiled)
     print(json.dumps({
-        "stage": args.stage, "n": n, "grid": [grid.height, grid.width],
+        **head,
         "trace_lower_s": round(lowered_at - start, 1),
         "compile_s": round(compiled_at - lowered_at, 1),
-        "plan_bytes": (mem.argument_size_in_bytes + mem.output_size_in_bytes
-                       + mem.temp_size_in_bytes - mem.alias_size_in_bytes),
+        "plan_bytes": (parts["argument"] + parts["output"] + parts["temp"]
+                       - parts["alias"]),
+        "plan_parts": parts,
         "hlo_lines": text.count("\n") + 1,
+        "callbacks": text.count("custom_call_target=\"xla_python"),
         "serialized_bytes": serialized, "cache_entry_bytes": entry,
         "cache_entry_limit": CACHE_ENTRY_LIMIT,
         "fits": entry <= CACHE_ENTRY_LIMIT,
         "counters": {name: {",".join(f"{k}={v}" for k, v in labels): count
                             for (_n, labels), count
                             in reg.counters(name).items()}
-                     for name in ("gemm_route", "dc_merge")}}), flush=True)
+                     for name in counters}}), flush=True)
     return 0 if entry <= CACHE_ENTRY_LIMIT else 1
+
+
+def _svd(args):
+    """The donated ``jit(el.svd)`` of ``svd.1x1.b2b`` (square float32, ONE
+    described v5e chip, no ``nb``: the blocks are the driver's)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    jax.config.update("jax_enable_compilation_cache", False)
+    import elemental_tpu as el
+    from elemental_tpu.core.distmatrix import DistMatrix
+
+    n = args.n
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    grid = el.Grid([topo.devices[0]])
+    meta = DistMatrix(None, (n, n), el.MC, el.MR, 0, 0, grid)
+    A = meta.with_local(jax.ShapeDtypeStruct(
+        (n, n), jnp.float32, sharding=grid.sharding(meta.spec)))
+    return _rehearse(jax.jit(lambda a: el.svd(a), donate_argnums=0), (A,),
+                     args.hlo,
+                     ("svd_route", "qdwh_step", "polar_block",
+                      "herm_tridiag_hemv", "dc_merge"),
+                     program="svd", n=n, grid=[grid.height, grid.width])
 
 
 def _stripped(text):
@@ -130,7 +174,12 @@ def _drivers(_args):
     import elemental_tpu as el
 
     def line(grid_name, driver, fn, *operands):
-        text = jax.jit(fn).lower(*operands).compile().as_text()
+        try:
+            text = jax.jit(fn).lower(*operands).compile().as_text()
+        except jax.errors.ConcretizationTypeError:
+            # a tree whose driver reads a value on the host (svd before 53)
+            print(grid_name, driver, "cannot-be-traced", 0, flush=True)
+            return
         print(grid_name, driver,
               hashlib.sha256(_stripped(text).encode()).hexdigest()[:16],
               text.count("\n") + 1, flush=True)
@@ -156,6 +205,7 @@ def _drivers(_args):
         line(grid_name, "least_squares", el.least_squares,
              dist(rng.normal(size=(4096, 16))),
              dist(rng.normal(size=(4096, 4))))
+        line(grid_name, "svd", lambda a: el.svd(a, nb=64), G)
     return 0
 
 
@@ -167,6 +217,9 @@ def main(argv=None):
     eig.add_argument("--n", type=int, default=16384)
     eig.add_argument("--stage", choices=STAGES, default="whole")
     eig.add_argument("--hlo", help="write the optimized HLO here")
+    svd = sub.add_parser("svd")
+    svd.add_argument("--n", type=int, default=16384)
+    svd.add_argument("--hlo", help="write the optimized HLO here")
     sub.add_parser("drivers")
     args = parser.parse_args(argv)
     # a rehearsal describes a chip and a hash needs the virtual CPU mesh:
@@ -174,7 +227,7 @@ def main(argv=None):
     os.environ["JAX_PLATFORMS"] = "cpu"
     if args.root:
         sys.path.insert(0, os.path.abspath(args.root))
-    return {"eig": _eig, "drivers": _drivers}[args.what](args)
+    return {"eig": _eig, "svd": _svd, "drivers": _drivers}[args.what](args)
 
 
 if __name__ == "__main__":

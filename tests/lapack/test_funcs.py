@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 import elemental_tpu as el
+from ..conftest import compiled
 
 
 def _g(F, grid):
@@ -21,7 +22,7 @@ def _t(A):
 def test_polar_square(grid24):
     rng = np.random.default_rng(0)
     F = rng.normal(size=(24, 24))
-    U, H = el.polar(_g(F, grid24))
+    U, H = compiled(el.polar)(_g(F, grid24))
     Ug, Hg = _t(U), _t(H)
     assert np.linalg.norm(Ug.T @ Ug - np.eye(24)) < 1e-13
     assert np.linalg.norm(Ug @ Hg - F) / np.linalg.norm(F) < 1e-14
@@ -33,17 +34,17 @@ def test_polar_square(grid24):
 def test_polar_tall_wide_complex(grid24):
     rng = np.random.default_rng(1)
     F = rng.normal(size=(32, 16))
-    U, H = el.polar(_g(F, grid24))
+    U, H = compiled(el.polar)(_g(F, grid24))
     Ug, Hg = _t(U), _t(H)
     assert np.linalg.norm(Ug.T @ Ug - np.eye(16)) < 1e-13
     assert np.linalg.norm(Ug @ Hg - F) / np.linalg.norm(F) < 1e-14
     W = rng.normal(size=(16, 32))
-    U2, H2 = el.polar(_g(W, grid24))
+    U2, H2 = compiled(el.polar)(_g(W, grid24))
     U2g, H2g = _t(U2), _t(H2)
     assert np.linalg.norm(U2g @ U2g.T - np.eye(16)) < 1e-13
     assert np.linalg.norm(U2g @ H2g - W) / np.linalg.norm(W) < 1e-13
     C = rng.normal(size=(24, 24)) + 1j * rng.normal(size=(24, 24))
-    U3, H3 = el.polar(_g(C, grid24))
+    U3, H3 = compiled(el.polar)(_g(C, grid24))
     U3g, H3g = _t(U3), _t(H3)
     assert np.linalg.norm(U3g.conj().T @ U3g - np.eye(24)) < 1e-13
     assert np.linalg.norm(U3g @ H3g - C) / np.linalg.norm(C) < 1e-14
@@ -55,7 +56,7 @@ def test_polar_ill_conditioned(grid24):
     Q2, _ = np.linalg.qr(rng.normal(size=(24, 24)))
     s = np.logspace(0, -10, 24)          # cond 1e10
     F = (Q1 * s) @ Q2.T
-    U, H = el.polar(_g(F, grid24))
+    U, H = compiled(el.polar)(_g(F, grid24))
     Ug, Hg = _t(U), _t(H)
     assert np.linalg.norm(Ug.T @ Ug - np.eye(24)) < 1e-10
     assert np.linalg.norm(Ug @ Hg - F) / np.linalg.norm(F) < 1e-12

@@ -10,6 +10,7 @@ import pytest
 
 import elemental_tpu as el
 from elemental_tpu.lapack.funcs import _qdwh_eig
+from ..conftest import compiled
 
 
 def _g(F, grid):
@@ -138,7 +139,7 @@ def test_svd_square(grid24):
     """Round-2 regression: svd() on square input crashed (missing funcs)."""
     rng = np.random.default_rng(8)
     F = rng.normal(size=(24, 24))
-    U, s, V = el.svd(_g(F, grid24))
+    U, s, V = compiled(el.svd)(_g(F, grid24))
     _check_svd(F, U, s, V)
 
 
@@ -146,28 +147,28 @@ def test_svd_square(grid24):
 def test_svd_square_complex(grid24):
     rng = np.random.default_rng(9)
     F = rng.normal(size=(16, 16)) + 1j * rng.normal(size=(16, 16))
-    U, s, V = el.svd(_g(F, grid24))
+    U, s, V = compiled(el.svd)(_g(F, grid24))
     _check_svd(F, U, s, V)
 
 
 def test_svd_tall_chan(grid24):
     rng = np.random.default_rng(10)
     F = rng.normal(size=(48, 16))
-    U, s, V = el.svd(_g(F, grid24), approach="chan")
+    U, s, V = compiled(el.svd, approach="chan")(_g(F, grid24))
     _check_svd(F, U, s, V)
 
 
 def test_svd_wide(grid24):
     rng = np.random.default_rng(11)
     F = rng.normal(size=(16, 40))
-    U, s, V = el.svd(_g(F, grid24))
+    U, s, V = compiled(el.svd)(_g(F, grid24))
     _check_svd(F, U, s, V)
 
 
 def test_svd_values_only(grid24):
     rng = np.random.default_rng(12)
     F = rng.normal(size=(24, 24))
-    s = el.svd(_g(F, grid24), vectors=False)
+    s = compiled(el.svd, vectors=False)(_g(F, grid24))
     assert np.allclose(np.asarray(s), np.linalg.svd(F, compute_uv=False),
                        atol=1e-12)
 

@@ -774,3 +774,40 @@ def test_divide_and_conquer_hand_off_places_blocks_and_gathers_nothing(topo,
             d, e).compile().as_text()
     assert not big_gathers(text, n * n)
     assert re.search(r'op_name="[^"]*/el\.tridiag_eig/[^"]*k03/fill/', text)
+
+
+def test_svd_is_one_program_for_the_described_chip(topo):
+    """The whole donated ``jit(el.svd)`` at n = 256 with an explicit block
+    of 64, for ONE described v5e chip (ISSUE 53; half a minute; the cell's
+    size is ``python -m perf.program_size svd --n 16384``'s).  ``polar``
+    read its scale on the host before the first step, so ``jit(svd)``
+    raised at trace time.  It lowers and compiles as one program with
+    nothing for the host to do: no callback, no infeed or outfeed, no
+    send or receive; the static schedule's 2 QR-based and 4
+    Cholesky-based steps all in it, each variant's as one loop body under
+    its own segment; and the
+    inner eigensolve is the one-chip cell's (the one-pass triangle kernel
+    under ``el.svd/el.herm_eig``)."""
+    import elemental_tpu as el
+    from elemental_tpu import obs
+    n, nb = 256, 64
+    grid = el.Grid([topo.devices[0]])
+    A = _abstract(grid, n, n, el.MC, el.MR)
+    with obs.metrics_scope() as reg:
+        text = jax.jit(lambda a: el.svd(a, nb=nb),
+                       donate_argnums=0).lower(A).compile().as_text()
+    assert reg.counter_value("svd_route", approach="polar") == 1
+    assert reg.counter_value("qdwh_step", kind="qr") == 2
+    assert reg.counter_value("qdwh_step", kind="chol") == 4
+    assert dict(reg.counters("herm_tridiag_hemv")) == {
+        ("herm_tridiag_hemv", (("impl", "symv"),)): n // nb}
+    assert not re.search(r"callback|infeed|outfeed| send\(| recv\(", text)
+    steps = set(re.findall(
+        r"/el\.svd/el\.polar/while/body/closed_call/(qdwh_\w+)/", text))
+    assert steps == {"qdwh_qr01_02", "qdwh_chol03_06"}
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert kernels and all(
+        re.search(r'op_name="[^"]*/el\.svd/el\.herm_eig/'
+                  r'el\.hermitian_tridiag/[^"]*/hemv/el_symv_lower/', line)
+        for line in kernels)
