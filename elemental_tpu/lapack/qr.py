@@ -32,6 +32,8 @@ from ..core.distmatrix import DistMatrix
 from ..core.view import view, update_view
 from ..core.compat import shard_map
 from ..redist.engine import apply_fault, redistribute
+from ..redist.interior import interior_view, vstack, _blank
+from ..blas.level1 import shift_diagonal
 from ..blas.level3 import _blocksize, _check_mcmr, trsm
 from ..obs import metrics as _metrics
 from .lu import (_update_cols_lt, _update_cols_ge, _hi, _phase_hook,
@@ -205,6 +207,20 @@ def _panel_qr_tsqr(P, r: int, precision=None):
     return packed, tau
 
 
+def _wy_apply(B: DistMatrix, V, Tm, rows, cols, precision) -> DistMatrix:
+    """The view ``B[rows, cols]`` less ``V Tm V^H`` times itself: one block
+    reflector in compact-WY form, V (the view's rows, k) and ``Tm``
+    replicated.  ``V^H B2`` is a storage matmul whose mc-sharded
+    contraction lands [STAR,MR]."""
+    V_ss = DistMatrix(V, V.shape, STAR, STAR, 0, 0, B.grid)
+    V_mc = redistribute(V_ss, MC, STAR)
+    B2 = view(B, rows=rows, cols=cols)
+    W = jnp.matmul(jnp.conj(V_mc.local).T, B2.local, precision=_hi(precision))
+    W = jnp.matmul(Tm, W, precision=_hi(precision))
+    upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
+    return B2.with_local(B2.local - upd.astype(B.dtype))
+
+
 # ---------------------------------------------------------------------
 # blocked Householder QR
 # ---------------------------------------------------------------------
@@ -349,16 +365,9 @@ def qr(A: DistMatrix, nb: int | str | None = None, precision=None,
             with tm.phase("update", k) as ph:
                 V = _panel_v(Pf)
                 T = Tk if Tk is not None else _larft(V, tau)
-                V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
-                V_mc = redistribute(V_ss, MC, STAR)
-                A2 = view(A, rows=(s, m), cols=(s, n))
-                W = jnp.matmul(jnp.conj(V_mc.local).T, A2.local,
-                               precision=_hi(precision))   # [STAR,MR] storage
-                W = jnp.matmul(jnp.conj(T).T, W, precision=_hi(precision))
-                upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
                 A = _update_cols_ge(
-                    A, A2.with_local(A2.local - upd.astype(A.dtype)),
-                    (s, m), (s, n), e)
+                    A, _wy_apply(A, V, jnp.conj(T).T, (s, m), (s, n),
+                                 precision), (s, m), (s, n), e)
                 ph.done(A)
     _record_qr_nb(A, ib)
     if hm is not None:
@@ -422,13 +431,7 @@ def apply_q(Ap: DistMatrix, tau, B: DistMatrix, orient: str = "N",
         V = _panel_v(panel.local[:, :nbw])
         T = _larft(V, tau[s:e])
         Tm = jnp.conj(T).T if orient == "C" else T
-        V_ss = DistMatrix(V, (m - s, nbw), STAR, STAR, 0, 0, g)
-        V_mc = redistribute(V_ss, MC, STAR)
-        B2 = view(B, rows=(s, m))
-        W = jnp.matmul(jnp.conj(V_mc.local).T, B2.local, precision=_hi(precision))
-        W = jnp.matmul(Tm, W, precision=_hi(precision))
-        upd = jnp.matmul(V_mc.local, W, precision=_hi(precision))
-        B = update_view(B, B2.with_local(B2.local - upd.astype(B.dtype)),
+        B = update_view(B, _wy_apply(B, V, Tm, (s, m), None, precision),
                         rows=(s, m))
     return B
 
@@ -439,6 +442,100 @@ def explicit_q(Ap: DistMatrix, tau, nb: int | None = None,
     from ..matrices.basic import identity
     I = identity(Ap.gshape[0], grid=Ap.grid, dtype=Ap.dtype)
     return apply_q(Ap, tau, I, orient="N", nb=nb, precision=_hi(precision))
+
+
+def _stack_qr_thin_q(X: DistMatrix, sc, nb: int | None = None,
+                     precision=None):
+    """The thin Q of QDWH's stack ``[sc X; I] = Q R`` ((m + n) x n, X m x n
+    with m >= n) as its two blocks ``(Q1, Q2)``, m x n and n x n: what
+    :func:`qr` of the stack and :func:`apply_q` on ``[I; 0]`` give, over
+    the rows and columns that are not structurally zero.
+
+    The lower block starts as the identity and stays upper triangular, so
+    the panel at columns ``[s, e)`` has non-zero rows ``(s, m + e)`` only:
+    it is gathered, reduced and applied over those (m + e - s rows,
+    whatever s) and the rows below are never read or written.  Each
+    panel's T is kept, and the backward sweep that forms the thin Q
+    builds ``[I; 0]``'s columns ``[s, e)`` under panel s directly
+    (``[I; 0] - V T V_top^H``: ``V^H`` of an identity's columns is a
+    slice) and applies the panel to columns ``(e, n)``, which alone are
+    non-zero in its rows.  The same reflectors, the same products less
+    those with exact zeros: Q2 comes out upper triangular exactly.  R is
+    not returned (its diagonal blocks lie in the packed panels).
+
+    On a grid a row range ends on the column distribution's grain: a
+    panel whose ``m + e`` does not keeps rows ``(s, m + n)``.  Ticks
+    ``qdwh_stack_qr{route}``: ``structured``, or ``dense`` where some
+    panel kept all its rows.  The factorization's ops carry ``el.qr``
+    with ``k<panel>/panel`` and ``/update`` as :func:`qr`'s do, the thin
+    Q's ``el.thin_q/k<panel>/apply``.  Only a caller that KNOWS its lower
+    block is the identity may come here (``funcs._qdwh_step_qr``): a
+    traced operand does not show it."""
+    from ..kernels import resolve_panel
+    _check_mcmr(X)
+    m, n = X.gshape
+    if m < n:
+        raise ValueError(
+            f"the stack's upper block must be tall, got {X.gshape}")
+    g = X.grid
+    r = g.height
+    rows = m + n
+    ib = _blocksize(nb, math.lcm(r, g.width), n)
+    plan = resolve_panel(None, dtype=X.dtype)
+    # (s, e, hi): the panel at columns [s, e) lives in rows (s, hi)
+    starts = range(0, n, ib)
+    ends = [min(s + ib, n) for s in starts]
+    spans = [(s, e, m + e if (m + e) % r == 0 or e == n else rows)
+             for s, e in zip(starts, ends)]
+    _metrics.inc("qdwh_stack_qr", route="structured" if all(
+        hi == m + e for _s, e, hi in spans) else "dense")
+
+    def place(M, block, s, e, hi):
+        """``M`` with the replicated ``block`` at rows (s, hi), columns
+        [s, e)."""
+        block_ss = DistMatrix(block, block.shape, STAR, STAR, 0, 0, g)
+        return update_view(M, redistribute(block_ss, MC, MR),
+                           rows=(s, hi), cols=(s, e))
+
+    S = vstack(X.with_local(sc * X.local), shift_diagonal(_blank(n, n, X), 1))
+    Ts = []
+    with jax.named_scope("el.qr"):
+        tm = _phase_hook("qr", None)
+        tm.start()
+        for k, (s, e, hi) in enumerate(spans):
+            with tm.phase("panel", k):
+                P = redistribute(view(S, rows=(s, hi), cols=(s, e)),
+                                 STAR, STAR)
+                Pf, tau, Tk = _panel_qr_dispatch(P.local, plan)
+                S = place(S, Pf, s, e, hi)
+            with tm.phase("update", k):
+                V = _panel_v(Pf)
+                Ts.append(Tk if Tk is not None else _larft(V, tau))
+                if e < n:
+                    S = update_view(
+                        S, _wy_apply(S, V, jnp.conj(Ts[k]).T, (s, hi), (e, n),
+                                     precision), rows=(s, hi), cols=(e, n))
+    Q = _blank(rows, n, X)
+    with jax.named_scope("el.thin_q"):
+        tm = _phase_hook("thin_q", None)
+        tm.start()
+        for k, (s, e, hi) in reversed(list(enumerate(spans))):
+            with tm.phase("apply", k):
+                P = redistribute(view(S, rows=(s, hi), cols=(s, e)),
+                                 STAR, STAR)
+                V = _panel_v(P.local)
+                # columns [s, e) of [I; 0] under this panel
+                W = jnp.matmul(Ts[k], jnp.conj(V[:e - s]).T,
+                               precision=_hi(precision))
+                Q = place(Q, jnp.eye(hi - s, e - s, dtype=V.dtype)
+                          - jnp.matmul(V, W, precision=_hi(precision)),
+                          s, e, hi)
+                if e < n:
+                    Q = update_view(
+                        Q, _wy_apply(Q, V, Ts[k], (s, hi), (e, n), precision),
+                        rows=(s, hi), cols=(e, n))
+    return (interior_view(Q, (0, m), (0, n)),
+            interior_view(Q, (m, rows), (0, n)))
 
 
 @_scoped("el.least_squares")

@@ -140,7 +140,7 @@ time by them.  A path is made of:
                            ``el.polar/while/body/qdwh_chol03_06/...``):
                            ``qdwh_qr<steps>`` (the QR-based form:
                            ``el.qr`` of the stack ``[sqrt(c) X; I]``,
-                           ``apply_q``, ``el.gemm``) |
+                           ``el.thin_q``, ``el.gemm``) |
                            ``qdwh_chol<steps>`` (the Cholesky-based
                            form: ``el.herk``, ``el.cholesky``, two
                            ``el.trsm``), and
@@ -152,8 +152,16 @@ time by them.  A path is made of:
                            (``.../qdwh_qr01_02/el.qr/k03/panel``
                            reads ``qr/panel``), and
                            ``benchmark/svd_share.py`` reads what stands
-                           over them.  ``apply_q`` opens no scope: its ops
-                           read ``polar/-``
+                           over them.  The QR-based step factors its
+                           stack and forms the thin Q over the rows and
+                           columns that are not structurally zero
+                           (``lapack/qr.py:_stack_qr_thin_q``): the
+                           factorization under ``el.qr`` with
+                           ``k<panel>/panel`` and ``/update`` as ``qr``'s
+                           own, the backward sweep that forms
+                           ``(Q1, Q2)`` under ``el.thin_q/k<panel>/apply``
+                           (it read ``polar/-`` while ``apply_q``, which
+                           opens no scope, formed it)
   ``el.redist.<SRC>.to.<DST>``  every public ``redistribute`` entry
                            (``el.redist.MC_MR.to.VC_STAR``), around ALL
                            it emits: the collectives and the local pack /
@@ -296,9 +304,19 @@ not tick again).  Read them under ``metrics_scope()``:
                            the dtype's eps: 2 and 4 in float32, 2 and 6
                            in float64 (a loop body is traced once and
                            ticks for each of its trips)
+  ``qdwh_stack_qr{route}``  one QR-based step's factorization of its stack
+                           and thin Q, beside ``qdwh_step{kind=qr}`` and
+                           weighed by the trips as it is: ``route``
+                           ``structured`` (every panel at columns
+                           ``[s, e)`` gathered, reduced and applied over
+                           rows ``(s, m + e)``, the T of each kept for the
+                           thin Q; 2 in ``svd.1x1.b2b``'s program) |
+                           ``dense`` (on a grid whose grain ``m + e``
+                           misses, some panel kept rows ``(s, m + n)``)
   ``polar_block{stage,nb}``  the block one stage of ``polar`` / ``svd``'s
                            polar route runs with: ``stage`` ``qr`` (the
-                           QR-based steps' ``qr`` and ``apply_q``) |
+                           QR-based steps' stack: its panels and the
+                           thin Q's sweep) |
                            ``chol`` (``herk``, ``cholesky``, ``trsm`` and
                            the ``gemm``s) | ``eig`` (``svd``'s inner
                            ``herm_eig``), ``nb`` the explicit one or, with

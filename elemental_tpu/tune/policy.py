@@ -60,8 +60,9 @@ def blocksize_policy(nb, grain: int, extent: int) -> int:
 #: MXU's width, what the ``hpd`` cells measured.  ``qr``: the blocked
 #: Householder QR and the reflector sweep that must share its blocking; its
 #: panel is a column loop too, but each panel is unrolled into the program
-#: twice (``qr``, ``apply_q``), and at 256 the SVD cell's program passed the
-#: compile cache's limit on one entry (``perf/program_size.py``).
+#: twice (the factorization, the reflector sweep), and at 256 the SVD
+#: cell's program passed the compile cache's limit on one entry
+#: (``perf/program_size.py``).
 STAGE_BLOCKS = {"reduce": 256, "qr": 512, "block": 2048}
 
 

@@ -152,8 +152,8 @@ def _svd(args):
         (n, n), jnp.float32, sharding=grid.sharding(meta.spec)))
     return _rehearse(jax.jit(lambda a: el.svd(a), donate_argnums=0), (A,),
                      args.hlo,
-                     ("svd_route", "qdwh_step", "polar_block",
-                      "herm_tridiag_hemv", "dc_merge"),
+                     ("svd_route", "qdwh_step", "qdwh_stack_qr",
+                      "polar_block", "herm_tridiag_hemv", "dc_merge"),
                      program="svd", n=n, grid=[grid.height, grid.width])
 
 

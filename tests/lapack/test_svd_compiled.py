@@ -201,6 +201,11 @@ def test_the_stages_carry_their_scopes(program_text):
                  "k00", "panel")
     assert under("el.polar", "qdwh_qr01_02", "el.qr", "k01", "update")
     assert under("el.polar", "qdwh_qr01_02", "el.gemm")
+    # the thin Q's sweep under a name of its own, beside the factorization
+    assert under("el.polar", "while", "body", "qdwh_qr01_02", "el.thin_q",
+                 "k00", "apply")
+    assert under("el.polar", "qdwh_qr01_02", "el.thin_q", "k03", "apply")
+    assert not under("el.qr", "el.thin_q") and not under("el.thin_q", "el.qr")
     assert under("el.polar", "while", "body", "qdwh_chol03_06", "el.herk")
     assert under("el.polar", "qdwh_chol03_06", "el.cholesky")
     assert under("el.polar", "qdwh_chol03_06", "el.trsm")
@@ -212,6 +217,23 @@ def test_the_stages_carry_their_scopes(program_text):
     assert not under("el.polar", "el.herm_eig")
     assert not under("el.polar", "svd_u")
     assert "custom_call_target=\"xla_python" not in program_text
+
+
+def test_the_qr_based_step_runs_over_its_stacks_structure(program_text):
+    """ISSUE 54, n = 64, nb 16: no (2n x n) identity is made (the thin Q
+    starts from zeros and builds a panel's own columns of ``[I; 0]``
+    directly, so the program holds no mask of that shape), and every
+    product of the factorization and of the thin Q has the panel's nb rows
+    or the cut block's n + nb, never the stack's ``2n - s``."""
+    n, nb = 64, 16
+    assert not re.search(rf"pred\[{2 * n},{n}\]", program_text)
+    rows = set()
+    for line in program_text.splitlines():
+        found = re.search(r"= f32\[(\d+),\d+\]\S* dot\(", line)
+        if found and re.search(
+                r'op_name="[^"]*/qdwh_qr01_02/el\.(qr|thin_q)/', line):
+            rows.add(int(found.group(1)))
+    assert rows == {nb, n + nb}
 
 
 # ---------------------------------------------------- the picked blocks

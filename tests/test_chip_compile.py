@@ -785,7 +785,7 @@ def test_svd_is_one_program_for_the_described_chip(topo):
     nothing for the host to do: no callback, no infeed or outfeed, no
     send or receive; the static schedule's 2 QR-based and 4
     Cholesky-based steps all in it, each variant's as one loop body under
-    its own segment; and the
+    its own segment, the QR-based ones over their stack's structure; and the
     inner eigensolve is the one-chip cell's (the one-pass triangle kernel
     under ``el.svd/el.herm_eig``)."""
     import elemental_tpu as el
@@ -799,12 +799,26 @@ def test_svd_is_one_program_for_the_described_chip(topo):
     assert reg.counter_value("svd_route", approach="polar") == 1
     assert reg.counter_value("qdwh_step", kind="qr") == 2
     assert reg.counter_value("qdwh_step", kind="chol") == 4
+    assert dict(reg.counters("qdwh_stack_qr")) == {
+        ("qdwh_stack_qr", (("route", "structured"),)): 2}
     assert dict(reg.counters("herm_tridiag_hemv")) == {
         ("herm_tridiag_hemv", (("impl", "symv"),)): n // nb}
     assert not re.search(r"callback|infeed|outfeed| send\(| recv\(", text)
     steps = set(re.findall(
         r"/el\.svd/el\.polar/while/body/closed_call/(qdwh_\w+)/", text))
     assert steps == {"qdwh_qr01_02", "qdwh_chol03_06"}
+    # ISSUE 54: the QR-based step's products run over the stack's non-zero
+    # rows (n + nb of them for every panel, never 2n - s), the thin Q's
+    # under a scope of their own, and no (2n x n) identity is made
+    products = {}
+    for line in text.splitlines():
+        found = re.search(
+            r'= f32\[(\d+),\d+\]\S* (?:convolution|dot)\(.*op_name="[^"]*'
+            r'/qdwh_qr01_02/el\.(qr|thin_q)/k\d+/(?:update|apply)/', line)
+        if found:
+            products.setdefault(found.group(2), set()).add(int(found.group(1)))
+    assert products == {"qr": {nb, n + nb}, "thin_q": {nb, n + nb}}
+    assert not re.search(rf"pred\[{2 * n},{n}\]", text)
     kernels = [line for line in text.splitlines()
                if "tpu_custom_call" in line]
     assert kernels and all(
