@@ -70,7 +70,7 @@ from ..blas.level2 import gemv
 from ..blas.level1 import _global_indices
 from ..blas.level3 import _blocksize, _check_mcmr, _mask_triangle
 from ..obs import metrics as _metrics
-from ..obs.tracer import NULL_HOOK
+from ..obs.tracer import NULL_HOOK, redist_part as _redist_part
 from ..kernels.symv import shard_block, symv_lower, symv_lower_shard
 from .lu import _update_cols_lt, _hi, _phase_hook, _scoped
 from .qr import _larft
@@ -198,7 +198,7 @@ def _symv_grid(Atrail: DistMatrix, v, interpret: bool):
         part = symv_lower_shard(a, v, lax.axis_index("mc"),
                                 lax.axis_index("mr"), stride=r, nt=nt,
                                 interpret=interpret)
-        with jax.named_scope("el.redist.hemv_join"):
+        with jax.named_scope("el.redist.hemv_join"), _redist_part("wire"):
             return lax.psum(part, ("mc", "mr"))
 
     return shard_map(f, mesh=g.mesh,

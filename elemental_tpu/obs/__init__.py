@@ -173,6 +173,42 @@ time by them.  A path is made of:
                            partial matvecs over all chips: one ``psum``
                            of a replicated vector, the reference's
                            ``Contract`` to ``[STAR,STAR]``)
+  ``pack`` / ``wire`` /    under an ``el.redist.<name>``, one of
+  ``unpack``               :data:`REDIST_PARTS`, opened by the engine's
+                           primitives where the work is emitted
+                           (:func:`redist_part`): ``wire`` around each
+                           EXPLICIT collective call and nothing else
+                           (``all_gather``, ``all_to_all``, ``ppermute``,
+                           ``psum``, ``psum_scatter``; an async pair's
+                           ``-start`` and ``-done`` inherit it);
+                           ``pack`` the local ops that feed it (the pad,
+                           the reshape into per-peer blocks, the local
+                           transpose of a column form, the cast or
+                           quantize-encode to the wire dtype, the plan
+                           executor's gather by its index tables);
+                           ``unpack`` the local ops after it (the
+                           alignment's ``roll`` of the gathered blocks,
+                           the interleave, the cyclic filter with its
+                           ``optimization_barrier``, the slice to the true
+                           extent, the masks, the slot permutation's
+                           ``take``, the decode, the panel spread's
+                           adjoint transpose, the plan executor's
+                           scatter); an exchange with NO collective (a
+                           pure local filter such as ``[STAR,STAR] ->
+                           [MC,MR]``, a degenerate hop on a 1-wide grid)
+                           is all ``unpack``.  The part of an op is the
+                           FIRST of the three that stands after the first
+                           ``el.redist.`` segment of its path
+                           (``.../el.redist.MC_MR.to.VC_STAR/
+                           jit(_redistribute_jit)/shard_map/wire/
+                           all_to_all``; a chain of hops names each
+                           hop's parts in turn under the one name).  An
+                           op under an ``el.redist.`` name with NO part
+                           is ``planned``: motion the COMPILER plans
+                           (``el.redist.row_permute``: ``move_rows``,
+                           ``permute_rows_storage``) and the literals it
+                           materialises; ``benchmark/redist_parts.py``
+                           books it as the remainder
   ``el_potrf_inv_panel`` / the ``name=`` of the Pallas kernels
   ``el_lu_panel`` /        (``kernels/``: the three panel kernels, the
   ``el_qr_panel`` /        one-pass triangle ``symv`` and the unpivoted
@@ -190,7 +226,14 @@ An op in an ``el.`` scope but outside any phase (a driver's final
 assembly or mask) belongs to the driver; an op with no ``el.`` segment
 was made by the compiler or was not named.  The persistent compile cache
 is keyed with these names (``core/compile_cache.py``), so an executable
-from the cache carries this build's.
+from the cache carries this build's.  Names only, with one thing to know:
+XLA names an instruction it MERGES (two transposes into one) after the
+tail of the merged ``op_name``, so a scope opened inside a ``shard_map``
+renames a few instructions (``%transpose_transpose.84`` ->
+``%transpose.353``) and shifts the numbers after them; the ops, shapes,
+operands and order are the same (``perf.program_size drivers`` and
+``tests/obs/test_scopes.py`` compare with the instructions renamed in
+order).
 
 Counters of the engine and the drivers, ticked where their Python runs:
 every eager call, and once per trace under ``jit`` (a cached jaxpr does
@@ -360,7 +403,8 @@ from .metrics import (SCHEMA as METRICS_SCHEMA, FAMILIES as HIST_FAMILIES,
                       set_hist_family)
 from .tracer import (TRACE_SCHEMA, CommEvent, InstantEvent, NullHook,
                      NULL_HOOK, PhaseHook, PhaseRecord, Span, Tracer,
-                     active_tracer, phase_hook, ring_bytes, scoped)
+                     REDIST_PARTS, active_tracer, phase_hook, redist_part,
+                     ring_bytes, scoped)
 from .phase_timer import PHASES, SCHEMA as PHASE_TIMINGS_SCHEMA, PhaseTimer
 from .export import (CHROME_SCHEMA, chrome_trace_doc,
                      phase_timings_to_chrome, write_json)
@@ -377,7 +421,8 @@ __all__ = [
     "TRACE_SCHEMA", "CommEvent", "InstantEvent", "NullHook", "NULL_HOOK",
     "PhaseHook", "PhaseRecord", "Span", "Tracer", "active_tracer",
     "phase_hook", "ring_bytes", "scoped",
-    "PHASES", "PHASE_TIMINGS_SCHEMA", "PhaseTimer",
+    "PHASES", "REDIST_PARTS", "redist_part", "PHASE_TIMINGS_SCHEMA",
+    "PhaseTimer",
     "CHROME_SCHEMA", "chrome_trace_doc", "phase_timings_to_chrome",
     "write_json", "compile_log",
     "TIMELINE_SCHEMA", "LIFECYCLE_EDGES", "RequestTrace", "check_timeline",

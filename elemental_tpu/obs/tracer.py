@@ -178,6 +178,22 @@ def scoped(name: str):
     return deco
 
 
+#: the three parts of an exchange, the segments that may stand under an
+#: ``el.redist.<name>`` scope: the local ops that feed its collective, the
+#: explicit collective itself, the local ops after it (grammar in
+#: :mod:`elemental_tpu.obs`)
+REDIST_PARTS = ("pack", "wire", "unpack")
+
+
+def redist_part(part: str):
+    """``jax.named_scope(part)`` for one of :data:`REDIST_PARTS`, opened by
+    the redistribution engine's primitives where the work is emitted.  Like
+    every scope it is looked up at call time and names ops only."""
+    if part not in REDIST_PARTS:
+        raise ValueError(f"part must be one of {REDIST_PARTS}, got {part!r}")
+    return jax.named_scope(part)
+
+
 class _Phase:
     """One ``with hook.phase(phase, step) as ph:`` block."""
     __slots__ = ("_hook", "_phase", "_step", "_arrays", "_scope")

@@ -23,6 +23,18 @@ mesh; the public jit-able entry point is :func:`redistribute`.
 Alignment support: the generic path handles arbitrary alignments; fast paths
 currently require zero alignments (the blocked algorithms only use zero) and
 fall back otherwise.
+
+Every primitive here is a local step, one explicit collective, a local step,
+and names them so under its caller's ``el.redist.<name>`` scope
+(``obs.redist_part``, grammar in :mod:`elemental_tpu.obs`): ``pack`` (what
+feeds the collective: ``_pad_dim``, the reshape into per-peer blocks, the
+cast or ``q8_pack`` to the wire dtype, ``_direct_exec``'s table gather),
+``wire`` (the ``lax`` collective call and nothing else), ``unpack`` (what
+follows: ``_interleave``, ``_deinterleave``, the ``_filter_*`` family, the
+slices, ``_zero_padding`` and the masks, ``q8_unpack``, ``_direct_exec``'s
+scatter; all of an exchange that has no collective).  ``move_rows`` and
+``permute_rows_storage`` issue no collective of their own and name no part:
+``benchmark/redist_parts.py`` books them as ``planned``.
 """
 from __future__ import annotations
 
@@ -43,7 +55,7 @@ from ..core.dist import (
 )
 from ..core.distmatrix import DistMatrix, _check_pair
 from ..obs import metrics as _metrics
-from ..obs.tracer import scoped as _scoped
+from ..obs.tracer import redist_part as _part, scoped as _scoped
 from .plan import compile_plan
 from .quantize import (QUANT_TILE, check_comm_precision, q8_pack, q8_unpack,
                        quantizable)
@@ -344,25 +356,28 @@ def _deinterleave(x, dim: int, S: int, shift):
 
 def _gather_dim(x, dim: int, d: Dist, align: int, extent: int, r: int, c: int):
     """Rebuild the full (true-extent) dimension on every device."""
-    if d is MD:
-        if r * c == 1:
+    S = r * c if d is MD else dist_stride(d, r, c)
+    if S == 1:
+        with _part("unpack"):
             return lax.slice_in_dim(x, 0, extent, axis=dim)
+    if d is MD:
         # p slot-ranges of length l gathered mc-major, then the static
         # slot permutation rebuilds global order (copy:: for [MD,*])
-        g = lax.all_gather(x, ("mc", "mr"), axis=0)       # (p, l, ...)
-        shape = list(x.shape)
-        shape[dim] = x.shape[dim] * r * c
-        g = jnp.moveaxis(g, 0, dim)
-        gflat = g.reshape(shape)                          # slot-major flat
-        idx = jnp.asarray(md_slot_of_global(r, c, extent))
-        return jnp.take(gflat, idx, axis=dim)
-    S = dist_stride(d, r, c)
-    if S == 1:
-        return lax.slice_in_dim(x, 0, extent, axis=dim)
-    g = lax.all_gather(x, gather_axes(d), axis=0)        # (S, ...) rank-ordered
-    if align:
-        g = jnp.roll(g, -align, axis=0)                   # block s <- shift s
-    return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
+        with _part("wire"):
+            g = lax.all_gather(x, ("mc", "mr"), axis=0)   # (p, l, ...)
+        with _part("unpack"):
+            shape = list(x.shape)
+            shape[dim] = x.shape[dim] * r * c
+            g = jnp.moveaxis(g, 0, dim)
+            gflat = g.reshape(shape)                      # slot-major flat
+            idx = jnp.asarray(md_slot_of_global(r, c, extent))
+            return jnp.take(gflat, idx, axis=dim)
+    with _part("wire"):
+        g = lax.all_gather(x, gather_axes(d), axis=0)    # (S, ...) rank-ordered
+    with _part("unpack"):
+        if align:
+            g = jnp.roll(g, -align, axis=0)               # block s <- shift s
+        return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
 
 
 def _filter_md(x, dim: int, extent: int, r: int, c: int):
@@ -392,9 +407,12 @@ def _partial_gather_dim(x, dim: int, axes, nblocks: int, l_out: int):
     yields the coarse-cyclic local block.
     """
     if nblocks == 1:                    # degenerate: nothing to exchange
-        return lax.slice_in_dim(x, 0, l_out, axis=dim)
-    g = lax.all_gather(x, axes, axis=0)                   # (nblocks, l_in, ...)
-    return lax.slice_in_dim(_interleave(g, dim), 0, l_out, axis=dim)
+        with _part("unpack"):
+            return lax.slice_in_dim(x, 0, l_out, axis=dim)
+    with _part("wire"):
+        g = lax.all_gather(x, axes, axis=0)               # (nblocks, l_in, ...)
+    with _part("unpack"):
+        return lax.slice_in_dim(_interleave(g, dim), 0, l_out, axis=dim)
 
 
 def _partial_filter_dim(x, dim: int, nblocks: int, sub_rank, l_out: int):
@@ -424,16 +442,19 @@ def _fused_to_v(A: DistMatrix) -> DistMatrix:
     else:                                   # (MR, MC)
         ax, n_other, dst = "mc", r, VR
     lt = ix.max_local_length(m, p)
-    x = _pad_dim(A.local, 0, n_other * lt)
-    lc = x.shape[1]
-    x3 = x.reshape(lt, n_other, lc)         # row t = w*n_other + g
-    y = x3 if n_other == 1 \
-        else lax.all_to_all(x3, ax, split_axis=1, concat_axis=1)
-    z = _interleave(jnp.moveaxis(y, 1, 0), 1)  # col j = jLoc*n_other + g
-    z = lax.slice_in_dim(z, 0, n, axis=1)
-    v = rank_of(dst, r, c)
-    gi = jnp.arange(lt) * p + v
-    z = jnp.where((gi < m)[:, None], z, 0)
+    with _part("pack" if n_other > 1 else "unpack"):
+        x = _pad_dim(A.local, 0, n_other * lt)
+        lc = x.shape[1]
+        x3 = x.reshape(lt, n_other, lc)     # row t = w*n_other + g
+    with _part("wire"):
+        y = x3 if n_other == 1 \
+            else lax.all_to_all(x3, ax, split_axis=1, concat_axis=1)
+    with _part("unpack"):
+        z = _interleave(jnp.moveaxis(y, 1, 0), 1)  # col j = jLoc*n_other + g
+        z = lax.slice_in_dim(z, 0, n, axis=1)
+        v = rank_of(dst, r, c)
+        gi = jnp.arange(lt) * p + v
+        z = jnp.where((gi < m)[:, None], z, 0)
     return DistMatrix(z, A.gshape, dst, STAR, 0, 0, g)
 
 
@@ -452,18 +473,21 @@ def _fused_from_v(A: DistMatrix) -> DistMatrix:
         S_row = c
     lp = A.local.shape[0]                   # ceil(m/p)
     lcd = ix.max_local_length(n, n_other)
-    x = _pad_dim(A.local, 1, n_other * lcd)
-    x3 = x.reshape(lp, lcd, n_other)        # col j = u*n_other + s
-    y = x3 if n_other == 1 \
-        else lax.all_to_all(x3, ax, split_axis=2, concat_axis=2)
-    z = _interleave(jnp.moveaxis(y, 2, 0), 0)  # row i = iLoc*n_other + s
-    lr = ix.max_local_length(m, S_row)
-    z = lax.slice_in_dim(z, 0, lr, axis=0)
-    q_row = rank_of(dst[0], r, c)
-    gi = jnp.arange(lr) * S_row + q_row
-    q_col = rank_of(dst[1], r, c)
-    gj = jnp.arange(lcd) * n_other + q_col
-    z = jnp.where((gi < m)[:, None] & (gj < n)[None, :], z, 0)
+    with _part("pack" if n_other > 1 else "unpack"):
+        x = _pad_dim(A.local, 1, n_other * lcd)
+        x3 = x.reshape(lp, lcd, n_other)    # col j = u*n_other + s
+    with _part("wire"):
+        y = x3 if n_other == 1 \
+            else lax.all_to_all(x3, ax, split_axis=2, concat_axis=2)
+    with _part("unpack"):
+        z = _interleave(jnp.moveaxis(y, 2, 0), 0)  # row i = iLoc*n_other + s
+        lr = ix.max_local_length(m, S_row)
+        z = lax.slice_in_dim(z, 0, lr, axis=0)
+        q_row = rank_of(dst[0], r, c)
+        gi = jnp.arange(lr) * S_row + q_row
+        q_col = rank_of(dst[1], r, c)
+        gj = jnp.arange(lcd) * n_other + q_col
+        z = jnp.where((gi < m)[:, None] & (gj < n)[None, :], z, 0)
     return DistMatrix(z, A.gshape, dst[0], dst[1], 0, 0, g)
 
 
@@ -499,9 +523,11 @@ def _fused_to_star_star(A: DistMatrix) -> DistMatrix | None:
     m, n = A.gshape
     x = A.local
     lr, lc = x.shape
-    gx = lax.all_gather(x, ("mc", "mr"), axis=0)      # (r*c, lr, lc), mc-major
-    full = lax.slice(_interleave_2d(gx.reshape(r, c, lr, lc), A.dist),
-                     (0, 0), (m, n))
+    with _part("wire"):
+        gx = lax.all_gather(x, ("mc", "mr"), axis=0)  # (r*c, lr, lc), mc-major
+    with _part("unpack"):
+        full = lax.slice(_interleave_2d(gx.reshape(r, c, lr, lc), A.dist),
+                         (0, 0), (m, n))
     return DistMatrix(full, A.gshape, STAR, STAR, 0, 0, g)
 
 
@@ -518,15 +544,20 @@ def _fused_dispatch(A: DistMatrix, dst) -> DistMatrix | None:
     if src == (VR, STAR) and dst == (MR, MC):
         return _fused_from_v(A)
     # transposed (column) forms ride the row kernels on the local transpose
-    if src == (MC, MR) and dst == (STAR, VR):
-        return _t_meta(_fused_to_v(_t_meta(A)))
-    if src == (MR, MC) and dst == (STAR, VC):
-        return _t_meta(_fused_to_v(_t_meta(A)))
-    if src == (STAR, VR) and dst == (MC, MR):
-        return _t_meta(_fused_from_v(_t_meta(A)))
-    if src == (STAR, VC) and dst == (MR, MC):
-        return _t_meta(_fused_from_v(_t_meta(A)))
+    if (src, dst) in (((MC, MR), (STAR, VR)), ((MR, MC), (STAR, VC))):
+        return _on_transpose(_fused_to_v, A)
+    if (src, dst) in (((STAR, VR), (MC, MR)), ((STAR, VC), (MR, MC))):
+        return _on_transpose(_fused_from_v, A)
     return None
+
+
+def _on_transpose(kernel, A: DistMatrix) -> DistMatrix:
+    """A fused row kernel on the local transpose, transposed back."""
+    with _part("pack"):
+        At = _t_meta(A)
+    out = kernel(At)
+    with _part("unpack"):
+        return _t_meta(out)
 
 
 # ---------------------------------------------------------------------
@@ -547,7 +578,8 @@ def _realign(A: DistMatrix, calign: int, ralign: int) -> DistMatrix:
         if S == 1 or a_old == a_new:
             continue
         axes, perm = _rot_perm(d, (a_old - a_new) % S, r, c)
-        x = lax.ppermute(x, axes, perm)
+        with _part("wire"):
+            x = lax.ppermute(x, axes, perm)
     return DistMatrix(x, A.gshape, A.cdist, A.rdist, calign, ralign, A.grid)
 
 
@@ -568,18 +600,19 @@ def _from_star_star(xg, gshape, cdist, rdist, calign, ralign, grid) -> DistMatri
     Sc, Sr = dist_stride(cdist, r, c), dist_stride(rdist, r, c)
     lr = ix.max_local_length(gshape[0], Sc)
     lc = ix.max_local_length(gshape[1], Sr)
-    if cdist is MD:
-        loc = _filter_md(xg, 0, gshape[0], r, c)
-    else:
-        loc = _filter_dim(xg, 0, Sc,
-                          ix.shift(rank_of(cdist, r, c), calign, Sc), lr)
-    if rdist is MD:
-        loc = _filter_md(loc, 1, gshape[1], r, c)
-    else:
-        loc = _filter_dim(loc, 1, Sr,
-                          ix.shift(rank_of(rdist, r, c), ralign, Sr), lc)
-    # zero the padding tail (rows whose global index >= extent)
-    loc = _zero_padding(loc, gshape, cdist, rdist, calign, ralign, grid)
+    with _part("unpack"):
+        if cdist is MD:
+            loc = _filter_md(xg, 0, gshape[0], r, c)
+        else:
+            loc = _filter_dim(xg, 0, Sc,
+                              ix.shift(rank_of(cdist, r, c), calign, Sc), lr)
+        if rdist is MD:
+            loc = _filter_md(loc, 1, gshape[1], r, c)
+        else:
+            loc = _filter_dim(loc, 1, Sr,
+                              ix.shift(rank_of(rdist, r, c), ralign, Sr), lc)
+        # zero the padding tail (rows whose global index >= extent)
+        loc = _zero_padding(loc, gshape, cdist, rdist, calign, ralign, grid)
     return DistMatrix(loc, gshape, cdist, rdist, calign, ralign, grid)
 
 
@@ -717,7 +750,9 @@ def _rowdim_change(A: DistMatrix, rdist: Dist) -> DistMatrix | None:
     if src is STAR:
         Sr = dist_stride(rdist, r, c)
         lc = ix.max_local_length(n, Sr)
-        loc = _filter_dim(A.local, 1, Sr, ix.shift(rank_of(rdist, r, c), 0, Sr), lc)
+        with _part("unpack"):
+            loc = _filter_dim(A.local, 1, Sr,
+                              ix.shift(rank_of(rdist, r, c), 0, Sr), lc)
         return DistMatrix(loc, A.gshape, A.cdist, rdist, A.calign, 0, g)
     # distributed -> replicated: gather
     if rdist is STAR:
@@ -740,7 +775,9 @@ def _coldim_change(A: DistMatrix, cdist: Dist) -> DistMatrix | None:
     if src is STAR:
         Sc = dist_stride(cdist, r, c)
         lr = ix.max_local_length(m, Sc)
-        loc = _filter_dim(A.local, 0, Sc, ix.shift(rank_of(cdist, r, c), 0, Sc), lr)
+        with _part("unpack"):
+            loc = _filter_dim(A.local, 0, Sc,
+                              ix.shift(rank_of(cdist, r, c), 0, Sc), lr)
         return DistMatrix(loc, A.gshape, cdist, A.rdist, 0, A.ralign, g)
     if cdist is STAR:
         loc = _gather_dim(A.local, 0, src, A.calign, m, r, c)
@@ -771,9 +808,10 @@ def _partial_ladder(A: DistMatrix, dim: int, src: Dist, dst: Dist) -> DistMatrix
         return _retag(A, dim, dst, loc)
     if (src, dst) == (MC, VC) or (src, dst) == (MR, VR):
         nblocks = c if dst is VC else r
-        sub = lax.axis_index("mr") if dst is VC else lax.axis_index("mc")
         l_out = ix.max_local_length(extent, p)
-        loc = _partial_filter_dim(A.local, dim, nblocks, sub, l_out)
+        with _part("unpack"):
+            sub = lax.axis_index("mr") if dst is VC else lax.axis_index("mc")
+            loc = _partial_filter_dim(A.local, dim, nblocks, sub, l_out)
         return _retag(A, dim, dst, loc)
     if {src, dst} == {VC, VR}:
         loc = _vc_vr_permute(A.local, src, r, c)
@@ -802,7 +840,8 @@ def _vc_vr_permute(x, src: Dist, r: int, c: int):
         perm = [(vc_dev[v], v) for v in range(p)]
     else:
         perm = [(v, vc_dev[v]) for v in range(p)]
-    return lax.ppermute(x, ("mc", "mr"), perm)
+    with _part("wire"):
+        return lax.ppermute(x, ("mc", "mr"), perm)
 
 
 def _retag(A: DistMatrix, dim: int, d: Dist, loc) -> DistMatrix:
@@ -978,35 +1017,40 @@ def _direct_exec(x, plan, wire, dt):
     :mod:`.quantize` codec) so the ONE collective moves int8; bf16 is
     cast by the caller around this function."""
     r, c = plan.grid_shape
-    dev = lax.axis_index("mc") * c + lax.axis_index("mr")
-    sr = jnp.take(jnp.asarray(plan.send_rows), dev, axis=0)     # (K, R)
-    sc = jnp.take(jnp.asarray(plan.send_cols), dev, axis=0)     # (K, C)
-    lr_s, lc_s = plan.src_local
-    ok = (sr < lr_s)[:, :, None] & (sc < lc_s)[:, None, :]
-    vals = x[jnp.clip(sr, 0, lr_s - 1)[:, :, None],
-             jnp.clip(sc, 0, lc_s - 1)[:, None, :]]
-    vals = jnp.where(ok, vals, 0)                               # (K, R, C)
     R, C = plan.slot_shape
     q8 = wire == "int8" and plan.kind != "local"
-    if q8:
-        vals = jax.vmap(lambda s: q8_pack(s, QUANT_TILE))(vals)
-    if plan.kind == "a2a":
-        # ragged subgroup a2a: the plan's equal-size participant groups
-        # (or None for the full comm product); the K* slots are addressed
-        # by GROUP position, which the remapped index tables encode
-        gg = [list(g) for g in plan.groups] if plan.groups else None
-        recv = lax.all_to_all(vals, plan.comm_axes, split_axis=0,
-                              concat_axis=0, axis_index_groups=gg)
-    elif plan.kind == "ppermute":
-        recv = lax.ppermute(vals, plan.comm_axes, list(plan.perm))
-    else:
-        recv = vals
-    if q8:
-        recv = jax.vmap(lambda s: q8_unpack(s, (R, C), dt, QUANT_TILE))(recv)
-    rr = jnp.take(jnp.asarray(plan.recv_rows), dev, axis=0)
-    rc = jnp.take(jnp.asarray(plan.recv_cols), dev, axis=0)
-    out = jnp.zeros(plan.dst_local, recv.dtype)
-    return out.at[rr[:, :, None], rc[:, None, :]].set(recv, mode="drop")
+    with _part("unpack" if plan.kind == "local" else "pack"):
+        dev = lax.axis_index("mc") * c + lax.axis_index("mr")
+        sr = jnp.take(jnp.asarray(plan.send_rows), dev, axis=0)     # (K, R)
+        sc = jnp.take(jnp.asarray(plan.send_cols), dev, axis=0)     # (K, C)
+        lr_s, lc_s = plan.src_local
+        ok = (sr < lr_s)[:, :, None] & (sc < lc_s)[:, None, :]
+        vals = x[jnp.clip(sr, 0, lr_s - 1)[:, :, None],
+                 jnp.clip(sc, 0, lc_s - 1)[:, None, :]]
+        vals = jnp.where(ok, vals, 0)                           # (K, R, C)
+        if q8:
+            vals = jax.vmap(lambda s: q8_pack(s, QUANT_TILE))(vals)
+    with _part("wire"):
+        if plan.kind == "a2a":
+            # ragged subgroup a2a: the plan's equal-size participant groups
+            # (or None for the full comm product); the K* slots are
+            # addressed by GROUP position, which the remapped index tables
+            # encode
+            gg = [list(g) for g in plan.groups] if plan.groups else None
+            recv = lax.all_to_all(vals, plan.comm_axes, split_axis=0,
+                                  concat_axis=0, axis_index_groups=gg)
+        elif plan.kind == "ppermute":
+            recv = lax.ppermute(vals, plan.comm_axes, list(plan.perm))
+        else:
+            recv = vals
+    with _part("unpack"):
+        if q8:
+            recv = jax.vmap(
+                lambda s: q8_unpack(s, (R, C), dt, QUANT_TILE))(recv)
+        rr = jnp.take(jnp.asarray(plan.recv_rows), dev, axis=0)
+        rc = jnp.take(jnp.asarray(plan.recv_cols), dev, axis=0)
+        out = jnp.zeros(plan.dst_local, recv.dtype)
+        return out.at[rr[:, :, None], rc[:, None, :]].set(recv, mode="drop")
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
@@ -1023,9 +1067,11 @@ def _redistribute_direct_jit(A: DistMatrix, cdist: Dist, rdist: Dist,
     def f(a):
         x = a.local
         if wire == "bf16":
-            x = x.astype(jnp.bfloat16)
+            with _part("pack"):
+                x = x.astype(jnp.bfloat16)
         loc = _direct_exec(x, plan, wire, dt)
-        loc = loc.astype(dt)
+        with _part("unpack"):
+            loc = loc.astype(dt)
         return DistMatrix(loc, A.gshape, cdist, rdist, calign, ralign,
                           A.grid)
 
@@ -1079,9 +1125,12 @@ def _q8_gather_blocks(x, axes, tile: int):
     (payload + bitcast scales, one array), ONE collective, per-source
     decode.  Returns the ``(S, *x.shape)`` stack the interleave math of
     the full-precision kernels consumes unchanged."""
-    packed = q8_pack(x, tile)
-    gx = lax.all_gather(packed, axes, axis=0)
-    return jax.vmap(lambda b: q8_unpack(b, x.shape, x.dtype, tile))(gx)
+    with _part("pack"):
+        packed = q8_pack(x, tile)
+    with _part("wire"):
+        gx = lax.all_gather(packed, axes, axis=0)
+    with _part("unpack"):
+        return jax.vmap(lambda b: q8_unpack(b, x.shape, x.dtype, tile))(gx)
 
 
 def _gather_dim_q8(x, dim: int, d: Dist, extent: int, r: int, c: int,
@@ -1089,9 +1138,11 @@ def _gather_dim_q8(x, dim: int, d: Dist, extent: int, r: int, c: int,
     """Zero-aligned :func:`_gather_dim` with an int8 block-scaled wire."""
     S = dist_stride(d, r, c)
     if S == 1:
-        return lax.slice_in_dim(x, 0, extent, axis=dim)
+        with _part("unpack"):
+            return lax.slice_in_dim(x, 0, extent, axis=dim)
     g = _q8_gather_blocks(x, gather_axes(d), tile)
-    return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
+    with _part("unpack"):
+        return lax.slice_in_dim(_interleave(g, dim), 0, extent, axis=dim)
 
 
 def _to_star_star_q8(A: DistMatrix, tile: int) -> DistMatrix:
@@ -1104,8 +1155,10 @@ def _to_star_star_q8(A: DistMatrix, tile: int) -> DistMatrix:
     x = A.local
     if A.dist in ((MC, MR), (MR, MC)) and r > 1 and c > 1:
         lr, lc = x.shape
-        G = _q8_gather_blocks(x, ("mc", "mr"), tile).reshape(r, c, lr, lc)
-        full = lax.slice(_interleave_2d(G, A.dist), (0, 0), (m, n))
+        G = _q8_gather_blocks(x, ("mc", "mr"), tile)
+        with _part("unpack"):
+            full = lax.slice(_interleave_2d(G.reshape(r, c, lr, lc), A.dist),
+                             (0, 0), (m, n))
         return DistMatrix(full, A.gshape, STAR, STAR, 0, 0, g)
     xg = _gather_dim_q8(x, 0, A.cdist, m, r, c, tile)
     xg = _gather_dim_q8(xg, 1, A.rdist, n, r, c, tile)
@@ -1160,7 +1213,8 @@ def _panel_spread_to_pair(A: DistMatrix, conj: bool, tile: int | None = None):
     if r * c == 1:
         blocks = x[None]
     elif tile is None:
-        blocks = lax.all_gather(x, gather_axes(VC), axis=0)   # (p, l, k)
+        with _part("wire"):
+            blocks = lax.all_gather(x, gather_axes(VC), axis=0)   # (p, l, k)
     else:
         blocks = _q8_gather_blocks(x, gather_axes(VC), tile)
     l = x.shape[0]
@@ -1174,11 +1228,12 @@ def _panel_spread_to_pair(A: DistMatrix, conj: bool, tile: int | None = None):
         rows = _interleave(mine, 0)
         return lax.slice_in_dim(rows, 0, ix.max_local_length(m, S), axis=0)
 
-    mc = _zero_padding(kept(MC), (m, k), MC, STAR, 0, 0, g)
-    adj = kept(MR).T
-    if conj:
-        adj = jnp.conj(adj)
-    mr = _zero_padding(adj, (k, m), STAR, MR, 0, 0, g)
+    with _part("unpack"):
+        mc = _zero_padding(kept(MC), (m, k), MC, STAR, 0, 0, g)
+        adj = kept(MR).T
+        if conj:
+            adj = jnp.conj(adj)
+        mr = _zero_padding(adj, (k, m), STAR, MR, 0, 0, g)
     return (DistMatrix(mc, (m, k), MC, STAR, 0, 0, g),
             DistMatrix(mr, (k, m), STAR, MR, 0, 0, g))
 
@@ -1195,11 +1250,13 @@ def _panel_spread_jit(A: DistMatrix, conj: bool, wire=None):
         if wire == "int8":
             return _panel_spread_to_pair(a, conj, QUANT_TILE)
         if wire == "bf16":
-            a = a.with_local(a.local.astype(jnp.bfloat16))
+            with _part("pack"):
+                a = a.with_local(a.local.astype(jnp.bfloat16))
         mc, mr = _panel_spread_to_pair(a, conj)
         if wire == "bf16":
-            mc = mc.with_local(mc.local.astype(dt))
-            mr = mr.with_local(mr.local.astype(dt))
+            with _part("unpack"):
+                mc = mc.with_local(mc.local.astype(dt))
+                mr = mr.with_local(mr.local.astype(dt))
         return mc, mr
 
     return shard_map(
@@ -1392,7 +1449,8 @@ def contract(A: DistMatrix, cdist: Dist, rdist: Dist) -> DistMatrix:
         return DistMatrix(loc, A.gshape, MC, MR, 0, 0, g)
     if src == (STAR, STAR) and dst == (STAR, STAR):
         # partial replicated -> full sum everywhere
-        loc = lax.psum(lax.psum(A.local, "mc"), "mr")
+        with _part("wire"):
+            loc = lax.psum(lax.psum(A.local, "mc"), "mr")
         return DistMatrix(loc, A.gshape, STAR, STAR, 0, 0, g)
     if src == (STAR, STAR) and dst == (VC, STAR):
         ss = contract(A, STAR, STAR)
@@ -1403,16 +1461,20 @@ def contract(A: DistMatrix, cdist: Dist, rdist: Dist) -> DistMatrix:
 def _scatter_sum_dim(x, dim: int, axis_name: str, S: int, l_out: int):
     """psum_scatter a replicated-partial dimension onto its cyclic owners."""
     if S == 1:
-        return _pad_dim(x, dim, l_out)
-    x = _pad_dim(x, dim, S * l_out)
-    shape = list(x.shape)
-    shape[dim : dim + 1] = [l_out, S]
-    x = x.reshape(shape)                       # (..., l_out, S, ...)
-    x = jnp.moveaxis(x, dim + 1, dim)          # (..., S, l_out, ...) residue-major
-    shape2 = list(x.shape)
-    shape2[dim : dim + 2] = [S * l_out]
-    x = x.reshape(shape2)
-    return lax.psum_scatter(x, axis_name, scatter_dimension=dim, tiled=True)
+        with _part("unpack"):
+            return _pad_dim(x, dim, l_out)
+    with _part("pack"):
+        x = _pad_dim(x, dim, S * l_out)
+        shape = list(x.shape)
+        shape[dim : dim + 1] = [l_out, S]
+        x = x.reshape(shape)                   # (..., l_out, S, ...)
+        x = jnp.moveaxis(x, dim + 1, dim)      # (..., S, l_out, ...) residue-major
+        shape2 = list(x.shape)
+        shape2[dim : dim + 2] = [S * l_out]
+        x = x.reshape(shape2)
+    with _part("wire"):
+        return lax.psum_scatter(x, axis_name, scatter_dimension=dim,
+                                tiled=True)
 
 
 # ---------------------------------------------------------------------
@@ -1596,10 +1658,12 @@ def _redistribute_jit(A: DistMatrix, cdist: Dist, rdist: Dist,
         # jaxpr-level analyzer reads the true payload dtype off the
         # collective operand
         if wire == "bf16":
-            a = a.with_local(a.local.astype(jnp.bfloat16))
+            with _part("pack"):
+                a = a.with_local(a.local.astype(jnp.bfloat16))
         out = to_dist(a, cdist, rdist, calign, ralign)
         if wire == "bf16":
-            out = out.with_local(out.local.astype(dt))
+            with _part("unpack"):
+                out = out.with_local(out.local.astype(dt))
         return out
 
     return shard_map(

@@ -16,8 +16,9 @@ described ``v5e:2x2`` and prints the entry's bytes beside the plan, the
 lines and the trace-time counters; ``svd`` the donated ``jit(el.svd)`` of
 ``svd.1x1.b2b`` (no ``nb``) for ONE described v5e chip, the same way.
 ``drivers`` prints a hash of the
-stripped optimized HLO (CPU backend, n = 256, one device and 2x2) of every
-driver a cell of the benchmark compiles.  ``--root <checkout>`` imports
+stripped optimized HLO (CPU backend, n = 256, one device and 2x2; metadata
+out, instructions renamed in order) of every driver a cell of the benchmark
+compiles, ``least_squares`` on 2x2 by both routes.  ``--root <checkout>`` imports
 ``elemental_tpu`` from another tree: run both on two trees to see which
 programs a change moved (equal hashes: the program is the other tree's)
 and by how much.  ONE n = 16384 rehearsal at a time (two at most, with
@@ -158,12 +159,19 @@ def _svd(args):
 
 
 def _stripped(text):
-    """Optimized HLO less what moves with a source line or a module's
-    number: the metadata and the header's layout line."""
+    """Optimized HLO less what moves with a source line, a module's number
+    or a scope's name: the metadata, the header's layout line, and every
+    ``%name``, replaced by its rank of first appearance (XLA names a merged
+    instruction after the tail of its merged ``op_name``: a scope opened
+    inside a ``shard_map`` turns ``%transpose_transpose.84`` into
+    ``%transpose.353`` and moves the numbers after it; PR 55)."""
     text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
     head, _, rest = text.partition("\n")
     starts = [i for i in (rest.find("\n%"), rest.find("\nENTRY")) if i >= 0]
-    return head.split(",")[0] + rest[min(starts):]
+    seen = {}
+    return head.split(",")[0] + re.sub(
+        r"%[\w.\-]+", lambda m: seen.setdefault(m.group(0), f"%n{len(seen)}"),
+        rest[min(starts):])
 
 
 def _drivers(_args):
@@ -206,6 +214,20 @@ def _drivers(_args):
              dist(rng.normal(size=(4096, 16))),
              dist(rng.normal(size=(4096, 4))))
         line(grid_name, "svd", lambda a: el.svd(a, nb=64), G)
+        if chips > 1:
+            # the tall route at a CPU size: the rule's aspect lowered while
+            # the program is traced, as the tier-1 tests do
+            import importlib
+            qr = importlib.import_module("elemental_tpu.lapack.qr")
+            aspect, qr._TALL_ASPECT = qr._TALL_ASPECT, 4
+            try:
+                # a new function: jax keys its trace cache by the function
+                line(grid_name, "least_squares_tall",
+                     lambda a, b: el.least_squares(a, b),
+                     dist(rng.normal(size=(4096, 16))),
+                     dist(rng.normal(size=(4096, 4))))
+            finally:
+                qr._TALL_ASPECT = aspect
     return 0
 
 

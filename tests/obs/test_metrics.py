@@ -202,7 +202,7 @@ def test_tune_show_surfaces_invalid_files(cache_env, capsys):
 
 @contextlib.contextmanager
 def _fresh_engine_jits():
-    """The engine's two jitted entries rebuilt around NEW function objects,
+    """The engine's four jitted entries rebuilt around NEW function objects,
     for the time of the block: jax keys its trace cache by the function, so
     whatever is traced under the block runs the entries' Python again (the
     counters tick at trace time), whatever an earlier test left cached, and
@@ -214,6 +214,8 @@ def _fresh_engine_jits():
         return lambda *args: fn(*args)
     with pytest.MonkeyPatch.context() as patch:
         for name, static in (("_redistribute_jit", (1, 2, 3, 4, 5)),
+                             ("_redistribute_direct_jit", (1, 2, 3, 4, 5)),
+                             ("_redistribute_q8_jit", (1,)),
                              ("_panel_spread_jit", (1, 2))):
             patch.setattr(engine, name, jax.jit(
                 anew(getattr(engine, name).__wrapped__),

@@ -151,6 +151,76 @@ def test_scopes_cost_the_compiled_program_nothing(op, grid_name,
     assert stripped(with_scopes) == stripped(without)
 
 
+def renamed(text):
+    """A stripped text with every ``%name`` replaced by its rank of first
+    appearance.  XLA names an instruction it MERGES after the tail of the
+    merged ``op_name`` (two transposes under ``shard_map`` make
+    ``%transpose_transpose.4``; under ``shard_map/unpack`` the tail is
+    ``unpack/transpose;unpack/transpose`` and the name ``%transpose.13``),
+    so a scope opened inside a ``shard_map`` renames such instructions and
+    moves the numbers of those made after them.  Ops, shapes, operands and
+    order are compared."""
+    seen = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: seen.setdefault(m.group(0), f"%n{len(seen)}"),
+                  text)
+
+
+def _panel(A):
+    return el.redistribute(A, el.VC, el.STAR)
+
+
+#: exchanges by the engine's routes: the parts are opened INSIDE the
+#: engine's jitted entries, whose traces jax caches by the function
+PART_CALLS = {
+    "to_v": lambda A, B: el.redistribute(A, el.VC, el.STAR),
+    "from_v": lambda A, B: el.redistribute(_panel(A), el.MC, el.MR),
+    "to_star_v": lambda A, B: el.redistribute(A, el.STAR, el.VR),
+    "gather": lambda A, B: el.redistribute(A, el.STAR, el.STAR),
+    "chain": lambda A, B: el.redistribute(A, el.MR, el.STAR),
+    "ladder": lambda A, B: el.redistribute(_panel(B), el.MC, el.STAR),
+    "filter": lambda A, B: el.redistribute(
+        el.redistribute(B, el.STAR, el.STAR), el.MC, el.MR),
+    "bf16": lambda A, B: el.redistribute(A, el.VC, el.STAR,
+                                         comm_precision="bf16"),
+    "int8": lambda A, B: el.redistribute(A, el.STAR, el.STAR,
+                                         comm_precision="int8"),
+    "direct": lambda A, B: el.redistribute(A, el.VC, el.STAR,
+                                           path="direct"),
+    "panel_spread": lambda A, B: el.panel_spread(_panel(B)),
+    "panel_spread.int8": lambda A, B: el.panel_spread(
+        _panel(B), comm_precision="int8"),
+}
+
+
+@pytest.mark.parametrize("call", sorted(PART_CALLS))
+def test_part_scopes_cost_the_compiled_program_nothing(call, monkeypatch):
+    """The parts of an exchange name ops and do nothing else: with the
+    engine's entries traced anew under a null ``jax.named_scope`` (the
+    helper ``obs.redist_part`` looks it up at call time) the optimized HLO
+    is the same program, metadata apart and the instructions renamed in
+    order."""
+    from .test_metrics import _fresh_engine_jits
+    A, B = _operands("gemm", _grid("2x2"))
+
+    def text():
+        def bench_solve(A, B):
+            return PART_CALLS[call](A, B)
+        with _fresh_engine_jits():
+            return jax.jit(bench_solve).lower(A, B).compile().as_text()
+
+    with_parts = text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = text()
+    parts = {seg for n in op_names(with_parts) if "/el.redist." in n
+             for seg in n.split("/")} & set(obs.REDIST_PARTS)
+    assert parts, "no part under the exchange's name"
+    assert not any(seg in obs.REDIST_PARTS for n in op_names(without)
+                   for seg in n.split("/"))
+    assert renamed(stripped(with_parts)) == renamed(stripped(without))
+
+
 def test_step_has_two_digits_or_more_and_nests():
     seen = []
 
